@@ -105,8 +105,9 @@ Writes ``SERVING_r<N>.json`` at the repo root:
               failover journeys a postmortem reads first...}}
 
 Usage: python benchmarks/serving_lane.py [round_number]
-(no args: derives the round from the highest existing BENCH_r*.json,
-matching benchmarks/tpu_test_lane.py).
+(no args: one past the highest round any ``*_r<N>.json`` record holds,
+matching benchmarks/tpu_test_lane.py). The children measure the chip:
+``llama_serving.py``'s default model needs a TPU and fails without one.
 """
 
 from __future__ import annotations
@@ -155,9 +156,9 @@ def _run_json(script: str, timeout: int = 900, args: tuple = ()):
 
 def main() -> int:
     rnd = _round_number(sys.argv)
-    # platform comes from a CHILD's report — importing jax in this parent
-    # could initialize a broken TPU backend and abort the whole lane (the
-    # same reason __graft_entry__.dryrun_multichip re-execs)
+    # platform comes from a CHILD's report, and this parent never imports
+    # jax: a chip belongs to one process at a time, so a parent that had
+    # touched JAX would hold it and every child below would fail or hang
     result = {
         "round": rnd,
         "decode": _run_json("llama_decode.py"),
@@ -253,8 +254,6 @@ def main() -> int:
         "accept_rate": spec.get("accept_rate"),
         "tokens_identical": spec.get("tokens_identical"),
         "pass": spec.get("pass"),
-        "cache_cold_vs_warm_s": ((result["slo"].get("cold_start") or {})
-                                 .get("persistent_cache")),
     }
     # r14: lift the SLO headline — the alert/explained-perf/cold-start
     # bars an operator (or the next round's reviewer) checks first

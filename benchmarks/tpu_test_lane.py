@@ -1,14 +1,20 @@
 """TPU test lane: run the TPU-only pallas-kernel tests on the real chip and
-record the result as a per-round artifact next to BENCH (VERDICT r2 weak #5:
-the kernel tests are invisible to the CPU-forced default suite, so a silent
+record the result as a per-round artifact (VERDICT r2 weak #5: the kernel
+tests are invisible to the CPU-forced default suite, so a silent
 flash-kernel regression would only surface as a bench drop).
 
 Writes ``TPU_TESTS_r<N>.json`` at the repo root:
   {"passed": n, "failed": n, "skipped": n, "duration_s": s,
    "tests": [{"id": ..., "outcome": ..., "duration_s": ...}, ...]}
+and keeps the children's own reports under ``chiprun_out/tpu_test_lane/``.
+
+This parent never imports jax. A chip belongs to one process at a time:
+a parent that had touched JAX would hold it and every child below would
+fail or hang. The children run one after another, and the platform in the
+result is the one a child's jax reported.
 
 Usage: python benchmarks/tpu_test_lane.py [round_number]
-(no args: derives the round from the highest existing BENCH_r*.json).
+(no args: one past the highest round any ``*_r<N>.json`` record holds).
 """
 
 from __future__ import annotations
@@ -22,6 +28,9 @@ import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the children's report files: one fixed place, which the chip tool
+# brings back from the machine
+REPORT_DIR = os.path.join(ROOT, "chiprun_out", "tpu_test_lane")
 
 TPU_TEST_FILES = [
     "tests/test_flash_attention_tpu.py",
@@ -136,10 +145,9 @@ def _run_budget_gate(env) -> dict:
     ledger counts the REAL tiled-layout copies, so a chip-only
     regression (a new relayout XLA:TPU materialises that the CPU
     lowering fused) fails here even when tier-1 stayed green."""
-    import tempfile
-
-    out_json = os.path.join(tempfile.gettempdir(),
-                            f"_analysis_gate_{os.getpid()}.json")
+    out_json = os.path.join(REPORT_DIR, "analysis_gate.json")
+    if os.path.exists(out_json):
+        os.remove(out_json)  # never read a previous run's gate
     proc = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.analysis", "--gate",
          "--json", out_json],
@@ -148,7 +156,6 @@ def _run_budget_gate(env) -> dict:
     if os.path.exists(out_json):
         with open(out_json) as f:
             gate["programs"] = json.load(f)
-        os.remove(out_json)
     # r24: the per-program liveness peak ON CHIP — the XLA:TPU schedule
     # fuses/tiles differently from the CPU lowering, so these are the
     # measurements a "tpu"-scoped peak_bytes_max budget gets pinned
@@ -179,6 +186,7 @@ def _run_serving_telemetry(env) -> dict:
         try:
             ev = json.loads(line)
             out["telemetry"] = ev.get("telemetry")
+            out["platform"] = ev.get("platform")
             out["throughput_vs_fixed"] = ev.get("throughput_vs_fixed")
             out["ttft_p50_s"] = ev.get("ttft_p50_s")
             break
@@ -192,14 +200,17 @@ def _run_serving_telemetry(env) -> dict:
 def _round_number(argv) -> int:
     if len(argv) > 1:
         return int(argv[1])
-    rounds = [int(m.group(1)) for f in glob.glob(os.path.join(ROOT, "BENCH_r*.json"))
-              if (m := re.search(r"BENCH_r(\d+)\.json$", f))]
+    rounds = [int(m.group(1)) for f in glob.glob(os.path.join(ROOT, "*_r*.json"))
+              if (m := re.search(r"_r(\d+)\.json$", f))]
     return (max(rounds) + 1) if rounds else 1
 
 
 def main() -> int:
     rnd = _round_number(sys.argv)
-    report = os.path.join(ROOT, f"_tpu_lane_report_{os.getpid()}.xml")
+    os.makedirs(REPORT_DIR, exist_ok=True)
+    report = os.path.join(REPORT_DIR, "junit.xml")
+    if os.path.exists(report):
+        os.remove(report)  # never count a previous run's report
     env = dict(os.environ, PADDLE_TPU_TEST_LANE="1")
     t0 = time.time()
     proc = subprocess.run(
@@ -224,7 +235,6 @@ def main() -> int:
                 "id": f"{tc.get('classname', '')}::{tc.get('name', '')}",
                 "outcome": outcome,
                 "duration_s": round(float(tc.get("time", 0.0)), 3)})
-        os.remove(report)
     else:
         # junit report missing (collection error): parse the summary line
         m = re.search(r"(\d+) passed", proc.stdout)
@@ -237,7 +247,8 @@ def main() -> int:
     serving_telemetry = _run_serving_telemetry(env)
     result = {
         "round": rnd,
-        "platform": "tpu" if counts["passed"] else "unknown",
+        # what the serving child's jax reported; this parent cannot ask
+        "platform": serving_telemetry.get("platform") or "unknown",
         "passed": counts.get("passed", 0),
         "failed": counts.get("failed", 0),
         "skipped": counts.get("skipped", 0),

@@ -42,7 +42,11 @@ class Budget:
     # — the number that actually OOMs a chip). Platform-scoped like the
     # other byte ledgers: XLA:CPU and XLA:TPU schedule and fuse
     # differently, so the chip cell gets pinned from the lane's
-    # TPU_TESTS peak_hbm_bytes artifact, not from this CPU value.
+    # TPU_TESTS peak_hbm_bytes artifact, not from this CPU value. The
+    # CPU values follow the INSTALLED jaxlib's schedule too: PR 21
+    # re-measured the serving programs' peaks on jaxlib 0.9.0, whose
+    # XLA:CPU keeps 10-20% (quant: 59%) more live at the peak than the
+    # one r24 pinned them on, with the programs unchanged.
     peak_bytes_max: Optional[int] = None
     bytes_platform: str = "cpu"
     require_collectives_clean: bool = True
@@ -84,9 +88,9 @@ BUDGETS: Dict[str, Budget] = {
         relayout_bytes_max=700_000,
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0 (tiny weights)
-        # liveness peak measured 1,315,880 B (weights live whole-
+        # liveness peak measured 1,560,660 B (weights live whole-
         # program + the decode while carry) + ~5%
-        peak_bytes_max=1_380_000,
+        peak_bytes_max=1_639_000,
         notes="pure device loop; cache donated, weights live by design"),
     # One fused segment = ONE dispatch + ONE event fetch (the measured
     # r7 contract). The fetch is the allowed per-segment sync; anything
@@ -100,9 +104,9 @@ BUDGETS: Dict[str, Budget] = {
         relayout_bytes_max=1_050_000,
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0
-        # liveness peak measured 1,578,828 B (weights + donated dense
+        # liveness peak measured 1,823,805 B (weights + donated dense
         # cache counted once + segment while carry) + ~5%
-        peak_bytes_max=1_657_000,
+        peak_bytes_max=1_915_000,
         notes="r7 contract: one dispatch + one fetch per segment"),
     # The PAGED segment (r11): same one-dispatch/one-fetch contract as
     # serving_segment, with page tables as DATA (no prefix-width shape
@@ -118,9 +122,9 @@ BUDGETS: Dict[str, Budget] = {
         relayout_bytes_max=1_095_000,
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0 (pool+table donated)
-        # liveness peak measured 1,659,516 B (weights + donated pool
+        # liveness peak measured 1,893,693 B (weights + donated pool
         # counted once + segment while carry) + ~5%
-        peak_bytes_max=1_742_000,
+        peak_bytes_max=1_988_000,
         notes="r11 contract: paged pool + page tables, one fetch/segment, "
               "prefix reuse is refcount data not program shape"),
     # The CHUNKED-PREFILL paged segment (r13, ISSUE 8a): the
@@ -142,9 +146,9 @@ BUDGETS: Dict[str, Budget] = {
         relayout_bytes_max=1_015_000,
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0 (pool+table donated)
-        # liveness peak measured 1,652,516 B (pool counted once; chunk
+        # liveness peak measured 1,893,997 B (pool counted once; chunk
         # windows carry less than the full admit) + ~5%
-        peak_bytes_max=1_735_000,
+        peak_bytes_max=1_989_000,
         notes="r13 contract: chunked prefill interleaved with decode — "
               "bounded time-between-tokens at zero extra syncs/compiles"),
     # The SPECULATIVE paged segment (r15, ISSUE 10): multi-token
@@ -166,9 +170,9 @@ BUDGETS: Dict[str, Budget] = {
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0 (pool+table+hist
                                         # donated; rng rides tiny)
-        # liveness peak measured 1,664,136 B (pool counted once + the
+        # liveness peak measured 1,908,150 B (pool counted once + the
         # verify tick's [K+1]-wide windows) + ~5%
-        peak_bytes_max=1_747_000,
+        peak_bytes_max=2_004_000,
         notes="r15 contract: K-token drafts verified in one paged tick "
               "— accepted-length>1 per weight stream at zero extra "
               "syncs/compiles/shapes"),
@@ -193,9 +197,9 @@ BUDGETS: Dict[str, Budget] = {
         relayout_bytes_max=1_097_000,
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0 (pool+table donated)
-        # liveness peak measured 1,662,972 B (pool counted once + the
+        # liveness peak measured 1,898,878 B (pool counted once + the
         # [steps, slots, k] digest carries) + ~5%
-        peak_bytes_max=1_746_000,
+        peak_bytes_max=1_994_000,
         notes="r17 contract: in-program logit digests ride the single "
               "event fetch — quality evidence at zero extra syncs/"
               "compiles/shapes"),
@@ -220,9 +224,10 @@ BUDGETS: Dict[str, Budget] = {
         relayout_bytes_max=663_000,
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0 (pool+table donated)
-        # liveness peak measured 503,804 B — int8 weights + quarter-
-        # width pool put the whole envelope under a third of bf16 + ~5%
-        peak_bytes_max=528_000,
+        # liveness peak measured 800,573 B — int8 weights + quarter-
+        # width pool put the whole envelope at 42% of bf16's (a third
+        # on the older XLA:CPU, which held less dequantized) + ~5%
+        peak_bytes_max=841_000,
         notes="r21 contract: narrow weight/KV streams at zero extra "
               "syncs/compiles/shapes — the quantized roofline win is "
               "pure bytes, not a hazard trade"),
@@ -249,9 +254,9 @@ BUDGETS: Dict[str, Budget] = {
         relayout_bytes_max=1_162_000,
         pack_bytes_max=_MiB // 2,      # measured 0
         undonated_bytes_max=_MiB // 2,  # measured 0 (pool+table donated)
-        # liveness peak measured 1,660,016 B (pool counted once + the
+        # liveness peak measured 1,894,249 B (pool counted once + the
         # [sp, C] slab windows) + ~5%
-        peak_bytes_max=1_743_000,
+        peak_bytes_max=1_989_000,
         notes="r23 contract: sp-slab prefill scattering into the paged "
               "pool — long context at zero extra syncs/compiles and "
               "zero boundary relayout"),
@@ -284,10 +289,16 @@ BUDGETS: Dict[str, Budget] = {
     "fused_optimizer_update": Budget(
         flagged_syncs=0,
         warm_compiles=0,
-        # measured 0/0 on this CPU lowering (the flat-pack concats fuse
-        # into kLoop bodies as index math); headroom = one stray copy
+        # relayout measured 0 on this CPU lowering; headroom = one stray
+        # copy
         relayout_bytes_max=256 * 1024,
-        pack_bytes_max=256 * 1024,
+        # measured 1,009,920 B on jaxlib 0.9.0 (PR 21): its XLA:CPU
+        # materialises the three flat-pack concats (params, grads,
+        # velocity: f32[84160] each) that the older one fused into kLoop
+        # bodies as index math and r8 pinned at 0. This is the jnp
+        # fallback's ledger; on a TPU the group takes the Pallas kernel
+        # over the same flat buffers. + ~5%
+        pack_bytes_max=1_060_000,
         # measured 262,144 B: exactly the two (128,256) f32 gradient
         # inputs — grads are inputs, never donated; params+velocity alias
         undonated_bytes_max=300_000,

@@ -56,13 +56,9 @@ class CompileWatch:
         return self
 
     def __exit__(self, *exc):
-        from jax._src import monitoring as mon
+        import jax.monitoring as mon
 
-        try:
-            mon._unregister_event_duration_listener_by_callback(
-                self._listener)
-        except Exception:
-            pass  # listener API changed: leak one no-op listener
+        mon.unregister_event_duration_listener(self._listener)
         return False
 
     def mark(self) -> None:

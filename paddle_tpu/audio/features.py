@@ -5,9 +5,7 @@ LogMelSpectrogram / MFCC layers (reference:
 
 Windows/filterbanks/DCT bases are STATIC HOST MATH and stay numpy: they
 embed as constants in the ops' closures, which follow the input tensor's
-committed device. (On the TPU env ``signal.stft`` is host-resident —
-complex dtypes don't cross the transport — so the whole feature chain
-runs on host; a device-committed filterbank tensor would clash with it.)
+committed device.
 """
 
 from __future__ import annotations

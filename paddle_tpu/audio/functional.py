@@ -94,8 +94,7 @@ def power_to_db(spect, ref_value: float = 1.0, amin: float = 1e-10,
                 top_db: Optional[float] = 80.0):
     """10·log10(spect/ref) with an optional dynamic-range floor. Runs as
     one op whose scalar constants live in the closure, so it follows the
-    input's committed device (host-resident on the TPU env, where the
-    upstream stft chain is host math)."""
+    input's committed device."""
     import jax.numpy as jnp
 
     from ..ops.dispatch import run_op

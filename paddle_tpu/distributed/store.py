@@ -13,6 +13,7 @@ code with the GIL released.
 from __future__ import annotations
 
 import ctypes
+import glob
 import os
 import subprocess
 from typing import Optional
@@ -27,16 +28,27 @@ def _lib_path() -> str:
                         "lib", "libpaddle_tpu_native.so")
 
 
+def _needs_build(path: str, native_dir: str) -> bool:
+    """True when the library is missing or older than a ``native/*.cpp``:
+    the library is not tracked, so one left by another build of the tree
+    must not outlive a change to its sources."""
+    if not os.path.exists(path):
+        return True
+    built = os.path.getmtime(path)
+    return any(os.path.getmtime(src) > built
+               for src in glob.glob(os.path.join(native_dir, "*.cpp")))
+
+
 def load_native() -> ctypes.CDLL:
     """Load (building if necessary) the native runtime library."""
     global _LIB
     if _LIB is not None:
         return _LIB
     path = _lib_path()
-    if not os.path.exists(path):
-        native_dir = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
-            "native")
+    native_dir = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
+        "native")
+    if _needs_build(path, native_dir):
         subprocess.run(["make", "-C", native_dir], check=True,
                        capture_output=True)
     lib = ctypes.CDLL(path)

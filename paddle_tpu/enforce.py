@@ -2,7 +2,7 @@
 
 TPU-native counterpart of the reference's ``PADDLE_ENFORCE_*`` /
 ``paddle/fluid/platform/enforce.h`` (SURVEY.md §2.3 item 25): structured
-exceptions carrying an error-type taxonomy and the raising frame, so op
+exceptions carrying an error-type classification and the raising frame, so op
 implementations can validate inputs with one-liners.
 """
 

@@ -14,7 +14,7 @@ from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
-from ..core.tensor import Tensor, to_tensor
+from ..core.tensor import Tensor
 from ..enforce import InvalidArgumentError
 from ..framework.io import load as _load
 from ..framework.io import save as _save
@@ -28,8 +28,7 @@ __all__ = ["Model"]
 
 def _as_tensor_batch(data):
     """Host batch -> device Tensors. All host arrays ride ONE device_put
-    (a transfer round trip per batch element adds up fast on
-    dispatch-latency-bound transports)."""
+    (a transfer per batch element adds up fast)."""
     import jax
 
     items = list(data) if isinstance(data, (list, tuple)) else [data]
@@ -37,12 +36,8 @@ def _as_tensor_batch(data):
     for i, d in enumerate(items):
         if isinstance(d, Tensor):
             continue
-        a = np.asarray(d)
-        if np.issubdtype(a.dtype, np.complexfloating):
-            items[i] = to_tensor(a)  # complex is host-resident (see fft)
-        else:
-            host_idx.append(i)
-            host_arrs.append(a)
+        host_idx.append(i)
+        host_arrs.append(np.asarray(d))
     if host_idx:
         from ..core.place import device_for_place, expected_place
 
@@ -175,8 +170,7 @@ class Model:
 
                 # metrics providing compute_traced fuse INTO the step: only
                 # their (small) pre-computed results cross to the host per
-                # batch, not the full output logits (the transfer dominates
-                # on dispatch-latency-bound transports)
+                # batch, not the full output logits
                 def _loss_and_outs(*args):
                     outputs = net(*args[:n_in])
                     loss = self._compute_loss(outputs, list(args[n_in:]))

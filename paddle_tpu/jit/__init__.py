@@ -44,46 +44,42 @@ _TRACING = [False]
 # Persistent compilation cache (r15 — ROADMAP item 5's knob): opt in to
 # JAX's on-disk XLA executable cache so fleet replicas and process
 # restarts pay each program's compile cost once per BINARY instead of
-# once per process. The r14 SLO lane measured the gap this closes:
-# serving.cold_start_s is 0.06 s with a warm program cache vs ~2.6 s
-# paying a fresh segment compile — a restart with the persistent cache
-# populated lands near the warm number. Enabled explicitly via
-# ``paddle.jit.enable_persistent_cache(dir)`` or ambiently via the
-# ``PADDLE_TPU_PERSISTENT_CACHE=<dir>`` env var (read at import, the
-# production-rollout hook: no code change in the serving binary).
+# once per process. Whoever deploys the program places the cache:
+# ``JAX_COMPILATION_CACHE_DIR`` names the directory when it is set, and
+# otherwise it is one fixed path inside the checkout. The path is part of
+# the cache's key, so it is never a temporary, per-process or per-run
+# name, and nothing else in the tree sets a directory.
 # ---------------------------------------------------------------------------
+
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _PERSISTENT_CACHE_DIR: List[Optional[str]] = [None]
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None,
-                            min_compile_time_s: float = 0.0) -> str:
+def enable_persistent_cache(min_compile_time_s: float = 0.0) -> str:
     """Route XLA compiles through JAX's persistent on-disk cache.
 
-    ``cache_dir`` defaults to ``$PADDLE_TPU_PERSISTENT_CACHE``. Entries
-    below ``min_compile_time_s`` are skipped (0 caches everything —
-    right for serving binaries whose whole point is the 2.5 s segment
-    compile class). Returns the resolved directory. Safe to call before
-    or after backend init; calling again re-points the directory."""
-    cache_dir = cache_dir or os.environ.get("PADDLE_TPU_PERSISTENT_CACHE")
-    if not cache_dir:
-        raise InvalidArgumentError(
-            "enable_persistent_cache needs a directory (argument or "
-            "PADDLE_TPU_PERSISTENT_CACHE)")
+    The directory is ``$JAX_COMPILATION_CACHE_DIR`` when that is set and
+    ``<checkout>/.jax_cache`` otherwise. Entries below
+    ``min_compile_time_s`` are skipped (0 caches everything — right for
+    serving binaries whose whole point is the 2.5 s segment compile
+    class). Returns the directory. Safe to call before or after backend
+    init."""
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or _DEFAULT_CACHE_DIR)
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_time_s))
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        # jax latches the no-cache decision at the first compile; a
-        # reset lets a long-running process opt in mid-flight (the
-        # serving engine's build path does exactly this)
-        from jax._src import compilation_cache as _cc
+    # jax latches the no-cache decision at the first compile; a reset
+    # lets a long-running process opt in mid-flight (the serving
+    # engine's build path does exactly this)
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:
-        pass
+    _cc.reset_cache()
     _PERSISTENT_CACHE_DIR[0] = cache_dir
     _flight.record("persistent_cache", dir=cache_dir,
                    min_compile_time_s=float(min_compile_time_s))
@@ -93,10 +89,6 @@ def enable_persistent_cache(cache_dir: Optional[str] = None,
 def persistent_cache_dir() -> Optional[str]:
     """The active persistent-cache directory (None = not enabled)."""
     return _PERSISTENT_CACHE_DIR[0]
-
-
-if os.environ.get("PADDLE_TPU_PERSISTENT_CACHE"):
-    enable_persistent_cache()
 
 # ---------------------------------------------------------------------------
 # Compiled-program cache registry (analysis.recompile introspection):
@@ -629,8 +621,7 @@ class FusedTrainStep:
     TPU-native rationale: the reference pays per-op launch costs and so
     splits compute/optimizer into streams; under XLA the whole step as a
     single program lets the compiler overlap everything AND costs exactly
-    one host->device dispatch — which dominates when dispatch latency is
-    non-trivial (remote/tunneled PJRT). This is the Layer/Optimizer-API
+    one host->device dispatch. This is the Layer/Optimizer-API
     counterpart of ``models.llama.make_sharded_train_step``.
 
     Usage::

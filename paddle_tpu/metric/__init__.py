@@ -57,8 +57,7 @@ class Accuracy(Metric):
         """Traceable form of ``compute`` (paddle ops on device tensors):
         hapi fuses this INTO the compiled train step, so per batch only
         the tiny [N, maxk] correctness matrix crosses to the host instead
-        of the whole logits tensor (SURVEY §3.2's hot loop; the transfer
-        dominates on dispatch-latency-bound transports)."""
+        of the whole logits tensor (SURVEY §3.2's hot loop)."""
         from ..ops import logic, manipulation
 
         if label.ndim == pred.ndim and label.shape[-1] == 1:

@@ -67,7 +67,7 @@ def enabled() -> bool:
 
 
 # Default latency bucket ladder: ~1 ms .. 64 s in powers of two — wide
-# enough for TTFT on a tunneled dispatch path AND e2e on long batches.
+# enough for TTFT on a fast dispatch path AND e2e on long batches.
 LATENCY_BUCKETS_S = tuple(0.001 * 2 ** i for i in range(17))
 
 

@@ -35,11 +35,11 @@ gap with host arithmetic only:
   10%-slower classes both become operator-visible events instead of a
   vibe in a dashboard.
 
-Roofline constants are the repo's published v5e assumptions (SCALING.md
-§2: 819 GB/s HBM, 197 TF/s bf16) regardless of backend — matching
-``llama_decode.py``: off-chip lanes report the fraction of the CHIP
-ceiling their wall-clock achieves, and the artifact records the
-platform so the number is self-describing.
+The analytic ledger models ONE chip, the v5e, whatever backend the
+process runs on: it is arithmetic on shapes, and the gate pins it on
+the CPU. A MEASURED utilization divides by the peak of the device that
+ran the work, looked up by its ``device_kind`` in ``CHIP_PEAKS`` through
+:func:`chip_peaks`, which refuses a device it has no entry for.
 """
 
 from __future__ import annotations
@@ -52,12 +52,33 @@ import numpy as np
 from . import flight as _flight
 from . import metrics as _metrics
 
-__all__ = ["serving_ledger", "PerfMonitor", "V5E_HBM_BPS",
-           "V5E_PEAK_FLOPS", "install", "uninstall"]
+__all__ = ["serving_ledger", "PerfMonitor", "CHIP_PEAKS", "chip_peaks",
+           "V5E_HBM_BPS", "V5E_PEAK_FLOPS", "install", "uninstall"]
 
-# The repo's pinned roofline constants (SCALING.md §2, public v5e specs)
-V5E_HBM_BPS = 819e9
-V5E_PEAK_FLOPS = 197e12
+# Published peaks of one chip, keyed by the ``device_kind`` jax reports.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM). The one table every utilization in the tree divides by.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"bf16_flops_s": 197e12, "hbm_bytes_s": 819e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of the chip whose ``jax.Device.device_kind`` is given. A
+    device that is not in the table is an error, never a default: a
+    utilization against another chip's peak is a wrong number."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(CHIP_PEAKS)}); a utilization can only be "
+            f"reported for a chip in CHIP_PEAKS") from None
+
+
+# the chip the analytic ledgers model (SCALING.md §2)
+V5E_HBM_BPS = CHIP_PEAKS["TPU v5 lite"]["hbm_bytes_s"]
+V5E_PEAK_FLOPS = CHIP_PEAKS["TPU v5 lite"]["bf16_flops_s"]
 
 
 def serving_ledger(cfg, params, batch: int, avg_pos: float,

@@ -89,10 +89,14 @@ def _make_kernel(nH: int, Hkv: int, D: int, block_k: int, n_blocks: int,
 
     def kernel(pos_ref, q_ref, k_ref, v_ref, *rest):
         if quant:
-            # per-row KV scales ride along as [1, block_k] blocks under
-            # the SAME clamped index map as their K/V rows — the HBM
-            # stream carried the narrow dtype; dequant happens here, on
-            # VMEM-resident tiles (r21 quantized serving)
+            # per-row KV scales ride along as [1, block_k] lane vectors
+            # under the SAME clamped index map as their K/V rows — the
+            # HBM stream carried the narrow dtype; dequant happens here,
+            # on VMEM-resident tiles (r21 quantized serving). A row's
+            # scale is a COLUMN of the score / probability tiles, so it
+            # multiplies those ([nH, block_k] * [1, block_k], a plain
+            # sublane broadcast) instead of the [block_k, D] K/V tiles,
+            # which would need the scales moved from lanes to sublanes
             sk_ref, sv_ref, o_ref, acc_ref, m_ref, l_ref = rest
         else:
             o_ref, acc_ref, m_ref, l_ref = rest
@@ -116,12 +120,14 @@ def _make_kernel(nH: int, Hkv: int, D: int, block_k: int, n_blocks: int,
                 kh = k_ref[0, :, h * D:(h + 1) * D]       # [block_k, D]
                 qh = q[h * rep:(h + 1) * rep]             # [rep, D]
                 if quant:
-                    kh = kh.astype(jnp.float32) * sk_ref[0][:, None]
+                    kh = kh.astype(jnp.float32)
                     qh = qh.astype(jnp.float32)
                 parts.append(jax.lax.dot_general(
                     qh, kh, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32))
             s = jnp.concatenate(parts, axis=0)            # [nH, block_k]
+            if quant:
+                s = s * sk_ref[0, 0]
             kpos = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (nH, block_k), 1)
             s = jnp.where(kpos <= pos, s, -jnp.inf)       # tail-block mask
@@ -130,12 +136,12 @@ def _make_kernel(nH: int, Hkv: int, D: int, block_k: int, n_blocks: int,
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)  # block 0: exp(-inf - m) = 0
             l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            pb = p if quant else p.astype(v_ref.dtype)
+            pb = p * sv_ref[0, 0] if quant else p.astype(v_ref.dtype)
             pv_parts = []
             for h in range(Hkv):
                 vh = v_ref[0, :, h * D:(h + 1) * D]       # [block_k, D]
                 if quant:
-                    vh = vh.astype(jnp.float32) * sv_ref[0][:, None]
+                    vh = vh.astype(jnp.float32)
                 ph = pb[h * rep:(h + 1) * rep]            # [rep, block_k]
                 pv_parts.append(jax.lax.dot_general(
                     ph, vh, (((1,), (0,)), ((), ())),
@@ -165,10 +171,12 @@ def ragged_decode_attention(q, kc, vc, pos, scale=None, block_k: int = 0,
     untileable shapes — callers gate with ``decode_attention_active``.
 
     ``k_scale``/``v_scale`` ([B, max_len] fp32, optional): a QUANTIZED
-    cache's per-row scales (r21). Their [1, block_k] blocks ride the
-    same clamped index maps as the K/V blocks, so the per-slot
+    cache's per-row scales (r21). They are viewed as
+    ``[B, n_blocks, 1, block_k]`` so that a block's last two dims equal
+    the array's (Mosaic tiles nothing narrower than 8 sublanes), and
+    ride the same clamped index maps as the K/V blocks: the per-slot
     bytes-read property holds for them too, and the kernel dequantizes
-    narrow K/V tiles in VMEM — HBM carried int8/fp8.
+    in VMEM — HBM carried int8/fp8.
     """
     B, nH, D = q.shape
     Smax, Hkv = kc.shape[1], kc.shape[2]
@@ -193,7 +201,7 @@ def ragged_decode_attention(q, kc, vc, pos, scale=None, block_k: int = 0,
         return (b, jnp.minimum(j, pos_ref[b] // block_k), 0)
 
     def sc_map(b, j, pos_ref):
-        return (b, jnp.minimum(j, pos_ref[b] // block_k))
+        return (b, jnp.minimum(j, pos_ref[b] // block_k), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, nH, D), lambda b, j, pos_ref: (b, 0, 0)),
@@ -202,10 +210,10 @@ def ragged_decode_attention(q, kc, vc, pos, scale=None, block_k: int = 0,
     ]
     operands = [qs, kf, vf]
     if quant:
-        in_specs += [pl.BlockSpec((1, block_k), sc_map),
-                     pl.BlockSpec((1, block_k), sc_map)]
-        operands += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+        in_specs += [pl.BlockSpec((1, 1, 1, block_k), sc_map)] * 2
+        operands += [
+            jnp.asarray(sc, jnp.float32).reshape(B, n_blocks, 1, block_k)
+            for sc in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, n_blocks),

@@ -57,8 +57,9 @@ __all__ = ["FlatPlan", "fused_update_active", "fused_update_signature",
 # tests set this True to force the kernels (pallas interpret mode) on CPU
 FORCE_INTERPRET = False
 
-_HYPER_LEN = 8  # SMEM scalar vector: [lr, step, skip, b1/mu, b2/nesterov,
-#                 eps, wd, decoupled]
+_HYPER_LEN = 8  # SMEM scalar vector: [lr, 1-b1^step, skip, b1/mu, b2, eps,
+#                 wd, 1-b2^step] (the bias corrections are taken outside
+#                 the kernel: Mosaic lowers no scalar pow)
 
 _KINDS = ("sgd", "momentum", "adam", "lamb")
 
@@ -225,8 +226,9 @@ def _adam_kernel(has_master: bool, decoupled: bool):
             (w_ref, op_ref, om_ref, ov_ref, ow_ref) = refs
         else:
             (op_ref, om_ref, ov_ref) = refs
-        lr, stepf, skip = h_ref[0], h_ref[1], h_ref[2]
-        b1, b2, eps, wd = h_ref[3], h_ref[4], h_ref[5], h_ref[6]
+        lr, bc1, skip = h_ref[0], h_ref[1], h_ref[2]
+        b1, b2, eps, wd, bc2 = (h_ref[3], h_ref[4], h_ref[5], h_ref[6],
+                                h_ref[7])
         p, m, v = p_ref[...], m_ref[...], v_ref[...]
         dt = m.dtype
         gf = g_ref[...].astype(dt)
@@ -234,8 +236,8 @@ def _adam_kernel(has_master: bool, decoupled: bool):
         v_new = b2.astype(dt) * v + (1 - b2).astype(dt) * gf * gf
         # bias correction in fp32 (matches Adam._update_one: the division
         # by a strong-typed fp32 scalar promotes)
-        mhat = m_new.astype(jnp.float32) / (1 - b1 ** stepf)
-        vhat = v_new.astype(jnp.float32) / (1 - b2 ** stepf)
+        mhat = m_new.astype(jnp.float32) / bc1
+        vhat = v_new.astype(jnp.float32) / bc2
         upd = lr * mhat / (jnp.sqrt(vhat) + eps)
         if has_master:
             w = w_ref[...]
@@ -264,16 +266,17 @@ def _lamb_kernel(has_master: bool):
             (w_ref, om_ref, ov_ref, or_ref) = refs
         else:
             (om_ref, ov_ref, or_ref) = refs
-        stepf, skip = h_ref[1], h_ref[2]
-        b1, b2, eps, wd = h_ref[3], h_ref[4], h_ref[5], h_ref[6]
+        bc1, skip = h_ref[1], h_ref[2]
+        b1, b2, eps, wd, bc2 = (h_ref[3], h_ref[4], h_ref[5], h_ref[6],
+                                h_ref[7])
         m, v = m_ref[...], v_ref[...]
         pf = (w_ref[...] if has_master
               else p_ref[...].astype(jnp.float32))
         gf = g_ref[...].astype(jnp.float32)
         m_new = b1 * m + (1 - b1) * gf
         v_new = b2 * v + (1 - b2) * gf * gf
-        mhat = m_new / (1 - b1 ** stepf)
-        vhat = v_new / (1 - b2 ** stepf)
+        mhat = m_new / bc1
+        vhat = v_new / bc2
         or_ref[...] = mhat / (jnp.sqrt(vhat) + eps) + wd * pf
         om_ref[...] = _gate(skip, m, m_new)
         ov_ref[...] = _gate(skip, v, v_new)
@@ -331,8 +334,13 @@ def apply_flat_update(kind: str, plan: FlatPlan,
              else jnp.asarray(skip, jnp.float32))
     hvec = jnp.zeros((_HYPER_LEN,), jnp.float32)
     hvec = hvec.at[0].set(jnp.asarray(lr, jnp.float32))
-    hvec = hvec.at[1].set(jnp.asarray(step, jnp.float32))
     hvec = hvec.at[2].set(skipf)
+
+    def with_betas(hvec):
+        stepf = jnp.asarray(step, jnp.float32)
+        b1, b2 = np.float32(hyper["beta1"]), np.float32(hyper["beta2"])
+        return (hvec.at[3].set(b1).at[4].set(b2)
+                .at[1].set(1 - b1 ** stepf).at[7].set(1 - b2 ** stepf))
 
     pbuf = plan.pack(pvals)
     gbuf = plan.pack(gvals, dtype=pvals[0].dtype)
@@ -350,8 +358,7 @@ def apply_flat_update(kind: str, plan: FlatPlan,
                    {1: 0, 3: 1})
         new_p_buf, new_sbufs = out[0], {"velocity": out[1]}
     elif kind == "adam":
-        hvec = hvec.at[3].set(np.float32(hyper["beta1"]))
-        hvec = hvec.at[4].set(np.float32(hyper["beta2"]))
+        hvec = with_betas(hvec)
         hvec = hvec.at[5].set(np.float32(hyper["epsilon"]))
         hvec = hvec.at[6].set(np.float32(hyper.get("decay", 0.0)))
         decoupled = bool(hyper.get("decoupled")) and \
@@ -371,8 +378,7 @@ def apply_flat_update(kind: str, plan: FlatPlan,
         if has_master:
             new_sbufs["master"] = out[3]
     elif kind == "lamb":
-        hvec = hvec.at[3].set(np.float32(hyper["beta1"]))
-        hvec = hvec.at[4].set(np.float32(hyper["beta2"]))
+        hvec = with_betas(hvec)
         hvec = hvec.at[5].set(np.float32(hyper["epsilon"]))
         hvec = hvec.at[6].set(np.float32(hyper.get("decay", 0.0)))
         bufs = [pbuf, gbuf, sbufs["moment1"], sbufs["moment2"]]

@@ -27,13 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _axis_size(axis_name):
-    # jax.lax.axis_size is newer than this container's jax; psum(1) is
-    # the portable spelling (resolved at trace time, zero runtime cost)
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
 __all__ = ["ring_attention", "RingFlashAttention",
            "context_parallel_attention", "ulysses_attention",
            "ulysses_parallel_attention", "sp_slab_ring_attention",
@@ -86,7 +79,7 @@ def ring_attention(q, k, v, axis_name: str = "sep", is_causal: bool = False,
     """Ring attention over the ``axis_name`` mesh axis (call inside
     shard_map with q/k/v seq-sharded). Exact — numerically equal to full
     attention over the gathered sequence."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -248,7 +241,7 @@ def sp_slab_ring_attention(q, k, v, q_offset, axis_name: str = "sp",
     rows are chunks of one prompt, not contiguous shards of a padded
     sequence. Exact: matches ``_slab_dense_attention`` bit-for-bit in
     fp32 accumulation terms (same online-softmax algebra)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, c, h, d = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     my_off = q_offset[0]
@@ -341,7 +334,7 @@ def ulysses_attention(q, k, v, axis_name: str = "sep",
     """
     from .flash_attention import _xla_attention
 
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h = q.shape[2]
     if h % n:
         raise ValueError(f"ulysses_attention: head count {h} must be "

@@ -137,8 +137,10 @@ def _rope_qk_kernel(D, nq, nk, theta):
 
     def kernel(pos_ref, q_ref, k_ref, oq_ref, ok_ref):
         B = q_ref.shape[0]
-        # angles in fp32 like llama._rope_at: pos * theta^(-2i/D)
-        i2 = jax.lax.broadcasted_iota(jnp.float32, (B, half), 1) * 2.0
+        # angles in fp32 like llama._rope_at: pos * theta^(-2i/D). Mosaic
+        # only makes integer iotas, so the index is cast after the fact
+        i2 = jax.lax.broadcasted_iota(jnp.int32, (B, half), 1).astype(
+            jnp.float32) * 2.0
         freqs = jnp.power(jnp.float32(theta), -i2 / D)
         ang = pos_ref[...].astype(jnp.float32) * freqs  # [B, half]
         cos, sin = jnp.cos(ang), jnp.sin(ang)

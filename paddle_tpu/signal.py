@@ -2,18 +2,15 @@
 
 Reference counterpart: ``python/paddle/signal.py`` (stft/istft over the fft
 kernels; SURVEY.md §2.1 PHI kernel corpus). Framing/overlap-add run as XLA
-gather/scatter; the FFTs follow ``paddle_tpu.fft``'s host-resident complex
-policy (see fft._host).
+gather/scatter; the FFTs lower to ``jnp.fft`` like ``paddle_tpu.fft``.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .core.tensor import Tensor, to_tensor
-from . import fft as _fft
 from .ops.dispatch import run_op
 
 __all__ = ["stft", "istft"]
@@ -54,7 +51,7 @@ def stft(x, n_fft, hop_length=None, win_length=None, window=None,
             spec = spec / jnp.sqrt(jnp.asarray(n_fft, spec.real.dtype))
         return spec
 
-    return _fft._run_host_op("stft", _fft._host(lambda a, **kw: f(a)), x)
+    return run_op("stft", f, x)
 
 
 def istft(x, n_fft, hop_length=None, win_length=None, window=None,
@@ -91,4 +88,4 @@ def istft(x, n_fft, hop_length=None, win_length=None, window=None,
             out = out[..., :length]
         return out
 
-    return _fft._run_host_op("istft", _fft._host(lambda a, **kw: f(a)), x)
+    return run_op("istft", f, x)

@@ -1,0 +1,176 @@
+"""The Pallas kernels of the train and serve paths, compiled for the chip.
+
+Tier-1 runs the kernels in the Pallas interpreter, which accepts what
+Mosaic refuses: a float iota, a block whose second-minor dim is 1, a scalar
+``pow`` (the three refusals PR 21 found). The TPU's compiler is installed
+here and compiles for a chip that is DESCRIBED, not attached, so each case
+below lowers one kernel at ``LlamaConfig.bert_base_equiv`` widths (H=768,
+12 heads x 64, V=32000) for one chip of a ``v5e:2x2`` and asserts a
+``tpu_custom_call`` in the compiled text. Nothing runs: these say a kernel
+compiles, never that it is right or fast.
+
+The topology is described inside a fixture (loading the TPU's library at
+import would break collection under several workers) and everything is
+compiled in the test's own process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+H, NH, D, V = 768, 12, 64, 32000       # bert_base_equiv widths
+SLOTS, MAX_LEN, PAGE = 8, 512, 16      # the serving engine's defaults
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    with pytest.MonkeyPatch.context() as mp:
+        # read when the TPU's library loads: keeps its logs out of /tmp
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def shaped(topo):
+    """``shaped(shape, dtype)``: an abstract array on one described chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A program compiled for a described chip is written to the persistent
+    cache but cannot be read back without a chip: keep the cache out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _flash(batch, seq):
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def build(S):
+        def loss(q, k, v):
+            return fa._flash_custom_vjp(q, k, v, True).astype(F32).sum()
+
+        x = S((batch, seq, NH, D), BF16)
+        return jax.grad(loss, argnums=(0, 1, 2)), (x, x, x)
+    return build
+
+
+def _head_dx(S):
+    from paddle_tpu.ops.pallas.head_dx import head_dx_softmax
+
+    M = 44 * 512
+    return head_dx_softmax, (S((M, V), BF16), S((M,), F32), S((M,), F32),
+                             S((V, H), BF16))
+
+
+def _ragged_decode(kv_dtype):
+    from paddle_tpu.ops.pallas.decode_attention import ragged_decode_attention
+
+    def build(S):
+        kv = S((SLOTS, MAX_LEN, NH, D), kv_dtype)
+        args = [S((SLOTS, NH, D), BF16), kv, kv, S((SLOTS,), I32)]
+        if kv_dtype == I8:
+            scale = S((SLOTS, MAX_LEN), F32)
+            return (lambda q, k, v, pos, ks, vs: ragged_decode_attention(
+                q, k, v, pos, k_scale=ks, v_scale=vs)), args + [scale, scale]
+        return ragged_decode_attention, args
+    return build
+
+
+def _paged(tq):
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+
+    def build(S):
+        max_pages = MAX_LEN // PAGE
+        pool = S((SLOTS * max_pages + 1, PAGE, NH, D), BF16)
+        return ragged_paged_attention, (
+            S((SLOTS, tq, NH, D), BF16), pool, pool,
+            S((SLOTS, max_pages), I32), S((SLOTS,), I32), S((SLOTS,), I32))
+    return build
+
+
+def _tick(which):
+    from paddle_tpu.ops.pallas import tick_fusion as tf
+
+    def build(S):
+        x, w = S((SLOTS, H), BF16), S((H,), F32)
+        if which == "rms":
+            return (lambda x, w: tf.fused_rms_norm(x, w, 1e-6)), (x, w)
+        if which == "add_rms":
+            return (lambda x, y, w: tf.fused_add_rms_norm(x, y, w, 1e-6)), \
+                (x, x, w)
+        return (lambda q, k, pos: tf.fused_rope_qk(q, k, pos, D, 10000.0)), \
+            (x, x, S((SLOTS,), I32))
+    return build
+
+
+def _quant_matmul(S):
+    from paddle_tpu.ops.pallas.tick_fusion import quant_matmul
+
+    return quant_matmul, (S((SLOTS, H), BF16), S((H, V), I8), S((V,), F32))
+
+
+def _multi_tensor(kind):
+    from paddle_tpu.ops.pallas import multi_tensor_update as mtu
+
+    shapes = [(H, H), (H,), (4 * H, H), (V, H), (7,)]   # a mixed group
+    state = {"momentum": ("velocity",), "adam": ("moment1", "moment2")}[kind]
+    hyper = {"momentum": {"momentum": 0.9},
+             "adam": {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8,
+                      "decay": 0.01, "decoupled": True}}[kind]
+
+    def build(S):
+        plan = mtu.FlatPlan(shapes)
+        group = [S(s, F32) for s in shapes]
+
+        def update(pvals, gvals, *svals):
+            return mtu.apply_flat_update(
+                kind, plan, pvals, gvals,
+                [dict(zip(state, s)) for s in zip(*svals)], hyper, 1e-3, 1)
+
+        return update, (group, group) + (group,) * len(state)
+    return build
+
+
+CASES = {
+    "flash_fwd_bwd_packed_b44_s512": _flash(44, 512),
+    "flash_fwd_bwd_blocked_b4_s4096": _flash(4, 4096),
+    "head_dx_softmax_m22528": _head_dx,
+    "ragged_decode_bf16": _ragged_decode(BF16),
+    "ragged_decode_int8_kv_scales": _ragged_decode(I8),
+    "ragged_paged_tq1": _paged(1),
+    "ragged_paged_tq16": _paged(16),
+    "ragged_paged_tq64": _paged(64),
+    "fused_rms_norm": _tick("rms"),
+    "fused_add_rms_norm": _tick("add_rms"),
+    "fused_rope_qk": _tick("rope"),
+    "quant_matmul_int8_768x32000": _quant_matmul,
+    "multi_tensor_momentum": _multi_tensor("momentum"),
+    "multi_tensor_adam": _multi_tensor("adam"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, shaped, no_persistent_cache):
+    fn, args = CASES[name](shaped)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel in the " \
+                                      f"compiled program"

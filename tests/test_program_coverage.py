@@ -357,7 +357,7 @@ class TestEscapesFlagged:
 
 class TestPersistentCacheInterplay:
     def test_warm_restart_skips_recompiles_enumeration_unchanged(
-            self, tiny, tmp_path):
+            self, tiny, tmp_path, monkeypatch):
         """r15 interplay: aot_warmup through a populated persistent
         cache deserialises instead of recompiling — a restarted replica
         pays a fraction of the cold warmup's backend compiles — and the
@@ -370,9 +370,9 @@ class TestPersistentCacheInterplay:
 
         cfg, params = tiny
         saved = dict(S._SHARED_PROGS)
-        cc_dir = str(tmp_path / "cc")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
         try:
-            paddle.jit.enable_persistent_cache(cc_dir)
+            paddle.jit.enable_persistent_cache()
             S._SHARED_PROGS.clear()
 
             def build():
@@ -391,7 +391,7 @@ class TestPersistentCacheInterplay:
             S._SHARED_PROGS.clear()       # simulated process restart
             e2 = build()
             assert e2.program_space(env) == space1
-            import jax._src.monitoring as mon
+            import jax.monitoring as mon
 
             hits = [0]
 
@@ -404,7 +404,7 @@ class TestPersistentCacheInterplay:
                 with recompile.CompileWatch() as warm:
                     e2.aot_warmup(env)
             finally:
-                mon._unregister_event_listener_by_callback(_on_event)
+                mon.unregister_event_listener(_on_event)
             # the segment program (the 2.5 s class) comes off disk: the
             # warm restart hits the persistent cache instead of paying
             # XLA again (at most stray eager singletons still compile)

@@ -288,11 +288,14 @@ class TestAcceptanceAwareSLO:
 
 
 class TestPersistentCompileCache:
-    def test_knob_writes_cache_entries(self, tmp_path, _seeded):
+    def test_knob_writes_cache_entries(self, tmp_path, monkeypatch, _seeded):
+        """The directory comes from JAX_COMPILATION_CACHE_DIR."""
         import paddle_tpu as paddle
 
-        d = paddle.jit.enable_persistent_cache(str(tmp_path / "cc"))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+        d = paddle.jit.enable_persistent_cache()
         try:
+            assert d == str(tmp_path / "cc")
             assert paddle.jit.persistent_cache_dir() == d
             f = jax.jit(lambda x: x * 3 + 1)
             f(jnp.ones((37,)))        # odd shape: certainly uncached
@@ -302,9 +305,21 @@ class TestPersistentCompileCache:
             jax.config.update("jax_compilation_cache_dir", None)
             paddle.jit._PERSISTENT_CACHE_DIR[0] = None
 
-    def test_knob_requires_dir(self, monkeypatch):
+    def test_knob_without_env_uses_the_checkout_dir(self, monkeypatch):
+        """No directory named from outside: one fixed path inside the
+        checkout, never a temporary or per-process name (the path is
+        part of the cache's key)."""
+        import os
+
         import paddle_tpu as paddle
 
-        monkeypatch.delenv("PADDLE_TPU_PERSISTENT_CACHE", raising=False)
-        with pytest.raises(Exception, match="directory"):
-            paddle.jit.enable_persistent_cache()
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            d = paddle.jit.enable_persistent_cache()
+            root = os.path.dirname(os.path.dirname(
+                os.path.abspath(paddle.__file__)))
+            assert d == os.path.join(root, ".jax_cache")
+            assert paddle.jit.enable_persistent_cache() == d  # stable
+        finally:
+            jax.config.update("jax_compilation_cache_dir", None)
+            paddle.jit._PERSISTENT_CACHE_DIR[0] = None
