@@ -189,6 +189,15 @@ class OnlineReport:
     # a spill tier attached — pages staged/spilled/restored + the byte
     # counters the tier-transfer budget audits (None otherwise)
     tiers: Optional[dict] = None
+    # PR 25: what a segment costs the host, by phase — the always-on
+    # reduction of the ``serving.sched.ingest`` / ``serving.segment.*``
+    # spans: phase -> {"seconds", "count"} over the serve (``telemetry``
+    # is entered twice a segment: the engine's counters, then this
+    # loop's stamps) — and the means over requests of the four parts a
+    # first token's wait splits into (``_ttft_parts``; they sum to the
+    # mean first-token time)
+    segment_phases: Optional[Dict[str, dict]] = None
+    ttft_parts_mean_s: Optional[Dict[str, float]] = None
     per_request: List[dict] = field(default_factory=list)
 
     def as_dict(self, with_requests: bool = False) -> dict:
@@ -196,6 +205,33 @@ class OnlineReport:
         if with_requests:
             d["per_request"] = self.per_request
         return d
+
+
+TTFT_PARTS = ("ingest_s", "slot_wait_s", "admit_wait_s", "delivery_wait_s")
+
+
+def _ttft_parts(r: Request) -> dict:
+    """A first token's wait in its four parts, from stamps the serve loop
+    takes anyway (no clock read of their own): due -> the loop top that
+    ingested the request (``ingest_s``: the loop was inside a running
+    segment, or the bounded queue was full) -> the dispatch of the segment
+    that admitted it (``slot_wait_s``: seen, but no slot, page or pick) ->
+    the end of step ``admit_step`` of that segment's ``seg_steps``
+    (``admit_wait_s``: earlier admissions and steps, then its own prefill;
+    the token now exists on the device) -> the return of the segment's
+    fetch (``delivery_wait_s``: the token waits to be seen). The segment's
+    dispatch -> fetch span is split BY STEP INDEX, which assumes equal
+    steps inside one segment (an admission is one step of the loop like a
+    decode tick; on the chip they differ by a few percent, PERF.md §2).
+    The four sum to the request's first-token time."""
+    t_dispatch, step, steps = r.first_token_seg
+    in_seg = r.first_token_time - t_dispatch
+    admit_wait = in_seg * (step + 1) / max(steps, 1)
+    return {"ingest_s": round(r.ingest_time - r.arrival_time, 6),
+            "slot_wait_s": round(t_dispatch - r.ingest_time, 6),
+            "admit_step": step, "seg_steps": steps,
+            "admit_wait_s": round(admit_wait, 6),
+            "delivery_wait_s": round(in_seg - admit_wait, 6)}
 
 
 # percentiles: the ONE shared nearest-rank rule (r10 dedup — this module's
@@ -283,6 +319,7 @@ class OnlineScheduler:
             r = self.engine._queue[-1]
             assert r.rid == rid
             r.arrival_time = t0 + a.t   # client-side timestamp
+            r.ingest_time = t0 + now    # the loop top that saw it
             self._reqs[rid] = r
             self._note_arrival(r, a)
             _journal.record("arrival", rid=rid, at=a.t,
@@ -345,18 +382,42 @@ class OnlineScheduler:
         m_ttft = _metrics.histogram("serving.ttft_s")
         m_e2e = _metrics.histogram("serving.e2e_s")
         m_qwait = _metrics.histogram("serving.queue_wait_s")
+        hists = (m_ttft, m_e2e, m_qwait)
+        # per-phase host time of this serve's segments (always on): the
+        # engine's phase spans and this loop's tally into one dict
+        phases = eng.segment_phases = {}
         t0 = _journal.now()
         self._serve_t0 = t0
         while pending or eng._queue or eng.free_slot_count() < eng.slots:
             now = _journal.now() - t0
-            self._ingest(pending, now, t0)
-            m_queue.set(len(eng._queue))
-            # r13 SLO hook: the subclass sheds unmeetable-deadline
-            # requests and preempts for blocked higher classes here —
-            # host bookkeeping between segments, zero device contact
-            self._pre_segment(now, t0)
-            idle = (not eng._queue
-                    and eng.free_slot_count() == eng.slots)
+            seg = eng.seg_index      # the segment this loop turn precedes
+            cap = self.capacity_monitor
+            with _hooks.span("serving.sched.ingest", "serving",
+                             tally=phases, seg=seg):
+                self._ingest(pending, now, t0)
+                m_queue.set(len(eng._queue))
+                # r13 SLO hook: the subclass sheds unmeetable-deadline
+                # requests and preempts for blocked higher classes here —
+                # host bookkeeping between segments, zero device contact
+                self._pre_segment(now, t0)
+                idle = (not eng._queue
+                        and eng.free_slot_count() == eng.slots)
+                if not idle and cap is not None and eng.paged:
+                    # r18: evaluate time-to-exhaustion BEFORE the dispatch
+                    # that could hit pages-backpressure — the alert must
+                    # lead the valve (ISSUE 13 acceptance bar). r19: the
+                    # availability term gains the tier dimension — host-
+                    # tier pages ride the same evaluation as a separate
+                    # (reclaimable-at-restore-cost) pool.
+                    pc = self.prefix_cache
+                    has_rec = (pc is not None
+                               and hasattr(pc, "reclaimable_pages"))
+                    cap.begin_segment(
+                        eng.pager.pages_free,
+                        pc.reclaimable_pages() if has_rec else 0,
+                        host_pages=(pc.host_pages if has_rec
+                                    and getattr(pc, "host_tier", None)
+                                    is not None else None))
             if idle:
                 # nothing admitted and nothing decoding: sleep to the
                 # next arrival instead of spinning
@@ -365,100 +426,20 @@ class OnlineScheduler:
                     if gap > 0:
                         _journal.sleep(min(gap, 0.05))
                 continue
-            cap = self.capacity_monitor
-            if cap is not None and eng.paged:
-                # r18: evaluate time-to-exhaustion BEFORE the dispatch
-                # that could hit pages-backpressure — the alert must
-                # lead the valve (ISSUE 13 acceptance bar). r19: the
-                # availability term gains the tier dimension — host-
-                # tier pages ride the same evaluation as a separate
-                # (reclaimable-at-restore-cost) pool.
-                pc = self.prefix_cache
-                has_rec = (pc is not None
-                           and hasattr(pc, "reclaimable_pages"))
-                cap.begin_segment(
-                    eng.pager.pages_free,
-                    pc.reclaimable_pages() if has_rec else 0,
-                    host_pages=(pc.host_pages if has_rec
-                                and getattr(pc, "host_tier", None)
-                                is not None else None))
+            # the segment's span carries its index and its own start on
+            # perf_counter's clock: the measured offset by which spans
+            # stamped after the fact are placed on a live trace's clock
             t_seg = _hooks.now_ns()
-            t_seg_pc = _journal.now()
-            ev = eng.run_segment(self.seg_steps,
-                                 prefix_cache=self.prefix_cache)
-            t_sync = _journal.now()
-            _hooks.emit("serving.segment", t_seg, _hooks.now_ns(),
-                        kind="serving")
-            segments += 1
-            mon = self.slo_monitor
-            for rid in ev["admitted"]:
-                r = self._reqs[rid]
-                _journal.record("admit", rid=rid,
-                                prefix_hit_len=r.prefix_hit_len,
-                                priority=r.priority,
-                                resumed=bool(r.preemptions or r.requeues),
-                                tokens_done=len(r.tokens))
-            for rid in ev["first_tokens"]:
-                r = self._reqs[rid]
-                r.first_token_time = t_sync
-                m_ttft.observe(t_sync - r.arrival_time)
-                m_qwait.observe(r.admit_time - r.arrival_time)
-                if mon is not None:
-                    mon.note_ttft(r.priority, t_sync - r.arrival_time)
-                self._on_first_token(r, t_sync)
-                _journal.record("first_token", rid=rid,
-                                ttft_s=t_sync - r.arrival_time)
-            for rid in ev["finished"]:
-                # the engine stamps finish during replay (marginally
-                # earlier); the sync is when the client can SEE the
-                # tokens, and keeps finish >= first_token by definition
-                r = self._reqs[rid]
-                r.finish_time = t_sync
-                self._finished_count += 1
-                m_e2e.observe(t_sync - r.arrival_time)
-                if mon is not None:
-                    mon.note_e2e(r.priority, t_sync - r.arrival_time)
-                self._on_finish(r, t_sync)
-                _tracing.emit_request_trace(
-                    rid, r.arrival_time, r.admit_time, r.first_token_time,
-                    r.finish_time, prefix_hit_len=r.prefix_hit_len)
-                # the token-identity ground truth: the FULL emitted
-                # stream rides the finish record (host mirrors of the
-                # segment fetch — nothing extra was synced for this)
-                _journal.record("finish", rid=rid, tokens=r.tokens,
-                                n_tokens=len(r.tokens),
-                                e2e_s=t_sync - r.arrival_time,
-                                priority=r.priority,
-                                preemptions=r.preemptions,
-                                requeues=r.requeues,
-                                spec_proposed=r.spec_proposed,
-                                spec_accepted=r.spec_accepted)
-            # r14 monitor hooks: advance the SLO burn windows and feed
-            # the explained-perf intervals — host ints from the event
-            # log just fetched, plus this segment's dispatch→fetch span
-            if mon is not None:
-                # r17 accept-drift feed (ISSUE 12 satellite): this
-                # segment's speculative acceptance rate, from the spec
-                # stats the replay already recovered
-                sp = ev.get("spec")
-                if sp and sp.get("proposed"):
-                    mon.note_accept_rate(sp["accepted"] / sp["proposed"])
-                mon.end_segment()
-            if self.perf_monitor is not None:
-                self.perf_monitor.note_segment(
-                    ev["steps"], ev.get("tokens", 0),
-                    elapsed_s=t_sync - t_seg_pc)
-            if cap is not None and eng.paged:
-                cap.note_admission(
-                    sum(self._reqs[rid].pages_fresh
-                        for rid in ev["admitted"]),
-                    admitted=len(ev["admitted"]))
-                cap.close_segment()
-            # r15: per-tick wall EWMA (host arithmetic on already-taken
-            # stamps) — the acceptance-aware service estimates' clock
-            dt = (t_sync - t_seg_pc) / max(ev["steps"], 1)
-            self._per_tick_s = (dt if not self._per_tick_s
-                                else 0.5 * self._per_tick_s + 0.5 * dt)
+            with _hooks.span("serving.segment", "serving", seg=seg,
+                             pc_ns=t_seg):
+                t_seg_pc = _journal.now()
+                ev = eng.run_segment(self.seg_steps,
+                                     prefix_cache=self.prefix_cache)
+                t_sync = _journal.now()
+                segments += 1
+                with _hooks.span("serving.segment.telemetry", "serving",
+                                 tally=phases, seg=seg):
+                    self._stamp_segment(ev, t_seg_pc, t_sync, hists)
         makespan = _journal.now() - t0
 
         reqs = list(self._reqs.values())
@@ -471,6 +452,7 @@ class OnlineScheduler:
         qwaits = [r.admit_time - r.arrival_time for r in reqs]
         occupancy = (total_tokens / (eng.last_run_ticks * eng.slots)
                      if eng.last_run_ticks else 0.0)
+        parts = [_ttft_parts(r) for r in reqs]
         _metrics.gauge("serving.slot_occupancy").set(occupancy)
         _metrics.gauge("serving.throughput_tok_s").set(
             total_tokens / makespan if makespan else 0.0)
@@ -511,6 +493,11 @@ class OnlineScheduler:
                    if self.prefix_cache is not None
                    and getattr(self.prefix_cache, "host_tier", None)
                    is not None else None),
+            segment_phases={
+                name.rsplit(".", 1)[1]: {"seconds": ns / 1e9, "count": c}
+                for name, (ns, c) in phases.items()},
+            ttft_parts_mean_s=({k: sum(p[k] for p in parts) / len(parts)
+                                for k in TTFT_PARTS} if parts else None),
             **self._report_extras(reqs),
             per_request=[{
                 "rid": r.rid,
@@ -529,8 +516,91 @@ class OnlineScheduler:
                 # r19: the request's tier-transfer bill (0 untiered)
                 "tier_pages": r.tier_pages,
                 "tier_bytes": r.tier_bytes,
-            } for r in reqs],
+                **p,
+            } for r, p in zip(reqs, parts)],
         )
+
+    def _stamp_segment(self, ev: dict, t_seg_pc: float, t_sync: float,
+                       hists) -> None:
+        """The scheduler's own work after a segment's fetch returned: the
+        per-request stamps, journal records, histograms and monitor hooks
+        (the second half of ``serving.segment.telemetry``; the engine's
+        counters are the first)."""
+        eng = self.engine
+        cap = self.capacity_monitor
+        m_ttft, m_e2e, m_qwait = hists
+        mon = self.slo_monitor
+        for rid in ev["admitted"]:
+            r = self._reqs[rid]
+            _journal.record("admit", rid=rid,
+                            prefix_hit_len=r.prefix_hit_len,
+                            priority=r.priority,
+                            resumed=bool(r.preemptions or r.requeues),
+                            tokens_done=len(r.tokens))
+        for rid, step in zip(ev["first_tokens"], ev["first_token_steps"]):
+            r = self._reqs[rid]
+            r.first_token_time = t_sync
+            # where in this segment the token came to exist on the device
+            # (OnlineReport's split of the first-token wait reads it)
+            r.first_token_seg = (t_seg_pc, step, ev["steps"])
+            m_ttft.observe(t_sync - r.arrival_time)
+            m_qwait.observe(r.admit_time - r.arrival_time)
+            if mon is not None:
+                mon.note_ttft(r.priority, t_sync - r.arrival_time)
+            self._on_first_token(r, t_sync)
+            _journal.record("first_token", rid=rid,
+                            ttft_s=t_sync - r.arrival_time)
+        for rid in ev["finished"]:
+            # the engine stamps finish during replay (marginally
+            # earlier); the sync is when the client can SEE the
+            # tokens, and keeps finish >= first_token by definition
+            r = self._reqs[rid]
+            r.finish_time = t_sync
+            self._finished_count += 1
+            m_e2e.observe(t_sync - r.arrival_time)
+            if mon is not None:
+                mon.note_e2e(r.priority, t_sync - r.arrival_time)
+            self._on_finish(r, t_sync)
+            _tracing.emit_request_trace(
+                rid, r.arrival_time, r.admit_time, r.first_token_time,
+                r.finish_time, prefix_hit_len=r.prefix_hit_len)
+            # the token-identity ground truth: the FULL emitted
+            # stream rides the finish record (host mirrors of the
+            # segment fetch — nothing extra was synced for this)
+            _journal.record("finish", rid=rid, tokens=r.tokens,
+                            n_tokens=len(r.tokens),
+                            e2e_s=t_sync - r.arrival_time,
+                            priority=r.priority,
+                            preemptions=r.preemptions,
+                            requeues=r.requeues,
+                            spec_proposed=r.spec_proposed,
+                            spec_accepted=r.spec_accepted)
+        # r14 monitor hooks: advance the SLO burn windows and feed
+        # the explained-perf intervals — host ints from the event
+        # log just fetched, plus this segment's dispatch→fetch span
+        if mon is not None:
+            # r17 accept-drift feed (ISSUE 12 satellite): this
+            # segment's speculative acceptance rate, from the spec
+            # stats the replay already recovered
+            sp = ev.get("spec")
+            if sp and sp.get("proposed"):
+                mon.note_accept_rate(sp["accepted"] / sp["proposed"])
+            mon.end_segment()
+        if self.perf_monitor is not None:
+            self.perf_monitor.note_segment(
+                ev["steps"], ev.get("tokens", 0),
+                elapsed_s=t_sync - t_seg_pc)
+        if cap is not None and eng.paged:
+            cap.note_admission(
+                sum(self._reqs[rid].pages_fresh
+                    for rid in ev["admitted"]),
+                admitted=len(ev["admitted"]))
+            cap.close_segment()
+        # r15: per-tick wall EWMA (host arithmetic on already-taken
+        # stamps) — the acceptance-aware service estimates' clock
+        dt = (t_sync - t_seg_pc) / max(ev["steps"], 1)
+        self._per_tick_s = (dt if not self._per_tick_s
+                            else 0.5 * self._per_tick_s + 0.5 * dt)
 
     def _reset_monitors(self) -> None:
         """Warm-run isolation for the attached monitors: the warm pass
@@ -715,6 +785,7 @@ class SLOScheduler(OnlineScheduler):
             r = self.engine._queue[-1]
             assert r.rid == rid
             r.arrival_time = t0 + a.t
+            r.ingest_time = t0 + now
             self._reqs[rid] = r
             self._note_arrival(r, a)
             _journal.record("arrival", rid=rid, at=a.t,

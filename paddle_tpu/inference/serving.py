@@ -70,6 +70,7 @@ from ..models import llama
 from ..observability import flight as _flight
 from ..observability import journal as _journal
 from ..observability import metrics as _metrics
+from ..profiler import _hooks
 from .program_space import PROGRAM_SPACE, WorkloadEnvelope, chunk_for
 
 __all__ = ["Request", "ServingEngine", "SEGMENT_HOOKS", "PROGRAM_SPACE",
@@ -126,6 +127,12 @@ def _subkeys_rows(raw, n: int):
     return jax.vmap(lambda k: jax.random.split(k, n))(raw)
 
 
+@llama.scoped("sample")
+def _greedy(logits):
+    """Every program's greedy token pick, under its scope's name."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _categorical_rows(filt, keys):
     """One independent categorical draw per row: ``filt`` [..., V]
     filtered logits, ``keys`` [..., 2] raw key per row."""
@@ -179,6 +186,7 @@ class _PendingSegment:
     # prefill-progress state (a long prefill may span segments; the
     # host keeps its page reservation and resumes it next dispatch)
     sp: bool = False
+    seg: int = 0                   # the engine's index of this segment
 
 
 @dataclass
@@ -197,6 +205,12 @@ class Request:
     arrival_time: float = 0.0     # entered the system (arrival process)
     admit_time: float = 0.0       # packed into a slot (prefill dispatched)
     first_token_time: float = 0.0  # first generated token host-visible
+    # PR 25: what splits the first-token wait into its parts (telemetry,
+    # never a decision input): the serve loop's top that ingested the
+    # request, and (dispatch time, step, steps) of the segment whose
+    # event log holds its first token — see scheduler._ttft_parts
+    ingest_time: float = 0.0
+    first_token_seg: Optional[tuple] = None
     prefix_hit_len: int = 0       # KV rows reused from the prefix cache
     # r13 SLO-aware serving: smaller priority = more important (class 0
     # outranks class 1); deadline is an ABSOLUTE perf_counter e2e
@@ -572,6 +586,12 @@ class ServingEngine:
         self._rem = self._slot_vec()
         self._init_spec_state()
         self._pending_seg = None  # at most ONE in-flight dispatched segment
+        # PR 25: segments dispatched so far (the ``seg`` id every
+        # ``serving.segment.*`` span carries) and per-phase host time,
+        # span name -> [ns, count] — the serve loop resets the dict and
+        # reduces it into ``OnlineReport.segment_phases``
+        self.seg_index = 0
+        self.segment_phases: Dict[str, list] = {}
         # r14 cold-start metric (ISSUE 9 satellite; ROADMAP item 5's
         # first deliverable): build→first-emitted-token wall time, the
         # number autoscaling/rollout decisions gate on. Stamped ONCE per
@@ -798,7 +818,7 @@ class ServingEngine:
             logits, c = llama.forward_with_cache(
                 params, prompts, cfg, c, jnp.int32(0),
                 logit_pos=true_lens - 1)
-            tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok0 = _greedy(logits)
             k = cache["k"].at[:, slot_ids].set(c["k"])
             v = cache["v"].at[:, slot_ids].set(c["v"])
             pos = pos.at[slot_ids].set(true_lens)
@@ -828,7 +848,7 @@ class ServingEngine:
                 live = rem > 0
                 logits, cache = llama.forward_with_cache(
                     params, nxt[:, None], cfg, cache, pos)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = _greedy(logits)
                 tok = jnp.where(live, tok, nxt)  # frozen slots idle
                 pos = pos + live.astype(jnp.int32)
                 rem = rem - live.astype(jnp.int32)
@@ -1202,6 +1222,7 @@ class ServingEngine:
             def cond(st):
                 return jnp.any(st["rem"] > 0) | (st["qidx"] < n_real)
 
+            @llama.scoped("segment.admit")
             def admit(st):
                 s = jnp.argmin(st["rem"])  # a rem==0 slot (min is 0)
                 q = st["qidx"]
@@ -1215,7 +1236,7 @@ class ServingEngine:
                 c1 = llama.init_kv_cache(cfg, 1, p_max)
                 logits, c1 = llama.forward_with_cache(
                     params, prow, cfg, c1, jnp.int32(0), logit_pos=ln - 1)
-                t0 = jnp.argmax(logits, axis=-1).astype(i32).reshape(())
+                t0 = _greedy(logits).reshape(())
                 k = jax.lax.dynamic_update_slice(
                     st["cache"]["k"], c1["k"], (0, s, 0, 0, 0))
                 v = jax.lax.dynamic_update_slice(
@@ -1237,11 +1258,12 @@ class ServingEngine:
                     qidx=q + 1, step=st["step"], ndec=st["ndec"],
                 )
 
+            @llama.scoped("segment.decode")
             def decode(st):
                 live = st["rem"] > 0
                 logits, cache = llama.forward_with_cache(
                     params, st["nxt"][:, None], cfg, st["cache"], st["pos"])
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = _greedy(logits)
                 tok = jnp.where(live, tok, st["nxt"])
                 rows = jnp.where(live, st["rid"], n_pad)
                 cols = jnp.minimum(st["cnt"], g_max - 1)
@@ -1388,6 +1410,7 @@ class ServingEngine:
                 work = jnp.any(st["rem"] > 0) | (st["qidx"] < n_real)
                 return work & (st["step"] < max_steps)
 
+            @llama.scoped("segment.admit")
             def admit(st):
                 s = jnp.argmin(st["rem"])          # a rem==0 slot
                 q = st["qidx"]
@@ -1417,7 +1440,7 @@ class ServingEngine:
                     }
                 logits, c1 = llama.forward_with_cache(
                     params, prow, cfg, c1, pln, logit_pos=ln - 1)
-                t0 = jnp.argmax(logits, axis=-1).astype(i32).reshape(())
+                t0 = _greedy(logits).reshape(())
                 k = jax.lax.dynamic_update_slice(
                     st["cache"]["k"], c1["k"], (0, s, 0, 0, 0))
                 v = jax.lax.dynamic_update_slice(
@@ -1436,11 +1459,12 @@ class ServingEngine:
                     qidx=q + 1, step=st["step"],
                 )
 
+            @llama.scoped("segment.decode")
             def decode(st):
                 live = st["rem"] > 0
                 logits, cache = llama.forward_with_cache(
                     params, st["nxt"][:, None], cfg, st["cache"], st["pos"])
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = _greedy(logits)
                 tok = jnp.where(live, tok, st["nxt"])
                 rem = st["rem"] - live.astype(jnp.int32)
                 if eos is not None:
@@ -1466,6 +1490,14 @@ class ServingEngine:
                     st["qidx"])
 
         return segment
+
+    def _phase(self, phase: str, seg: Optional[int] = None):
+        """One phase of a segment's host work as a span (PR 25):
+        ``serving.segment.<phase>`` with the segment's index, timed into
+        ``segment_phases``."""
+        return _hooks.span("serving.segment." + phase, "serving",
+                           tally=self.segment_phases,
+                           seg=self.seg_index if seg is None else seg)
 
     def _replay_segment(self, picked, toks, aq, aslot, steps: int, n: int,
                         on_admit=None, on_retire=None,
@@ -1496,6 +1528,7 @@ class ServingEngine:
         SAME single fetched log, zero extra device contact."""
         spec_k = self.speculative
         admitted, first_tokens, finished = [], [], []
+        first_steps = []    # the step whose log row holds the first token
         new_tokens = eos_stops = 0
         for st in range(steps):
             q = int(aq[st])
@@ -1523,6 +1556,7 @@ class ServingEngine:
                     # delivered its first token before losing its slot —
                     # only a fresh admit opens the TTFT clock
                     first_tokens.append(r.rid)
+                    first_steps.append(st)
                 hit_eos = self.eos is not None and t == self.eos
                 eos_stops += hit_eos
                 if r.done or hit_eos:
@@ -1552,6 +1586,7 @@ class ServingEngine:
                     new_tokens += 1
                     if len(r.tokens) == 1:
                         first_tokens.append(r.rid)
+                        first_steps.append(st)
                     self._rem_host[s] -= 1
                     if self.eos is not None and t == self.eos:
                         self._rem_host[s] = 0
@@ -1593,6 +1628,7 @@ class ServingEngine:
                             spec_stats["emitted"] += 1
                         if len(r.tokens) == 1:
                             first_tokens.append(r.rid)
+                            first_steps.append(st)
                         self._rem_host[s] -= 1
                         if self.eos is not None and t == self.eos:
                             self._rem_host[s] = 0
@@ -1607,7 +1643,8 @@ class ServingEngine:
                     spec_stats["verify_steps"] += 1
         if new_tokens and self.cold_start_s is None:
             self._note_cold_start()
-        return admitted, first_tokens, finished, new_tokens, eos_stops
+        return (admitted, first_tokens, first_steps, finished, new_tokens,
+                eos_stops)
 
     @staticmethod
     def _append_digest(r: Request, dig, st: int, s: int) -> None:
@@ -1893,6 +1930,8 @@ class ServingEngine:
         else:
             pending = self._dispatch_segment_dense(max_steps, prefix_cache,
                                                    n_pad, now)
+        pending.seg = self.seg_index
+        self.seg_index += 1
         self._pending_seg = pending
         return pending
 
@@ -1912,91 +1951,92 @@ class ServingEngine:
 
     def _dispatch_segment_dense(self, max_steps: int, prefix_cache,
                                 n_pad: int, now: float) -> _PendingSegment:
-        # pick up to n_pad regardless of CURRENT free slots: in-program
-        # admission refills slots the moment they retire mid-segment, so
-        # over-picking is exactly what keeps the batch full (requests the
-        # step budget couldn't admit are re-queued below)
-        picked = self._queue[:n_pad]
-        del self._queue[:len(picked)]
-        n = len(picked)
+        with self._phase("pick"):
+            # pick up to n_pad regardless of CURRENT free slots: in-program
+            # admission refills slots the moment they retire mid-segment, so
+            # over-picking is exactly what keeps the batch full (requests the
+            # step budget couldn't admit are re-queued below)
+            picked = self._queue[:n_pad]
+            del self._queue[:len(picked)]
+            n = len(picked)
 
-        # admission view (r13): a fresh request prefills its prompt, a
-        # preempted/failed-over one resumes from prompt + generated
-        # tokens and owes only the tail
-        fulls = [r.resume_view() for r in picked]
+            # admission view (r13): a fresh request prefills its prompt, a
+            # preempted/failed-over one resumes from prompt + generated
+            # tokens and owes only the tail
+            fulls = [r.resume_view() for r in picked]
 
-        # prefix-cache lookup (admission-time detection): per request the
-        # longest cached block-aligned prefix; suffix = the rest
-        pre_lens = np.zeros((n_pad,), np.int32)
-        pre_entries = [None] * n
-        if prefix_cache is not None:
-            for j, r in enumerate(picked):
-                fp = fulls[j][0]
-                ent = prefix_cache.match(fp)
-                if ent is not None and ent.length < len(fp):
-                    pre_entries[j] = ent
-                    pre_lens[j] = ent.length
-                    r.prefix_hit_len = ent.length
-        pre_max = int(max(pre_lens)) if n else 0
-        if pre_max:
-            pre_max = prefix_cache.round_up(pre_max)
-
-        # prompt width: WITHOUT prefix reuse, pin to the largest bucket —
-        # prefill pads there anyway on the drain path (HBM-bound: it
-        # streams the full weight set regardless of width) and ONE
-        # program shape means no mid-serve XLA compile when arrival
-        # jitter regroups admissions (measured: a stray 64-wide segment
-        # compiled 2.5s into an online run, dwarfing the work). WITH
-        # prefix reuse the suffix width IS the saving, so bucket it —
-        # shared-prefix workloads have uniform tails, so the shape set
-        # stays small and the warm pass covers it.
-        if prefix_cache is None or pre_max == 0:
-            s_max = self.buckets[-1]
-        else:
-            suf_max = max((len(fulls[j][0]) - int(pre_lens[j])
-                           for j in range(n)), default=1)
-            s_max = self._bucket_for(suf_max)
-        if pre_max and pre_max + s_max > self.max_len:
-            # prefix + suffix window must fit the cache; drop the hits
-            pre_max = 0
-            pre_lens[:] = 0
+            # prefix-cache lookup (admission-time detection): per request the
+            # longest cached block-aligned prefix; suffix = the rest
+            pre_lens = np.zeros((n_pad,), np.int32)
             pre_entries = [None] * n
-            for r in picked:
-                r.prefix_hit_len = 0
-            s_max = self.buckets[-1]
+            if prefix_cache is not None:
+                for j, r in enumerate(picked):
+                    fp = fulls[j][0]
+                    ent = prefix_cache.match(fp)
+                    if ent is not None and ent.length < len(fp):
+                        pre_entries[j] = ent
+                        pre_lens[j] = ent.length
+                        r.prefix_hit_len = ent.length
+            pre_max = int(max(pre_lens)) if n else 0
+            if pre_max:
+                pre_max = prefix_cache.round_up(pre_max)
 
-        prompts = np.zeros((n_pad, s_max), np.int32)
-        lens = np.ones((n_pad,), np.int32)
-        gens = np.zeros((n_pad,), np.int32)   # gen 0 -> never admitted
-        for j, r in enumerate(picked):
-            fp, remaining = fulls[j]
-            suf = fp[int(pre_lens[j]):]
-            prompts[j, :len(suf)] = suf
-            lens[j] = len(suf)
-            gens[j] = remaining
-            r.admit_time = now
-        if pre_max:
-            L = self.cfg.num_layers
-            Hkv, D = self.cfg.num_kv_heads, self.cfg.head_dim
-            pk = jnp.zeros((n_pad, L, pre_max, Hkv, D), self._cache["k"].dtype)
-            pv = jnp.zeros((n_pad, L, pre_max, Hkv, D), self._cache["v"].dtype)
+            # prompt width: WITHOUT prefix reuse, pin to the largest bucket —
+            # prefill pads there anyway on the drain path (HBM-bound: it
+            # streams the full weight set regardless of width) and ONE
+            # program shape means no mid-serve XLA compile when arrival
+            # jitter regroups admissions (measured: a stray 64-wide segment
+            # compiled 2.5s into an online run, dwarfing the work). WITH
+            # prefix reuse the suffix width IS the saving, so bucket it —
+            # shared-prefix workloads have uniform tails, so the shape set
+            # stays small and the warm pass covers it.
+            if prefix_cache is None or pre_max == 0:
+                s_max = self.buckets[-1]
+            else:
+                suf_max = max((len(fulls[j][0]) - int(pre_lens[j])
+                               for j in range(n)), default=1)
+                s_max = self._bucket_for(suf_max)
+            if pre_max and pre_max + s_max > self.max_len:
+                # prefix + suffix window must fit the cache; drop the hits
+                pre_max = 0
+                pre_lens[:] = 0
+                pre_entries = [None] * n
+                for r in picked:
+                    r.prefix_hit_len = 0
+                s_max = self.buckets[-1]
+
+        with self._phase("inputs"):
+            prompts = np.zeros((n_pad, s_max), np.int32)
+            lens = np.ones((n_pad,), np.int32)
+            gens = np.zeros((n_pad,), np.int32)   # gen 0 -> never admitted
+            for j, r in enumerate(picked):
+                fp, remaining = fulls[j]
+                suf = fp[int(pre_lens[j]):]
+                prompts[j, :len(suf)] = suf
+                lens[j] = len(suf)
+                gens[j] = remaining
+                r.admit_time = now
+            # staged prefix rows; a zero-width block when nothing was
+            # reused: the program specialises pre_max=0 and skips the
+            # prefix writes entirely
+            pshape = (n_pad, self.cfg.num_layers, pre_max,
+                      self.cfg.num_kv_heads, self.cfg.head_dim)
+            pk = jnp.zeros(pshape, self._cache["k"].dtype)
+            pv = jnp.zeros(pshape, self._cache["v"].dtype)
             for j, ent in enumerate(pre_entries):
                 if ent is not None:
-                    pk = pk.at[j, :, :ent.length].set(ent.k[:, :ent.length])
-                    pv = pv.at[j, :, :ent.length].set(ent.v[:, :ent.length])
-        else:
-            # zero-width prefix block: the program specialises pre_max=0
-            # and skips the prefix writes entirely
-            L = self.cfg.num_layers
-            Hkv, D = self.cfg.num_kv_heads, self.cfg.head_dim
-            pk = jnp.zeros((n_pad, L, 0, Hkv, D), self._cache["k"].dtype)
-            pv = jnp.zeros((n_pad, L, 0, Hkv, D), self._cache["v"].dtype)
+                    m = ent.length
+                    pk = pk.at[j, :, :m].set(ent.k[:, :m])
+                    pv = pv.at[j, :, :m].set(ent.v[:, :m])
 
-        with _mesh_scope(self.mesh):
+            # the host -> device copies (one small program each)
+            dev_in = (jnp.asarray(prompts), jnp.asarray(lens),
+                      jnp.asarray(gens), pk, pv, jnp.asarray(pre_lens),
+                      jnp.int32(n))
+        with self._phase("launch"), _mesh_scope(self.mesh):
             out = self._segment_prog(n_pad, s_max, pre_max, max_steps)(
                 self.params, self._cache, self._pos, self._nxt, self._rem,
-                jnp.asarray(prompts), jnp.asarray(lens), jnp.asarray(gens),
-                pk, pv, jnp.asarray(pre_lens), jnp.int32(n))
+                *dev_in)
         self._cache, self._pos, self._nxt, self._rem = out[:4]
         return _PendingSegment(paged=False, picked=picked, n=n, now=now,
                                prefix_cache=prefix_cache, dev=out[4:],
@@ -2009,45 +2049,50 @@ class ServingEngine:
         # THE per-segment sync: the one place the online serve loop is
         # allowed to block on the device (audited — see analysis.syncs;
         # the budget pins it to exactly one per segment)
-        with allowed_sync("serving.segment_event_fetch"):
+        with self._phase("fetch", p.seg), \
+                allowed_sync("serving.segment_event_fetch"):
             toks, aq, aslot, steps, qadm = jax.device_get(p.dev)
         steps, qadm = int(steps), int(qadm)
         self.last_run_ticks += steps
         self.last_run_chunks += 1
 
-        admitted, first_tokens, finished, new_tokens, eos_stops = \
-            self._replay_segment(picked, toks, aq, aslot, steps, n)
-        if qadm < n:
-            # step budget ran out before every picked request found a
-            # slot: back to the queue head, FCFS order preserved
-            for r in picked[qadm:]:
-                r.admit_time = 0.0
-            self._queue[:0] = picked[qadm:]
+        with self._phase("replay", p.seg):
+            (admitted, first_tokens, first_steps, finished, new_tokens,
+             eos_stops) = self._replay_segment(picked, toks, aq, aslot,
+                                               steps, n)
+            if qadm < n:
+                # step budget ran out before every picked request found a
+                # slot: back to the queue head, FCFS order preserved
+                for r in picked[qadm:]:
+                    r.admit_time = 0.0
+                self._queue[:0] = picked[qadm:]
 
-        # prefix-cache population: insert each admitted request's full
-        # prompt KV (block-trimmed device slices of the slot cache —
-        # rows [0, plen) hold exactly the prompt's keys until the slot
-        # is reused, and insertion right after the sync precedes any
-        # donation of this cache buffer)
-        if prefix_cache is not None:
-            last_admit = {}                # slot -> its latest admit event
-            for st in range(steps):
-                q = int(aq[st])
-                if q < n:
-                    last_admit[int(aslot[st])] = q
-            for s, q in last_admit.items():
-                fp = p.full_prompts[q]     # the span actually prefilled
-                plen_b = prefix_cache.round_down(len(fp))
-                if plen_b > int(pre_lens[q]):
-                    prefix_cache.insert(
-                        fp[:plen_b],
-                        self._cache["k"][:, s, :plen_b],
-                        self._cache["v"][:, s, :plen_b])
+            # prefix-cache population: insert each admitted request's full
+            # prompt KV (block-trimmed device slices of the slot cache —
+            # rows [0, plen) hold exactly the prompt's keys until the slot
+            # is reused, and insertion right after the sync precedes any
+            # donation of this cache buffer)
+            if prefix_cache is not None:
+                last_admit = {}            # slot -> its latest admit event
+                for st in range(steps):
+                    q = int(aq[st])
+                    if q < n:
+                        last_admit[int(aslot[st])] = q
+                for s, q in last_admit.items():
+                    fp = p.full_prompts[q]     # the span actually prefilled
+                    plen_b = prefix_cache.round_down(len(fp))
+                    if plen_b > int(pre_lens[q]):
+                        prefix_cache.insert(
+                            fp[:plen_b],
+                            self._cache["k"][:, s, :plen_b],
+                            self._cache["v"][:, s, :plen_b])
 
-        self._segment_telemetry(steps, admitted, finished, eos_stops,
-                                new_tokens, max(0, n - qadm))
+        with self._phase("telemetry", p.seg):
+            self._segment_telemetry(steps, admitted, finished, eos_stops,
+                                    new_tokens, max(0, n - qadm))
         return {"steps": steps, "admitted": admitted,
-                "first_tokens": first_tokens, "finished": finished,
+                "first_tokens": first_tokens,
+                "first_token_steps": first_steps, "finished": finished,
                 "tokens": new_tokens}
 
     # --- paged segments (r11: page-table KV, inference/paged_kv.py) -------
@@ -2144,6 +2189,7 @@ class ServingEngine:
                 work = jnp.any(st["rem"] > 0) | (st["qidx"] < n_real)
                 return work & (st["step"] < max_steps)
 
+            @llama.scoped("segment.admit")
             def admit(st):
                 s = jnp.argmin(st["rem"])          # a rem==0 slot
                 q = st["qidx"]
@@ -2160,7 +2206,7 @@ class ServingEngine:
                 logits, pool = llama.forward_with_pages(
                     params, prow, cfg, st["pool"], row,
                     jnp.reshape(pln, (1,)), logit_pos=ln - 1)
-                t0 = jnp.argmax(logits, axis=-1).astype(i32).reshape(())
+                t0 = _greedy(logits).reshape(())
                 rem_new = gens[q] - 1
                 if eos is not None:
                     rem_new = jnp.where(t0 == eos, 0, rem_new)
@@ -2185,12 +2231,13 @@ class ServingEngine:
                     new["dtv"] = st["dtv"].at[st["step"], s].set(tv[0])
                 return new
 
+            @llama.scoped("segment.decode")
             def decode(st):
                 live = st["rem"] > 0
                 logits, pool = llama.forward_with_pages(
                     params, st["nxt"][:, None], cfg, st["pool"],
                     st["pt"], st["pos"], live=live)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = _greedy(logits)
                 tok = jnp.where(live, tok, st["nxt"])
                 rem = st["rem"] - live.astype(jnp.int32)
                 if eos is not None:
@@ -2323,6 +2370,7 @@ class ServingEngine:
                         | _startable(st))
                 return work & (st["step"] < max_steps)
 
+            @llama.scoped("segment.admit")
             def chunk(st):
                 starting = st["pf"] < 0
                 s = jnp.where(starting,
@@ -2346,7 +2394,7 @@ class ServingEngine:
                     jnp.reshape(pln + off, (1,)),
                     logit_pos=jnp.minimum(ln - 1 - off, C - 1))
                 done = off + C >= ln
-                t0 = jnp.argmax(logits, axis=-1).astype(i32).reshape(())
+                t0 = _greedy(logits).reshape(())
                 rem_new = gens[q] - 1
                 if eos is not None:
                     rem_new = jnp.where(t0 == eos, 0, rem_new)
@@ -2370,12 +2418,13 @@ class ServingEngine:
                     step=st["step"],
                 )
 
+            @llama.scoped("segment.decode")
             def decode(st):
                 live = st["rem"] > 0
                 logits, pool = llama.forward_with_pages(
                     params, st["nxt"][:, None], cfg, st["pool"],
                     st["pt"], st["pos"], live=live)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = _greedy(logits)
                 tok = jnp.where(live, tok, st["nxt"])
                 rem = st["rem"] - live.astype(jnp.int32)
                 if eos is not None:
@@ -2491,6 +2540,7 @@ class ServingEngine:
                         | _startable(st))
                 return work & (st["step"] < max_steps)
 
+            @llama.scoped("segment.admit")
             def chunk(st):
                 starting = st["pf"] < 0
                 s = jnp.where(starting,
@@ -2521,7 +2571,7 @@ class ServingEngine:
                 # the winner row holds the suffix's true last token;
                 # rows past it see garbage their clamp masks out
                 r_star = jnp.clip((ln - 1 - off) // C, 0, sp - 1)
-                t0 = jnp.argmax(logits, axis=-1).astype(i32)[r_star]
+                t0 = _greedy(logits)[r_star]
                 rem_new = gens[q] - 1
                 if eos is not None:
                     rem_new = jnp.where(t0 == eos, 0, rem_new)
@@ -2545,12 +2595,13 @@ class ServingEngine:
                     step=st["step"],
                 )
 
+            @llama.scoped("segment.decode")
             def decode(st):
                 live = st["rem"] > 0
                 logits, pool = llama.forward_with_pages(
                     params, st["nxt"][:, None], cfg, st["pool"],
                     st["pt"], st["pos"], live=live)
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = _greedy(logits)
                 tok = jnp.where(live, tok, st["nxt"])
                 rem = st["rem"] - live.astype(jnp.int32)
                 if eos is not None:
@@ -2666,6 +2717,7 @@ class ServingEngine:
                         | _startable(st))
                 return work & (st["step"] < max_steps)
 
+            @llama.scoped("segment.admit")
             def chunk(st):
                 # the admit path — the r13 chunk branch plus the spec
                 # state writes: the chunk's tokens land in the slot's
@@ -2690,7 +2742,7 @@ class ServingEngine:
                     logit_pos=jnp.minimum(ln - 1 - off, C - 1))
                 done = off + C >= ln
                 if sampling is None:
-                    t0 = jnp.argmax(logits, axis=-1).astype(i32).reshape(())
+                    t0 = _greedy(logits).reshape(())
                     rng_new = st["rng"]
                 else:
                     k0, kuse = jax.random.split(
@@ -2733,6 +2785,7 @@ class ServingEngine:
                     step=st["step"],
                 )
 
+            @llama.scoped("segment.decode")
             def verify(st):
                 live = st["rem"] > 0
                 pos, nxt = st["pos"], st["nxt"]
@@ -2775,7 +2828,7 @@ class ServingEngine:
                 if sampling is None:
                     # greedy: the target argmax chain IS the emitted
                     # stream; drafts only gate how much of it lands
-                    e = jnp.argmax(logits, axis=-1).astype(i32)
+                    e = _greedy(logits)
                     ok = drafts == e[:, :K]
                     rng_new = st["rng"]
                 else:
@@ -2881,199 +2934,207 @@ class ServingEngine:
                             f"{type(prefix_cache).__name__}")
         pgr = self.pager
         psz = self.page_size
-        picked: List[Request] = []
-        fulls: List[np.ndarray] = []      # admission (resume) views
-        req_pages: List[List[int]] = []
-        pre_lens_l: List[int] = []
-        tables: List[np.ndarray] = []
-        deferred = 0
-        while self._queue and len(picked) < n_pad:
-            r = self._queue[0]
-            sp_info = (self._sp_inflight.get(r.rid)
-                       if self.seq_parallel else None)
-            if sp_info is not None:
-                # r23 long-prefill continuation: the pages were
-                # reserved at first admission and the first
-                # ``resident`` rows already landed in the pool — reuse
-                # both (zero allocator / prefix-cache / meter traffic;
-                # the reservation is HELD across the spanned segments)
-                fp, _ = r.resume_view()
-                row = np.zeros((pgr.max_pages,), np.int32)
-                row[:len(sp_info["pages"])] = sp_info["pages"]
+        with self._phase("pick"):
+            picked: List[Request] = []
+            fulls: List[np.ndarray] = []      # admission (resume) views
+            req_pages: List[List[int]] = []
+            pre_lens_l: List[int] = []
+            tables: List[np.ndarray] = []
+            deferred = 0
+            while self._queue and len(picked) < n_pad:
+                r = self._queue[0]
+                sp_info = (self._sp_inflight.get(r.rid)
+                           if self.seq_parallel else None)
+                if sp_info is not None:
+                    # r23 long-prefill continuation: the pages were
+                    # reserved at first admission and the first
+                    # ``resident`` rows already landed in the pool — reuse
+                    # both (zero allocator / prefix-cache / meter traffic;
+                    # the reservation is HELD across the spanned segments)
+                    fp, _ = r.resume_view()
+                    row = np.zeros((pgr.max_pages,), np.int32)
+                    row[:len(sp_info["pages"])] = sp_info["pages"]
+                    self._queue.pop(0)
+                    if not r.admit_time:
+                        r.admit_time = now
+                    picked.append(r)
+                    fulls.append(fp)
+                    req_pages.append(sp_info["pages"])
+                    pre_lens_l.append(sp_info["resident"])
+                    tables.append(row)
+                    continue
+                fp, remaining = r.resume_view()
+                rows = len(fp) + remaining - 1
+                total = pgr.pages_needed(rows)
+                hit_pages: List[int] = []
+                hit_len = 0
+                restored = 0
+                if prefix_cache is not None:
+                    m = prefix_cache.match(fp)
+                    if m is not None and getattr(m, "tier", "hbm") != "host":
+                        hit_pages, hit_len = list(m.pages), m.length
+                    elif m is not None:
+                        # r19 tiered KV (ISSUE 14): host-tier hit —
+                        # restore-on-hit is reserve + async staged upload +
+                        # the normal ref-bump share. Restoring consumes free
+                        # pages itself, so the WHOLE request span must fit;
+                        # the pressure valve may spill colder entries first.
+                        # A failed restore degrades to a plain miss (full
+                        # prefill) — never an error.
+                        if total > pgr.pages_free:
+                            prefix_cache.evict_until(total)
+                        if total <= pgr.pages_free:
+                            rp = prefix_cache.restore(m.key, m.length)
+                            if rp:
+                                hit_pages, hit_len = rp, len(rp) * psz
+                                restored = len(rp)
+                need_new = total - len(hit_pages)
+                if need_new > pgr.pages_free:
+                    if prefix_cache is not None:
+                        # page-pressure valve: cached history yields LRU
+                        # pages before live traffic defers; eviction may
+                        # have freed the very pages the hit named, so trim
+                        # the hit at the first no-longer-referenced page
+                        prefix_cache.evict_until(need_new)
+                        k = 0
+                        while (k < len(hit_pages)
+                               and pgr.allocator.ref(hit_pages[k]) > 0):
+                            k += 1
+                        hit_pages, hit_len = hit_pages[:k], k * psz
+                        need_new = total - k
+                    if need_new > pgr.pages_free:
+                        # FCFS: the queue head blocks, everything waits —
+                        # pages free as live requests retire
+                        deferred = len(self._queue)
+                        if (not picked
+                                and all(not p for p in pgr.slot_pages)):
+                            # nothing live to free pages and nothing being
+                            # admitted: the pool is pinned by references
+                            # outside this engine's control — fail loudly
+                            # rather than spin the serve loop forever
+                            raise RuntimeError(
+                                f"page pool starved: request needs "
+                                f"{need_new} pages, {pgr.pages_free} free, "
+                                f"no live slots to retire (pages held by an "
+                                f"external prefix cache or fork?)")
+                        break
+                pages, row = pgr.reserve(rows, hit_pages)
                 self._queue.pop(0)
-                if not r.admit_time:
-                    r.admit_time = now
+                r.prefix_hit_len = hit_len
+                r.admit_time = now
+                r._meter_reserve(len(pages), len(pages) - len(hit_pages))
+                if restored:
+                    # r19: bill the promotion to the request it admitted
+                    r.tier_pages += restored
+                    r.tier_bytes += (restored
+                                     * prefix_cache.host_tier.page_bytes())
                 picked.append(r)
                 fulls.append(fp)
-                req_pages.append(sp_info["pages"])
-                pre_lens_l.append(sp_info["resident"])
+                req_pages.append(pages)
+                pre_lens_l.append(hit_len)
                 tables.append(row)
-                continue
-            fp, remaining = r.resume_view()
-            rows = len(fp) + remaining - 1
-            total = pgr.pages_needed(rows)
-            hit_pages: List[int] = []
-            hit_len = 0
-            restored = 0
-            if prefix_cache is not None:
-                m = prefix_cache.match(fp)
-                if m is not None and getattr(m, "tier", "hbm") != "host":
-                    hit_pages, hit_len = list(m.pages), m.length
-                elif m is not None:
-                    # r19 tiered KV (ISSUE 14): host-tier hit —
-                    # restore-on-hit is reserve + async staged upload +
-                    # the normal ref-bump share. Restoring consumes free
-                    # pages itself, so the WHOLE request span must fit;
-                    # the pressure valve may spill colder entries first.
-                    # A failed restore degrades to a plain miss (full
-                    # prefill) — never an error.
-                    if total > pgr.pages_free:
-                        prefix_cache.evict_until(total)
-                    if total <= pgr.pages_free:
-                        rp = prefix_cache.restore(m.key, m.length)
-                        if rp:
-                            hit_pages, hit_len = rp, len(rp) * psz
-                            restored = len(rp)
-            need_new = total - len(hit_pages)
-            if need_new > pgr.pages_free:
-                if prefix_cache is not None:
-                    # page-pressure valve: cached history yields LRU
-                    # pages before live traffic defers; eviction may
-                    # have freed the very pages the hit named, so trim
-                    # the hit at the first no-longer-referenced page
-                    prefix_cache.evict_until(need_new)
-                    k = 0
-                    while (k < len(hit_pages)
-                           and pgr.allocator.ref(hit_pages[k]) > 0):
-                        k += 1
-                    hit_pages, hit_len = hit_pages[:k], k * psz
-                    need_new = total - k
-                if need_new > pgr.pages_free:
-                    # FCFS: the queue head blocks, everything waits —
-                    # pages free as live requests retire
-                    deferred = len(self._queue)
-                    if (not picked
-                            and all(not p for p in pgr.slot_pages)):
-                        # nothing live to free pages and nothing being
-                        # admitted: the pool is pinned by references
-                        # outside this engine's control — fail loudly
-                        # rather than spin the serve loop forever
-                        raise RuntimeError(
-                            f"page pool starved: request needs "
-                            f"{need_new} pages, {pgr.pages_free} free, "
-                            f"no live slots to retire (pages held by an "
-                            f"external prefix cache or fork?)")
-                    break
-            pages, row = pgr.reserve(rows, hit_pages)
-            self._queue.pop(0)
-            r.prefix_hit_len = hit_len
-            r.admit_time = now
-            r._meter_reserve(len(pages), len(pages) - len(hit_pages))
-            if restored:
-                # r19: bill the promotion to the request it admitted
-                r.tier_pages += restored
-                r.tier_bytes += (restored
-                                 * prefix_cache.host_tier.page_bytes())
-            picked.append(r)
-            fulls.append(fp)
-            req_pages.append(pages)
-            pre_lens_l.append(hit_len)
-            tables.append(row)
-        if deferred:
-            self.page_backpressure_events += 1
-            _metrics.counter("serving.backpressure_pages").inc()
-            _flight.record("backpressure", reason="pages",
-                           deferred=deferred, pages_free=pgr.pages_free)
-        n = len(picked)
+            if deferred:
+                self.page_backpressure_events += 1
+                _metrics.counter("serving.backpressure_pages").inc()
+                _flight.record("backpressure", reason="pages",
+                               deferred=deferred, pages_free=pgr.pages_free)
+            n = len(picked)
 
-        spec = bool(self.speculative or self.sampling)
-        # suffix width: same pinning rule as the contiguous segment —
-        # largest bucket when nothing was reused, the suffix bucket when
-        # prefix hits shorten the prefill. SPEC segments always pin to
-        # the largest bucket: the ("sseg", n_pad, K, steps) key family
-        # deliberately carries no width, so prefix hits stay page DATA
-        # and add zero program shapes.
-        # r23: the segment runs the sequence-parallel slab family when
-        # any picked request is a long prefill — a fresh suffix past
-        # the largest regular bucket, or a continuation mid-flight.
-        # Everything else (sp engines included) rides pseg/cseg
-        # unchanged: sp=1 or short-only traffic degenerates exactly.
-        sp_engaged = [j for j in range(n) if self.seq_parallel and (
-            picked[j].rid in self._sp_inflight
-            or len(fulls[j]) - pre_lens_l[j] > self.buckets[-1])]
-        sp_mode = bool(sp_engaged)
+            spec = bool(self.speculative or self.sampling)
+            # suffix width: same pinning rule as the contiguous segment —
+            # largest bucket when nothing was reused, the suffix bucket when
+            # prefix hits shorten the prefill. SPEC segments always pin to
+            # the largest bucket: the ("sseg", n_pad, K, steps) key family
+            # deliberately carries no width, so prefix hits stay page DATA
+            # and add zero program shapes.
+            # r23: the segment runs the sequence-parallel slab family when
+            # any picked request is a long prefill — a fresh suffix past
+            # the largest regular bucket, or a continuation mid-flight.
+            # Everything else (sp engines included) rides pseg/cseg
+            # unchanged: sp=1 or short-only traffic degenerates exactly.
+            sp_engaged = [j for j in range(n) if self.seq_parallel and (
+                picked[j].rid in self._sp_inflight
+                or len(fulls[j]) - pre_lens_l[j] > self.buckets[-1])]
+            sp_mode = bool(sp_engaged)
 
-        chunk_marker = None
-        if sp_mode:
-            # slab width: the largest declared prefill chunk per shard;
-            # admit window: the largest engaged rung, slab-rounded.
-            # Rungs shrink as continuations land rows, and every rung
-            # at or below the first admission's is enumerated.
-            C = self.prefill_chunks[-1]
-            Cs = self.seq_parallel * C
-            lb = max(self._long_rung(max(1, len(fulls[j]) - pre_lens_l[j]))
-                     for j in sp_engaged)
-            s_max = -(-lb // Cs) * Cs
-            chunk_marker = n_pad + 1
-        elif spec or prefix_cache is None or not any(pre_lens_l):
-            s_max = self.buckets[-1]
-        else:
-            suf_max = max((len(fulls[j]) - pre_lens_l[j]
-                           for j in range(n)), default=1)
-            s_max = self._bucket_for(suf_max)
+            chunk_marker = None
+            if sp_mode:
+                # slab width: the largest declared prefill chunk per shard;
+                # admit window: the largest engaged rung, slab-rounded.
+                # Rungs shrink as continuations land rows, and every rung
+                # at or below the first admission's is enumerated.
+                C = self.prefill_chunks[-1]
+                Cs = self.seq_parallel * C
+                lb = max(self._long_rung(max(1, len(fulls[j]) - pre_lens_l[j]))
+                         for j in sp_engaged)
+                s_max = -(-lb // Cs) * Cs
+                chunk_marker = n_pad + 1
+            elif spec or prefix_cache is None or not any(pre_lens_l):
+                s_max = self.buckets[-1]
+            else:
+                suf_max = max((len(fulls[j]) - pre_lens_l[j]
+                               for j in range(n)), default=1)
+                s_max = self._bucket_for(suf_max)
 
-        if self.chunked and not sp_mode:
-            C = self._prefill_chunk_for(s_max)
-            s_max = -(-s_max // C) * C        # chunk-aligned admit window
-            worst = 2 * (s_max // C)
-            if max_steps < worst:
-                raise ValueError(
-                    f"seg_steps {max_steps} cannot fit one chunked "
-                    f"prefill ({s_max // C} chunks x {C} interleaved = "
-                    f"{worst} steps) — raise seg_steps or shrink the "
-                    f"prompt buckets / chunk ladder")
-            chunk_marker = n_pad + 1
-        if spec:
-            # the spec program admits through the chunk branch (one
-            # full-width chunk when unchunked), so non-final chunk
-            # steps log the same marker and a start needs 2*chunks of
-            # step budget
-            chunk_marker = n_pad + 1
-            if max_steps < 2:
-                raise ValueError("speculative segments need seg_steps "
-                                 ">= 2 (a prefill start reserves one "
-                                 "chunk + one verify step)")
+            if self.chunked and not sp_mode:
+                C = self._prefill_chunk_for(s_max)
+                s_max = -(-s_max // C) * C        # chunk-aligned admit window
+                worst = 2 * (s_max // C)
+                if max_steps < worst:
+                    raise ValueError(
+                        f"seg_steps {max_steps} cannot fit one chunked "
+                        f"prefill ({s_max // C} chunks x {C} interleaved = "
+                        f"{worst} steps) — raise seg_steps or shrink the "
+                        f"prompt buckets / chunk ladder")
+                chunk_marker = n_pad + 1
+            if spec:
+                # the spec program admits through the chunk branch (one
+                # full-width chunk when unchunked), so non-final chunk
+                # steps log the same marker and a start needs 2*chunks of
+                # step budget
+                chunk_marker = n_pad + 1
+                if max_steps < 2:
+                    raise ValueError("speculative segments need seg_steps "
+                                     ">= 2 (a prefill start reserves one "
+                                     "chunk + one verify step)")
 
-        prompts = np.zeros((n_pad, s_max), np.int32)
-        lens = np.ones((n_pad,), np.int32)
-        gens = np.zeros((n_pad,), np.int32)   # gen 0 -> never admitted
-        pre_lens = np.zeros((n_pad,), np.int32)
-        req_tables = np.zeros((n_pad, pgr.max_pages), np.int32)
-        seeds = np.zeros((n_pad,), np.int32)
-        for j, r in enumerate(picked):
-            suf = fulls[j][pre_lens_l[j]:]
-            prompts[j, :len(suf)] = suf
-            lens[j] = len(suf)
-            gens[j] = r.max_new_tokens - len(r.tokens)
-            pre_lens[j] = pre_lens_l[j]
-            req_tables[j] = tables[j]
-            # the slot's RNG stream derives from (request seed, tokens
-            # already delivered): a fresh serve replays identically, a
-            # preempt/failover resume continues from a deterministic
-            # fold instead of re-playing consumed draws
-            seeds[j] = (r.seed + 0x9E3779B1 * len(r.tokens)) & 0x7FFFFFFF
+        with self._phase("inputs"):
+            prompts = np.zeros((n_pad, s_max), np.int32)
+            lens = np.ones((n_pad,), np.int32)
+            gens = np.zeros((n_pad,), np.int32)   # gen 0 -> never admitted
+            pre_lens = np.zeros((n_pad,), np.int32)
+            req_tables = np.zeros((n_pad, pgr.max_pages), np.int32)
+            seeds = np.zeros((n_pad,), np.int32)
+            for j, r in enumerate(picked):
+                suf = fulls[j][pre_lens_l[j]:]
+                prompts[j, :len(suf)] = suf
+                lens[j] = len(suf)
+                gens[j] = r.max_new_tokens - len(r.tokens)
+                pre_lens[j] = pre_lens_l[j]
+                req_tables[j] = tables[j]
+                # the slot's RNG stream derives from (request seed, tokens
+                # already delivered): a fresh serve replays identically, a
+                # preempt/failover resume continues from a deterministic
+                # fold instead of re-playing consumed draws
+                seeds[j] = (r.seed + 0x9E3779B1 * len(r.tokens)) & 0x7FFFFFFF
+
+            # the host -> device copies (one small program each: the
+            # jit_convert_element_type programs a device trace shows
+            # between two segments)
+            dev_in = [jnp.asarray(a) for a in
+                      (prompts, lens, gens, pre_lens, req_tables)]
+            if spec:
+                dev_in.append(jnp.asarray(seeds))
+            dev_in.append(jnp.int32(n))
 
         if spec:
-            rng = (self._rng if self._rng is not None
-                   else jnp.zeros((self.slots, 2), jnp.uint32))
-            with _mesh_scope(self.mesh):
+            with self._phase("launch"), _mesh_scope(self.mesh):
+                rng = (self._rng if self._rng is not None
+                       else jnp.zeros((self.slots, 2), jnp.uint32))
                 out = self._spec_segment_prog(n_pad, max_steps)(
                     self.params, pgr.pool, pgr.page_table, self._pos,
                     self._nxt, self._rem, self._hist, self._hstart, rng,
-                    jnp.asarray(prompts), jnp.asarray(lens),
-                    jnp.asarray(gens), jnp.asarray(pre_lens),
-                    jnp.asarray(req_tables), jnp.asarray(seeds),
-                    jnp.int32(n))
+                    *dev_in)
             pgr.pool, pgr.page_table = out[0], out[1]
             self._pos, self._nxt, self._rem = out[2:5]
             self._hist, self._hstart = out[5], out[6]
@@ -3086,17 +3147,16 @@ class ServingEngine:
                                    full_prompts=fulls,
                                    chunk_marker=chunk_marker, spec=True)
 
-        prog = (self._sp_segment_prog(n_pad, s_max, C, max_steps)
-                if sp_mode
-                else self._chunked_segment_prog(n_pad, s_max, C, max_steps)
-                if self.chunked
-                else self._paged_segment_prog(n_pad, s_max, max_steps))
-        with _mesh_scope(self.mesh):
+        with self._phase("launch"), _mesh_scope(self.mesh):
+            prog = (self._sp_segment_prog(n_pad, s_max, C, max_steps)
+                    if sp_mode
+                    else self._chunked_segment_prog(n_pad, s_max, C,
+                                                    max_steps)
+                    if self.chunked
+                    else self._paged_segment_prog(n_pad, s_max, max_steps))
             out = prog(
                 self.params, pgr.pool, pgr.page_table, self._pos, self._nxt,
-                self._rem, jnp.asarray(prompts), jnp.asarray(lens),
-                jnp.asarray(gens), jnp.asarray(pre_lens),
-                jnp.asarray(req_tables), jnp.int32(n))
+                self._rem, *dev_in)
         pgr.pool, pgr.page_table = out[0], out[1]
         self._pos, self._nxt, self._rem = out[2:5]
         return _PendingSegment(paged=True, picked=picked, n=n, now=now,
@@ -3121,7 +3181,8 @@ class ServingEngine:
         tier = getattr(prefix_cache, "host_tier", None) \
             if prefix_cache is not None else None
         staged = tier.take_pending() if tier is not None else []
-        with allowed_sync("serving.segment_event_fetch"):
+        with self._phase("fetch", p.seg), \
+                allowed_sync("serving.segment_event_fetch"):
             payload = (p.dev if not staged
                        else (p.dev, [s[2:] for s in staged]))
             got = jax.device_get(payload)
@@ -3149,89 +3210,92 @@ class ServingEngine:
             spec_stats = {"proposed": 0, "accepted": 0, "emitted": 0,
                           "verify_steps": 0, "slot_ticks": 0}
 
-        # page bookkeeping rides the SHARED replay via hooks; retired
-        # slots' releases are DEFERRED past the prefix-cache inserts so
-        # harvest-by-reference can still retain a finished request's
-        # prompt pages
-        pending_frees: List[List[int]] = []
+        with self._phase("replay", p.seg):
+            # page bookkeeping rides the SHARED replay via hooks; retired
+            # slots' releases are DEFERRED past the prefix-cache inserts so
+            # harvest-by-reference can still retain a finished request's
+            # prompt pages
+            pending_frees: List[List[int]] = []
 
-        def on_admit(q, s):
-            pgr.install(s, req_pages[q])
+            def on_admit(q, s):
+                pgr.install(s, req_pages[q])
 
-        def on_retire(r, s):
-            r._meter_release()
-            pending_frees.append(pgr.slot_pages[s])
-            pgr.slot_pages[s] = []
+            def on_retire(r, s):
+                r._meter_release()
+                pending_frees.append(pgr.slot_pages[s])
+                pgr.slot_pages[s] = []
 
-        admitted, first_tokens, finished, new_tokens, eos_stops = \
-            self._replay_segment(picked, toks, aq, aslot, steps, n,
-                                 on_admit, on_retire,
-                                 chunk_marker=p.chunk_marker,
-                                 acc=acc, spec_stats=spec_stats, dig=dig)
-        if p.chunk_marker is not None:
-            chunk_steps = int(np.sum(np.asarray(aq[:steps])
-                                     >= p.chunk_marker))
-            if chunk_steps:
-                _metrics.counter("serving.prefill_chunks").inc(chunk_steps)
-        if p.sp:
-            # completed admissions retire their carry-over entries; a
-            # prefill the budget cut mid-flight re-registers below
-            for r in picked:
-                self._sp_inflight.pop(r.rid, None)
-        if p.sp and int(sp_pf) >= 0:
-            # r23 multi-segment prefill: keep the mid-flight request's
-            # reservation AND meter open (its pages hold landed KV
-            # rows), record the resident row count, and requeue it at
-            # the head so the next dispatch continues the slab stream;
-            # everything behind it releases and requeues as usual
-            j = int(sp_pfq)
-            assert qadm == j + 1, (
-                f"sp prefill progress desynced: pf row {j}, qadm {qadm}")
-            self._sp_inflight[picked[j].rid] = {
-                "pages": req_pages[j],
-                "resident": pre_lens_l[j] + int(sp_pfo)}
-            for k in range(qadm, n):
-                picked[k].admit_time = 0.0
-                picked[k]._meter_release()
-                pgr.release_pages(req_pages[k])
-            _flight.record("sp_carryover", rid=picked[j].rid,
-                           resident=pre_lens_l[j] + int(sp_pfo),
-                           total=len(p.full_prompts[j]))
-            self._queue[:0] = picked[j:]
-        elif qadm < n:
-            # step budget ran out before every picked request found a
-            # slot: release the reservations and requeue FCFS
-            for j in range(qadm, n):
-                picked[j].admit_time = 0.0
-                picked[j]._meter_release()
-                pgr.release_pages(req_pages[j])
-            self._queue[:0] = picked[qadm:]
+            (admitted, first_tokens, first_steps, finished, new_tokens,
+             eos_stops) = self._replay_segment(
+                 picked, toks, aq, aslot, steps, n, on_admit, on_retire,
+                 chunk_marker=p.chunk_marker, acc=acc,
+                 spec_stats=spec_stats, dig=dig)
+            if p.chunk_marker is not None:
+                chunk_steps = int(np.sum(np.asarray(aq[:steps])
+                                         >= p.chunk_marker))
+                if chunk_steps:
+                    _metrics.counter("serving.prefill_chunks").inc(chunk_steps)
+            if p.sp:
+                # completed admissions retire their carry-over entries; a
+                # prefill the budget cut mid-flight re-registers below
+                for r in picked:
+                    self._sp_inflight.pop(r.rid, None)
+            if p.sp and int(sp_pf) >= 0:
+                # r23 multi-segment prefill: keep the mid-flight request's
+                # reservation AND meter open (its pages hold landed KV
+                # rows), record the resident row count, and requeue it at
+                # the head so the next dispatch continues the slab stream;
+                # everything behind it releases and requeues as usual
+                j = int(sp_pfq)
+                assert qadm == j + 1, (
+                    f"sp prefill progress desynced: pf row {j}, qadm {qadm}")
+                self._sp_inflight[picked[j].rid] = {
+                    "pages": req_pages[j],
+                    "resident": pre_lens_l[j] + int(sp_pfo)}
+                for k in range(qadm, n):
+                    picked[k].admit_time = 0.0
+                    picked[k]._meter_release()
+                    pgr.release_pages(req_pages[k])
+                _flight.record("sp_carryover", rid=picked[j].rid,
+                               resident=pre_lens_l[j] + int(sp_pfo),
+                               total=len(p.full_prompts[j]))
+                self._queue[:0] = picked[j:]
+            elif qadm < n:
+                # step budget ran out before every picked request found a
+                # slot: release the reservations and requeue FCFS
+                for j in range(qadm, n):
+                    picked[j].admit_time = 0.0
+                    picked[j]._meter_release()
+                    pgr.release_pages(req_pages[j])
+                self._queue[:0] = picked[qadm:]
 
-        # prefix-cache population: harvest BY REFERENCE — retain the
-        # admitted request's prompt-spanning pages (zero row copies; the
-        # cache and the slot share physical pages from this moment)
-        if prefix_cache is not None:
-            last_admit = {}                # slot -> its latest admit event
-            for st in range(steps):
-                q = int(aq[st])
-                if q < n:
-                    last_admit[int(aslot[st])] = q
-            for s, q in last_admit.items():
-                fp = p.full_prompts[q]     # the span actually prefilled
-                plen_b = prefix_cache.round_down(len(fp))
-                if plen_b > pre_lens_l[q]:
-                    prefix_cache.insert(fp[:plen_b],
-                                        req_pages[q][:plen_b // psz])
-        for pages in pending_frees:
-            pgr.release_pages(pages)
-        pgr._gauges()
+            # prefix-cache population: harvest BY REFERENCE — retain the
+            # admitted request's prompt-spanning pages (zero row copies; the
+            # cache and the slot share physical pages from this moment)
+            if prefix_cache is not None:
+                last_admit = {}                # slot -> its latest admit event
+                for st in range(steps):
+                    q = int(aq[st])
+                    if q < n:
+                        last_admit[int(aslot[st])] = q
+                for s, q in last_admit.items():
+                    fp = p.full_prompts[q]     # the span actually prefilled
+                    plen_b = prefix_cache.round_down(len(fp))
+                    if plen_b > pre_lens_l[q]:
+                        prefix_cache.insert(fp[:plen_b],
+                                            req_pages[q][:plen_b // psz])
+            for pages in pending_frees:
+                pgr.release_pages(pages)
+        with self._phase("telemetry", p.seg):
+            pgr._gauges()
 
-        if spec_stats is not None:
-            self._spec_telemetry(spec_stats)
-        self._segment_telemetry(steps, admitted, finished, eos_stops,
-                                new_tokens, max(0, n - qadm))
+            if spec_stats is not None:
+                self._spec_telemetry(spec_stats)
+            self._segment_telemetry(steps, admitted, finished, eos_stops,
+                                    new_tokens, max(0, n - qadm))
         out = {"steps": steps, "admitted": admitted,
-               "first_tokens": first_tokens, "finished": finished,
+               "first_tokens": first_tokens,
+               "first_token_steps": first_steps, "finished": finished,
                "tokens": new_tokens}
         if spec_stats is not None:
             out["spec"] = spec_stats

@@ -266,6 +266,20 @@ def init_params(cfg: LlamaConfig, key: Optional[jax.Array] = None,
 # Forward
 # ---------------------------------------------------------------------------
 
+def scoped(name: str):
+    """Decorator: trace ``fn`` under ``jax.named_scope(name)`` — the name a
+    device trace shows its ops under (``profiler._xplane.SCOPES`` lists
+    them). A fresh scope per call: the context manager jax hands out keeps
+    state, so one instance shared by every caller is not re-entrant."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def traced(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return traced
+    return deco
+
+
 def _rms_norm(x, w, eps):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -363,6 +377,7 @@ def layer_params(params, cfg: "LlamaConfig"):
     return out
 
 
+@scoped("qkv")
 def _qkv_proj(cfg: LlamaConfig, x, lp, positions=None):
     """rms → q/k/v projections → rope at ``positions`` (default 0..S-1).
     Returns q [B,S,nH,D] and UNREPEATED k/v [B,S,Hkv,D] — the single
@@ -395,18 +410,20 @@ def _qkv_proj(cfg: LlamaConfig, x, lp, positions=None):
 def _layer_qkv(cfg: LlamaConfig, x, lp):
     """Pre-attention half of a block: rms → qkv projections → rope → GQA."""
     q, k, v = _qkv_proj(cfg, x, lp)
-    if cfg.num_kv_heads != cfg.num_heads:  # GQA: repeat kv heads
-        rep = cfg.num_heads // cfg.num_kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    # heads are mp-sharded (follows from wq's output sharding); under SP
-    # the seq dim STAYS sep-sharded — pinning it replicated here would
-    # all-gather the sequence right before the ring attention
-    seq_ax = "sep" if cfg.sequence_parallel else None
-    q = wsc(q, P(("dp", "sharding"), seq_ax, "mp", None))
+    with jax.named_scope("qkv"):
+        if cfg.num_kv_heads != cfg.num_heads:  # GQA: repeat kv heads
+            rep = cfg.num_heads // cfg.num_kv_heads
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        # heads are mp-sharded (follows from wq's output sharding); under
+        # SP the seq dim STAYS sep-sharded — pinning it replicated here
+        # would all-gather the sequence right before the ring attention
+        seq_ax = "sep" if cfg.sequence_parallel else None
+        q = wsc(q, P(("dp", "sharding"), seq_ax, "mp", None))
     return q, k, v
 
 
+@scoped("post")
 def _layer_post(cfg: LlamaConfig, x, attn, lp):
     """Post-attention half: output projection, residual, mlp."""
     B, S, H = x.shape
@@ -429,6 +446,7 @@ def _layer_post(cfg: LlamaConfig, x, attn, lp):
     return x
 
 
+@scoped("attention")
 def _attention(cfg: LlamaConfig, q, k, v):
     """Training attention dispatch: under sequence parallelism with a >1
     'sep' axis the seq dim is SHARDED, so attention must be the RING
@@ -460,8 +478,9 @@ def forward_hidden(params: Dict[str, jax.Array], tokens: jax.Array,
                    cfg: LlamaConfig) -> jax.Array:
     """Final hidden states (post ln_f). tokens: [B, S] int32 → [B, S, H]."""
     dt = cfg.dtype
-    x = params["embed"].astype(dt)[tokens]
-    x = wsc(x, _act_spec(cfg))
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]
+        x = wsc(x, _act_spec(cfg))
 
     layer_weights = {k: params[k] for k in layer_keys(cfg)}
 
@@ -494,15 +513,17 @@ def forward_hidden(params: Dict[str, jax.Array], tokens: jax.Array,
         for i in range(cfg.num_layers):
             x, _ = body(x, {k: w[i] for k, w in layer_weights.items()})
 
-    return _rms_norm(x, params["ln_f"], cfg.rms_eps)
+    with jax.named_scope("head"):     # the head's own norm
+        return _rms_norm(x, params["ln_f"], cfg.rms_eps)
 
 
 def forward(params: Dict[str, jax.Array], tokens: jax.Array,
             cfg: LlamaConfig) -> jax.Array:
     """Logits for next-token prediction. tokens: [B, S] int32 → [B, S, V]."""
     x = forward_hidden(params, tokens, cfg)
-    logits = x @ params["lm_head"].astype(cfg.dtype)
-    return wsc(logits, P(("dp", "sharding"), None, "mp"))
+    with jax.named_scope("head"):
+        logits = x @ params["lm_head"].astype(cfg.dtype)
+        return wsc(logits, P(("dp", "sharding"), None, "mp"))
 
 
 def _nll_sum(logits, targets, weights) -> jax.Array:
@@ -636,7 +657,14 @@ def loss_fn(params, tokens, labels, cfg: LlamaConfig) -> jax.Array:
 
     ``labels`` is the same [B, S] token stream; the shift happens HERE:
     position i's logits are scored against labels[i+1]."""
-    h = forward_hidden(params, tokens, cfg)
+    return _head_ce(params, forward_hidden(params, tokens, cfg), labels, cfg)
+
+
+@scoped("head_ce")
+def _head_ce(params, h, labels, cfg: LlamaConfig) -> jax.Array:
+    """``loss_fn``'s tail: lm_head matmul + mean next-token nll over the
+    final hidden states ``h`` [B, S, H] (the three forms its docstring
+    describes)."""
     dt = cfg.dtype
     B, S, _ = h.shape
     nc = cfg.ce_chunks
@@ -745,24 +773,26 @@ def adamw_update(params, grads, opt_state, lr=3e-4, beta1=0.9, beta2=0.95,
 def train_step(params, opt_state, tokens, labels, cfg: LlamaConfig,
                lr=3e-4):
     """One full step: fwd, bwd, global-norm clip, AdamW. Pure → jit it."""
-    if cfg.bf16_grads:
-        # differentiate w.r.t. the bf16 view: the fwd is numerically
-        # IDENTICAL (every use site casts to cfg.dtype anyway) but the
-        # cotangents stay bf16 — no [params]-sized fp32 convert pass
-        diff = jax.tree.map(lambda p: p.astype(cfg.dtype)
-                            if p.dtype == jnp.float32 else p, params)
+    with jax.named_scope("loss"):       # forward and backward
+        diff = params
+        if cfg.bf16_grads:
+            # differentiate w.r.t. the bf16 view: the fwd is numerically
+            # IDENTICAL (every use site casts to cfg.dtype anyway) but the
+            # cotangents stay bf16 — no [params]-sized fp32 convert pass
+            diff = jax.tree.map(lambda p: p.astype(cfg.dtype)
+                                if p.dtype == jnp.float32 else p, params)
         loss, grads = jax.value_and_grad(loss_fn)(diff, tokens, labels, cfg)
-    else:
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, labels, cfg)
-    # HybridParallelClipGrad analog: global norm across ALL parallel axes
-    # (GSPMD reduces over every mesh axis for free)
-    gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                         for g in jax.tree.leaves(grads)))
-    clip = jnp.minimum(1.0, 1.0 / (gnorm + 1e-6))
-    # keep each leaf's dtype: a strong fp32 scalar would PROMOTE bf16
-    # grads to fp32 (defeating bf16_grads' traffic contract)
-    grads = jax.tree.map(lambda g: g * clip.astype(g.dtype), grads)
-    params, opt_state = adamw_update(params, grads, opt_state, lr=lr)
+    with jax.named_scope("grad_clip"):
+        # HybridParallelClipGrad analog: global norm across ALL parallel
+        # axes (GSPMD reduces over every mesh axis for free)
+        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                             for g in jax.tree.leaves(grads)))
+        clip = jnp.minimum(1.0, 1.0 / (gnorm + 1e-6))
+        # keep each leaf's dtype: a strong fp32 scalar would PROMOTE bf16
+        # grads to fp32 (defeating bf16_grads' traffic contract)
+        grads = jax.tree.map(lambda g: g * clip.astype(g.dtype), grads)
+    with jax.named_scope("optimizer"):
+        params, opt_state = adamw_update(params, grads, opt_state, lr=lr)
     return params, opt_state, loss
 
 
@@ -796,6 +826,9 @@ def make_sharded_train_step(cfg: LlamaConfig, mesh, lr=3e-4):
     data_sh = NamedSharding(mesh, P(("dp", "sharding"), None))
 
     step = functools.partial(train_step, cfg=cfg, lr=lr)
+    # a partial has no name of its own; this one is the program's name in
+    # a device trace (jit_train_step)
+    step.__name__ = "train_step"
     return jax.jit(
         step,
         in_shardings=(ps, opt_sh, data_sh, data_sh),
@@ -839,6 +872,7 @@ def paged_pool_spec() -> P:
     return P(None, None, None, "mp", None)
 
 
+@scoped("attention")
 def _cache_attention(cfg: LlamaConfig, q, kc, vc, positions):
     """q [B,T,nH,D] against the UNREPEATED cache kc/vc [B,Smax,Hkv,D].
     GQA contracts via a grouped einsum (q reshaped [B,T,Hkv,rep,D]) —
@@ -896,6 +930,7 @@ def _tick_fused_active(cfg: LlamaConfig) -> bool:
             and cfg.head_dim % 8 == 0 and cfg.head_dim % 2 == 0)
 
 
+@scoped("qkv")
 def _decode_qkv(cfg: LlamaConfig, x, lp, pos_b):
     """T=1 fused-tick variant of ``_qkv_proj``: the rmsnorm chain is one
     Pallas op and the q/k rope chains (cos/sin/slice/concat per head,
@@ -923,6 +958,7 @@ def _decode_qkv(cfg: LlamaConfig, x, lp, pos_b):
     return q, k, v
 
 
+@scoped("post")
 def _decode_post(cfg: LlamaConfig, x, attn, lp):
     """T=1 fused-tick variant of ``_layer_post``: the attention-residual
     add and the mlp pre-norm are ONE kernel emitting both the new
@@ -957,7 +993,8 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
     depth, matching the training path's scan_layers design."""
     dt = cfg.dtype
     B, T = tokens.shape
-    x = params["embed"].astype(dt)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]
     ragged = getattr(pos, "ndim", 0) == 1
     if ragged and T != 1:
         raise ValueError("per-slot pos requires single-token decode (T=1)")
@@ -984,16 +1021,17 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
     def body(x, per_layer):
         lp, kc, vc = per_layer
         q, k_new, v_new = _qkv(x, lp)
-        if ragged:
-            # scatter each slot's new row at its own position
-            rows = jnp.arange(B)
-            kc = kc.at[rows, pos].set(k_new[:, 0].astype(kc.dtype))
-            vc = vc.at[rows, pos].set(v_new[:, 0].astype(vc.dtype))
-        else:
-            kc = jax.lax.dynamic_update_slice(
-                kc, k_new.astype(kc.dtype), (0, pos, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v_new.astype(vc.dtype), (0, pos, 0, 0))
+        with jax.named_scope("kv_write"):
+            if ragged:
+                # scatter each slot's new row at its own position
+                rows = jnp.arange(B)
+                kc = kc.at[rows, pos].set(k_new[:, 0].astype(kc.dtype))
+                vc = vc.at[rows, pos].set(v_new[:, 0].astype(vc.dtype))
+            else:
+                kc = jax.lax.dynamic_update_slice(
+                    kc, k_new.astype(kc.dtype), (0, pos, 0, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    vc, v_new.astype(vc.dtype), (0, pos, 0, 0))
         attn = _cache_attention(cfg, q, kc, vc, positions)
         return _post(x, attn, lp), (kc, vc)
 
@@ -1014,23 +1052,42 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
         for i in range(cfg.num_layers):
             lp = {kk: layer_weights[kk][i] for kk in layer_weights}
             q, k_new, v_new = _qkv(x, lp)
-            if ragged:
-                rows = jnp.arange(B)
-                kcs = kcs.at[i, rows, pos].set(k_new[:, 0].astype(kcs.dtype))
-                vcs = vcs.at[i, rows, pos].set(v_new[:, 0].astype(vcs.dtype))
-            else:
-                kcs = jax.lax.dynamic_update_slice(
-                    kcs, k_new[None].astype(kcs.dtype), (i, 0, pos, 0, 0))
-                vcs = jax.lax.dynamic_update_slice(
-                    vcs, v_new[None].astype(vcs.dtype), (i, 0, pos, 0, 0))
+            with jax.named_scope("kv_write"):
+                if ragged:
+                    rows = jnp.arange(B)
+                    kcs = kcs.at[i, rows, pos].set(
+                        k_new[:, 0].astype(kcs.dtype))
+                    vcs = vcs.at[i, rows, pos].set(
+                        v_new[:, 0].astype(vcs.dtype))
+                else:
+                    kcs = jax.lax.dynamic_update_slice(
+                        kcs, k_new[None].astype(kcs.dtype),
+                        (i, 0, pos, 0, 0))
+                    vcs = jax.lax.dynamic_update_slice(
+                        vcs, v_new[None].astype(vcs.dtype),
+                        (i, 0, pos, 0, 0))
             attn = _cache_attention(cfg, q, kcs[i], vcs[i], positions)
             x = _post(x, attn, lp)
+    logits = _head_logits(cfg, params, x, fused_tick, logit_pos)
+    return logits, {"k": kcs, "v": vcs}
+
+
+@scoped("head")
+def _head_logits(cfg: LlamaConfig, params, x, fused_tick: bool,
+                 logit_pos=None, logits_all: bool = False):
+    """The decode paths' head: final norm, then the lm_head matmul on the
+    last position's row — or the row at ``logit_pos`` (traced scalar, or
+    [B] per row), or EVERY position with ``logits_all``. fp32 logits."""
+    dt = cfg.dtype
+    B = x.shape[0]
     if fused_tick:
         from ..ops.pallas.tick_fusion import fused_rms_norm
 
         x = fused_rms_norm(x[:, 0], params["ln_f"], cfg.rms_eps)[:, None]
     else:
         x = _rms_norm(x, params["ln_f"], cfg.rms_eps)
+    if logits_all:
+        return _mm(x, params, "lm_head", dt).astype(jnp.float32)  # [B,T,V]
     if logit_pos is None:
         last = x[:, -1]
     elif getattr(logit_pos, "ndim", 0) == 1:
@@ -1038,10 +1095,10 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
     else:
         last = jax.lax.dynamic_index_in_dim(x, logit_pos, axis=1,
                                             keepdims=False)
-    logits = _mm(last, params, "lm_head", dt)  # [B, V]
-    return logits.astype(jnp.float32), {"k": kcs, "v": vcs}
+    return _mm(last, params, "lm_head", dt).astype(jnp.float32)  # [B, V]
 
 
+@scoped("attention")
 def _paged_attention(cfg: LlamaConfig, q, kc, vc, page_table, positions,
                      ks=None, vs=None):
     """Attention over a paged KV pool. q [B,T,nH,D]; kc/vc
@@ -1103,7 +1160,8 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
     B, T = tokens.shape
     psz = pool["k"].shape[2]
     max_pages = page_table.shape[1]
-    x = params["embed"].astype(dt)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]
     # r23 (ISSUE 18): sequence-parallel prefill slabs arrive with the
     # slab's ROW axis as the batch axis ([sp, C] — one C-token chunk of
     # the same prompt per row). When the live mesh carries an 'sp' axis
@@ -1163,13 +1221,14 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
         else:
             (lp, kc, vc), ks, vs = per_layer, None, None
         q, k_new, v_new = _qkv(x, lp)
-        if quant:
-            k_new, k_sc = quantize_kv_rows(k_new, kc.dtype)
-            v_new, v_sc = quantize_kv_rows(v_new, vc.dtype)
-            ks = ks.at[phys, prow].set(k_sc)
-            vs = vs.at[phys, prow].set(v_sc)
-        kc = kc.at[phys, prow].set(k_new.astype(kc.dtype))
-        vc = vc.at[phys, prow].set(v_new.astype(vc.dtype))
+        with jax.named_scope("kv_write"):
+            if quant:
+                k_new, k_sc = quantize_kv_rows(k_new, kc.dtype)
+                v_new, v_sc = quantize_kv_rows(v_new, vc.dtype)
+                ks = ks.at[phys, prow].set(k_sc)
+                vs = vs.at[phys, prow].set(v_sc)
+            kc = kc.at[phys, prow].set(k_new.astype(kc.dtype))
+            vc = vc.at[phys, prow].set(v_new.astype(vc.dtype))
         attn = _paged_attention(cfg, q, kc, vc, page_table, positions,
                                 ks=ks, vs=vs)
         planes = (kc, vc, ks, vs) if quant else (kc, vc)
@@ -1186,15 +1245,16 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
         for i in range(cfg.num_layers):
             lp = {kk: layer_weights[kk][i] for kk in layer_weights}
             q, k_new, v_new = _qkv(x, lp)
-            if quant:
-                k_new, k_sc = quantize_kv_rows(k_new, planes["k"].dtype)
-                v_new, v_sc = quantize_kv_rows(v_new, planes["v"].dtype)
-                planes["ks"] = planes["ks"].at[i, phys, prow].set(k_sc)
-                planes["vs"] = planes["vs"].at[i, phys, prow].set(v_sc)
-            planes["k"] = planes["k"].at[i, phys, prow].set(
-                k_new.astype(planes["k"].dtype))
-            planes["v"] = planes["v"].at[i, phys, prow].set(
-                v_new.astype(planes["v"].dtype))
+            with jax.named_scope("kv_write"):
+                if quant:
+                    k_new, k_sc = quantize_kv_rows(k_new, planes["k"].dtype)
+                    v_new, v_sc = quantize_kv_rows(v_new, planes["v"].dtype)
+                    planes["ks"] = planes["ks"].at[i, phys, prow].set(k_sc)
+                    planes["vs"] = planes["vs"].at[i, phys, prow].set(v_sc)
+                planes["k"] = planes["k"].at[i, phys, prow].set(
+                    k_new.astype(planes["k"].dtype))
+                planes["v"] = planes["v"].at[i, phys, prow].set(
+                    v_new.astype(planes["v"].dtype))
             attn = _paged_attention(
                 cfg, q, planes["k"][i], planes["v"][i], page_table,
                 positions,
@@ -1202,24 +1262,8 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
                 vs=planes["vs"][i] if quant else None)
             x = _post(x, attn, lp)
         new_pool = planes
-    if fused_tick:
-        from ..ops.pallas.tick_fusion import fused_rms_norm
-
-        x = fused_rms_norm(x[:, 0], params["ln_f"], cfg.rms_eps)[:, None]
-    else:
-        x = _rms_norm(x, params["ln_f"], cfg.rms_eps)
-    if logits_all:
-        logits = _mm(x, params, "lm_head", dt)        # [B, T, V]
-        return logits.astype(jnp.float32), new_pool
-    if logit_pos is None:
-        last = x[:, -1]
-    elif getattr(logit_pos, "ndim", 0) == 1:
-        last = x[jnp.arange(B), logit_pos]
-    else:
-        last = jax.lax.dynamic_index_in_dim(x, logit_pos, axis=1,
-                                            keepdims=False)
-    logits = _mm(last, params, "lm_head", dt)  # [B, V]
-    return logits.astype(jnp.float32), new_pool
+    return (_head_logits(cfg, params, x, fused_tick, logit_pos, logits_all),
+            new_pool)
 
 
 def init_paged_pool(cfg: LlamaConfig, num_pages: int, page_size: int,
@@ -1295,6 +1339,7 @@ def sample_filter_logits(logits, temperature, top_k=0, top_p=1.0):
     return logits
 
 
+@scoped("sample")
 def _sample(logits, temperature, top_k, key, top_p=1.0):
     if temperature == 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -8,9 +8,9 @@ Three layers over signals the framework already holds on the host:
   rank-tagged snapshot merge for multi-process runs (launcher log-dir
   aggregation; no collective required).
 * :mod:`tracing` — per-request lifecycle spans (enqueue → admit →
-  prefill → decode → finish) and per-step training spans emitted through
-  ``profiler._hooks`` so they land in the SAME chrome-trace/xplane
-  timeline as op dispatch and serving segments.
+  prefill → decode → finish), journeys and scaling timelines emitted
+  through ``profiler._hooks`` so they land in the SAME chrome trace as
+  op dispatch and the serve loop's segment spans.
 * :mod:`flight` — a bounded ring of recent structured events
   (admissions, backpressure, EOS, recompiles, loss-scale skips,
   prefix-cache hits/evictions) dumpable on demand, on exception, or
@@ -88,7 +88,7 @@ from .metrics import (counter, enabled, gauge, histogram, merge_log_dir,
 from .perf import PerfMonitor, serving_ledger
 from .replay import replay_serve
 from .slo import Objective, SLOMonitor
-from .tracing import emit_journey_trace, emit_request_trace, span, step_span
+from .tracing import emit_journey_trace, emit_request_trace, span
 
 __all__ = [
     "metrics", "tracing", "flight", "slo", "perf", "exporter", "journal",
@@ -99,7 +99,7 @@ __all__ = [
     "gauge", "histogram", "percentile", "registry", "snapshot",
     "render_prometheus", "merge_snapshots", "merge_log_dir",
     "write_snapshot", "reset", "set_enabled", "enabled", "span",
-    "step_span", "emit_request_trace", "emit_journey_trace", "FLIGHT",
+    "emit_request_trace", "emit_journey_trace", "FLIGHT",
     "dump_on_exception",
     "install_compile_listener", "Objective", "SLOMonitor", "PerfMonitor",
     "serving_ledger", "OpsServer", "Journal", "read_journal",

@@ -1,38 +1,41 @@
-"""Per-request and per-step spans over ``profiler._hooks``.
+"""Request, journey and scaling lifecycles as spans over ``profiler._hooks``.
 
-The host-span channel already exists (r7: the scheduler emits one
-``serving.segment`` span per segment and ``paddle.profiler`` merges host
-spans into its chrome-trace/xplane timeline). This module generalises it
-into a request/step vocabulary WITHOUT adding a clock source or a sync:
+``profiler._hooks.span`` is the program's one span primitive: it writes a
+``jax.profiler.TraceAnnotation`` into a live jax trace (the xplane's host
+plane, on the device planes' clock) and reports to every recording
+``paddle.profiler.Profiler``. The serve loop's spans are there, where the
+work happens: ``serving.sched.ingest``, ``serving.segment`` and its phases
+``serving.segment.{pick,inputs,launch,fetch,replay,telemetry}``
+(inference/scheduler.py, inference/serving.py).
+
+This module adds what can only be stamped AFTER the fact, from
+``perf_counter`` stamps the serve loop already took, with no clock source
+or sync of its own. These reach collectors only (a TraceAnnotation cannot
+be back-dated); ``Profiler.export_chrome_tracing`` places them on the
+trace's clock by a measured offset:
 
 * **Request traces** — the scheduler stamps each ``Request``'s lifecycle
   (arrival → admit → first-token → finish) at the per-segment
-  ``allowed_sync`` fetch; ``emit_request_trace`` replays those host
-  stamps as spans (``request.queue_wait`` / ``request.prefill`` /
+  ``allowed_sync`` fetch; ``emit_request_trace`` replays those stamps as
+  spans (``request.queue_wait`` / ``request.prefill`` /
   ``request.decode`` / ``request.e2e``) so a p99 outlier decomposes in
   the same trace viewer that shows segments and op dispatch.
-* **Step spans** — ``step_span("hapi.train_batch")`` wraps a training
-  step; free when no profiler records (two clock reads).
+* **Journeys** and **scaling timelines** — the same, from journal records
+  (``emit_journey_trace``, ``emit_scaling_trace``).
 
-Everything is emit-only: when no ``Profiler`` is active, ``emit`` walks
-an empty collector list and ``_hooks.active()`` short-circuits the
-request replay entirely.
+Everything is emit-only: when no ``Profiler`` is active the replays
+return at their first line.
 """
 
 from __future__ import annotations
 
 from ..profiler import _hooks
 
-__all__ = ["span", "step_span", "emit_request_trace",
-           "emit_journey_trace", "emit_scaling_trace", "active"]
+__all__ = ["span", "emit_request_trace", "emit_journey_trace",
+           "emit_scaling_trace", "active"]
 
 span = _hooks.span          # re-export: the RAII host span
 active = _hooks.active
-
-
-def step_span(name: str = "train.step"):
-    """RAII span for one training step (kind='train')."""
-    return _hooks.span(name, kind="train")
 
 
 def _ns(t_s: float) -> int:

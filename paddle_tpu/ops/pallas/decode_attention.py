@@ -227,6 +227,7 @@ def ragged_decode_attention(q, kc, vc, pos, scale=None, block_k: int = 0,
     )
     return pl.pallas_call(
         _make_kernel(nH, Hkv, D, block_k, n_blocks, quant=quant),
+        name="ragged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nH, D), q.dtype),
         interpret=interpret or (FORCE_INTERPRET and not _on_tpu()),

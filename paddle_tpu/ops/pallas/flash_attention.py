@@ -242,6 +242,7 @@ def _pallas_flash_attention(q, k, v, is_causal=False, scale=None,
                      jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32)]
     result = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(b * h, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -439,6 +440,7 @@ def _pallas_flash_bwd(q, k, v, do, out, lse, is_causal, scale=None,
     dq = pl.pallas_call(
         _make_pallas_bwd_dq(block_q, block_k, is_causal, scale, off,
                             seq_k=sk),
+        name="flash_attention_bwd_dq",
         grid=(b * h, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -455,6 +457,7 @@ def _pallas_flash_bwd(q, k, v, do, out, lse, is_causal, scale=None,
     dk, dv = pl.pallas_call(
         _make_pallas_bwd_dkv(block_q, block_k, is_causal, off,
                              seq_q=sq),
+        name="flash_attention_bwd_dkv",
         grid=(b * h, sk // block_k),
         in_specs=[
             pl.BlockSpec((1, sq, d), lambda i, j: (i, 0, 0)),
@@ -643,6 +646,7 @@ def _pallas_flash_fwd_packed(q, k, v, is_causal, scale=None):
 
     out, lse = pl.pallas_call(
         _make_packed_fwd(S, d, hp, is_causal, q_cst=scale * _LOG2_E),
+        name="flash_attention_packed_fwd",
         grid=(b, G),
         in_specs=[blk, blk, blk],
         out_specs=[blk, pl.BlockSpec((1, 1, hp, S),
@@ -679,6 +683,7 @@ def _pallas_flash_bwd_packed(q, k, v, do, out, lse, is_causal, scale=None):
     dq, dk, dv = pl.pallas_call(
         _make_packed_bwd(S, d, hp, is_causal, scale,
                          q_cst=scale * _LOG2_E),
+        name="flash_attention_packed_bwd",
         grid=(b, G),
         in_specs=[blk, blk, blk, blk, blk, lse_blk],
         out_specs=[blk, blk, blk],

@@ -85,6 +85,7 @@ def head_dx_softmax(logits, m, scale, wt, bm: int = 1408, bk: int = 512):
     c_pad = jnp.zeros((grid_m * bm, 1), jnp.float32).at[:M, 0].set(scale)
     out = pl.pallas_call(
         _dx_kernel,
+        name="head_dx_softmax",
         grid=(grid_m, V // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, v: (i, v)),

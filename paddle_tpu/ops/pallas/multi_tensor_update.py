@@ -284,12 +284,13 @@ def _lamb_kernel(has_master: bool):
     return kernel
 
 
-def _run(kernel, plan: FlatPlan, bufs: Sequence[jax.Array],
+def _run(kind: str, kernel, plan: FlatPlan, bufs: Sequence[jax.Array],
          hyper: jax.Array, out_structs, aliases: Dict[int, int]):
     br = plan.block_rows
     block = lambda: pl.BlockSpec((br, FlatPlan.LANES), lambda i: (i, 0))
     return pl.pallas_call(
         kernel,
+        name="apply_flat_update_" + kind,
         grid=(plan.grid,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [block() for _ in bufs],
@@ -347,13 +348,13 @@ def apply_flat_update(kind: str, plan: FlatPlan,
     sbufs = {k: plan.pack([s[k] for s in svals]) for k in state_keys}
 
     if kind == "sgd":
-        out = _run(_sgd_kernel, plan, [pbuf, gbuf], hvec,
+        out = _run(kind, _sgd_kernel, plan, [pbuf, gbuf], hvec,
                    [_struct(pbuf)], {1: 0})
         new_p_buf, new_sbufs = out[0], {}
     elif kind == "momentum":
         hvec = hvec.at[3].set(np.float32(hyper["momentum"]))
-        out = _run(_momentum_kernel(bool(hyper.get("nesterov"))), plan,
-                   [pbuf, gbuf, sbufs["velocity"]], hvec,
+        out = _run(kind, _momentum_kernel(bool(hyper.get("nesterov"))),
+                   plan, [pbuf, gbuf, sbufs["velocity"]], hvec,
                    [_struct(pbuf), _struct(sbufs["velocity"])],
                    {1: 0, 3: 1})
         new_p_buf, new_sbufs = out[0], {"velocity": out[1]}
@@ -371,8 +372,8 @@ def apply_flat_update(kind: str, plan: FlatPlan,
             bufs.append(sbufs["master"])
             outs.append(_struct(sbufs["master"]))
             aliases[5] = 3
-        out = _run(_adam_kernel(has_master, decoupled), plan, bufs, hvec,
-                   outs, aliases)
+        out = _run(kind, _adam_kernel(has_master, decoupled), plan, bufs,
+                   hvec, outs, aliases)
         new_p_buf = out[0]
         new_sbufs = {"moment1": out[1], "moment2": out[2]}
         if has_master:
@@ -387,7 +388,7 @@ def apply_flat_update(kind: str, plan: FlatPlan,
         aliases = {3: 0, 4: 1}
         if has_master:
             bufs.append(sbufs["master"])
-        out = _run(_lamb_kernel(has_master), plan, bufs, hvec, outs,
+        out = _run(kind, _lamb_kernel(has_master), plan, bufs, hvec, outs,
                    aliases)
         m_new, v_new, r = out
         # flat epilogue: per-tensor trust ratios via segment-sum — every

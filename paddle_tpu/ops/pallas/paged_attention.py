@@ -193,6 +193,7 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
     )
     out = pl.pallas_call(
         _make_kernel(nH, Hkv, D, Tq, psz, max_pages),
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv * Tq * rep, D), q.dtype),
         interpret=interpret or (FORCE_INTERPRET and not _on_tpu()),

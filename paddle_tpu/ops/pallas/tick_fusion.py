@@ -87,6 +87,7 @@ def fused_rms_norm(x, w, eps: float):
     B, H = x.shape
     return pl.pallas_call(
         _rms_kernel(float(eps)),
+        name="fused_rms_norm",
         out_shape=jax.ShapeDtypeStruct((B, H), x.dtype),
         interpret=_interp(),
     )(x, jnp.broadcast_to(w, (1, H)))
@@ -110,6 +111,7 @@ def fused_add_rms_norm(x, y, w, eps: float):
     B, H = x.shape
     return pl.pallas_call(
         _add_rms_kernel(float(eps)),
+        name="fused_add_rms_norm",
         out_shape=[jax.ShapeDtypeStruct((B, H), x.dtype),
                    jax.ShapeDtypeStruct((B, H), x.dtype)],
         interpret=_interp(),
@@ -161,6 +163,7 @@ def fused_rope_qk(zq, zk, pos, head_dim: int, theta: float):
     return pl.pallas_call(
         _rope_qk_kernel(head_dim, Hq // head_dim, Hk // head_dim,
                         float(theta)),
+        name="fused_rope_qk",
         out_shape=[jax.ShapeDtypeStruct((B, Hq), zq.dtype),
                    jax.ShapeDtypeStruct((B, Hk), zk.dtype)],
         interpret=_interp(),
@@ -214,6 +217,7 @@ def quant_matmul(x, w, scale, block_n: int = 0, interpret: bool = False):
                          f"with quant_matmul_active")
     return pl.pallas_call(
         _quant_matmul_kernel,
+        name="quant_matmul",
         grid=(N // block_n,),
         in_specs=[
             pl.BlockSpec((B, K), lambda j: (0, 0)),

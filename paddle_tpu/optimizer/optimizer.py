@@ -372,12 +372,14 @@ class Optimizer:
 
             @functools.partial(jax.jit, donate_argnums=(0, 2))
             def fused(pvals, gvals, svals, evals, lr_, step_):
-                gvals = [g.astype(p.dtype) if g.dtype != p.dtype else g
-                         for p, g in zip(pvals, gvals)]
-                if l2 and decay_in_grad:
-                    gvals = [g + l2 * p for p, g in zip(pvals, gvals)]
-                return opt.apply_updates(pvals, gvals, svals, evals,
-                                         opt._static_evals, lr_, step_)
+                # the same scope name as llama.train_step's update
+                with jax.named_scope("optimizer"):
+                    gvals = [g.astype(p.dtype) if g.dtype != p.dtype else g
+                             for p, g in zip(pvals, gvals)]
+                    if l2 and decay_in_grad:
+                        gvals = [g + l2 * p for p, g in zip(pvals, gvals)]
+                    return opt.apply_updates(pvals, gvals, svals, evals,
+                                             opt._static_evals, lr_, step_)
 
             self._jit_update = fused
 

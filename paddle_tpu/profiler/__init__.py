@@ -95,8 +95,7 @@ class Profiler:
         recording = self._state in (ProfilerState.RECORD,
                                     ProfilerState.RECORD_AND_RETURN)
         if recording and not self._timer_only:
-            jax.profiler.start_trace(self._log_dir)
-            self._running = True
+            self._start_trace()
         # host spans track the RECORD windows only, matching the device
         # trace (timer_only profilers have no device trace — collect
         # whenever the scheduler says record)
@@ -104,6 +103,18 @@ class Profiler:
             _hooks.COLLECTORS.append(self)
         self._last = time.perf_counter()
         return self
+
+    def _start_trace(self):
+        """Open the jax trace and leave one span in it that carries its
+        own ``perf_counter_ns`` start: the measured offset by which
+        ``export_chrome_tracing`` places spans stamped on that clock."""
+        from . import _hooks
+
+        jax.profiler.start_trace(self._log_dir)
+        self._running = True
+        with _hooks.span("profiler.clock", "profiler",
+                         pc_ns=_hooks.now_ns()):
+            pass
 
     def stop(self):
         from . import _hooks
@@ -138,8 +149,7 @@ class Profiler:
         if self._running and new_state == ProfilerState.CLOSED:
             self.stop()
         elif not self._running and recording:
-            jax.profiler.start_trace(self._log_dir)
-            self._running = True
+            self._start_trace()
 
     def __enter__(self):
         return self.start()
@@ -149,12 +159,19 @@ class Profiler:
         return False
 
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
-                time_unit="ms", views=None):
+                time_unit="ms", views=None, scopes=None):
         """Reference-shaped summary tables (SURVEY §5.1): step overview,
         host operator view (dispatch spans + RecordEvent ranges), and —
         when an xplane trace was captured — the device op-level (XLA
-        modules) and kernel-level (HLO instructions) views with device
-        occupancy. ``views`` selects a subset (SummaryView values)."""
+        modules), kernel-level (HLO opcodes) and scope-level views with
+        device occupancy. The scope view puts every device op under the
+        ``jax.named_scope`` path of its ``op_name`` (``embed``, ``qkv``,
+        ``kv_write``, ``attention``, ``post``, ``head``, ``sample`` under
+        ``segment.admit`` / ``segment.decode``; ``loss`` with ``.bwd``
+        halves, ``grad_clip``, ``optimizer``; a named Pallas kernel is a
+        leaf). A TPU trace carries the names; where a backend's does not
+        (CPU), pass ``scopes=_xplane.scope_map(compiled.as_text())``.
+        ``views`` selects a subset (SummaryView values)."""
         from . import _xplane
 
         import numpy as np
@@ -179,7 +196,7 @@ class Profiler:
                                        self._host_ops))
         if self._running or self._timer_only:
             return
-        tables, _ = _xplane.parse(self._log_dir)
+        tables, _ = _xplane.parse(self._log_dir, scopes=scopes)
         if tables is None:
             return
         if tables["modules"] and wanted(SummaryView.ModelView):
@@ -191,6 +208,9 @@ class Profiler:
         if tables["kernels"] and wanted(SummaryView.KernelView):
             print(_xplane.format_table("Device kernel view (HLO)",
                                        tables["kernels"]))
+            print(_xplane.format_table("Device scope view (named_scope)",
+                                       tables["scopes"], limit=40,
+                                       width=46))
 
     def export_chrome_tracing(self, dir_name: Optional[str] = None,
                               worker_name: Optional[str] = None) -> str:
@@ -204,23 +224,19 @@ class Profiler:
 
         out_dir = dir_name or self._log_dir
         os.makedirs(out_dir, exist_ok=True)
-        _, events = _xplane.parse(self._log_dir)
-        # host spans (perf_counter epoch) and xplane spans (capture
-        # timebase) live on unrelated clocks: zero-base each source so the
-        # viewer shows both tracks from a common origin (alignment is
-        # approximate — the common origin is each source's first event)
-        if events:
-            base = min(e["ts"] for e in events)
-            for e in events:
-                e["ts"] -= base
-        if self._host_spans:
-            hbase = min(s[2] for s in self._host_spans)
-            for name, kind, start_ns, dur_ns in self._host_spans:
-                events.append({
-                    "ph": "X", "name": name, "cat": kind,
-                    "pid": "host", "tid": f"host {kind}",
-                    "ts": (start_ns - hbase) / 1e3, "dur": dur_ns / 1e3,
-                })
+        tables, events = _xplane.parse(self._log_dir)
+        # host spans are stamped on perf_counter, xplane spans on the
+        # trace's clock: place the former by the offset MEASURED on a
+        # trace span that carries its own perf_counter start (this
+        # profiler's ``profiler.clock``). With no xplane (timer_only)
+        # there is one clock and nothing to align.
+        offset = (tables or {}).get("clock_offset_ns") or 0
+        for name, kind, start_ns, dur_ns in self._host_spans:
+            events.append({
+                "ph": "X", "name": name, "cat": kind,
+                "pid": "host", "tid": f"host {kind}",
+                "ts": (start_ns + offset) / 1e3, "dur": dur_ns / 1e3,
+            })
         path = os.path.join(
             out_dir, f"{worker_name or 'worker'}.chrome_trace.json")
         with open(path, "w") as f:
@@ -232,28 +248,25 @@ class Profiler:
 
 
 class RecordEvent:
-    """Named range in the device/host timeline (reference RAII RecordEvent →
-    ``jax.profiler.TraceAnnotation`` for the xplane timeline, plus a host
-    span reported to any recording Profiler for its tables/chrome trace)."""
+    """Named range in the device/host timeline (reference RAII RecordEvent):
+    a ``_hooks.span`` of kind ``range`` — a ``TraceAnnotation`` on the
+    xplane timeline plus a host span reported to any recording Profiler
+    for its tables/chrome trace."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._ann = jax.profiler.TraceAnnotation(name)
-        self._t0 = None
+        self._span = None
 
     def begin(self):
         from . import _hooks
 
-        self._t0 = _hooks.now_ns()
-        self._ann.__enter__()
+        self._span = _hooks.span(self.name, kind="range")
+        self._span.__enter__()
 
     def end(self):
-        from . import _hooks
-
-        self._ann.__exit__(None, None, None)
-        if self._t0 is not None:
-            _hooks.emit(self.name, self._t0, _hooks.now_ns(), kind="range")
-            self._t0 = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
     def __enter__(self):
         self.begin()

@@ -6,12 +6,14 @@ op/kernel summary tables. On TPU the device timeline already exists — XLA
 emits xplane protos into the trace dir — so this module PARSES it
 (``jax.profiler.ProfileData``) instead of re-collecting it:
 
-* ``device_tables``: per-plane aggregation of the "XLA Modules" line
+* ``parse`` -> tables: per-plane aggregation of the "XLA Modules" line
   (program-level spans — the op-level view) and the "XLA Ops" line
-  (HLO-instruction spans — the kernel-level view), plus device occupancy
-  (busy module time / observed wall).
-* ``chrome_events``: the same spans as chrome-trace "X" events, merged with
-  the profiler's host spans into one loadable ``chrome_trace.json``.
+  (HLO-instruction spans — the kernel-level view, and the per-SCOPE view:
+  each op under the ``jax.named_scope`` path of its ``op_name``, PR 25),
+  device occupancy (busy module time / observed wall), and the measured
+  offset between the trace's clock and ``perf_counter_ns``.
+* ``parse`` -> chrome events: the same spans as chrome-trace "X" events,
+  merged with the profiler's host spans into one ``chrome_trace.json``.
 """
 
 from __future__ import annotations
@@ -44,10 +46,63 @@ def _profile_data():
 
 _HLO_RE = re.compile(r"=\s*\S+\s+([a-zA-Z][\w-]*)\(")
 
+# The program's scope vocabulary: every ``jax.named_scope`` name in
+# models/llama.py, inference/serving.py (segment programs) and optimizer/.
+# The per-scope table keeps these components of an op's ``op_name`` path
+# and drops jax's own (jit(..), while, body, cond, branch_N_fun, ...).
+SCOPES = ("embed", "qkv", "kv_write", "attention", "post", "head", "sample",
+          "segment.admit", "segment.decode",
+          "loss", "head_ce", "grad_clip", "optimizer")
+
+
+def scope_key(op_name: str) -> str:
+    """An op's scope from its HLO ``op_name`` metadata:
+    ``jit(segment)/while/body/cond/branch_0_fun/segment.decode/while/body/
+    closed_call/qkv/dot_general`` -> ``segment.decode/qkv``. A backward op
+    (a ``transpose(..)`` component anywhere in the path) gets ``.bwd`` on
+    its innermost scope: ``jit(train_step)/loss/transpose(jvp(post))/mul``
+    -> ``loss/post.bwd``. A named Pallas kernel keeps its name as the
+    leaf: ``.../qkv/fused_rms_norm/pallas_call`` ->
+    ``segment.decode/qkv/fused_rms_norm``."""
+    parts = op_name.split("/")
+    out, bwd = [], False
+    for part in parts:
+        bwd = bwd or part.startswith("transpose(")
+        inner = part.rstrip(")").rsplit("(", 1)[-1]
+        if inner in SCOPES and inner not in out[-1:]:
+            out.append(inner)
+    if not out:
+        return "(no scope)"
+    if bwd:
+        out[-1] += ".bwd"
+    if len(parts) > 1 and parts[-1] == "pallas_call":
+        out.append(parts[-2])
+    return "/".join(out)
+
+
+_META_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?op_name=\"([^\"]*)\"", re.M)
+
+
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """instruction name -> scope, from a compiled program's ``as_text()``:
+    the way to the per-scope table where the trace's op events carry no
+    ``op_name`` of their own (``parse(..., scopes=scope_map(text))``)."""
+    return {name: scope_key(op) for name, op in _META_RE.findall(hlo_text)}
+
 
 def _kernel_key(event_name: str) -> str:
-    """%fusion.3 = f32[..] fusion(...) -> 'fusion' (HLO opcode)."""
-    m = _HLO_RE.search(event_name)
+    """``%fusion.3 = f32[..] fusion(...)`` -> ``fusion`` (HLO opcode); the
+    result type may be a tuple: ``%while.2 = (s32[], f32[8]) while(..)``."""
+    head, eq, rest = event_name.partition(" = ")
+    if eq and rest.startswith("("):     # skip the tuple type to its close
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                rest = "x" + rest[i + 1:]
+                break
+    m = _HLO_RE.search("= " + rest) if eq else None
     if m:
         return m.group(1)
     return event_name.split(" ", 1)[0].lstrip("%")
@@ -58,78 +113,117 @@ def _module_key(name: str) -> str:
     return name.split("(", 1)[0]
 
 
-def parse(log_dir: str):
+# control-flow ops: their events span the ops of their bodies, which the
+# line lists too — counting both would bill the same device time twice
+_CONTAINERS = frozenset({"while", "conditional", "call"})
+
+
+def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
     """Returns (tables, chrome_events) or (None, []) when no xplane exists.
 
     tables = {
       'modules': {name: [calls, total_ns]},
       'kernels': {opcode: [calls, total_ns]},
+      'scopes':  {scope: [calls, total_ns]},   # see scope_key
       'occupancy': float | None,   # busy/wall over the device plane
       'device': plane name,
-    }"""
+      'clock_offset_ns': int | None,  # trace clock - perf_counter_ns
+    }
+
+    An op's scope comes from its ``op_name``: on a TPU trace the event
+    metadata's ``tf_op`` stat (``_xplane_pb.op_names``); else ``scopes``
+    (instruction name -> scope: ``scope_map`` of the compiled program's
+    ``as_text()``); else ``(no scope)``. The CPU backend writes no
+    ``XLA Ops`` line: there a host-thread event with an ``hlo_op`` stat
+    is an op. ``clock_offset_ns`` is measured on a host-plane span that
+    carries its own ``perf_counter_ns`` start as the stat ``pc_ns``
+    (``serving.segment``, ``profiler.clock``)."""
     path = latest_xplane(log_dir)
     if path is None:
         return None, []
+    from ._xplane_pb import op_names
+
     pd = _profile_data().from_file(path)
-    tables = None
+    ops_meta = op_names(path)
+    scopes = scopes or {}
+    tables = {"modules": {}, "kernels": {}, "scopes": {}, "occupancy": None,
+              "device": "", "clock_offset_ns": None}
     chrome: List[dict] = []
     occs: List[float] = []
+
+    def add(table: str, key: str, ns: float) -> None:
+        # accumulate across planes (multi-chip: every device plane runs
+        # the same modules — counts and times must SUM, not overwrite)
+        cur = tables[table].setdefault(key, [0, 0.0])
+        cur[0] += 1
+        cur[1] += ns
+
+    def add_op(plane, tid, ev, instr: str, opcode: str) -> None:
+        if opcode in _CONTAINERS:   # its body's ops are events of their own
+            return
+        add("kernels", opcode, ev.duration_ns)
+        op = ops_meta.get(ev.name)
+        add("scopes", scope_key(op) if op
+            else scopes.get(instr, "(no scope)"), ev.duration_ns)
+        chrome.append({"ph": "X", "name": opcode, "cat": "XLA Ops",
+                       "pid": plane.name, "tid": tid,
+                       "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3})
+
     for plane in pd.planes:
         is_device = plane.name.startswith("/device:")
         for line in plane.lines:
-            if line.name not in ("XLA Modules", "XLA Ops"):
-                continue
-            agg: Dict[str, List[float]] = {}
-            lo, hi, busy = None, None, 0.0
-            for ev in line.events:
-                key = (_module_key(ev.name) if line.name == "XLA Modules"
-                       else _kernel_key(ev.name))
-                a = agg.setdefault(key, [0, 0.0])
-                a[0] += 1
-                a[1] += ev.duration_ns
-                if line.name == "XLA Modules":
+            if line.name == "XLA Ops":
+                for ev in line.events:
+                    add_op(plane, line.name, ev,
+                           ev.name.split(" ", 1)[0].lstrip("%"),
+                           _kernel_key(ev.name))
+            elif line.name == "XLA Modules":
+                lo, hi, busy = None, None, 0.0
+                for ev in line.events:
+                    key = _module_key(ev.name)
+                    add("modules", key, ev.duration_ns)
+                    end = ev.start_ns + ev.duration_ns
                     lo = ev.start_ns if lo is None else min(lo, ev.start_ns)
-                    hi = (ev.start_ns + ev.duration_ns if hi is None
-                          else max(hi, ev.start_ns + ev.duration_ns))
+                    hi = end if hi is None else max(hi, end)
                     busy += ev.duration_ns
-                chrome.append({
-                    "ph": "X", "name": key, "cat": line.name,
-                    "pid": plane.name, "tid": line.name,
-                    "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3,
-                })
-            if not agg:
-                continue
-            if tables is None:
-                tables = {"modules": {}, "kernels": {}, "occupancy": None,
-                          "device": plane.name if is_device else ""}
-            # accumulate across planes (multi-chip: every device plane runs
-            # the same modules — counts and times must SUM, not overwrite)
-            dst = tables["modules"] if line.name == "XLA Modules" \
-                else tables["kernels"]
-            for k, (c, ns) in agg.items():
-                cur = dst.setdefault(k, [0, 0.0])
-                cur[0] += c
-                cur[1] += ns
-            if line.name == "XLA Modules" and is_device:
-                if lo is not None and hi > lo:
-                    occs.append(busy / (hi - lo))
-                tables["device"] = plane.name
-    if tables is not None and occs:
+                    chrome.append({
+                        "ph": "X", "name": key, "cat": line.name,
+                        "pid": plane.name, "tid": line.name,
+                        "ts": ev.start_ns / 1e3, "dur": ev.duration_ns / 1e3,
+                    })
+                if is_device and lo is not None:
+                    if hi > lo:
+                        occs.append(busy / (hi - lo))
+                    tables["device"] = plane.name
+            elif not is_device:
+                for ev in line.events:
+                    # (the in-tree fallback reader's events have no stats)
+                    st = dict(getattr(ev, "stats", None) or ())
+                    if "hlo_op" in st:      # the CPU backend's op events
+                        add_op(plane, line.name, ev, str(st["hlo_op"]),
+                               str(st["hlo_op"]).split(".", 1)[0])
+                    elif "pc_ns" in st and \
+                            tables["clock_offset_ns"] is None:
+                        tables["clock_offset_ns"] = (
+                            int(ev.start_ns) - int(st["pc_ns"]))
+    if occs:
         tables["occupancy"] = sum(occs) / len(occs)  # mean over planes
     return tables, chrome
 
 
 def format_table(title: str, rows: Dict[str, List[float]],
-                 total_ns: Optional[float] = None, limit: int = 20) -> str:
+                 total_ns: Optional[float] = None, limit: int = 20,
+                 width: int = 34) -> str:
     """name / calls / total / avg / share — the reference's summary shape."""
     if not rows:
         return ""
     total = total_ns or sum(v[1] for v in rows.values()) or 1.0
-    out = [f"\n--- {title} " + "-" * max(1, 58 - len(title)),
-           f"{'name':<34} {'calls':>6} {'total(ms)':>10} {'avg(us)':>9} "
-           f"{'share':>6}"]
-    for name, (calls, ns) in sorted(rows.items(), key=lambda kv: -kv[1][1])[:limit]:
-        out.append(f"{name[:34]:<34} {calls:>6} {ns / 1e6:>10.3f} "
+    out = [f"\n--- {title} " + "-" * max(1, 24 + width - len(title)),
+           f"{'name':<{width}} {'calls':>6} {'total(ms)':>10} "
+           f"{'avg(us)':>9} {'share':>6}"]
+    for name, (calls, ns) in sorted(rows.items(),
+                                    key=lambda kv: -kv[1][1])[:limit]:
+        out.append(f"{name[:width]:<{width}} {calls:>6} {ns / 1e6:>10.3f} "
                    f"{ns / calls / 1e3:>9.1f} {ns / total:>6.1%}")
     return "\n".join(out)
 

@@ -20,13 +20,25 @@ tensorflow/protobuf dependency. Field numbers from
 The facade classes mirror the ``ProfileData`` attribute surface the
 table builders consume (``planes[].lines[].events[]`` with ``name`` /
 ``start_ns`` / ``duration_ns``).
+
+``op_names`` reads what ``ProfileData`` does not expose at all: the stats
+of an event's METADATA. On a TPU trace that is where an op's HLO
+``op_name`` lives (stat ``tf_op``, as ``<op_name>:<op type>``; seen by
+hand on a v5e trace, PR 25) — the ``jax.named_scope`` path the per-scope
+table is built from::
+
+    XPlane   { map<int64, XStatMetadata> stat_metadata = 5; }
+    XEventMetadata { repeated XStat stats = 5; }
+    XStat    { int64 metadata_id = 1; string str_value = 5;
+               uint64 ref_value = 7; }   # ref: a stat_metadata id
+    XStatMetadata { int64 id = 1; string name = 2; }
 """
 
 from __future__ import annotations
 
 from typing import Dict, Iterator, List, Tuple
 
-__all__ = ["XSpaceData"]
+__all__ = ["XSpaceData", "op_names"]
 
 
 def _read_varint(buf: bytes, i: int) -> Tuple[int, int]:
@@ -90,6 +102,14 @@ class _Plane:
         self.lines = lines
 
 
+def _map_value(buf: bytes) -> bytes:
+    """The value (field 2) of one protobuf map entry."""
+    for field, _wt, val in _fields(buf):
+        if field == 2:
+            return val
+    return b""
+
+
 def _parse_event_metadata(buf: bytes) -> Tuple[int, str]:
     mid, name = 0, ""
     for field, _wt, val in _fields(buf):
@@ -141,10 +161,8 @@ def _parse_plane(buf: bytes) -> _Plane:
             line_bufs.append(val)
         elif field == 4:
             # map entry { key = 1 (varint), value = 2 (XEventMetadata) }
-            for f2, _w2, v2 in _fields(val):
-                if f2 == 2:
-                    mid, mname = _parse_event_metadata(v2)
-                    meta[mid] = mname
+            mid, mname = _parse_event_metadata(_map_value(val))
+            meta[mid] = mname
     return _Plane(name, [_parse_line(b, meta) for b in line_bufs])
 
 
@@ -161,3 +179,45 @@ class XSpaceData:
         planes = [_parse_plane(val) for field, _wt, val in _fields(buf)
                   if field == 1]
         return cls(planes)
+
+
+def op_names(path: str, stat: str = "tf_op") -> Dict[str, str]:
+    """event name -> the ``stat`` of its metadata (the HLO ``op_name``,
+    without the ``:<type>`` tail), over the device planes of one xplane
+    file. Events whose metadata has no such stat are left out."""
+    with open(path, "rb") as f:
+        buf = f.read()
+    out: Dict[str, str] = {}
+    for field, _wt, plane in _fields(buf):
+        if field != 1:
+            continue
+        name, stat_names, metas = "", {}, []
+        for f2, _w2, v2 in _fields(plane):
+            if f2 == 2:
+                name = v2.decode("utf-8", "replace")
+            elif f2 == 4:
+                metas.append(_map_value(v2))
+            elif f2 == 5:
+                sid, sname = _parse_event_metadata(_map_value(v2))
+                stat_names[sid] = sname
+        if not name.startswith("/device:"):
+            continue
+        for meta in metas:
+            ev_name, op = "", None
+            for f3, _w3, v3 in _fields(meta):
+                if f3 == 2:
+                    ev_name = v3.decode("utf-8", "replace")
+                elif f3 == 5:
+                    sid, val = 0, None
+                    for f4, _w4, v4 in _fields(v3):
+                        if f4 == 1:
+                            sid = v4
+                        elif f4 == 5:
+                            val = v4.decode("utf-8", "replace")
+                        elif f4 == 7:
+                            val = stat_names.get(v4)
+                    if stat_names.get(sid) == stat and val:
+                        op = val
+            if op:
+                out[ev_name] = op.rsplit(":", 1)[0]
+    return out
