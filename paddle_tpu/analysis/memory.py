@@ -404,7 +404,7 @@ def hot_transients(report: MemoryReport, *, frac_bytes: float = 0.33,
 
 def page_bytes_for(cfg, page_size: int, quant: Optional[str] = None) -> int:
     """Bytes one pool page occupies across all layers: K + V planes
-    [L, page_size, Hkv, D] (+ the fp32 ``ks``/``vs`` scale planes under
+    [L, page_size, Hkv*D] (+ the fp32 ``ks``/``vs`` scale planes under
     per-page quantization) — the §3f page arithmetic, byte-priced."""
     if quant is not None:
         from ..quantization.serving import quant_dtype

@@ -5,7 +5,7 @@ The HBM page pool (inference/paged_kv.py) is the capacity that actually
 bounds a prefix-cache working set: before this module a cold prefix
 evicted under page pressure was simply GONE, and the next request of
 that tenant re-paid its whole prefill. Host RAM is order-10x HBM on a
-serving host, and the paged layout's fixed ``[page_size, Hkv, D]`` tiles
+serving host, and the paged layout's fixed ``[page_size, Hkv*D]`` tiles
 are exactly the unit a capacity tier wants to move — so this module adds
 the tier: cold prefix pages demote to pinned host buffers and promote
 back on a hit, multiplying effective prefix-cache capacity by

@@ -4,7 +4,7 @@ Reference counterpart: vLLM's PagedAttention block manager and the
 Ragged Paged Attention TPU serving design (PAPERS.md #1): instead of one
 contiguous ``[slots, max_len]`` KV block per engine — provisioned for
 the WORST-CASE length of every slot — KV rows live in one flat pool of
-fixed-size pages (``[L, num_pages, page_size, Hkv, D]``) and each slot's
+fixed-size pages (``[L, num_pages, page_size, Hkv*D]``) and each slot's
 sequence is the ordered list of pages its page table names. Three
 consequences, each a serving-memory property the contiguous layout
 cannot express:
@@ -169,9 +169,12 @@ class PagedKVCache:
     """Device page pool + per-slot page tables over a ``PageAllocator``.
 
     The serving engine's paged memory: ``pool`` is the flat
-    ``[L, num_pages, page_size, Hkv, D]`` K/V store and ``page_table``
+    ``[L, num_pages, page_size, Hkv*D]`` K/V store and ``page_table``
     the device-side ``[slots, max_pages]`` int32 map the segment program
-    consumes (both donated through the program; the host keeps
+    consumes (both donated through the program and updated in place;
+    everything here addresses pages on axis 1 and never looks past the
+    page axis, so the row layout is ``llama.init_paged_pool``'s alone;
+    the host keeps
     ``slot_pages`` mirrors for bookkeeping). ``max_pages`` bounds ONE
     slot's virtual length (``max_pages * page_size`` = the engine's
     ``max_len`` contract); ``num_pages`` bounds the POOL — sizing it
@@ -202,9 +205,10 @@ class PagedKVCache:
         self.page_table = jnp.zeros((self.slots, self.max_pages),
                                     jnp.int32)
         if mesh is not None:
-            # tensor-parallel serving (r12): the pool shards on the
-            # kv-head dim over 'mp' (llama.paged_pool_spec — the dim the
-            # column-parallel wk/wv projections produce sharded); page
+            # tensor-parallel serving (r12): the pool's flat Hkv*D minor
+            # dim shards over 'mp' by whole kv heads
+            # (llama.paged_pool_spec — the dim the column-parallel
+            # wk/wv projections produce sharded); page
             # TABLES stay replicated int32 indices, so every page-id
             # operation in this class (reserve/install/fork/COW) is
             # untouched — paging is mesh-oblivious by construction

@@ -127,6 +127,21 @@ def _subkeys_rows(raw, n: int):
     return jax.vmap(lambda k: jax.random.split(k, n))(raw)
 
 
+def _one_of(pred, a, b, st):
+    """``lax.cond(pred, a, b, st)`` for a loop state that holds the paged
+    pool: two loops of zero or one trip, ``a`` when ``pred`` and ``b``
+    when not. Same branches, same result — but a ``while`` carries its
+    state in place, where a ``conditional`` gives each branch a copy of
+    every buffer the branch updates: around ``forward_with_pages`` that
+    is the whole K and V pool once a step and twice per LAYER inside the
+    branch (PERF.md, PR 26). ``tests/test_chip_compile.py
+    ::test_paged_segment_holds_pool_once`` keeps the compiled program
+    free of them."""
+    n = jnp.asarray(pred).astype(jnp.int32)
+    st = jax.lax.fori_loop(0, n, lambda _, s: a(s), st)
+    return jax.lax.fori_loop(0, 1 - n, lambda _, s: b(s), st)
+
+
 @llama.scoped("sample")
 def _greedy(logits):
     """Every program's greedy token pick, under its scope's name."""
@@ -615,10 +630,21 @@ class ServingEngine:
         self.aot_warmup_s: Optional[float] = None
         self.first_token_s: Optional[float] = None
         self.aot_key_seconds: Dict[tuple, float] = {}
+        self.aot_key_temp_bytes: Dict[tuple, int] = {}
         self.prog_key_hits: Dict[tuple, int] = {}
         from ..jit import register_compiled_cache
 
         register_compiled_cache(self)  # analysis.recompile introspection
+
+    @property
+    def pool_bytes(self) -> Dict[str, int]:
+        """Bytes of each plane of the paged pool as it lies on the device
+        ({} on a contiguous engine): the yardstick for ``aot_warmup``'s
+        ``temp_bytes`` — a paged segment program that holds the pool
+        once has temporaries under ``pool_bytes["k"]``."""
+        if not self.paged:
+            return {}
+        return {n: int(a.nbytes) for n, a in self.pager.pool.items()}
 
     def _slot_vec(self):
         """A zeroed [slots] int32 slot-state vector, replicated over the
@@ -1020,9 +1046,14 @@ class ServingEngine:
         backend compiles (``analysis.recompile.enforce_zero_compiles``
         is the budget; ``analysis.coverage`` diffs enumerated vs used).
 
-        Returns {family: {"keys": n, "seconds": s}} and stamps
-        ``aot_warmup_s`` (the cold-start split's first half). Requires
-        an idle engine (no live slots, queue, or in-flight segment).
+        Returns {family: {"keys": n, "seconds": s, "temp_bytes": b}} and
+        stamps ``aot_warmup_s`` (the cold-start split's first half).
+        ``b`` is the largest ``memory_analysis().temp_size_in_bytes``
+        among the family's compiled programs — what a program needs
+        BESIDE its arguments; against ``pool_bytes`` it says whether a
+        paged segment holds the pool once (temporaries under one pool
+        plane). Requires an idle engine (no live slots, queue, or
+        in-flight segment).
 
         Pass the serve loop's ``prefix_cache`` when one will be
         attached: a tiered cache's D2H-stage/H2D-restore transfer
@@ -1044,7 +1075,10 @@ class ServingEngine:
                 self._aot_run_key(fam, key)
                 self.aot_key_seconds[key] = time.perf_counter() - tk
             report[fam] = {"keys": len(by_family[fam]),
-                           "seconds": time.perf_counter() - tf}
+                           "seconds": time.perf_counter() - tf,
+                           "temp_bytes": max(
+                               (self.aot_key_temp_bytes[k]
+                                for k in by_family[fam]), default=0)}
         # prewarm the between-segment eager singletons so the first
         # preempt / slot reset after warmup compiles nothing: the
         # preempt freeze scatter (device-operand index — one program
@@ -1078,7 +1112,9 @@ class ServingEngine:
         _metrics.gauge("serving.aot_warmup_s").set(self.aot_warmup_s)
         _metrics.gauge("serving.program_space_keys").set(n_keys)
         _flight.record("aot_warmup", seconds=round(self.aot_warmup_s, 4),
-                       keys=n_keys, families=sorted(report))
+                       keys=n_keys, families=sorted(report),
+                       temp_bytes={f: r["temp_bytes"]
+                                   for f, r in report.items()})
         return report
 
     def _aot_run_key(self, family: str, key: tuple) -> None:
@@ -1086,27 +1122,40 @@ class ServingEngine:
         empty dummy state. The dummy calls mirror the dispatch paths'
         real argument shapes exactly (that is what makes the jit cache
         hit later); donated state arrays thread through so the engine
-        stays consistent."""
+        stays consistent. ``aot_key_temp_bytes[key]`` keeps what the
+        compiled program says it needs besides its arguments."""
         i32 = jnp.int32
         cfg = self.cfg
         L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+
+        def run(prog, *args):
+            # lowering the call's own arguments compiles the executable
+            # the call below then finds: one compile, and its
+            # memory_analysis() (a segment program that holds the paged
+            # pool once has temporaries far under one pool plane)
+            mem = prog.lower(*args).compile().memory_analysis()
+            self.aot_key_temp_bytes[key] = int(
+                getattr(mem, "temp_size_in_bytes", 0))
+            return prog(*args)
+
         with _mesh_scope(self.mesh):
             if family == "admit":
                 bucket, nb = key
-                out = self._admit_prog(bucket, nb)(
-                    self.params, self._cache,
+                out = run(
+                    self._admit_prog(bucket, nb), self.params, self._cache,
                     jnp.zeros((nb, bucket), i32), jnp.ones((nb,), i32),
                     jnp.arange(nb, dtype=i32), self._pos, self._nxt,
                     self._rem, jnp.zeros((nb,), i32))
                 self._cache = out[0]
             elif family == "decode":
-                out = self._decode_prog(self.params, self._cache,
-                                        self._pos, self._nxt, self._rem)
+                out = run(self._decode_prog, self.params, self._cache,
+                          self._pos, self._nxt, self._rem)
                 (self._cache, self._pos, self._nxt, self._rem) = out[:4]
             elif family == "drain":
                 _, n_pad, p_max, g_max = key
-                out = self._drain_prog(n_pad, p_max, g_max)(
-                    self.params, self._cache,
+                out = run(
+                    self._drain_prog(n_pad, p_max, g_max), self.params,
+                    self._cache,
                     jnp.zeros((n_pad, p_max), i32),
                     jnp.ones((n_pad,), i32), jnp.zeros((n_pad,), i32),
                     i32(0))
@@ -1114,7 +1163,8 @@ class ServingEngine:
             elif family == "seg":
                 _, n_pad, s_max, pre_max, steps = key
                 kdt = self._cache["k"].dtype
-                out = self._segment_prog(n_pad, s_max, pre_max, steps)(
+                out = run(
+                    self._segment_prog(n_pad, s_max, pre_max, steps),
                     self.params, self._cache, self._pos, self._nxt,
                     self._rem, jnp.zeros((n_pad, s_max), i32),
                     jnp.ones((n_pad,), i32), jnp.zeros((n_pad,), i32),
@@ -1132,8 +1182,8 @@ class ServingEngine:
                         if family == "cseg"
                         else self._paged_segment_prog(n_pad, s_max, steps))
                 pgr = self.pager
-                out = prog(
-                    self.params, pgr.pool, pgr.page_table, self._pos,
+                out = run(
+                    prog, self.params, pgr.pool, pgr.page_table, self._pos,
                     self._nxt, self._rem, jnp.zeros((n_pad, s_max), i32),
                     jnp.ones((n_pad,), i32), jnp.zeros((n_pad,), i32),
                     jnp.zeros((n_pad,), i32),
@@ -1143,7 +1193,8 @@ class ServingEngine:
             elif family == "spseg":
                 _, n_pad, s_max, C, _sp, steps = key
                 pgr = self.pager
-                out = self._sp_segment_prog(n_pad, s_max, C, steps)(
+                out = run(
+                    self._sp_segment_prog(n_pad, s_max, C, steps),
                     self.params, pgr.pool, pgr.page_table, self._pos,
                     self._nxt, self._rem, jnp.zeros((n_pad, s_max), i32),
                     jnp.ones((n_pad,), i32), jnp.zeros((n_pad,), i32),
@@ -1160,7 +1211,8 @@ class ServingEngine:
                 if self.chunked:
                     C = self._prefill_chunk_for(s_max)
                     s_max = -(-s_max // C) * C
-                out = self._spec_segment_prog(n_pad, steps)(
+                out = run(
+                    self._spec_segment_prog(n_pad, steps),
                     self.params, pgr.pool, pgr.page_table, self._pos,
                     self._nxt, self._rem, self._hist, self._hstart, rng,
                     jnp.zeros((n_pad, s_max), i32),
@@ -2101,7 +2153,13 @@ class ServingEngine:
         event log, same one-dispatch/one-fetch contract — three changes:
 
         * slot KV state is (pool, page_table) instead of a contiguous
-          block; both are donated and updated in place;
+          block; both are donated and updated in place: the pool rides
+          the while_loop's carry through ``_one_of`` (never a
+          ``lax.cond``) into ``llama.forward_with_pages``, whose layer
+          loop carries it too, so the compiled program holds ONE copy of
+          each plane and moves no more of it than the rows a step
+          writes and the pages its attention reads (``aot_warmup``'s
+          ``temp_bytes`` against ``pool_bytes`` says so);
         * the admit branch INSTALLS the request's host-reserved page
           list into the slot's table row and prefills the suffix
           directly into those pages (``llama.forward_with_pages``) —
@@ -2262,7 +2320,7 @@ class ServingEngine:
 
             def body(st):
                 can_admit = (st["qidx"] < n_real) & jnp.any(st["rem"] == 0)
-                st = jax.lax.cond(can_admit, admit, decode, st)
+                st = _one_of(can_admit, admit, decode, st)
                 st["step"] = st["step"] + 1
                 return st
 
@@ -2447,7 +2505,7 @@ class ServingEngine:
                              & _startable(st))
                 do_chunk = ((pf_active | can_start)
                             & ((st["phase"] == 0) | ~live_any))
-                st = jax.lax.cond(do_chunk, chunk, decode, st)
+                st = _one_of(do_chunk, chunk, decode, st)
                 st["step"] = st["step"] + 1
                 return st
 
@@ -2624,7 +2682,7 @@ class ServingEngine:
                              & _startable(st))
                 do_chunk = ((pf_active | can_start)
                             & ((st["phase"] == 0) | ~live_any))
-                st = jax.lax.cond(do_chunk, chunk, decode, st)
+                st = _one_of(do_chunk, chunk, decode, st)
                 st["step"] = st["step"] + 1
                 return st
 
@@ -2908,7 +2966,7 @@ class ServingEngine:
                              & _startable(st))
                 do_chunk = ((pf_active | can_start)
                             & ((st["phase"] == 0) | ~live_any))
-                st = jax.lax.cond(do_chunk, chunk, verify, st)
+                st = _one_of(do_chunk, chunk, verify, st)
                 st["step"] = st["step"] + 1
                 return st
 

@@ -865,11 +865,13 @@ def kv_cache_spec() -> P:
 
 
 def paged_pool_spec() -> P:
-    """PartitionSpec of the paged KV pool [L, pages, page, Hkv, D]:
-    same rule as ``kv_cache_spec`` — pages replicate, heads shard, so
-    the host-side page tables (pure int32 indices) stay replicated and
-    page bookkeeping is unchanged under 'mp'."""
-    return P(None, None, None, "mp", None)
+    """PartitionSpec of the paged KV pool [L, pages, page, Hkv*D]: same
+    rule as ``kv_cache_spec`` — pages replicate, heads shard (a head's D
+    lanes are contiguous in the flat minor dim, so splitting it over
+    'mp' splits whole kv heads), so the host-side page tables (pure
+    int32 indices) stay replicated and page bookkeeping is unchanged
+    under 'mp'."""
+    return P(None, None, None, "mp")
 
 
 @scoped("attention")
@@ -1099,42 +1101,44 @@ def _head_logits(cfg: LlamaConfig, params, x, fused_tick: bool,
 
 
 @scoped("attention")
-def _paged_attention(cfg: LlamaConfig, q, kc, vc, page_table, positions,
-                     ks=None, vs=None):
-    """Attention over a paged KV pool. q [B,T,nH,D]; kc/vc
-    [P, page_size, Hkv, D] (the flat pool); page_table [B, max_pages];
-    ``positions`` [B, T] absolute query positions (row t of slot b at
-    ``positions[b, t]``, keys [0, positions[b, t]] visible). Dispatches
-    to the unified page-indirect Pallas kernel when the shape tiles
-    (per-slot KV reads scale with position); the fallback gathers the
-    slot's pages into a contiguous window and reuses the dense
-    formulation — identical math, CPU/tier-1's path.
-
-    ``ks``/``vs`` ([P, page_size] fp32, optional): a QUANTIZED pool's
-    per-page scale planes — the gather fetches the scale rows with
-    their pages and dequantizes the [B, W] window before the dense
-    contraction (so HBM→gather traffic carried the narrow dtype; the
-    slot-contiguous kernel analog dequantizes in VMEM —
-    ops/pallas/decode_attention.py)."""
+def _paged_attention(cfg: LlamaConfig, q, planes, layer, page_table,
+                     positions):
+    """Attention over layer ``layer`` of a paged KV pool. q [B,T,nH,D];
+    ``planes``: the WHOLE pool as it lies ({"k","v"} [L, P, page_size,
+    Hkv*D], plus a quantized pool's fp32 scale planes "ks"/"vs" [L, P,
+    page_size]); ``layer``: int32 scalar, static or traced;
+    page_table [B, max_pages]; ``positions`` [B, T] absolute query
+    positions (row t of slot b at ``positions[b, t]``, keys [0,
+    positions[b, t]] visible). Dispatches to the unified page-indirect
+    Pallas kernel when the shape tiles: the kernel indexes the pool by
+    (layer, page), so per-slot KV reads scale with position and no
+    layer is ever sliced out of the pool. The fallback (CPU/tier-1, the
+    quantized pool, meshes) gathers ``pool[layer, page_table]`` — the
+    slot's pages — and reshapes the GATHERED window for the dense
+    formulation, identical math; a quantized pool's scale rows are
+    fetched with their pages and the [B, W] window dequantized before
+    the contraction (so HBM→gather traffic carried the narrow dtype)."""
     from ..ops.pallas.paged_attention import (paged_attention_active,
                                               ragged_paged_attention)
 
     B, T = q.shape[:2]
-    psz = kc.shape[1]
-    if ks is None and paged_attention_active(psz, cfg.num_heads,
-                                             cfg.num_kv_heads, cfg.head_dim):
-        return ragged_paged_attention(q, kc, vc, page_table,
-                                      positions[:, 0])
+    kp, vp = planes["k"], planes["v"]
+    psz = kp.shape[2]
+    if "ks" not in planes and paged_attention_active(
+            psz, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim):
+        return ragged_paged_attention(q, kp, vp, page_table,
+                                      positions[:, 0], layer=layer)
     dt = q.dtype
-    W = page_table.shape[1] * psz
-    gk = kc[page_table]
-    gv = vc[page_table]
-    if ks is not None:
-        gk = gk.astype(dt) * ks[page_table][..., None, None].astype(dt)
-        gv = gv.astype(dt) * vs[page_table][..., None, None].astype(dt)
-    gk = gk.reshape(B, W, kc.shape[2], kc.shape[3])
-    gv = gv.reshape(B, W, vc.shape[2], vc.shape[3])
-    return _dense_cache_attention(cfg, q, gk, gv, positions)
+    gk = kp[layer, page_table]               # [B, max_pages, psz, Hkv*D]
+    gv = vp[layer, page_table]
+    if "ks" in planes:
+        gk = gk.astype(dt) * planes["ks"][layer, page_table][
+            ..., None].astype(dt)
+        gv = gv.astype(dt) * planes["vs"][layer, page_table][
+            ..., None].astype(dt)
+    window = (B, page_table.shape[1] * psz, cfg.num_kv_heads, cfg.head_dim)
+    return _dense_cache_attention(cfg, q, gk.reshape(window),
+                                  gv.reshape(window), positions)
 
 
 def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
@@ -1145,17 +1149,26 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
     per row (``pos``: [B] int32 — every slot at its OWN base position:
     T == 1 is a ragged decode tick, T > 1 a prefill chunk at context
     offset ``pos[b]``). ``pool``: {"k","v"} [L, num_pages, page_size,
-    Hkv, D] flat page pools; ``page_table``: [B, max_pages] int32 —
-    virtual page slot j of row b is physical page ``page_table[b, j]``.
-    K/V rows scatter page-indirectly at their positions; ``live``
-    ([B] bool, optional) routes retired slots' writes to the reserved
-    trash page 0 instead (a frozen slot must never write a page the
-    allocator may have handed to someone else), as do positions past
-    the table. Returns (logits [B, V], updated pool) — or, with
-    ``logits_all=True``, logits at EVERY query position ([B, T, V]): the
-    speculative verify tick scores all K+1 drafted positions from the
-    same single weight stream (SCALING §3j), so the lm_head matmul runs
-    over the whole chunk instead of one gathered row."""
+    Hkv*D] flat page pools (``init_paged_pool``); ``page_table``:
+    [B, max_pages] int32 — virtual page slot j of row b is physical
+    page ``page_table[b, j]``.
+
+    The pool is ONE buffer per plane all the way through: the layer
+    loop (a rolled ``lax.scan`` over the stacked weights and the layer
+    index, or the unrolled loop of ``scan_layers=False``) CARRIES the
+    planes, each layer scatters its new K/V rows in place at
+    ``[layer, phys, prow]`` and attention reads the pool where it lies
+    (``_paged_attention``). No layer's pool is sliced out, stacked back
+    or reshaped, so a program that donates the pool holds it once.
+    ``live`` ([B] bool, optional) routes retired slots' writes to the
+    reserved trash page 0 instead (a frozen slot must never write a
+    page the allocator may have handed to someone else), as do
+    positions past the table. Returns (logits [B, V], updated pool) —
+    or, with ``logits_all=True``, logits at EVERY query position
+    ([B, T, V]): the speculative verify tick scores all K+1 drafted
+    positions from the same single weight stream (SCALING §3j), so the
+    lm_head matmul runs over the whole chunk instead of one gathered
+    row."""
     dt = cfg.dtype
     B, T = tokens.shape
     psz = pool["k"].shape[2]
@@ -1199,77 +1212,54 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
     # quantized pool: K/V pages carry a narrow dtype plus per-page fp32
     # scale planes (one scale per cache row — see init_paged_pool); new
     # rows quantize at write time and their scales land at the SAME
-    # [phys, prow] coordinates, so trash-page routing, COW and spill
-    # stay dtype-oblivious
+    # [layer, phys, prow] coordinates, so trash-page routing, COW and
+    # spill stay dtype-oblivious
     quant = "ks" in pool
     if quant:
         from ..quantization.serving import quantize_kv_rows
 
     fused_tick = T == 1 and _tick_fused_active(cfg)
 
-    def _qkv(x, lp):
-        return (_decode_qkv(cfg, x, lp, pos) if fused_tick
-                else _qkv_proj(cfg, x, lp, positions))
-
-    def _post(x, attn, lp):
-        return (_decode_post(cfg, x, attn, lp) if fused_tick
-                else _layer_post(cfg, x, attn, lp))
-
-    def body(x, per_layer):
-        if quant:
-            lp, kc, vc, ks, vs = per_layer
-        else:
-            (lp, kc, vc), ks, vs = per_layer, None, None
-        q, k_new, v_new = _qkv(x, lp)
+    def layer(x, planes, lp, i):
+        q, k_new, v_new = (_decode_qkv(cfg, x, lp, pos) if fused_tick
+                           else _qkv_proj(cfg, x, lp, positions))
         with jax.named_scope("kv_write"):
+            rows = {"k": k_new, "v": v_new}
             if quant:
-                k_new, k_sc = quantize_kv_rows(k_new, kc.dtype)
-                v_new, v_sc = quantize_kv_rows(v_new, vc.dtype)
-                ks = ks.at[phys, prow].set(k_sc)
-                vs = vs.at[phys, prow].set(v_sc)
-            kc = kc.at[phys, prow].set(k_new.astype(kc.dtype))
-            vc = vc.at[phys, prow].set(v_new.astype(vc.dtype))
-        attn = _paged_attention(cfg, q, kc, vc, page_table, positions,
-                                ks=ks, vs=vs)
-        planes = (kc, vc, ks, vs) if quant else (kc, vc)
-        return _post(x, attn, lp), planes
+                for n in ("k", "v"):
+                    rows[n], rows[n + "s"] = quantize_kv_rows(
+                        rows[n], planes[n].dtype)
+            # [B, T, Hkv, D] rows land as [B, T, Hkv*D], scales as [B, T]
+            planes = {n: a.at[i, phys, prow].set(
+                rows[n].reshape((B, T) + a.shape[3:]).astype(a.dtype))
+                for n, a in planes.items()}
+        attn = _paged_attention(cfg, q, planes, i, page_table, positions)
+        return (_decode_post(cfg, x, attn, lp) if fused_tick
+                else _layer_post(cfg, x, attn, lp)), planes
 
-    plane_names = ("k", "v", "ks", "vs") if quant else ("k", "v")
+    planes = dict(pool)
     if cfg.scan_layers:
-        x, planes = jax.lax.scan(
-            body, x,
-            (layer_weights,) + tuple(pool[n] for n in plane_names))
-        new_pool = dict(zip(plane_names, planes))
+        # the planes ride the CARRY (never xs/ys: that slices a layer's
+        # pool out and stacks a copy back, per layer); xs are the
+        # stacked weights and the layer's index
+        (x, planes), _ = jax.lax.scan(
+            lambda c, xs: (layer(*c, *xs), None), (x, planes),
+            (layer_weights, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     else:
-        planes = {n: pool[n] for n in plane_names}
         for i in range(cfg.num_layers):
             lp = {kk: layer_weights[kk][i] for kk in layer_weights}
-            q, k_new, v_new = _qkv(x, lp)
-            with jax.named_scope("kv_write"):
-                if quant:
-                    k_new, k_sc = quantize_kv_rows(k_new, planes["k"].dtype)
-                    v_new, v_sc = quantize_kv_rows(v_new, planes["v"].dtype)
-                    planes["ks"] = planes["ks"].at[i, phys, prow].set(k_sc)
-                    planes["vs"] = planes["vs"].at[i, phys, prow].set(v_sc)
-                planes["k"] = planes["k"].at[i, phys, prow].set(
-                    k_new.astype(planes["k"].dtype))
-                planes["v"] = planes["v"].at[i, phys, prow].set(
-                    v_new.astype(planes["v"].dtype))
-            attn = _paged_attention(
-                cfg, q, planes["k"][i], planes["v"][i], page_table,
-                positions,
-                ks=planes["ks"][i] if quant else None,
-                vs=planes["vs"][i] if quant else None)
-            x = _post(x, attn, lp)
-        new_pool = planes
+            x, planes = layer(x, planes, lp, i)
     return (_head_logits(cfg, params, x, fused_tick, logit_pos, logits_all),
-            new_pool)
+            planes)
 
 
 def init_paged_pool(cfg: LlamaConfig, num_pages: int, page_size: int,
                     dtype=None, quant=None) -> Dict[str, jax.Array]:
-    """Flat paged K/V pool: [L, num_pages, page_size, Hkv, D]. Page 0 is
-    the allocator's reserved trash page (see inference/paged_kv.py).
+    """Flat paged K/V pool: [L, num_pages, page_size, Hkv*D] — a cache
+    row's kv heads side by side in the minor dimension (head h at lanes
+    [h*D, (h+1)*D)), which is the block the paged kernel reads, so the
+    pool is never re-tiled between a write and a read. Page 0 is the
+    allocator's reserved trash page (see inference/paged_kv.py).
 
     ``quant`` ('int8' | 'fp8'): K/V pages store the narrow dtype and the
     pool carries per-page fp32 scale planes ``ks``/``vs``
@@ -1283,8 +1273,8 @@ def init_paged_pool(cfg: LlamaConfig, num_pages: int, page_size: int,
         dtype = quant_dtype(quant)
     else:
         dtype = dtype or cfg.dtype
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads,
-             cfg.head_dim)
+    shape = (cfg.num_layers, num_pages, page_size,
+             cfg.num_kv_heads * cfg.head_dim)
     pool = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if quant is not None:
         sshape = (cfg.num_layers, num_pages, page_size)

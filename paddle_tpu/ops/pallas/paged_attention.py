@@ -2,8 +2,13 @@
 
 The paged extension of ``decode_attention.py`` (the Ragged Paged
 Attention design, PAPERS.md #1): KV lives in a flat pool of fixed-size
-pages (``[num_pages, page_size, Hkv*D]``) and each slot's sequence is
-the concatenation of the pages its int32 page table names. The kernel
+pages and each slot's sequence is the concatenation of the pages its
+int32 page table names. The pool is read WHERE IT LIES: at rest it is
+``[L, num_pages, page_size, Hkv*D]`` (``llama.init_paged_pool`` — the
+minor dimension the kernel's blocks have, so nothing re-tiles it) and
+the model's layer loop hands the kernel the whole stacked pool plus the
+layer's index; a single layer's ``[num_pages, page_size, Hkv*D]`` pool
+is served by the same call without ``layer``. The kernel
 serves **prefill chunks and decode ticks in the same launch**: slot
 ``b`` carries ``q_len[b]`` query rows (1 = a decode tick, >1 = a
 prefill chunk) whose row ``t`` sits at absolute position
@@ -11,11 +16,11 @@ prefill chunk) whose row ``t`` sits at absolute position
 
 Page indirection and raggedness are BOTH BlockSpec index-map facts:
 
-- grid = (slot, page-slot) with the page tables, context lengths and
-  chunk widths SCALAR-PREFETCHED. The K/V index map clamps the page
-  slot at the slot's last *needed* page and then routes it through the
-  page table — so the pipeline fetches physical page
-  ``table[b, min(j, last)]``: per-slot KV HBM reads scale with
+- grid = (slot, page-slot) with the page tables, context lengths,
+  chunk widths and the layer index SCALAR-PREFETCHED. The K/V index
+  map clamps the page slot at the slot's last *needed* page and then
+  routes it through the page table — so the pipeline fetches block
+  ``(layer, table[b, min(j, last)])``: per-slot KV HBM reads scale with
   ``ctx+q_len`` (position), not the table width, and a page-table hop
   costs zero extra DMAs (the indirection happens in index arithmetic
   the Mosaic pipeline already does).
@@ -69,8 +74,8 @@ def _make_kernel(nH: int, Hkv: int, D: int, Tq: int, psz: int,
     rep = nH // Hkv
     TR = Tq * rep                     # query rows per kv head
 
-    def kernel(pt_ref, ctx_ref, qlen_ref, q_ref, k_ref, v_ref, o_ref,
-               acc_ref, m_ref, l_ref):
+    def kernel(pt_ref, ctx_ref, qlen_ref, lay_ref, q_ref, k_ref, v_ref,
+               o_ref, acc_ref, m_ref, l_ref):
         b = pl.program_id(0)
         j = pl.program_id(1)
         ctx = ctx_ref[b]
@@ -131,29 +136,42 @@ def _make_kernel(nH: int, Hkv: int, D: int, Tq: int, psz: int,
 
 
 def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
-                           scale=None, interpret: bool = False):
+                           scale=None, interpret: bool = False,
+                           layer=None):
     """Attention over a paged KV pool, mixed prefill/decode in one call.
 
     q: [B, Tq, nH, D] query chunks (row t of slot b sits at absolute
     position ``ctx_len[b] + t``; rows past ``q_len[b]`` are padding and
-    produce garbage outputs the caller discards). kp/vp:
-    [P, page_size, Hkv, D] — the flat page pool, already holding the
-    chunk's own K/V rows (the caller scatters before attending, the
-    same contract as the contiguous cache). page_table: [B, max_pages]
-    int32 physical page ids per virtual page slot. ctx_len: [B] rows
-    already in the cache before this chunk. q_len: [B] live rows per
-    chunk (None = all Tq). Returns [B, Tq, nH, D] in q.dtype. Raises on
+    produce garbage outputs the caller discards). kp/vp: the page pool
+    in its layout at rest, already holding the chunk's own K/V rows (the
+    caller scatters before attending, the same contract as the
+    contiguous cache) — with ``layer`` (an int32 scalar, traced or not)
+    the whole stacked ``[L, P, page_size, Hkv*D]`` pool, of which the
+    kernel reads layer ``layer``'s pages and nothing else; without it
+    one layer's ``[P, page_size, Hkv*D]``. The pool is never reshaped
+    here: any other rank raises. page_table: [B, max_pages] int32
+    physical page ids per virtual page slot. ctx_len: [B] rows already
+    in the cache before this chunk. q_len: [B] live rows per chunk
+    (None = all Tq). Returns [B, Tq, nH, D] in q.dtype. Raises on
     untileable shapes — callers gate with ``paged_attention_active``.
     """
     B, Tq, nH, D = q.shape
-    P, psz, Hkv = kp.shape[0], kp.shape[1], kp.shape[2]
+    if kp.ndim != (3 if layer is None else 4) or vp.shape != kp.shape:
+        raise ValueError(
+            f"paged kernel reads the pool where it lies: [L, P, psz, "
+            f"Hkv*D] with a layer, [P, psz, Hkv*D] without, got "
+            f"k{tuple(kp.shape)} v{tuple(vp.shape)} layer={layer}")
+    if layer is None:      # one layer is a stack of one (a leading unit
+        kp, vp, layer = kp[None], vp[None], 0   # dim re-tiles nothing)
+    psz, HD = kp.shape[-2:]
     max_pages = page_table.shape[1]
     _selected["count"] += 1  # trace-time: once per compiled program
-    if psz % 8 or (Hkv * D) % 128 or nH % Hkv:
+    if psz % 8 or HD % 128 or HD % D or nH % (HD // D):
         raise ValueError(
             f"paged kernel needs page_size%8==0 and lane-aligned KV "
-            f"minor dim, got psz={psz} Hkv*D={Hkv * D} — gate callers "
+            f"minor dim, got psz={psz} Hkv*D={HD} — gate callers "
             f"with paged_attention_active")
+    Hkv = HD // D
     rep = nH // Hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
     if q_len is None:
@@ -163,25 +181,25 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
     qs = (q * scale).astype(q.dtype)
     qh = qs.reshape(B, Tq, Hkv, rep, D).transpose(0, 2, 1, 3, 4)
     qh = qh.reshape(B, Hkv * Tq * rep, D)
-    kf = kp.reshape(P, psz, Hkv * D)  # lane-aligned flat minor dim
-    vf = vp.reshape(P, psz, Hkv * D)
 
-    def kv_map(b, j, pt_ref, ctx_ref, qlen_ref):
+    def kv_map(b, j, pt_ref, ctx_ref, qlen_ref, lay_ref):
         # clamp at the slot's last needed page slot, then route through
         # the page table: past the clamp the SAME physical page repeats
         # and Mosaic skips the HBM->VMEM copy — these two index hops are
         # the entire "paged + ragged" property
         last = (ctx_ref[b] + qlen_ref[b] - 1) // psz
-        return (pt_ref[b, jnp.minimum(j, last)], 0, 0)
+        return (lay_ref[0], pt_ref[b, jnp.minimum(j, last)], 0, 0)
 
+    # the kernel sees [1, psz, Hkv*D]: the BlockSpec squeezes the layer
+    kv_block = (None, 1, psz, HD)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, max_pages),
         in_specs=[
             pl.BlockSpec((1, Hkv * Tq * rep, D),
                          lambda b, j, *_: (b, 0, 0)),
-            pl.BlockSpec((1, psz, Hkv * D), kv_map),
-            pl.BlockSpec((1, psz, Hkv * D), kv_map),
+            pl.BlockSpec(kv_block, kv_map),
+            pl.BlockSpec(kv_block, kv_map),
         ],
         out_specs=pl.BlockSpec((1, Hkv * Tq * rep, D),
                                lambda b, j, *_: (b, 0, 0)),
@@ -198,7 +216,8 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
         out_shape=jax.ShapeDtypeStruct((B, Hkv * Tq * rep, D), q.dtype),
         interpret=interpret or (FORCE_INTERPRET and not _on_tpu()),
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(ctx_len, jnp.int32),
-      jnp.asarray(q_len, jnp.int32), qh, kf, vf)
+      jnp.asarray(q_len, jnp.int32),
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), qh, kp, vp)
     # back from h-major rows to [B, Tq, nH, D]
     return out.reshape(B, Hkv, Tq, rep, D).transpose(0, 2, 1, 3, 4) \
               .reshape(B, Tq, nH, D)

@@ -9,10 +9,19 @@ below lowers one kernel at ``LlamaConfig.bert_base_equiv`` widths (H=768,
 ``tpu_custom_call`` in the compiled text. Nothing runs: these say a kernel
 compiles, never that it is right or fast.
 
+One case compiles a whole program: ``test_paged_segment_holds_pool_once``
+lowers the paged segment loop (admit and decode steps around
+``llama.forward_with_pages``) and reads the compiled text and
+``memory_analysis()`` for copies of the KV pool, which tier-1 cannot see
+otherwise: they cost two thirds of a serve step before PR 26 (PERF.md).
+
 The topology is described inside a fixture (loading the TPU's library at
 import would break collection under several workers) and everything is
 compiled in the test's own process.
 """
+
+import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -95,15 +104,22 @@ def _ragged_decode(kv_dtype):
     return build
 
 
-def _paged(tq):
+def _paged(tq, layers=0):
+    """One layer's [P, page, Hkv*D] pool, or with ``layers`` the stacked
+    pool and the layer as a traced scalar: the call of the layer scan."""
     from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
 
     def build(S):
         max_pages = MAX_LEN // PAGE
-        pool = S((SLOTS * max_pages + 1, PAGE, NH, D), BF16)
-        return ragged_paged_attention, (
-            S((SLOTS, tq, NH, D), BF16), pool, pool,
-            S((SLOTS, max_pages), I32), S((SLOTS,), I32), S((SLOTS,), I32))
+        plane = (SLOTS * max_pages + 1, PAGE, NH * D)
+        pool = S(((layers,) if layers else ()) + plane, BF16)
+        args = (S((SLOTS, tq, NH, D), BF16), pool, pool,
+                S((SLOTS, max_pages), I32), S((SLOTS,), I32),
+                S((SLOTS,), I32))
+        if not layers:
+            return ragged_paged_attention, args
+        return (lambda q, k, v, pt, ctx, ql, lay: ragged_paged_attention(
+            q, k, v, pt, ctx, ql, layer=lay)), args + (S((), I32),)
     return build
 
 
@@ -159,6 +175,8 @@ CASES = {
     "ragged_paged_tq1": _paged(1),
     "ragged_paged_tq16": _paged(16),
     "ragged_paged_tq64": _paged(64),
+    "ragged_paged_layered_tq1": _paged(1, layers=12),
+    "ragged_paged_layered_tq16": _paged(16, layers=12),
     "fused_rms_norm": _tick("rms"),
     "fused_add_rms_norm": _tick("add_rms"),
     "fused_rope_qk": _tick("rope"),
@@ -174,3 +192,65 @@ def test_kernel_compiles_for_v5e(name, shaped, no_persistent_cache):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel in the " \
                                       f"compiled program"
+
+
+def test_paged_segment_holds_pool_once(shaped, no_persistent_cache,
+                                       monkeypatch):
+    """The paged segment program (``jit_segment``: a while_loop of admit
+    (1 x 64 prefill) or decode (8 x 1) steps around
+    ``forward_with_pages``, pool donated) compiled with the Mosaic kernel
+    chosen: the pool is updated in place and read where it lies. No
+    ``copy`` / ``reshape`` / ``dynamic-slice`` / ``dynamic-update-slice``
+    as large as ONE LAYER of a pool plane is in the compiled text, and the
+    program's temporaries are under one plane. Any of the three sites
+    undone — the layer scan taking the pool as xs/ys, the kernel's wrapper
+    reshaping it, a ``lax.cond`` around the branches — brings them back."""
+    from paddle_tpu.inference.serving import ServingEngine
+    from paddle_tpu.models import llama
+    from paddle_tpu.ops.pallas import flash_attention
+
+    # dispatch asks jax.default_backend(), which says cpu here
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    layers, pages, n_pad, s_max, steps = 4, 2049, 8, 64, 8
+    max_pages = MAX_LEN // PAGE
+    cfg = llama.LlamaConfig(
+        vocab_size=V, hidden_size=H, intermediate_size=4 * H,
+        num_layers=layers, num_heads=NH, num_kv_heads=NH,
+        max_seq_len=MAX_LEN, dtype=BF16, remat=False, scan_layers=True)
+
+    def abstract(build):
+        return jax.tree.map(lambda a: shaped(a.shape, a.dtype),
+                            jax.eval_shape(build))
+
+    params = abstract(lambda: llama.init_params(cfg, jax.random.PRNGKey(0),
+                                                dtype=BF16))
+    pool = abstract(lambda: llama.init_paged_pool(cfg, pages, PAGE))
+    engine = types.SimpleNamespace(        # all the builder reads of one
+        cfg=cfg, slots=SLOTS, eos=None,
+        pager=types.SimpleNamespace(max_pages=max_pages))
+    segment = ServingEngine._build_paged_segment_prog(engine, n_pad, s_max,
+                                                      steps)
+    vec = shaped((SLOTS,), I32)
+    req = shaped((n_pad,), I32)
+    compiled = segment.lower(
+        params, pool, shaped((SLOTS, max_pages), I32), vec, vec, vec,
+        shaped((n_pad, s_max), I32), req, req, req,
+        shaped((n_pad, max_pages), I32), shaped((), I32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the paged kernel was not chosen"
+
+    layer_elems = pages * PAGE * NH * D
+    moved = []
+    for dims, op in re.findall(
+            r"= bf16\[([\d,]+)\]\S* (copy|copy-start|reshape|dynamic-slice|"
+            r"dynamic-update-slice)\(", text):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d)
+        if n >= layer_elems:
+            moved.append((op, dims))
+    assert not moved, f"the compiled segment moves the pool: {moved}"
+    plane_bytes = layers * layer_elems * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < plane_bytes, \
+        f"temporaries {temp} B hold a pool plane ({plane_bytes} B)"

@@ -139,6 +139,191 @@ class TestCopyOnWrite:
 # ---------------------------------------------------------------------------
 
 
+def _paged_reference(params, tokens, cfg, cache, pos, quant_dtype=None):
+    """The dense-cache forward the paged one must equal: plain unrolled
+    layers over a contiguous [L, B, S, Hkv, D] cache (plus [L, B, S]
+    scale planes when ``quant_dtype``), every row of slot b written at
+    ``pos[b] + t`` and the whole window attended. No pages, no loop
+    carry, no kernel — the same projections and the same row math."""
+    from paddle_tpu.quantization.serving import quantize_kv_rows
+
+    B, T = tokens.shape
+    positions = pos[:, None] + jnp.arange(T)
+    rows = jnp.arange(B)[:, None]
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    lw = llama.layer_params(params, cfg)
+    cache = dict(cache)
+    for i in range(cfg.num_layers):
+        lp = {n: w[i] for n, w in lw.items()}
+        q, new_k, new_v = llama._qkv_proj(cfg, x, lp, positions)
+        win = {}
+        for n, new in (("k", new_k), ("v", new_v)):
+            if quant_dtype is not None:
+                new, sc = quantize_kv_rows(new, quant_dtype)
+                cache[n + "s"] = cache[n + "s"].at[i, rows, positions].set(sc)
+            cache[n] = cache[n].at[i, rows, positions].set(
+                new.astype(cache[n].dtype))
+            win[n] = cache[n][i]
+            if quant_dtype is not None:
+                win[n] = win[n].astype(cfg.dtype) * cache[n + "s"][i][
+                    ..., None, None].astype(cfg.dtype)
+        attn = llama._dense_cache_attention(cfg, q, win["k"], win["v"],
+                                            positions)
+        x = llama._layer_post(cfg, x, attn, lp)
+    return llama._head_logits(cfg, params, x, False), cache
+
+
+def _to_pages(plane, pt, psz):
+    """Dense rows [L, B, S, Hkv, D] (scales [L, B, S]) -> the pool's
+    planes [L, P, psz, Hkv*D] ([L, P, psz]) under page table ``pt``
+    (pages no slot names stay zero)."""
+    L, B, S = plane.shape[:3]
+    flat = plane.reshape(L, B, S // psz, psz, -1)
+    out = np.zeros((L, int(pt.max()) + 1, psz, flat.shape[-1]), plane.dtype)
+    for b in range(B):
+        out[:, pt[b]] = flat[:, b]
+    return out[..., 0] if plane.ndim == 3 else out
+
+
+class TestCarriedFlatPool:
+    """``forward_with_pages`` on the pool as it lies ([L, P, psz, Hkv*D],
+    carried through the layer loop, rows scattered in place) against the
+    dense-cache forward: same logits, same rows in every plane."""
+
+    @pytest.mark.parametrize("T", [1, 16])
+    @pytest.mark.parametrize("kind", ["bf16", "int8"])
+    @pytest.mark.parametrize("scan_layers", [True, False])
+    def test_matches_dense_cache_forward(self, scan_layers, kind, T):
+        set_mesh(None)
+        cfg = llama.LlamaConfig(
+            vocab_size=96, hidden_size=64, intermediate_size=128,
+            num_layers=3, num_heads=4, num_kv_heads=2, max_seq_len=64,
+            dtype=jnp.float32, remat=False, scan_layers=scan_layers)
+        params = llama.init_params(cfg, jax.random.PRNGKey(1))
+        rng = np.random.RandomState(5)
+        B, S, psz, L = 3, 64, 8, cfg.num_layers
+        quant = kind == "int8"
+        kv_dt = jnp.int8 if quant else jnp.bfloat16
+        shape = (L, B, S, cfg.num_kv_heads, cfg.head_dim)
+        if quant:
+            cache = {n: jnp.asarray(rng.randint(-127, 128, shape), kv_dt)
+                     for n in ("k", "v")}
+            cache.update({n: jnp.asarray(rng.rand(L, B, S) * 0.01 + 1e-3,
+                                         jnp.float32) for n in ("ks", "vs")})
+        else:
+            cache = {n: jnp.asarray(rng.randn(*shape) * 0.3, kv_dt)
+                     for n in ("k", "v")}
+        pt = rng.permutation(np.arange(1, 1 + B * S // psz)) \
+            .reshape(B, S // psz).astype(np.int32)
+        pool = {n: jnp.asarray(_to_pages(np.asarray(a), pt, psz))
+                for n, a in cache.items()}
+        assert pool["k"].shape == (L, 1 + B * S // psz, psz,
+                                   cfg.num_kv_heads * cfg.head_dim)
+        toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, T)), jnp.int32)
+        pos = jnp.asarray([9, 30, 17], jnp.int32)
+        live = jnp.asarray([True, False, True])      # slot 1 is retired
+        ref_l, ref_cache = _paged_reference(
+            params, toks, cfg, cache, pos, kv_dt if quant else None)
+        out_l, out_pool = jax.jit(
+            lambda pool: llama.forward_with_pages(
+                params, toks, cfg, pool, jnp.asarray(pt), pos, live=live))(
+                    pool)
+        alive = np.asarray(live)
+        # logits feel a row that rounded the other way (see below) by
+        # ~1e-3; a wrong page, row or layer moves them by ~1
+        np.testing.assert_allclose(np.asarray(out_l)[alive],
+                                   np.asarray(ref_l)[alive],
+                                   rtol=1e-2, atol=1e-2)
+        assert set(out_pool) == set(pool)
+        for n, a in out_pool.items():
+            # live slots: the dense forward's rows, page for page; the
+            # retired slot's pages: untouched (its write went to trash
+            # page 0, which nobody reads)
+            ref, was = (np.asarray(c[n]).astype(np.float32)
+                        for c in (ref_cache, cache))
+            keep = alive.reshape((1, B) + (1,) * (ref.ndim - 2))
+            want = _to_pages(np.where(keep, ref, was), pt, psz)
+            # a written row may round the other way (jit against eager)
+            # and later layers' rows feel it by ~1e-3: a step of the
+            # plane's dtype, where a wrong page or row is off by ~0.3
+            atol = {"k": 5e-3, "v": 5e-3}.get(n, 1e-6)
+            np.testing.assert_allclose(
+                np.asarray(a).astype(np.float32)[:, 1:], want[:, 1:],
+                rtol=2 ** -7, atol=1.0 if a.dtype == jnp.int8 else atol,
+                err_msg=n)
+
+    @pytest.mark.parametrize("i", [0, 1, 2])
+    @pytest.mark.parametrize("Tq", [1, 8])
+    def test_kernel_layer_index_reads_that_layer(self, monkeypatch, Tq, i):
+        """The kernel given the whole stacked pool and ``layer=i`` (a
+        TRACED scalar, as the layer scan passes it) equals the
+        single-layer call on ``pool[i]``."""
+        monkeypatch.setattr(pa, "FORCE_INTERPRET", True)
+        rng = np.random.RandomState(i)
+        L, B, nH, Hkv, D, psz, P, max_pages = 3, 2, 4, 2, 64, 16, 9, 4
+        q = jnp.asarray(rng.randn(B, Tq, nH, D), jnp.float32)
+        kp = jnp.asarray(rng.randn(L, P, psz, Hkv * D), jnp.float32)
+        vp = jnp.asarray(rng.randn(L, P, psz, Hkv * D), jnp.float32)
+        pt = jnp.asarray(rng.permutation(np.arange(1, P))
+                         .reshape(B, max_pages), jnp.int32)
+        ctx = jnp.asarray([3, 40], jnp.int32)
+        one = pa.ragged_paged_attention(q, kp[i], vp[i], pt, ctx)
+        stacked = jax.jit(lambda lay: pa.ragged_paged_attention(
+            q, kp, vp, pt, ctx, layer=lay))(jnp.int32(i))
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(one))
+
+    @pytest.mark.parametrize("shape,layer", [
+        ((9, 16, 2, 64), None),        # heads apart: [P, psz, Hkv, D]
+        ((3, 9, 16, 128), None),       # a stacked pool and no layer
+        ((9, 16, 128), 0),             # a layer and no stack
+    ])
+    def test_kernel_refuses_a_pool_it_would_have_to_reshape(self, shape,
+                                                            layer):
+        q = jnp.ones((2, 1, 4, 64), jnp.float32)
+        kp = jnp.ones(shape, jnp.float32)
+        with pytest.raises(ValueError, match="where it lies"):
+            pa.ragged_paged_attention(
+                q, kp, kp, jnp.zeros((2, 4), jnp.int32),
+                jnp.zeros((2,), jnp.int32), layer=layer, interpret=True)
+
+
+class TestOneOf:
+    """``serving._one_of``: the segment loops' two-way branch."""
+
+    @pytest.mark.parametrize("admits", [0, 2, 5])
+    def test_equals_lax_cond_in_a_segment_shaped_loop(self, admits):
+        from paddle_tpu.inference.serving import _one_of
+
+        steps = 6
+
+        def admit(st):
+            return dict(st, pool=st["pool"].at[st["q"] % 4].add(1.0),
+                        log=st["log"].at[st["step"]].set(st["q"]),
+                        q=st["q"] + 1)
+
+        def decode(st):
+            return dict(st, pool=st["pool"] * 2.0,
+                        log=st["log"].at[st["step"]].set(-1))
+
+        def run(branch, n_real):
+            def body(st):
+                st = branch(st["q"] < n_real, admit, decode, st)
+                return dict(st, step=st["step"] + 1)
+
+            st = dict(pool=jnp.arange(4.0), log=jnp.zeros((steps,), jnp.int32),
+                      q=jnp.int32(0), step=jnp.int32(0))
+            return jax.lax.while_loop(
+                lambda st: (st["step"] < steps) & (st["pool"][0] < 40.0),
+                body, st)
+
+        got = jax.jit(lambda n: run(_one_of, n))(jnp.int32(admits))
+        want = jax.jit(lambda n: run(jax.lax.cond, n))(jnp.int32(admits))
+        assert int(got["step"]) == int(want["step"]) > 0
+        for n in want:
+            np.testing.assert_array_equal(np.asarray(got[n]),
+                                          np.asarray(want[n]), err_msg=n)
+
+
 class TestUnifiedKernel:
     @pytest.mark.parametrize("nH,Hkv,D", [(4, 2, 64), (2, 2, 128),
                                           (8, 8, 64)])
@@ -149,8 +334,8 @@ class TestUnifiedKernel:
         rng = np.random.RandomState(0)
         B, Tq, psz, P, max_pages = 4, 8, 16, 33, 8
         q = jnp.asarray(rng.randn(B, Tq, nH, D), jnp.float32)
-        kp = jnp.asarray(rng.randn(P, psz, Hkv, D), jnp.float32)
-        vp = jnp.asarray(rng.randn(P, psz, Hkv, D), jnp.float32)
+        kp = jnp.asarray(rng.randn(P, psz, Hkv * D), jnp.float32)
+        vp = jnp.asarray(rng.randn(P, psz, Hkv * D), jnp.float32)
         pt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * max_pages]
                          .reshape(B, max_pages), jnp.int32)
         ctx = jnp.asarray([0, 5, 37, 100], jnp.int32)

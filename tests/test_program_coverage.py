@@ -244,6 +244,19 @@ class TestMixedWorkloadCoverage:
         assert eng.aot_warmup_s is not None and eng.aot_warmup_s > 0
         assert all(s >= 0 for s in eng.aot_key_seconds.values())
 
+    def test_aot_report_carries_the_programs_temporaries(self, served):
+        """``temp_bytes`` is the family's largest
+        ``memory_analysis().temp_size_in_bytes`` and ``pool_bytes`` the
+        planes as they lie: what "holds the pool once" is read from."""
+        eng, _, aot, _ = served
+        assert set(eng.aot_key_temp_bytes) == set(eng.aot_key_seconds)
+        assert aot["cseg"]["temp_bytes"] == max(
+            eng.aot_key_temp_bytes.values()) > 0
+        pool = eng.pager.pool
+        assert eng.pool_bytes == {n: a.size * a.dtype.itemsize
+                                  for n, a in pool.items()}
+        assert set(eng.pool_bytes) == {"k", "v"}
+
     def test_cold_start_gauge_splits(self, served):
         """cold_start_s = aot_warmup_s + first_token_s once warmed —
         the autoscaler's scale-up latency is a measured pair, not an

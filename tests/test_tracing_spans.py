@@ -400,7 +400,7 @@ class TestNames:
             a, a, jnp.zeros((8,), jnp.int32), 64, 1e4), x) == \
             ["fused_rope_qk"]
         q = jnp.ones((2, 1, 4, 64), bf)
-        pool = jnp.ones((9, 8, 2, 64), bf)
+        pool = jnp.ones((9, 8, 2 * 64), bf)
         assert names(lambda a, k: paged_attention.ragged_paged_attention(
             a, k, k, jnp.zeros((2, 4), jnp.int32),
             jnp.zeros((2,), jnp.int32)), q, pool) == \
