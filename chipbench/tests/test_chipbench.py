@@ -256,3 +256,36 @@ def test_rehearsal_end_to_end(cell, trace, want):
     window = [json.loads(x) for x in r.stdout.splitlines()
               if x.startswith('{"phase": "window"')][0]
     assert window["programs_built_in_window"] == 0
+
+
+KNEE_LINE = re.compile(r"^knee: ([0-9.]+) req/s$", re.M)
+SWEEP_FILE = re.compile(r"chipbench/sweeps/[A-Za-z0-9_.\-]+\.md")
+
+
+def serve_cells():
+    m = load(os.path.join(ROOT, "BENCHMARK.json"))
+    for w in m["workloads"]:
+        wl = load(os.path.join(BENCH, "workloads", w["name"] + ".json"))
+        if wl["kind"] == "serve":
+            yield pytest.param(wl, m["run_seconds"], id=w["name"])
+
+
+@pytest.mark.parametrize("wl,run_seconds", serve_cells())
+def test_serve_rate_is_its_multiple_of_the_sweeps_knee(wl, run_seconds):
+    """A re-pitch changes the sweep file and both cells together: each
+    rate is the multiple its file states (``rate_over_knee``) of the ONE
+    knee line of the sweep file its ``rate_from`` names; below the knee to
+    one decimal, above it to the nearest whole number."""
+    named = SWEEP_FILE.findall(wl["rate_from"])
+    assert len(named) == 1, wl["rate_from"]
+    with open(os.path.join(ROOT, named[0])) as f:
+        knees = KNEE_LINE.findall(f.read())
+    assert len(knees) == 1, f"{named[0]} has {len(knees)} 'knee:' lines"
+    knee, over = float(knees[0]), wl["rate_over_knee"]
+    assert over in (0.8, 1.75, 2.0)
+    want = round(over * knee, 1) if over < 1 else float(round(over * knee))
+    assert wl["rate_rps"] == pytest.approx(want, abs=1e-9)
+    assert f"{knee:g} req/s" in wl["rate_from"]
+    assert wl.get("saturated_from_s", 0.0) < run_seconds
+    if over > 1:  # judged by tokens/s: the span it is taken over is stated
+        assert wl["saturated_from_s"] >= 5.0 and wl["saturated_from_why"]
