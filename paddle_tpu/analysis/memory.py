@@ -403,27 +403,18 @@ def hot_transients(report: MemoryReport, *, frac_bytes: float = 0.33,
 
 
 def page_bytes_for(cfg, page_size: int, quant: Optional[str] = None) -> int:
-    """Bytes one pool page occupies across all layers: K + V planes
-    [L, page_size, Hkv*D] (+ the fp32 ``ks``/``vs`` scale planes under
-    per-page quantization) — the §3f page arithmetic, byte-priced."""
-    if quant is not None:
-        from ..quantization.serving import quant_dtype
-        import jax.numpy as jnp
+    """Bytes one pool page occupies across all layers — the §3f page
+    arithmetic, byte-priced. The row layout is the model's: the K + V
+    planes of ``llama`` (+ the scale planes under per-page quantization),
+    the one latent plane of ``latent_moe``."""
+    from ..models import family_of
 
-        itemsize = jnp.dtype(quant_dtype(quant)).itemsize
-    else:
-        import jax.numpy as jnp
-
-        itemsize = jnp.dtype(cfg.dtype).itemsize
-    kv = 2 * cfg.num_layers * page_size * cfg.num_kv_heads * cfg.head_dim \
-        * itemsize
-    scales = (2 * cfg.num_layers * page_size * 4) if quant else 0
-    return kv + scales
+    return family_of(cfg).page_bytes(cfg, page_size, quant)
 
 
 def pool_bytes_for(cfg, num_pages: int, page_size: int,
                    quant: Optional[str] = None) -> int:
-    """Provisioned pool bytes (``llama.init_paged_pool`` arithmetic):
+    """Provisioned pool bytes (``init_paged_pool`` arithmetic):
     every page is allocated up front, including the trash page."""
     return num_pages * page_bytes_for(cfg, page_size, quant)
 

@@ -85,6 +85,7 @@ import numpy as np
 
 from ..observability import flight as _flight
 from ..observability import journal as _journal
+from ..models import require
 from ..observability import metrics as _metrics
 from .fleet import FleetRouter, _Replica
 from .prefix_cache import make_prefix_cache
@@ -128,6 +129,7 @@ class DisaggRouter(FleetRouter):
                              "inside one pool's homogeneous FleetRouter")
         engines = prefill_engines + decode_engines
         for e in engines:
+            require(e.cfg, "disaggregated serving")
             if not e.paged:
                 raise ValueError("disaggregation needs paged engines — "
                                  "the handoff moves KV page sets")

@@ -90,6 +90,9 @@ class HostTier:
         if capacity_pages < 1:
             raise ValueError(f"capacity_pages must be >= 1, got "
                              f"{capacity_pages}")
+        from ..models import require
+
+        require(pager.cfg, "host tier")
         self.pager = pager
         self.capacity_pages = int(capacity_pages)
         # key -> {<plane>: np [L, n, psz, ...] per pool plane ("k"/"v",

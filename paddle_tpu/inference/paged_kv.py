@@ -173,7 +173,7 @@ class PagedKVCache:
     the device-side ``[slots, max_pages]`` int32 map the segment program
     consumes (both donated through the program and updated in place;
     everything here addresses pages on axis 1 and never looks past the
-    page axis, so the row layout is ``llama.init_paged_pool``'s alone;
+    page axis, so the row layout is the model's ``init_paged_pool``'s alone;
     the host keeps
     ``slot_pages`` mirrors for bookkeeping). ``max_pages`` bounds ONE
     slot's virtual length (``max_pages * page_size`` = the engine's
@@ -184,7 +184,7 @@ class PagedKVCache:
 
     def __init__(self, cfg, slots: int, page_size: int, num_pages: int,
                  max_pages: int, dtype=None, mesh=None, quant=None):
-        from ..models import llama
+        from ..models import family_of, llama
 
         self.cfg = cfg
         self.slots = int(slots)
@@ -199,9 +199,9 @@ class PagedKVCache:
         # changes, and every page-granular copy below iterates the pool
         # dict instead of naming k/v
         self.quant = quant
-        self.pool = llama.init_paged_pool(cfg, self.num_pages,
-                                          self.page_size, dtype=dtype,
-                                          quant=quant)
+        # the planes, their row width and their dtype are the model's
+        self.pool = family_of(cfg).init_paged_pool(
+            cfg, self.num_pages, self.page_size, dtype=dtype, quant=quant)
         self.page_table = jnp.zeros((self.slots, self.max_pages),
                                     jnp.int32)
         if mesh is not None:

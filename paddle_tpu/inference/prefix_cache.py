@@ -290,6 +290,9 @@ class PagedPrefixCache:
     the fleet cache directory's feed."""
 
     def __init__(self, pager, capacity_pages: int = 512, host_tier=None):
+        from ..models import require
+
+        require(pager.cfg, "prefix cache")
         self.pager = pager
         self.block = pager.page_size      # alignment rule = the page
         self.capacity_pages = int(capacity_pages)

@@ -198,6 +198,11 @@ class OnlineReport:
     # mean first-token time)
     segment_phases: Optional[Dict[str, dict]] = None
     ttft_parts_mean_s: Optional[Dict[str, float]] = None
+    # PR 29: the model's per-step counters over the serve, where it has
+    # any (``SEGMENT_COUNTERS`` of a sparse-expert model: picks, those
+    # that landed on held experts, held experts hit, the largest load of
+    # one expert in a step), beside the loop steps they were counted over
+    moe: Optional[Dict[str, int]] = None
     per_request: List[dict] = field(default_factory=list)
 
     def as_dict(self, with_requests: bool = False) -> dict:
@@ -386,6 +391,7 @@ class OnlineScheduler:
         # per-phase host time of this serve's segments (always on): the
         # engine's phase spans and this loop's tally into one dict
         phases = eng.segment_phases = {}
+        eng.segment_counts = {}
         t0 = _journal.now()
         self._serve_t0 = t0
         while pending or eng._queue or eng.free_slot_count() < eng.slots:
@@ -498,6 +504,8 @@ class OnlineScheduler:
                 for name, (ns, c) in phases.items()},
             ttft_parts_mean_s=({k: sum(p[k] for p in parts) / len(parts)
                                 for k in TTFT_PARTS} if parts else None),
+            moe=(dict(eng.segment_counts, steps=eng.last_run_ticks)
+                 if eng.segment_counts else None),
             **self._report_extras(reqs),
             per_request=[{
                 "rid": r.rid,

@@ -66,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.syncs import allowed_sync
-from ..models import llama
+from ..models import family_of, llama, require
 from ..observability import flight as _flight
 from ..observability import journal as _journal
 from ..observability import metrics as _metrics
@@ -201,6 +201,9 @@ class _PendingSegment:
     # prefill-progress state (a long prefill may span segments; the
     # host keeps its page reservation and resumes it next dispatch)
     sp: bool = False
+    # PR 29: True when the model counts per step (``SEGMENT_COUNTERS``) —
+    # its event log additionally carries a [steps, n] int32 column block
+    counters: bool = False
     seg: int = 0                   # the engine's index of this segment
 
 
@@ -346,6 +349,21 @@ class ServingEngine:
                  seq_parallel: int = 0,
                  long_buckets: Sequence[int] = ()):
         self.cfg = cfg
+        # the model seam (PR 29): what the engine needs of a model — its
+        # parameters, its paged pool, its forward over pages, whether its
+        # kernel is routed to — it asks of the module of ``cfg``'s family
+        # (``models.family_of``); a serving family that module does not
+        # list is refused here, by name
+        self.model = family_of(cfg)
+        for engaged, family in (
+                (not paged, "dense cache"), (mesh is not None, "mesh"),
+                (chunked_prefill, "chunked prefill"),
+                (speculative or sampling, "speculative"),
+                (quality_digest, "quality digest"),
+                (quant, "quantized pool"),
+                (seq_parallel, "sequence-parallel prefill")):
+            if engaged:
+                require(cfg, family)
         self.params = params
         self.slots = int(slots)
         # r12 tensor-parallel serving: an 'mp' mesh shards the weights
@@ -607,6 +625,9 @@ class ServingEngine:
         # reduces it into ``OnlineReport.segment_phases``
         self.seg_index = 0
         self.segment_phases: Dict[str, list] = {}
+        # PR 29: sums of the model's per-step counters (``serving.moe.*``)
+        # over the segments since the serve loop last reset the dict
+        self.segment_counts: Dict[str, int] = {}
         # r14 cold-start metric (ISSUE 9 satellite; ROADMAP item 5's
         # first deliverable): build→first-emitted-token wall time, the
         # number autoscaling/rollout decisions gate on. Stamped ONCE per
@@ -731,14 +752,11 @@ class ServingEngine:
         """True when this engine's paged segments route attention to the
         unified page-indirect Pallas kernel (trace-time dispatch — the
         paged serving lane asserts it like ``decode_kernel_active``)."""
-        from ..ops.pallas.paged_attention import paged_attention_active
-
         # a quantized pool takes the dequantizing gather path instead of
         # the page-indirect kernel (its per-page scales need the
         # gather); the weight stream is where the quant bytes win
-        return self.paged and not self.quant and paged_attention_active(
-            self.page_size, self.cfg.num_heads, self.cfg.num_kv_heads,
-            self.cfg.head_dim)
+        return self.paged and not self.quant \
+            and self.model.paged_kernel_active(self.cfg, self.page_size)
 
     def quant_kernel_active(self) -> bool:
         """True when this engine's quantized projection matmuls route to
@@ -1125,8 +1143,6 @@ class ServingEngine:
         stays consistent. ``aot_key_temp_bytes[key]`` keeps what the
         compiled program says it needs besides its arguments."""
         i32 = jnp.int32
-        cfg = self.cfg
-        L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
 
         def run(prog, *args):
             # lowering the call's own arguments compiles the executable
@@ -1162,6 +1178,8 @@ class ServingEngine:
                 self._cache = out[0]
             elif family == "seg":
                 _, n_pad, s_max, pre_max, steps = key
+                cfg = self.cfg
+                L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
                 kdt = self._cache["k"].dtype
                 out = run(
                     self._segment_prog(n_pad, s_max, pre_max, steps),
@@ -1760,6 +1778,26 @@ class ServingEngine:
             for hook in SEGMENT_HOOKS:
                 hook(steps, new_tokens, len(finished))
 
+    def _count_telemetry(self, counts) -> Dict[str, int]:
+        """One segment's ``SEGMENT_COUNTERS`` ([steps, n] int32, fetched
+        with the tokens) into the ``serving.moe.*`` counters and
+        ``segment_counts``: sums over the steps, a ``max_*`` column its
+        maximum. Returns the segment's own."""
+        seg = {}
+        for j, name in enumerate(self.model.SEGMENT_COUNTERS):
+            col = counts[:, j]
+            if name.startswith("max_"):
+                seg[name] = v = int(col.max(initial=0))
+                _metrics.gauge(f"serving.moe.{name}").set(v)
+                self.segment_counts[name] = max(
+                    self.segment_counts.get(name, 0), v)
+            else:
+                seg[name] = v = int(col.sum())
+                _metrics.counter(f"serving.moe.{name}").inc(v)
+                self.segment_counts[name] = \
+                    self.segment_counts.get(name, 0) + v
+        return seg
+
     def _spec_telemetry(self, stats: dict) -> None:
         """Per-segment speculative accounting (r15 satellite): counters
         for drafts proposed/accepted/rejected, the live accept-rate and
@@ -2220,6 +2258,18 @@ class ServingEngine:
                                   max_steps: int, digest_k: int = 0):
         cfg, slots, eos = self.cfg, self.slots, self.eos
         max_pages = self.pager.max_pages
+        model = family_of(cfg)
+        forward = model.forward_with_pages
+        # a model that counts per step (experts picked, held, hit) hands
+        # its counters back with the logits; they ride the event log
+        n_counters = len(getattr(model, "SEGMENT_COUNTERS", ()))
+
+        def forward_counted(st, new, *args, **kw):
+            if not n_counters:
+                return forward(*args, **kw)
+            logits, pool, c = forward(*args, with_counters=True, **kw)
+            new["cnt"] = st["cnt"].at[st["step"]].set(c)
+            return logits, pool
 
         @functools.partial(jax.jit, donate_argnums=(1, 2))
         def segment(params, pool, ptab, pos, nxt, rem, prompts, lens,
@@ -2232,6 +2282,8 @@ class ServingEngine:
                 aslot=jnp.zeros((max_steps,), i32),
                 qidx=i32(0), step=i32(0),
             )
+            if n_counters:
+                st["cnt"] = jnp.zeros((max_steps, n_counters), i32)
             if digest_k:
                 # r17 logit digests: emitted-token logit + top-k
                 # (ids, values) per step/slot — fp32 event-log columns
@@ -2261,8 +2313,9 @@ class ServingEngine:
                 # prefix pages in place — the prefix's quadratic
                 # attention, its per-token matmuls AND its KV writes are
                 # all skipped
-                logits, pool = llama.forward_with_pages(
-                    params, prow, cfg, st["pool"], row,
+                extra = {}
+                logits, pool = forward_counted(
+                    st, extra, params, prow, cfg, st["pool"], row,
                     jnp.reshape(pln, (1,)), logit_pos=ln - 1)
                 t0 = _greedy(logits).reshape(())
                 rem_new = gens[q] - 1
@@ -2277,7 +2330,7 @@ class ServingEngine:
                     out=st["out"].at[st["step"], s].set(t0),
                     aq=st["aq"].at[st["step"]].set(q),
                     aslot=st["aslot"].at[st["step"]].set(s),
-                    qidx=q + 1, step=st["step"],
+                    qidx=q + 1, step=st["step"], **extra,
                 )
                 if digest_k:
                     lg = logits.astype(jnp.float32)       # [1, V]
@@ -2292,8 +2345,9 @@ class ServingEngine:
             @llama.scoped("segment.decode")
             def decode(st):
                 live = st["rem"] > 0
-                logits, pool = llama.forward_with_pages(
-                    params, st["nxt"][:, None], cfg, st["pool"],
+                extra = {}
+                logits, pool = forward_counted(
+                    st, extra, params, st["nxt"][:, None], cfg, st["pool"],
                     st["pt"], st["pos"], live=live)
                 tok = _greedy(logits)
                 tok = jnp.where(live, tok, st["nxt"])
@@ -2306,7 +2360,7 @@ class ServingEngine:
                     nxt=tok, rem=rem,
                     out=st["out"].at[st["step"]].set(tok),
                     aq=st["aq"], aslot=st["aslot"],
-                    qidx=st["qidx"], step=st["step"],
+                    qidx=st["qidx"], step=st["step"], **extra,
                 )
                 if digest_k:
                     lg = logits.astype(jnp.float32)       # [slots, V]
@@ -2329,6 +2383,8 @@ class ServingEngine:
                     st["out"], st["aq"], st["aslot"])
             if digest_k:
                 outs += (st["dlg"], st["dti"], st["dtv"])
+            if n_counters:
+                outs += (st["cnt"],)
             return outs + (st["step"], st["qidx"])
 
         return segment
@@ -3222,7 +3278,9 @@ class ServingEngine:
                                pre_lens=pre_lens_l, req_pages=req_pages,
                                full_prompts=fulls,
                                chunk_marker=chunk_marker,
-                               digest=self.quality_digest, sp=sp_mode)
+                               digest=self.quality_digest, sp=sp_mode,
+                               counters=hasattr(self.model,
+                                                "SEGMENT_COUNTERS"))
 
     def _finish_segment_paged(self, p: _PendingSegment) -> dict:
         picked, n, prefix_cache = p.picked, p.n, p.prefix_cache
@@ -3235,7 +3293,7 @@ class ServingEngine:
         # r19 tiered KV (ISSUE 14): queued host-tier stage gathers fold
         # into the SAME single device_get — the D2H spill staging costs
         # zero additional sync events by construction.
-        acc = spec_stats = dig = None
+        acc = spec_stats = dig = counts = None
         tier = getattr(prefix_cache, "host_tier", None) \
             if prefix_cache is not None else None
         staged = tier.take_pending() if tier is not None else []
@@ -3257,6 +3315,9 @@ class ServingEngine:
                 # single fetch — a long prefill the step budget cut
                 # mid-flight resumes next dispatch at row pfo
                 toks, aq, aslot, sp_pf, sp_pfq, sp_pfo, steps, qadm = dev
+            elif p.counters:
+                # PR 29: the model's per-step counters, same fetch
+                toks, aq, aslot, counts, steps, qadm = dev
             else:
                 toks, aq, aslot, steps, qadm = dev
         if staged:
@@ -3351,12 +3412,16 @@ class ServingEngine:
                 self._spec_telemetry(spec_stats)
             self._segment_telemetry(steps, admitted, finished, eos_stops,
                                     new_tokens, max(0, n - qadm))
+            if counts is not None:
+                counts = self._count_telemetry(counts[:steps])
         out = {"steps": steps, "admitted": admitted,
                "first_tokens": first_tokens,
                "first_token_steps": first_steps, "finished": finished,
                "tokens": new_tokens}
         if spec_stats is not None:
             out["spec"] = spec_stats
+        if counts is not None:
+            out["counters"] = counts
         return out
 
     def collect_finished(self) -> Dict[int, List[int]]:

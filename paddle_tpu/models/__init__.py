@@ -8,6 +8,28 @@ eager/Layer-API model zoo lives in ``paddle_tpu.vision.models`` and the
 """
 
 from . import bert  # noqa: F401
+from . import latent_moe  # noqa: F401
 from . import llama  # noqa: F401
 
-__all__ = ["bert", "llama"]
+__all__ = ["bert", "llama", "latent_moe", "family_of", "require"]
+
+
+def family_of(cfg):
+    """The module that implements ``cfg``'s decoder family — the model
+    seam of the serving engine (``inference/serving.py``), which asks it
+    for ``init_paged_pool``, ``page_bytes``, ``paged_kernel_active`` and
+    ``forward_with_pages`` (and builds weights with its ``init_params``)."""
+    if isinstance(cfg, latent_moe.LatentMoEConfig):
+        return latent_moe
+    return llama
+
+
+def require(cfg, family: str) -> None:
+    """Refuse, by name, a serving family that ``cfg``'s model is not
+    served by (its module's ``SERVING_FAMILIES``)."""
+    mod = family_of(cfg)
+    if family not in mod.SERVING_FAMILIES:
+        raise ValueError(
+            f"{type(cfg).__name__} is not served by the {family!r} "
+            f"family: {mod.__name__.rsplit('.', 1)[-1]} is served by "
+            f"{', '.join(mod.SERVING_FAMILIES)}")

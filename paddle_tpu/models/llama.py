@@ -1283,6 +1283,38 @@ def init_paged_pool(cfg: LlamaConfig, num_pages: int, page_size: int,
     return pool
 
 
+def page_bytes(cfg: LlamaConfig, page_size: int, quant=None) -> int:
+    """Bytes one pool page occupies across all layers: the K and V planes
+    [L, page_size, Hkv*D], plus the fp32 ``ks``/``vs`` scale rows of a
+    quantized pool."""
+    if quant is not None:
+        from ..quantization.serving import quant_dtype
+
+        itemsize = jnp.dtype(quant_dtype(quant)).itemsize
+    else:
+        itemsize = jnp.dtype(cfg.dtype).itemsize
+    kv = 2 * cfg.num_layers * page_size * cfg.num_kv_heads * cfg.head_dim \
+        * itemsize
+    return kv + (2 * cfg.num_layers * page_size * 4 if quant else 0)
+
+
+def paged_kernel_active(cfg: LlamaConfig, page_size: int) -> bool:
+    """True when attention over this model's pool routes to the unified
+    page-indirect Pallas kernel."""
+    from ..ops.pallas.paged_attention import paged_attention_active
+
+    return paged_attention_active(page_size, cfg.num_heads,
+                                  cfg.num_kv_heads, cfg.head_dim)
+
+
+# every serving family the engine has serves this model (the model seam:
+# ``models.require`` refuses a family a model's module does not list)
+SERVING_FAMILIES = ("paged", "dense cache", "mesh", "chunked prefill",
+                    "sequence-parallel prefill", "speculative",
+                    "quality digest", "quantized pool", "prefix cache",
+                    "host tier", "disaggregated serving")
+
+
 def prompt_kv(params, prompt, cfg: LlamaConfig,
               max_len: Optional[int] = None):
     """KV rows for a prompt, standalone: the prefix-cache registration

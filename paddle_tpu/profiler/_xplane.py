@@ -51,6 +51,7 @@ _HLO_RE = re.compile(r"=\s*\S+\s+([a-zA-Z][\w-]*)\(")
 # The per-scope table keeps these components of an op's ``op_name`` path
 # and drops jax's own (jit(..), while, body, cond, branch_N_fun, ...).
 SCOPES = ("embed", "qkv", "kv_write", "attention", "post", "head", "sample",
+          "latent_qkv", "router", "experts", "shared_expert", "dense_ffn",
           "segment.admit", "segment.decode",
           "loss", "head_ce", "grad_clip", "optimizer")
 

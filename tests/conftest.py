@@ -47,6 +47,21 @@ def _seeded():
     yield
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_mesh_left_behind():
+    """A test file that sets the global mesh (``fleet.init``, a hybrid mesh
+    for a sharded step) leaves it set when it ends, and whichever file the
+    same xdist worker runs next traces its programs under it:
+    ``test_fleet.py`` before ``test_program_coverage.py`` costs the serve 3
+    backend compiles, before ``test_chip_compile.py`` a CPU mesh under a
+    described TPU. Which files meet in a worker changes with every file a
+    PR adds, so a module ends with no mesh."""
+    yield
+    from paddle_tpu.parallel import set_mesh
+
+    set_mesh(None)
+
+
 @pytest.fixture(scope="session")
 def tiny_llama():
     """Session-scoped tiny llama (r12 suite-time satellite): ONE seeded

@@ -166,7 +166,48 @@ def _multi_tensor(kind):
     return build
 
 
+def _mla_paged(slots, tq):
+    """``mla_paged_attention`` at openPangu-Ultra-MoE's widths: 128 heads
+    against one latent row of 640 lanes, a 5-layer pool of 128 x 96 pages:
+    a full house's decode tick (128 rows a slot) and an admission of 512
+    positions (65,536 query rows: the grid axis over query rows)."""
+    from paddle_tpu.ops.pallas.mla_attention import mla_paged_attention
+
+    def build(S):
+        pool = S((5, 128 * 96 + 1, PAGE, 640), BF16)
+        return (lambda q, c, pt, ctx, ql, lay: mla_paged_attention(
+            q, c, pt, ctx, ql, layer=lay, rank=512)), (
+                S((slots, tq, 128, 640), BF16), pool, S((slots, 96), I32),
+                S((slots,), I32), S((slots,), I32), S((), I32))
+    return build
+
+
+def _grouped_experts(tokens, swiglu):
+    """``grouped_expert_matmul`` over 4 layers x 16 held experts of 7680 x
+    2048, the stack indexed by a traced layer: the worst-case row buffer
+    of ``tokens`` x 8 picks."""
+    from paddle_tpu.ops.pallas.grouped_matmul import (buffer_rows,
+                                                      grouped_expert_matmul)
+
+    def build(S):
+        rows = buffer_rows(tokens * 8, 16)
+        k, n = (7680, 2048) if swiglu else (2048, 7680)
+        w = S((4, 16, k, n), BF16)
+        args = (S((rows, k), BF16),) + (w,) * (2 if swiglu else 1) + (
+            S((rows // 32,), I32), S((1,), I32), S((), I32))
+        if swiglu:
+            return (lambda x, wg, wu, te, nt, lay: grouped_expert_matmul(
+                x, (wg, wu), te, nt, swiglu=True, layer=lay)), args
+        return (lambda x, w, te, nt, lay: grouped_expert_matmul(
+            x, w, te, nt, layer=lay)), args
+    return build
+
+
 CASES = {
+    "mla_paged_decode_128slots": _mla_paged(128, 1),
+    "mla_paged_admit_tq512": _mla_paged(1, 512),
+    "grouped_experts_gate_up_t128": _grouped_experts(128, True),
+    "grouped_experts_down_t512": _grouped_experts(512, False),
     "flash_fwd_bwd_packed_b44_s512": _flash(44, 512),
     "flash_fwd_bwd_blocked_b4_s4096": _flash(4, 4096),
     "head_dx_softmax_m22528": _head_dx,

@@ -353,7 +353,7 @@ class TestNames:
                     kw = {k.arg: k.value for k in node.keywords}
                     assert "name" in kw, (path, node.lineno)
                     sites.append(kw["name"])
-        assert len(sites) == 13
+        assert len(sites) == 15
         fixed = sorted(n.value for n in sites if isinstance(n, ast.Constant))
         assert fixed == sorted([
             "flash_attention_fwd", "flash_attention_bwd_dq",
@@ -361,7 +361,8 @@ class TestNames:
             "flash_attention_packed_bwd", "fused_rms_norm",
             "fused_add_rms_norm", "fused_rope_qk", "quant_matmul",
             "ragged_paged_attention", "ragged_decode_attention",
-            "head_dx_softmax"])
+            "head_dx_softmax", "mla_paged_attention",
+            "grouped_expert_matmul"])
         assert [ast.unparse(n) for n in sites
                 if not isinstance(n, ast.Constant)] == \
             ["'apply_flat_update_' + kind"]
