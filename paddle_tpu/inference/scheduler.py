@@ -203,6 +203,10 @@ class OnlineReport:
     # that landed on held experts, held experts hit, the largest load of
     # one expert in a step), beside the loop steps they were counted over
     moe: Optional[Dict[str, int]] = None
+    # PR 31: a paged engine's ``page_slots`` (rows x table width of every
+    # paged attention call, a layer) and ``pages_fetched`` (the pages
+    # those rows hold: ``pages_read``) over the serve's segments
+    page_reads: Optional[Dict[str, int]] = None
     per_request: List[dict] = field(default_factory=list)
 
     def as_dict(self, with_requests: bool = False) -> dict:
@@ -392,6 +396,7 @@ class OnlineScheduler:
         # engine's phase spans and this loop's tally into one dict
         phases = eng.segment_phases = {}
         eng.segment_counts = {}
+        eng.segment_pages = {}
         t0 = _journal.now()
         self._serve_t0 = t0
         while pending or eng._queue or eng.free_slot_count() < eng.slots:
@@ -506,6 +511,7 @@ class OnlineScheduler:
                                 for k in TTFT_PARTS} if parts else None),
             moe=(dict(eng.segment_counts, steps=eng.last_run_ticks)
                  if eng.segment_counts else None),
+            page_reads=dict(eng.segment_pages) or None,
             **self._report_extras(reqs),
             per_request=[{
                 "rid": r.rid,

@@ -205,6 +205,7 @@ class _PendingSegment:
     # its event log additionally carries a [steps, n] int32 column block
     counters: bool = False
     seg: int = 0                   # the engine's index of this segment
+    s_max: int = 0                 # paged: the admit window's width
 
 
 @dataclass
@@ -628,6 +629,10 @@ class ServingEngine:
         # PR 29: sums of the model's per-step counters (``serving.moe.*``)
         # over the segments since the serve loop last reset the dict
         self.segment_counts: Dict[str, int] = {}
+        # PR 31: page slots the paged attention calls of those segments
+        # were handed (rows x table width a step) and the pages they had
+        # to fetch (``pages_read``), per layer — ``serving.pages_*``
+        self.segment_pages: Dict[str, int] = {}
         # r14 cold-start metric (ISSUE 9 satellite; ROADMAP item 5's
         # first deliverable): build→first-emitted-token wall time, the
         # number autoscaling/rollout decisions gate on. Stamped ONCE per
@@ -1573,7 +1578,7 @@ class ServingEngine:
                         on_admit=None, on_retire=None,
                         chunk_marker: Optional[int] = None,
                         acc=None, spec_stats: Optional[dict] = None,
-                        dig=None):
+                        dig=None, on_tick=None):
         """Host replay of a segment's event log — ONE contract for the
         contiguous and paged engines: walk the log chronologically,
         tracking slot occupancy (admits rebind a slot; decode ticks
@@ -1583,7 +1588,9 @@ class ServingEngine:
         ``on_retire(req, slot)`` are the paged engine's page-table
         bookkeeping hooks, called in event order so a slot freed and
         re-admitted mid-segment releases the old occupant's pages
-        before the new page list installs. ``chunk_marker`` (chunked
+        before the new page list installs; ``on_tick(live)`` sees each
+        decode tick's live (slot, request) pairs before their tokens
+        land. ``chunk_marker`` (chunked
         prefill): aq values >= it mark NON-FINAL prefill-chunk steps —
         no decode ran and no token surfaced there, so the replay skips
         the step.
@@ -1643,6 +1650,8 @@ class ServingEngine:
             elif acc is None:              # decode tick
                 live_now = [(s, r) for s, r in enumerate(self._active)
                             if r is not None and self._rem_host[s] > 0]
+                if on_tick is not None:
+                    on_tick(live_now)
                 share = 1.0 / len(live_now) if live_now else 0.0
                 for s, r in live_now:
                     # r18 meter: every live slot consumed this tick's
@@ -1797,6 +1806,13 @@ class ServingEngine:
                 self.segment_counts[name] = \
                     self.segment_counts.get(name, 0) + v
         return seg
+
+    def _page_telemetry(self, reads: Dict[str, int]) -> None:
+        """One segment's ``pages_fetched`` / ``page_slots`` into the
+        ``serving.*`` counters of those names and ``segment_pages``."""
+        for name, v in reads.items():
+            _metrics.counter(f"serving.{name}").inc(v)
+            self.segment_pages[name] = self.segment_pages.get(name, 0) + v
 
     def _spec_telemetry(self, stats: dict) -> None:
         """Per-segment speculative accounting (r15 satellite): counters
@@ -3276,13 +3292,15 @@ class ServingEngine:
         return _PendingSegment(paged=True, picked=picked, n=n, now=now,
                                prefix_cache=prefix_cache, dev=out[5:],
                                pre_lens=pre_lens_l, req_pages=req_pages,
-                               full_prompts=fulls,
+                               full_prompts=fulls, s_max=s_max,
                                chunk_marker=chunk_marker,
                                digest=self.quality_digest, sp=sp_mode,
                                counters=hasattr(self.model,
                                                 "SEGMENT_COUNTERS"))
 
     def _finish_segment_paged(self, p: _PendingSegment) -> dict:
+        from ..ops.pallas.paged_attention import pages_read
+
         picked, n, prefix_cache = p.picked, p.n, p.prefix_cache
         pre_lens_l, req_pages = p.pre_lens, p.req_pages
         pgr = self.pager
@@ -3335,9 +3353,28 @@ class ServingEngine:
             # harvest-by-reference can still retain a finished request's
             # prompt pages
             pending_frees: List[List[int]] = []
+            # what the segment's paged attention calls were handed and
+            # what they had to fetch, a layer: an admission is one row
+            # of ``s_max`` queries after its reused prefix, a tick one
+            # query a slot (free slots fetch nothing) at the position
+            # the host holds; the hooks only note the positions. The
+            # chunked, speculative and sequence-parallel programs'
+            # prefill steps are not replayed, so their segments are
+            # not reckoned.
+            counted = p.chunk_marker is None
+            admit_ctx: List[int] = []
+            tick_ctx: List[int] = []
+            ticks = 0
 
             def on_admit(q, s):
                 pgr.install(s, req_pages[q])
+                admit_ctx.append(pre_lens_l[q])
+
+            def on_tick(live):
+                nonlocal ticks
+                ticks += 1
+                tick_ctx.extend([len(r.prompt) + len(r.tokens) - 1
+                                 for _, r in live])
 
             def on_retire(r, s):
                 r._meter_release()
@@ -3348,7 +3385,8 @@ class ServingEngine:
              eos_stops) = self._replay_segment(
                  picked, toks, aq, aslot, steps, n, on_admit, on_retire,
                  chunk_marker=p.chunk_marker, acc=acc,
-                 spec_stats=spec_stats, dig=dig)
+                 spec_stats=spec_stats, dig=dig,
+                 on_tick=on_tick if counted else None)
             if p.chunk_marker is not None:
                 chunk_steps = int(np.sum(np.asarray(aq[:steps])
                                          >= p.chunk_marker))
@@ -3414,6 +3452,18 @@ class ServingEngine:
                                     new_tokens, max(0, n - qadm))
             if counts is not None:
                 counts = self._count_telemetry(counts[:steps])
+            reads = None
+            if counted:
+                def held(ctx, q_len):
+                    return int(np.minimum(pages_read(
+                        np.asarray(ctx, np.int64), q_len, psz),
+                        pgr.max_pages).sum())
+
+                reads = {"pages_fetched": held(admit_ctx, p.s_max)
+                         + held(tick_ctx, 1),
+                         "page_slots": (len(admit_ctx) + ticks
+                                        * self.slots) * pgr.max_pages}
+                self._page_telemetry(reads)
         out = {"steps": steps, "admitted": admitted,
                "first_tokens": first_tokens,
                "first_token_steps": first_steps, "finished": finished,
@@ -3422,6 +3472,8 @@ class ServingEngine:
             out["spec"] = spec_stats
         if counts is not None:
             out["counters"] = counts
+        if reads is not None:
+            out.update(reads)
         return out
 
     def collect_finished(self) -> Dict[int, List[int]]:

@@ -1102,17 +1102,20 @@ def _head_logits(cfg: LlamaConfig, params, x, fused_tick: bool,
 
 @scoped("attention")
 def _paged_attention(cfg: LlamaConfig, q, planes, layer, page_table,
-                     positions):
+                     positions, q_len=None):
     """Attention over layer ``layer`` of a paged KV pool. q [B,T,nH,D];
     ``planes``: the WHOLE pool as it lies ({"k","v"} [L, P, page_size,
     Hkv*D], plus a quantized pool's fp32 scale planes "ks"/"vs" [L, P,
     page_size]); ``layer``: int32 scalar, static or traced;
     page_table [B, max_pages]; ``positions`` [B, T] absolute query
     positions (row t of slot b at ``positions[b, t]``, keys [0,
-    positions[b, t]] visible). Dispatches to the unified page-indirect
-    Pallas kernel when the shape tiles: the kernel indexes the pool by
-    (layer, page), so per-slot KV reads scale with position and no
-    layer is ever sliced out of the pool. The fallback (CPU/tier-1, the
+    positions[b, t]] visible); ``q_len`` ([B] int32, optional): rows of
+    each slot whose output the caller keeps, 0 for a slot that is not
+    live. Dispatches to the unified page-indirect Pallas kernel when the
+    shape tiles: the kernel indexes the pool by (layer, page) and copies
+    only the pages a slot holds (none where ``q_len`` is 0), so per-slot
+    KV reads scale with position and no layer is ever sliced out of the
+    pool. The fallback (CPU/tier-1, the
     quantized pool, meshes) gathers ``pool[layer, page_table]`` — the
     slot's pages — and reshapes the GATHERED window for the dense
     formulation, identical math; a quantized pool's scale rows are
@@ -1127,7 +1130,7 @@ def _paged_attention(cfg: LlamaConfig, q, planes, layer, page_table,
     if "ks" not in planes and paged_attention_active(
             psz, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim):
         return ragged_paged_attention(q, kp, vp, page_table,
-                                      positions[:, 0], layer=layer)
+                                      positions[:, 0], q_len, layer=layer)
     dt = q.dtype
     gk = kp[layer, page_table]               # [B, max_pages, psz, Hkv*D]
     gv = vp[layer, page_table]
@@ -1163,8 +1166,9 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
     ``live`` ([B] bool, optional) routes retired slots' writes to the
     reserved trash page 0 instead (a frozen slot must never write a
     page the allocator may have handed to someone else), as do
-    positions past the table. Returns (logits [B, V], updated pool) —
-    or, with ``logits_all=True``, logits at EVERY query position
+    positions past the table; the paged kernel fetches no page for such
+    a slot, and its logits mean nothing. Returns (logits [B, V], updated
+    pool) — or, with ``logits_all=True``, logits at EVERY query position
     ([B, T, V]): the speculative verify tick scores all K+1 drafted
     positions from the same single weight stream (SCALING §3j), so the
     lm_head matmul runs over the whole chunk instead of one gathered
@@ -1219,6 +1223,9 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
         from ..quantization.serving import quantize_kv_rows
 
     fused_tick = T == 1 and _tick_fused_active(cfg)
+    # a retired slot's output is dropped by every caller: the paged
+    # kernel fetches none of its pages
+    q_len = None if live is None else jnp.where(live, T, 0)
 
     def layer(x, planes, lp, i):
         q, k_new, v_new = (_decode_qkv(cfg, x, lp, pos) if fused_tick
@@ -1233,7 +1240,8 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
             planes = {n: a.at[i, phys, prow].set(
                 rows[n].reshape((B, T) + a.shape[3:]).astype(a.dtype))
                 for n, a in planes.items()}
-        attn = _paged_attention(cfg, q, planes, i, page_table, positions)
+        attn = _paged_attention(cfg, q, planes, i, page_table, positions,
+                                q_len)
         return (_decode_post(cfg, x, attn, lp) if fused_tick
                 else _layer_post(cfg, x, attn, lp)), planes
 

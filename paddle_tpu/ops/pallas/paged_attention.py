@@ -4,50 +4,57 @@ The paged extension of ``decode_attention.py`` (the Ragged Paged
 Attention design, PAPERS.md #1): KV lives in a flat pool of fixed-size
 pages and each slot's sequence is the concatenation of the pages its
 int32 page table names. The pool is read WHERE IT LIES: at rest it is
-``[L, num_pages, page_size, Hkv*D]`` (``llama.init_paged_pool`` — the
-minor dimension the kernel's blocks have, so nothing re-tiles it) and
+``[L, num_pages, page_size, Hkv*D]`` (``llama.init_paged_pool``) and
 the model's layer loop hands the kernel the whole stacked pool plus the
 layer's index; a single layer's ``[num_pages, page_size, Hkv*D]`` pool
 is served by the same call without ``layer``. The kernel
 serves **prefill chunks and decode ticks in the same launch**: slot
 ``b`` carries ``q_len[b]`` query rows (1 = a decode tick, >1 = a
-prefill chunk) whose row ``t`` sits at absolute position
-``ctx_len[b] + t`` and attends keys ``[0, ctx_len[b] + t]``.
+prefill chunk, 0 = a free slot) whose row ``t`` sits at absolute
+position ``ctx_len[b] + t`` and attends keys ``[0, ctx_len[b] + t]``.
 
-Page indirection and raggedness are BOTH BlockSpec index-map facts:
+The kernel fetches its pages BY HAND, and only the pages a slot holds:
 
-- grid = (slot, page-slot) with the page tables, context lengths,
-  chunk widths and the layer index SCALAR-PREFETCHED. The K/V index
-  map clamps the page slot at the slot's last *needed* page and then
-  routes it through the page table — so the pipeline fetches block
-  ``(layer, table[b, min(j, last)])``: per-slot KV HBM reads scale with
-  ``ctx+q_len`` (position), not the table width, and a page-table hop
-  costs zero extra DMAs (the indirection happens in index arithmetic
-  the Mosaic pipeline already does).
-- grid steps past the clamp re-name the SAME physical page, so the
-  HBM→VMEM copy is elided; compute is skipped with ``pl.when``. The
-  grid itself stays static — nothing recompiles as sequences grow or
-  page tables change.
-- masking is in VIRTUAL coordinates: the key row ``r`` of page slot
-  ``j`` is position ``j*page_size + r`` regardless of which physical
-  page backs it.
+- the grid is over slots, nothing in it scales with the table's width.
+  Both pool planes stay in HBM (``memory_space=ANY``); the page table,
+  context lengths, chunk widths and the layer index are
+  SCALAR-PREFETCHED.
+- inside a slot a ``fori_loop`` runs over blocks of N pages up to the
+  slot's last NEEDED page (``pages_read``). A block's pages are brought
+  into a double-buffered VMEM scratch by one ``make_async_copy`` a page
+  and plane, ``pool[layer, table[b, j]]`` -> rows ``[p*page_size,
+  (p+1)*page_size)`` of the buffer, and the next block's copies (after
+  a slot's last block: the next slot's first) are in flight while this
+  one is computed. A slot's HBM reads are its
+  ``pages_read`` pages and nothing else; a free slot (``q_len`` 0)
+  copies nothing and computes nothing.
+- N follows the shapes the kernel is traced with: ``N * page_size``
+  key rows fill the MXU's 128 lanes, twice that where the query block
+  is small (``_block_pages``). The loops over
+  blocks and over a block's pages are rolled (``fori_loop``), so what
+  is traced, lowered and loaded does not grow with the table's width
+  nor with N; only the per-kv-head matmuls are unrolled.
+- masking is in VIRTUAL coordinates: key row ``r`` of page slot ``j``
+  is position ``j*page_size + r`` whichever physical page backs it.
+  Rows of a block's buffer that no copy of this slot wrote (the tail
+  past its last page) are masked the same way; the V buffer starts as
+  zeros so that what lies there is always finite.
 
 Query layout: the wrapper permutes q to kv-head-major
 ``[B, Hkv*Tq*rep, D]`` rows (``row = h*Tq*rep + t*rep + r`` — for
 Tq == 1 exactly the grouped-GQA row order of the decode kernel), so
 each kv head's queries are one contiguous row block and the repeated
 cache is never materialised. fp32 online-softmax state (running
-max/sum + accumulator) lives in VMEM scratch across the page-slot grid
-steps; the last step normalises and writes the slot's output.
+max/sum + accumulator) lives in VMEM scratch across a slot's blocks;
+after the last one the slot's output is normalised and written.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -63,74 +70,142 @@ FORCE_INTERPRET = False
 
 def pages_read(ctx_len, q_len, page_size: int):
     """Pages the kernel fetches for a slot whose chunk ends at position
-    ``ctx_len + q_len - 1`` (keys [0, end] visible -> end//page + 1).
-    The analytic half of the pages-per-tick evidence; the clamp in the
-    BlockSpec index map below is what enforces it."""
-    return (ctx_len + q_len - 1) // page_size + 1
+    ``ctx_len + q_len - 1`` (keys [0, end] visible -> end//page + 1), and
+    none for a slot with no query row. The kernel's loop over a slot's
+    pages runs to exactly this bound; the engine's ``pages_fetched``
+    counter is its sum over a segment's steps."""
+    return ((ctx_len + q_len - 1) // page_size + 1) * (q_len > 0)
 
 
-def _make_kernel(nH: int, Hkv: int, D: int, Tq: int, psz: int,
-                 n_blocks: int):
-    rep = nH // Hkv
+def _block_pages(page_size: int, max_pages: int, q_rows: int) -> int:
+    """Pages a block of the kernel's loop holds: as many as give the
+    score tile 128 key rows (the MXU's lanes) — 256 while the float32
+    tile of ``q_rows`` x key rows stays within 1 MiB of VMEM, which
+    halves a decode tick's trips round the loop — and never more than
+    the table names."""
+    key_rows = 256 if q_rows * 256 * 4 <= 2 ** 20 else 128
+    return max(1, min(key_rows // page_size, max_pages))
+
+
+def _make_kernel(Hkv: int, D: int, Tq: int, rep: int, psz: int, N: int,
+                 max_pages: int):
     TR = Tq * rep                     # query rows per kv head
+    R = Hkv * TR
+    KB = N * psz                      # key rows a block
 
-    def kernel(pt_ref, ctx_ref, qlen_ref, lay_ref, q_ref, k_ref, v_ref,
-               o_ref, acc_ref, m_ref, l_ref):
-        b = pl.program_id(0)
-        j = pl.program_id(1)
-        ctx = ctx_ref[b]
-        last = (ctx + qlen_ref[b] - 1) // psz   # last needed page slot
+    def kernel(pt_ref, ctx_ref, qlen_ref, lay_ref, q_ref, k_hbm, v_hbm,
+               o_ref, kbuf, vbuf, sem, first_ref, acc_ref, m_ref, l_ref):
+        # scalar control in lax primitives on int32 that is never
+        # negative: ``//`` and ``%`` on a tracer each trace a function
+        # of a dozen equations, at every start of the program
+        b, slots = pl.program_id(0), pl.num_programs(0)
+        ctx, qlen, lay = ctx_ref[b], qlen_ref[b], lay_ref[0]
 
-        @pl.when(j == 0)
+        def held(slot):
+            """``pages_read`` of ``slot``, inside the table (a chunk's
+            padding rows may reach past its end)."""
+            n = lax.div(ctx_ref[slot] + qlen_ref[slot] + (psz - 1), psz)
+            return lax.select(qlen_ref[slot] > 0, lax.min(n, max_pages), 0)
+
+        # this slot's pages, and those of the slot whose first block is
+        # copied while this one's last is computed (none past the end)
+        mine = held(b)
+        nb = lax.min(b + 1, slots - 1)
+        theirs = lax.select(b + 1 < slots, held(nb), 0)
+        n_blocks = lax.div(mine + (N - 1), N)
+
+        def each_page(slot, n_held, i, buf, act):
+            """``act`` on both planes' copies of the pages ``slot`` holds
+            (``n_held``) of its block ``i``, into buffer ``buf``."""
+            def page(p, carry):
+                src = pt_ref[slot, i * N + p]
+                rows = pl.ds(pl.multiple_of(p * psz, psz), psz)
+                for hbm, vmem in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                    act(pltpu.make_async_copy(
+                        hbm.at[lay, src], vmem.at[buf, rows], sem.at[buf]))
+                return carry
+
+            lax.fori_loop(0, lax.min(n_held - i * N, N), page, 0)
+
+        @pl.when(b == 0)
         def _():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-            l_ref[...] = jnp.zeros_like(l_ref)
+            # p = 0 times whatever lies past a slot's last page must be
+            # 0: from here on the buffer holds zeros or pool rows
+            vbuf[...] = jnp.zeros_like(vbuf)
+            first_ref[0] = 0
 
-        # page slots past the clamp: the index map already re-fetched
-        # nothing (same physical page as the previous step); skip compute
-        @pl.when(j <= last)
+        # a slot's first block is in flight when the slot begins, in
+        # buffer ``first``: the slot before it started the copies. Slot
+        # 0 starts its own, and a free slot, whose loop below never
+        # runs, the next slot's.
+        first = first_ref[0]
+        own = n_blocks > 0
+
+        @pl.when((b == 0) | ~own)
         def _():
+            each_page(lax.select(own, b, nb), lax.select(own, mine, theirs),
+                      0, first, lambda c: c.start())
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+        def block(i, carry):
+            buf = lax.rem(first + i, 2)
+            # what is computed next — this slot's next block or, after
+            # its last, the next slot's first — is copied meanwhile
+            last = i + 1 == n_blocks
+            each_page(lax.select(last, nb, b),
+                      lax.select(last, theirs, mine),
+                      lax.select(last, 0, i + 1), 1 - buf,
+                      lambda c: c.start())
+            each_page(b, mine, i, buf, lambda c: c.wait())
             q = q_ref[0]              # [Hkv*TR, D], PRE-SCALED, h-major
             parts = []
             for h in range(Hkv):
-                kh = k_ref[0, :, h * D:(h + 1) * D]       # [psz, D]
+                kh = kbuf[buf, :, h * D:(h + 1) * D]      # [KB, D]
                 qh = q[h * TR:(h + 1) * TR]               # [TR, D]
-                parts.append(jax.lax.dot_general(
+                parts.append(lax.dot_general(
                     qh, kh, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32))
-            s = jnp.concatenate(parts, axis=0)            # [Hkv*TR, psz]
-            # virtual key position of this page slot's rows vs the
-            # per-row query position ctx + t (t = (row % TR) // rep)
-            kpos = j * psz + jax.lax.broadcasted_iota(
-                jnp.int32, (Hkv * TR, psz), 1)
-            t = (jax.lax.broadcasted_iota(
-                jnp.int32, (Hkv * TR, psz), 0) % TR) // rep
-            s = jnp.where(kpos <= ctx + t, s, -jnp.inf)
+            s = lax.concatenate(parts, 0)                 # [Hkv*TR, KB]
+            # virtual key position of this block's rows vs the per-row
+            # query position ctx + t (t = (row % TR) // rep); a padding
+            # row (t >= q_len) sees what the last live row sees, so no
+            # row looks past the pages that were fetched
+            kpos = i * KB + lax.broadcasted_iota(jnp.int32, (R, KB), 1)
+            t = lax.div(lax.rem(
+                lax.broadcasted_iota(jnp.int32, (R, KB), 0), TR), rep)
+            s = lax.select(kpos <= ctx + lax.min(t, qlen - 1), s,
+                           lax.full_like(s, -jnp.inf))
             m_prev = m_ref[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)  # page 0: exp(-inf - m) = 0
-            l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1,
-                                                   keepdims=True)
-            pb = p.astype(v_ref.dtype)
+            m_new = lax.max(m_prev, lax.expand_dims(
+                lax.reduce_max(s, (1,)), (1,)))
+            p = lax.exp(s - m_new)
+            alpha = lax.exp(m_prev - m_new)  # block 0: exp(-inf - m) = 0
+            l_new = l_ref[:, :1] * alpha + lax.expand_dims(
+                lax.reduce_sum(p, (1,)), (1,))
+            pb = p.astype(vbuf.dtype)
             pv_parts = []
             for h in range(Hkv):
-                vh = v_ref[0, :, h * D:(h + 1) * D]       # [psz, D]
-                ph = pb[h * TR:(h + 1) * TR]              # [TR, psz]
-                pv_parts.append(jax.lax.dot_general(
+                vh = vbuf[buf, :, h * D:(h + 1) * D]      # [KB, D]
+                ph = pb[h * TR:(h + 1) * TR]              # [TR, KB]
+                pv_parts.append(lax.dot_general(
                     ph, vh, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32))
-            acc_ref[...] = acc_ref[...] * alpha + jnp.concatenate(
-                pv_parts, axis=0)                         # [Hkv*TR, D]
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            acc_ref[...] = acc_ref[...] * alpha + lax.concatenate(
+                pv_parts, 0)                              # [Hkv*TR, D]
+            m_ref[...] = lax.broadcast_in_dim(m_new, m_ref.shape, (0, 1))
+            l_ref[...] = lax.broadcast_in_dim(l_new, l_ref.shape, (0, 1))
+            return carry
 
-        @pl.when(j == n_blocks - 1)
-        def _():
-            # every query row has key 0 visible (ctx + t >= 0), so
-            # l >= exp(s_0 - m) > 0 — padding rows included
-            o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+        lax.fori_loop(0, n_blocks, block, 0)
+        first_ref[0] = lax.rem(first + n_blocks, 2)
+        # every live slot's rows have key 0 visible, so l > 0 there; a
+        # free slot (no block ran) writes zeros
+        l = l_ref[:, :1]
+        o_ref[0] = (acc_ref[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
 
     return kernel
 
@@ -142,17 +217,18 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
 
     q: [B, Tq, nH, D] query chunks (row t of slot b sits at absolute
     position ``ctx_len[b] + t``; rows past ``q_len[b]`` are padding and
-    produce garbage outputs the caller discards). kp/vp: the page pool
-    in its layout at rest, already holding the chunk's own K/V rows (the
-    caller scatters before attending, the same contract as the
-    contiguous cache) — with ``layer`` (an int32 scalar, traced or not)
-    the whole stacked ``[L, P, page_size, Hkv*D]`` pool, of which the
-    kernel reads layer ``layer``'s pages and nothing else; without it
-    one layer's ``[P, page_size, Hkv*D]``. The pool is never reshaped
-    here: any other rank raises. page_table: [B, max_pages] int32
-    physical page ids per virtual page slot. ctx_len: [B] rows already
-    in the cache before this chunk. q_len: [B] live rows per chunk
-    (None = all Tq). Returns [B, Tq, nH, D] in q.dtype. Raises on
+    produce outputs the caller discards: they attend what the slot's
+    last live row attends, and a slot with ``q_len`` 0 returns zeros).
+    kp/vp: the page pool in its layout at rest, already holding the
+    chunk's own K/V rows (the caller scatters before attending, the same
+    contract as the contiguous cache) — with ``layer`` (an int32 scalar,
+    traced or not) the whole stacked ``[L, P, page_size, Hkv*D]`` pool,
+    of which the kernel reads layer ``layer``'s pages and nothing else;
+    without it one layer's ``[P, page_size, Hkv*D]``. The pool is never
+    reshaped here: any other rank raises. page_table: [B, max_pages]
+    int32 physical page ids per virtual page slot. ctx_len: [B] rows
+    already in the cache before this chunk. q_len: [B] live rows per
+    chunk (None = all Tq). Returns [B, Tq, nH, D] in q.dtype. Raises on
     untileable shapes — callers gate with ``paged_attention_active``.
     """
     B, Tq, nH, D = q.shape
@@ -173,6 +249,8 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
             f"with paged_attention_active")
     Hkv = HD // D
     rep = nH // Hkv
+    R = Hkv * Tq * rep
+    N = _block_pages(psz, max_pages, R)
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
     if q_len is None:
         q_len = jnp.full((B,), Tq, jnp.int32)
@@ -180,40 +258,33 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
     # the decode kernel's grouped-GQA order); scale folded in outside
     qs = (q * scale).astype(q.dtype)
     qh = qs.reshape(B, Tq, Hkv, rep, D).transpose(0, 2, 1, 3, 4)
-    qh = qh.reshape(B, Hkv * Tq * rep, D)
+    qh = qh.reshape(B, R, D)
 
-    def kv_map(b, j, pt_ref, ctx_ref, qlen_ref, lay_ref):
-        # clamp at the slot's last needed page slot, then route through
-        # the page table: past the clamp the SAME physical page repeats
-        # and Mosaic skips the HBM->VMEM copy — these two index hops are
-        # the entire "paged + ragged" property
-        last = (ctx_ref[b] + qlen_ref[b] - 1) // psz
-        return (lay_ref[0], pt_ref[b, jnp.minimum(j, last)], 0, 0)
-
-    # the kernel sees [1, psz, Hkv*D]: the BlockSpec squeezes the layer
-    kv_block = (None, 1, psz, HD)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, Hkv * Tq * rep, D),
-                         lambda b, j, *_: (b, 0, 0)),
-            pl.BlockSpec(kv_block, kv_map),
-            pl.BlockSpec(kv_block, kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, Hkv * Tq * rep, D),
-                               lambda b, j, *_: (b, 0, 0)),
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, R, D), lambda b, *_: (b, 0, 0)),
+                  in_hbm, in_hbm],
+        out_specs=pl.BlockSpec((1, R, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hkv * Tq * rep, D), jnp.float32),    # accumulator
-            pltpu.VMEM((Hkv * Tq * rep, 128), jnp.float32),  # running max
-            pltpu.VMEM((Hkv * Tq * rep, 128), jnp.float32),  # running sum
+            pltpu.VMEM((2, N * psz, HD), kp.dtype),    # K blocks, 2 deep
+            pltpu.VMEM((2, N * psz, HD), vp.dtype),    # V blocks
+            pltpu.SemaphoreType.DMA((2,)),             # one a buffer
+            pltpu.SMEM((1,), jnp.int32),       # the next first block's
+            pltpu.VMEM((R, D), jnp.float32),           # accumulator
+            pltpu.VMEM((R, 128), jnp.float32),         # running max
+            pltpu.VMEM((R, 128), jnp.float32),         # running sum
         ],
     )
     out = pl.pallas_call(
-        _make_kernel(nH, Hkv, D, Tq, psz, max_pages),
+        _make_kernel(Hkv, D, Tq, rep, psz, N, max_pages),
         name="ragged_paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv * Tq * rep, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, R, D), q.dtype),
+        # slots in order on one core: the V buffer is zeroed at slot 0
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret or (FORCE_INTERPRET and not _on_tpu()),
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(ctx_len, jnp.int32),
       jnp.asarray(q_len, jnp.int32),

@@ -104,23 +104,32 @@ def _ragged_decode(kv_dtype):
     return build
 
 
-def _paged(tq, layers=0):
+def _paged(tq, layers=0, slots=SLOTS, heads=(NH, NH, D), pages=None,
+           max_pages=MAX_LEN // PAGE):
     """One layer's [P, page, Hkv*D] pool, or with ``layers`` the stacked
     pool and the layer as a traced scalar: the call of the layer scan."""
     from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
 
     def build(S):
-        max_pages = MAX_LEN // PAGE
-        plane = (SLOTS * max_pages + 1, PAGE, NH * D)
+        nh, hkv, d = heads
+        plane = (pages or slots * max_pages + 1, PAGE, hkv * d)
         pool = S(((layers,) if layers else ()) + plane, BF16)
-        args = (S((SLOTS, tq, NH, D), BF16), pool, pool,
-                S((SLOTS, max_pages), I32), S((SLOTS,), I32),
-                S((SLOTS,), I32))
+        args = (S((slots, tq, nh, d), BF16), pool, pool,
+                S((slots, max_pages), I32), S((slots,), I32),
+                S((slots,), I32))
         if not layers:
             return ragged_paged_attention, args
         return (lambda q, k, v, pt, ctx, ql, lay: ragged_paged_attention(
             q, k, v, pt, ctx, ql, layer=lay)), args + (S((), I32),)
     return build
+
+
+def _paged_cell(slots, tq):
+    """The kernel as ``internlm2-1.8b``'s serve cells call it (program
+    ``('pseg', 32, 256, 32)``): 16 / 8 heads x 128, the 24-layer pool of
+    2049 pages, a table 64 pages wide."""
+    return _paged(tq, layers=24, slots=slots, heads=(16, 8, 128),
+                  pages=2049, max_pages=64)
 
 
 def _tick(which):
@@ -218,6 +227,10 @@ CASES = {
     "ragged_paged_tq64": _paged(64),
     "ragged_paged_layered_tq1": _paged(1, layers=12),
     "ragged_paged_layered_tq16": _paged(16, layers=12),
+    "ragged_paged_cell_decode_b32": _paged_cell(32, 1),
+    "ragged_paged_cell_admit_tq256": _paged_cell(1, 256),
+    "ragged_paged_cell_tq16": _paged_cell(32, 16),
+    "ragged_paged_cell_tq64": _paged_cell(8, 64),
     "fused_rms_norm": _tick("rms"),
     "fused_add_rms_norm": _tick("add_rms"),
     "fused_rope_qk": _tick("rope"),
@@ -278,7 +291,11 @@ def test_paged_segment_holds_pool_once(shaped, no_persistent_cache,
         shaped((n_pad, s_max), I32), req, req, req,
         shaped((n_pad, max_pages), I32), shaped((), I32)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text, "the paged kernel was not chosen"
+    # one kernel a call site: the admit branch's and the decode branch's
+    # layer scan, whatever the table's width and the pages a block
+    kernels = re.findall(r"%(ragged_paged_attention[.\d]*) = [^\n]*"
+                         r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(kernels) == 2, f"paged kernel call sites: {kernels}"
 
     layer_elems = pages * PAGE * NH * D
     moved = []

@@ -41,6 +41,18 @@ def test_importing_the_package_initialises_no_backend():
     assert proc.returncode == 0, proc.stderr[-2000:]
 
 
+def test_importing_the_package_imports_no_kernel():
+    """``import paddle_tpu`` is part of every start (``setup_s``): the
+    Pallas kernels, Pallas itself and the serving engine load when a
+    program first needs them, not with the package."""
+    proc = _run("import sys, paddle_tpu\n"
+                "late = [m for m in sys.modules if m.startswith(("
+                "'jax.experimental.pallas', 'jax._src.pallas', "
+                "'paddle_tpu.ops.pallas', 'paddle_tpu.inference.serving'))]\n"
+                "assert not late, late\n")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 @pytest.fixture
 def cache_config_restored():
     import paddle_tpu as paddle

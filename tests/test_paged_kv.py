@@ -355,13 +355,19 @@ class TestUnifiedKernel:
                                        rtol=2e-5, atol=2e-5)
 
     def test_pages_read_scale_with_position(self):
-        """The analytic pages-fetched contract the BlockSpec clamp
-        enforces: reads track ctx + q_len, not the table width."""
+        """The analytic pages-fetched contract, which is the bound of
+        the kernel's loop over a slot's pages: reads track ctx + q_len,
+        not the table width, and a slot without a query row reads
+        nothing."""
         assert pa.pages_read(0, 1, 16) == 1
         assert pa.pages_read(15, 1, 16) == 1
         assert pa.pages_read(16, 1, 16) == 2
         assert pa.pages_read(100, 1, 16) == 7
         assert pa.pages_read(32, 8, 16) == 3   # prefill chunk spans more
+        assert pa.pages_read(0, 0, 16) == pa.pages_read(100, 0, 16) == 0
+        np.testing.assert_array_equal(
+            pa.pages_read(np.array([0, 16, 100]), np.array([1, 0, 8]), 16),
+            [1, 0, 7])
 
     def test_dispatch_gates(self, monkeypatch):
         if jax.default_backend() == "cpu":
@@ -425,6 +431,223 @@ class TestUnifiedKernel:
                                  cfg, pool, pt,
                                  jnp.asarray([4, 9], jnp.int32))
         assert pa.selection_count() == 0
+
+
+def _attend_reference(q, kp, vp, pt, ctx, qlen):
+    """Plain float32 ``jax.numpy``: every slot's pages gathered through
+    its table, keys [0, ctx + t] visible to row t, exact softmax. Rows
+    past ``qlen`` are not the kernel's to get right; a slot with no row
+    is zeros."""
+    B, Tq, nH, D = q.shape
+    psz, Hkv = kp.shape[1], kp.shape[2] // D
+    f32 = jnp.float32
+    gk = kp.astype(f32)[pt].reshape(B, -1, Hkv, D)
+    gv = vp.astype(f32)[pt].reshape(B, -1, Hkv, D)
+    qf = q.astype(f32).reshape(B, Tq, Hkv, nH // Hkv, D) / np.sqrt(D)
+    s = jnp.einsum("bthrd,bkhd->bthrk", qf, gk, precision="highest")
+    kpos = jnp.arange(gk.shape[1])
+    qpos = ctx[:, None] + jnp.arange(Tq)
+    s = jnp.where((kpos <= qpos[..., None])[:, :, None, None], s, -jnp.inf)
+    out = jnp.einsum("bthrk,bkhd->bthrd", jax.nn.softmax(s, axis=-1), gv,
+                     precision="highest").reshape(B, Tq, nH, D)
+    return jnp.where((qlen > 0)[:, None, None, None], out, 0.0)
+
+
+def _assert_live_rows_match(out, ref, qlen, tol=2e-5):
+    out, ref = np.asarray(out, np.float32), np.asarray(ref)
+    assert np.isfinite(out).all()
+    for b, n in enumerate(np.asarray(qlen)):
+        rows = slice(0, int(n)) if n else slice(None)   # free slot: zeros
+        np.testing.assert_allclose(out[b, rows], ref[b, rows], rtol=tol,
+                                   atol=tol, err_msg=f"slot {b}")
+
+
+class TestFetchedPages:
+    """The kernel copies by hand the pages a slot holds, ``_block_pages``
+    a block, up to ``pages_read``: parity with the plain float32
+    reference at every edge of a page, a block and the table."""
+
+    nH, Hkv, D, psz, max_pages = 4, 2, 64, 16, 64
+
+    def pool(self, rng, pages, dtype=jnp.float32, layers=None):
+        shape = (pages, self.psz, self.Hkv * self.D)
+        if layers:
+            shape = (layers,) + shape
+        return (jnp.asarray(rng.randn(*shape), dtype),
+                jnp.asarray(rng.randn(*shape), dtype))
+
+    def queries(self, rng, B, Tq, dtype=jnp.float32):
+        return jnp.asarray(rng.randn(B, Tq, self.nH, self.D), dtype)
+
+    def test_block_follows_the_shapes(self):
+        """128 key rows a block, 256 under a small query block."""
+        assert pa._block_pages(16, 64, 4096) == 8
+        assert pa._block_pages(16, 64, 16) == pa._block_pages(
+            16, 64, 1024) == 16
+        assert pa._block_pages(8, 64, 4096) == 16
+        assert pa._block_pages(32, 64, 4096) == 4
+        assert pa._block_pages(16, 4, 16) == 4      # never past the table
+        assert pa._block_pages(512, 64, 16) == 1
+
+    @pytest.mark.parametrize("table", ["shuffled", "repeated"])
+    @pytest.mark.parametrize("Tq", [1, 16, 64, 256])
+    def test_edges_of_a_page_a_block_and_the_table(self, Tq, table):
+        """One launch whose slots sit at context 0, 1, one short of /
+        exactly / one past a page and a block of N pages, one whose
+        table is full, one past the table's end with its padding rows,
+        and a FREE slot between live ones — over a table of shuffled
+        pages or one that names the same few pages again and again —
+        with ragged chunk widths."""
+        rng = np.random.RandomState(Tq)
+        psz, mp = self.psz, self.max_pages
+        blk = psz * pa._block_pages(psz, mp, self.nH * Tq)
+        ctx = np.array([0, 1, psz - 1, psz, psz + 1, 0, blk - 1, blk,
+                        blk + 1, 2 * blk - 1, mp * psz - Tq, mp * psz - 3])
+        B = len(ctx)
+        qlen = rng.randint(1, Tq + 1, size=B)
+        qlen[:2] = (Tq, 1)
+        qlen[5] = 0                                   # the free slot
+        qlen[-2] = Tq                                 # the table, full
+        qlen[-1] = min(Tq, 3)     # padding rows reach past the table
+        P = 1 + B * mp
+        if table == "shuffled":
+            pt = rng.permutation(np.arange(1, P)).reshape(B, mp)
+        else:
+            pt = rng.randint(1, 6, size=(B, mp))
+        q = self.queries(rng, B, Tq)
+        kp, vp = self.pool(rng, P)
+        args = [jnp.asarray(a, jnp.int32) for a in (pt, ctx, qlen)]
+        out = pa.ragged_paged_attention(q, kp, vp, *args, interpret=True)
+        _assert_live_rows_match(out, _attend_reference(q, kp, vp, *args),
+                                qlen)
+
+    @pytest.mark.parametrize("Tq", [1, 16])
+    def test_pages_a_slot_does_not_hold_are_never_read(self, Tq):
+        """Every page past a slot's ``pages_read`` is NaN, and so is
+        every page nobody's table names: a copy of one would poison the
+        output through ``0 * NaN``. A free slot's whole table is NaN."""
+        rng = np.random.RandomState(7)
+        psz, mp = self.psz, self.max_pages
+        ctx = np.array([0, 5, 37, 127, 128, 500, 300])
+        qlen = np.array([1, Tq, 1, Tq, 1, Tq, 0])
+        B = len(ctx)
+        P = 1 + B * mp
+        pt = rng.permutation(np.arange(1, P)).reshape(B, mp)
+        kp, vp = (np.array(a) for a in self.pool(rng, P))
+        held = np.asarray(pa.pages_read(ctx, qlen, psz))
+        assert held.tolist() == [(c + n - 1) // psz + 1 if n else 0
+                                 for c, n in zip(ctx, qlen)]
+        clean_k, clean_v = kp.copy(), vp.copy()
+        kp[0] = vp[0] = np.nan
+        for b in range(B):
+            kp[pt[b, held[b]:]] = vp[pt[b, held[b]:]] = np.nan
+        q = self.queries(rng, B, Tq)
+        args = [jnp.asarray(a, jnp.int32) for a in (pt, ctx, qlen)]
+        out = pa.ragged_paged_attention(q, jnp.asarray(kp), jnp.asarray(vp),
+                                        *args, interpret=True)
+        ref = _attend_reference(q, jnp.asarray(clean_k),
+                                jnp.asarray(clean_v), *args)
+        _assert_live_rows_match(out, ref, qlen)
+
+    @pytest.mark.parametrize("Tq", [1, 16, 64])
+    def test_stacked_bf16_pool_with_a_traced_layer(self, Tq):
+        """The call of the model's layer scan: the whole [L, P, psz,
+        Hkv*D] bf16 pool, the layer a traced scalar, under jit."""
+        rng = np.random.RandomState(3 + Tq)
+        L, B, mp = 3, 5, self.max_pages
+        P = 1 + B * mp
+        pt = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(B, mp),
+                         jnp.int32)
+        ctx = jnp.asarray([0, 130, 15, 700, 255], jnp.int32)
+        qlen = jnp.asarray(rng.randint(0, Tq + 1, size=B), jnp.int32)
+        q = self.queries(rng, B, Tq, jnp.bfloat16)
+        kp, vp = self.pool(rng, P, jnp.bfloat16, layers=L)
+        call = jax.jit(lambda lay: pa.ragged_paged_attention(
+            q, kp, vp, pt, ctx, qlen, layer=lay, interpret=True))
+        for lay in (0, 2):
+            ref = _attend_reference(q, kp[lay], vp[lay], pt, ctx, qlen)
+            # bf16 probabilities and output: 2^-8 of values of order 1
+            _assert_live_rows_match(call(jnp.int32(lay)), ref, qlen,
+                                    tol=2e-2)
+
+    @staticmethod
+    def kernel_equations(Tq, psz, max_pages, B=4):
+        """Equations in the kernel's body, inner jaxprs (the rolled
+        loops, ``pl.when`` branches) included: what every start traces
+        and lowers, cache hit or not."""
+        S = jax.ShapeDtypeStruct
+        pool = S((3, 1 + B * max_pages, psz, 256), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda *a: pa.ragged_paged_attention(
+            *a, layer=jnp.int32(1)))(
+                S((B, Tq, 4, 128), jnp.bfloat16), pool, pool,
+                S((B, max_pages), jnp.int32), S((B,), jnp.int32),
+                S((B,), jnp.int32))
+
+        def count(jp):
+            n = 0
+            for eqn in jp.eqns:
+                n += 1
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    n += count(sub)
+            return n
+
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        return count(calls[0].params["jaxpr"])
+
+    @pytest.mark.parametrize("Tq", [1, 256])
+    def test_traced_body_does_not_grow_with_the_table_or_the_block(self,
+                                                                    Tq):
+        """Set-up is traced and lowered at every start: the kernel's
+        body is the same size whatever the table's width (16, 64, 128
+        page slots) and whatever the block (4 to 32 pages), and small."""
+        base = self.kernel_equations(Tq, 16, 64)
+        assert base < 400
+        for psz, max_pages in ((16, 16), (16, 128), (8, 64), (32, 64)):
+            assert self.kernel_equations(Tq, psz, max_pages) == base, \
+                (psz, max_pages)
+
+
+class TestKernelThroughTheEngine:
+    """The paged segment program with the kernel chosen (interpreted)
+    against the same program on the gather path: admissions, decode
+    ticks with retired and never-filled slots (``live`` -> ``q_len`` 0),
+    re-admission into a slot that was left."""
+
+    @pytest.mark.parametrize("scan_layers", [True, False])
+    def test_serves_the_fallbacks_tokens(self, monkeypatch, scan_layers):
+        set_mesh(None)
+        tokens = {}
+        for kernel in (False, True):
+            # max_seq_len tells the two engines' cached programs apart
+            cfg = llama.LlamaConfig(
+                vocab_size=128, hidden_size=256, intermediate_size=512,
+                num_layers=2, num_heads=4, num_kv_heads=2,
+                max_seq_len=128 + kernel, dtype=jnp.float32, remat=False,
+                scan_layers=scan_layers)
+            params = llama.init_params(cfg, jax.random.PRNGKey(0))
+            monkeypatch.setattr(pa, "FORCE_INTERPRET", kernel)
+            pa.reset_selection_count()
+            eng = ServingEngine(cfg, params, slots=3, max_len=96,
+                                paged=True, page_size=8,
+                                prompt_buckets=(16,))
+            assert eng.paged_kernel_active() == kernel
+            rng = np.random.RandomState(4)
+            rids = [eng.add_request(
+                rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32), g)
+                for n, g in ((5, 9), (12, 3), (9, 14), (16, 6), (3, 11))]
+            reads = {"pages_fetched": 0, "page_slots": 0}
+            while eng._queue or eng.free_slot_count() < eng.slots:
+                ev = eng.run_segment(6)
+                for name in reads:
+                    reads[name] += ev[name]
+            assert (pa.selection_count() > 0) == kernel
+            done = eng.collect_finished()
+            tokens[kernel] = ([done[r] for r in rids], reads)
+        assert tokens[True] == tokens[False]
+        assert 0 < tokens[True][1]["pages_fetched"] \
+            < tokens[True][1]["page_slots"]
 
 
 # ---------------------------------------------------------------------------

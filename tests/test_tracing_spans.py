@@ -262,6 +262,72 @@ class TestFirstTokenSplit:
         assert victim["ttft_s"] < victim["e2e_s"]
 
 
+class TestPageCounters:
+    """PR 31: ``pages_fetched`` / ``page_slots`` of a paged segment — the
+    pages its attention calls had to fetch (``pages_read`` at the context
+    lengths the host holds) against the page slots they were handed (rows
+    x the table's width), a layer."""
+
+    def test_hand_made_segment(self, tiny):
+        """2 slots, page 8, table 12 wide, admit width 16: prompts of 6
+        and 12 tokens owed 2 and 4; admit, admit, then three ticks of
+        which the last two find slot 0 free."""
+        from paddle_tpu.ops.pallas.paged_attention import pages_read
+
+        cfg, params = tiny
+        eng = paged_engine(cfg, params, slots=2)
+        psz, width, s_max = eng.page_size, eng.pager.max_pages, 16
+        assert (psz, width) == (8, 12)
+        rng = np.random.RandomState(0)
+        for n, gen in ((6, 2), (12, 4)):
+            eng.add_request(rng.randint(0, cfg.vocab_size, (n,))
+                            .astype(np.int32), gen)
+        ev = eng.run_segment(8)
+        assert ev["steps"] == 5 and len(ev["admitted"]) == 2
+        admits = 2 * pages_read(0, s_max, psz)
+        ticks = [[(6, True), (12, True)],       # (position, live)
+                 [(7, False), (13, True)], [(8, False), (14, True)]]
+        fetched = admits + sum(int(pages_read(pos, int(live), psz))
+                               for tick in ticks for pos, live in tick)
+        assert fetched == 4 + (1 + 2) + 2 + 2
+        assert ev["pages_fetched"] == fetched
+        assert ev["page_slots"] == 2 * width + 3 * 2 * width
+        assert eng.segment_pages == {"pages_fetched": fetched,
+                                     "page_slots": ev["page_slots"]}
+
+    def test_report_sums_the_segments(self, tiny):
+        from paddle_tpu.observability import metrics
+
+        cfg, params = tiny
+        eng = paged_engine(cfg, params, slots=2)
+        seen = {"pages_fetched": 0, "page_slots": 0}
+        inner = eng.run_segment
+
+        def run_segment(*a, **k):
+            ev = inner(*a, **k)
+            for name in seen:
+                seen[name] += ev[name]
+            return ev
+
+        eng.run_segment = run_segment
+        before = {n: metrics.counter("serving." + n).value for n in seen}
+        rep = OnlineScheduler(eng, seg_steps=3).serve(
+            arrivals(cfg, n=5, gen=4))
+        assert rep.page_reads == seen == rep.as_dict()["page_reads"]
+        assert 0 < seen["pages_fetched"] < seen["page_slots"]
+        for n in seen:
+            assert metrics.counter("serving." + n).value - before[n] \
+                == seen[n]
+
+    def test_dense_engine_reports_none(self, tiny):
+        cfg, params = tiny
+        eng = ServingEngine(cfg, params, slots=2, max_len=96,
+                            prompt_buckets=(16,))
+        rep = OnlineScheduler(eng, seg_steps=3).serve(
+            arrivals(cfg, n=2, gen=3))
+        assert rep.page_reads is None
+
+
 # ---------------------------------------------------------------------------
 # (c) names for every device program, region and kernel
 # ---------------------------------------------------------------------------
