@@ -170,33 +170,6 @@ def _run_budget_gate(env) -> dict:
     return gate
 
 
-def _run_serving_telemetry(env) -> dict:
-    """r10: record a CHIP-SIDE runtime-telemetry snapshot — the serving
-    smoke workload on the real backend with the observability subsystem
-    on, so TPU_TESTS_r<N>.json embeds measured serving occupancy / TTFT
-    / admission metrics next to the test outcomes (the telemetry analog
-    of the budget gate: a metric that silently stops moving on chip is
-    visible in the round record)."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join("benchmarks", "llama_serving.py"),
-         "--smoke"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
-    out = {"returncode": proc.returncode}
-    for line in reversed(proc.stdout.strip().splitlines()):
-        try:
-            ev = json.loads(line)
-            out["telemetry"] = ev.get("telemetry")
-            out["platform"] = ev.get("platform")
-            out["throughput_vs_fixed"] = ev.get("throughput_vs_fixed")
-            out["ttft_p50_s"] = ev.get("ttft_p50_s")
-            break
-        except json.JSONDecodeError:
-            continue
-    if proc.returncode != 0:
-        out["tail"] = proc.stderr[-1500:]
-    return out
-
-
 def _round_number(argv) -> int:
     if len(argv) > 1:
         return int(argv[1])
@@ -244,18 +217,14 @@ def main() -> int:
         m = re.search(r"(\d+) skipped", proc.stdout)
         counts["skipped"] = int(m.group(1)) if m else 0
     gate = _run_budget_gate(env)
-    serving_telemetry = _run_serving_telemetry(env)
     result = {
         "round": rnd,
-        # what the serving child's jax reported; this parent cannot ask
-        "platform": serving_telemetry.get("platform") or "unknown",
         "passed": counts.get("passed", 0),
         "failed": counts.get("failed", 0),
         "skipped": counts.get("skipped", 0),
         "duration_s": round(dur, 1),
         "returncode": proc.returncode,
         "analysis_gate": gate,
-        "serving_telemetry": serving_telemetry,
         "tests": tests,
     }
     out_path = os.path.join(ROOT, f"TPU_TESTS_r{rnd:02d}.json")

@@ -77,42 +77,12 @@ BUDGETS: Dict[str, Budget] = {
         # single-device lowering schedules ~1 MiB tighter) + ~5%
         peak_bytes_max=10_580_000,
         notes="r8 class: GradScaler-free bf16 path; params+state alias"),
-    # The fused decode chunk is a pure device loop: no syncs, no
-    # compiles, and the KV cache must ride donated (an undonated cache
-    # doubles serving HBM — the r6 bug class).
-    "decode_tick": Budget(
-        flagged_syncs=0,
-        warm_compiles=0,
-        # measured 663,664 B (scan-carry cache copies + the scatter's
-        # KV-row transpose) + ~5%
-        relayout_bytes_max=700_000,
-        pack_bytes_max=_MiB // 2,      # measured 0
-        undonated_bytes_max=_MiB // 2,  # measured 0 (tiny weights)
-        # liveness peak measured 1,560,660 B (weights live whole-
-        # program + the decode while carry) + ~5%
-        peak_bytes_max=1_639_000,
-        notes="pure device loop; cache donated, weights live by design"),
-    # One fused segment = ONE dispatch + ONE event fetch (the measured
-    # r7 contract). The fetch is the allowed per-segment sync; anything
-    # else in the loop is the 2.5 s-mid-serve class.
-    "serving_segment": Budget(
-        flagged_syncs=0,
-        allowed_syncs_per_replay={"serving.segment_event_fetch": 1},
-        warm_compiles=0,
-        # measured 999,988 B (while-body cache carries + admit DUS
-        # copies) + ~5%
-        relayout_bytes_max=1_050_000,
-        pack_bytes_max=_MiB // 2,      # measured 0
-        undonated_bytes_max=_MiB // 2,  # measured 0
-        # liveness peak measured 1,823,805 B (weights + donated dense
-        # cache counted once + segment while carry) + ~5%
-        peak_bytes_max=1_915_000,
-        notes="r7 contract: one dispatch + one fetch per segment"),
-    # The PAGED segment (r11): same one-dispatch/one-fetch contract as
-    # serving_segment, with page tables as DATA (no prefix-width shape
-    # family — zero unbucketed-dim hazards from paging) and ZERO pack
-    # bytes (no pre_k/pre_v staging concats: a prefix hit contributes no
-    # row copies to the program — the acceptance criterion, enforced).
+    # The segment over the paged pool: ONE dispatch + ONE event fetch
+    # (the measured r7 contract). The fetch is the allowed per-segment
+    # sync; anything else in the loop is the 2.5 s-mid-serve class. Page
+    # tables are DATA (no prefix-width shape family — zero unbucketed-dim
+    # hazards from paging) and pack bytes are ZERO (a prefix hit
+    # contributes no row copies to the program).
     "paged_serving_segment": Budget(
         flagged_syncs=0,
         allowed_syncs_per_replay={"serving.segment_event_fetch": 1},
@@ -260,26 +230,26 @@ BUDGETS: Dict[str, Budget] = {
         notes="r23 contract: sp-slab prefill scattering into the paged "
               "pool — long context at zero extra syncs/compiles and "
               "zero boundary relayout"),
-    # The TENSOR-PARALLEL segment (r12): the serving_segment contract,
-    # GSPMD-sharded — same one fetch per segment and zero warm compiles,
-    # PLUS every collective must attribute to the 'mp' axis (enforced
-    # via require_collectives_clean + the handle's allowed_axes). Byte
-    # ceiling covers both lowering regimes the CPU lane produces:
-    # measured 500,356 B at mp=2 (per-shard while-body carries halve)
-    # and ~999,988 B at mp=1 (== serving_segment) + ~5%.
+    # The TENSOR-PARALLEL segment (r12): the paged_serving_segment
+    # contract, GSPMD-sharded — same one fetch per segment and zero warm
+    # compiles, PLUS every collective must attribute to the 'mp' axis
+    # (enforced via require_collectives_clean + the handle's
+    # allowed_axes). Pinned at mp=2, which is what the gate's 8 virtual
+    # devices and tier-1 build; on a single device the program is
+    # paged_serving_segment's and that row's ceilings are the ones that
+    # apply.
     "tp_serving_segment": Budget(
         flagged_syncs=0,
         allowed_syncs_per_replay={"serving.segment_event_fetch": 1},
         warm_compiles=0,
-        relayout_bytes_max=1_050_000,
-        pack_bytes_max=_MiB // 2,      # measured 0 at both degrees
-        undonated_bytes_max=_MiB // 2,  # measured 0 (sharded cache donates)
-        # liveness peak: the gate env (8 virtual devices) partitions
-        # mp=2, so the per-device text halves the sharded weights and
-        # carries — measured 791,888 B + ~5%. The mp=1 degenerate
-        # lowering (single-device hosts) peaks at 1,578,828 B
-        # (== serving_segment) and rides under the same ceiling.
-        peak_bytes_max=1_657_000,
+        # measured 246,988 B at mp=2 (per-shard while-body pool carries
+        # + the gathered window's attention transposes) + ~5%
+        relayout_bytes_max=260_000,
+        pack_bytes_max=_MiB // 2,      # measured 0
+        undonated_bytes_max=_MiB // 2,  # measured 0 (sharded pool donates)
+        # liveness peak measured 637,754 B at mp=2 (the per-device text
+        # halves the sharded weights, the pool and the carries) + ~5%
+        peak_bytes_max=670_000,
         notes="r12 contract: mp-sharded segment — one fetch/segment, "
               "all collectives ride the declared 'mp' axis"),
     # The donated multi-tensor update: the r8 ledger program. The pack

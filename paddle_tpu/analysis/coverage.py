@@ -90,8 +90,8 @@ def lint_registry_only(modules: Sequence = ()) -> List[str]:
 
 # --- 2. envelope reachability replay ---------------------------------------
 
-def _pow2(n: int, lo: int = 1) -> int:
-    p = lo
+def _pow2(n: int) -> int:
+    p = 1
     while p < n:
         p *= 2
     return p
@@ -126,7 +126,6 @@ def reachable_keys_replay(engine, envelope) -> FrozenSet[tuple]:
     # suffix — any (L, h) pair yields suffix L - h, and a hit-less row
     # in the same group can raise suf_max to any admissible length
     widths = {top}
-    pre_widths = {(0, top)}
     hits_possible = blk is not None and hi > blk
     if hits_possible and not spec:
         for L in range(lo, hi + 1):
@@ -137,18 +136,6 @@ def reachable_keys_replay(engine, envelope) -> FrozenSet[tuple]:
             # carry a hit) while THIS row missed and contributes its
             # full length as the group's longest suffix
             widths.add(engine._bucket_for(L))
-    if hits_possible:
-        # dense (pre_max, s_max) pairs: pre_max = the group's longest
-        # hit (block multiple), s_max = the bucket of the group's
-        # longest suffix — extremes may come from different rows, so
-        # every (hit, suffix-width) combination is reachable; pairs
-        # whose window exceeds max_len drop to (0, top) at dispatch
-        max_hit = ((hi - 1) // blk) * blk
-        for h in range(blk, max_hit + 1, blk):
-            for w in widths:
-                if h + w <= engine.max_len:
-                    pre_widths.add((h, w))
-
     # r23 sequence-parallel long-context (spseg): replay the long-rung
     # arithmetic by brute force — for every ENGAGING first-admission
     # suffix (past the largest regular bucket, up to the envelope /
@@ -158,7 +145,7 @@ def reachable_keys_replay(engine, envelope) -> FrozenSet[tuple]:
     # the same set via residues; check_envelope asserts they agree.
     sp = int(getattr(engine, "seq_parallel", 0) or 0)
     sp_widths: set = set()
-    if engine.paged and sp:
+    if sp:
         C = engine.prefill_chunks[-1]
         Cs = sp * C
         cap = min(env.max_prompt, engine.long_buckets[-1])
@@ -171,56 +158,34 @@ def reachable_keys_replay(engine, envelope) -> FrozenSet[tuple]:
 
     for n_pad in n_pads:
         for steps in env.seg_steps:
-            if engine.paged and sp:
-                for w, c in sp_widths:
-                    keys.add(space.key("spseg", n_pad=n_pad, s_max=w,
-                                       c=c, sp=sp, steps=steps))
-            if engine.paged:
-                if spec:
-                    if steps >= 2:
-                        keys.add(space.key("sseg", n_pad=n_pad,
-                                           k=engine.speculative,
+            for w, c in sp_widths:
+                keys.add(space.key("spseg", n_pad=n_pad, s_max=w,
+                                   c=c, sp=sp, steps=steps))
+            if spec:
+                if steps >= 2:
+                    keys.add(space.key("sseg", n_pad=n_pad,
+                                       k=engine.speculative,
+                                       steps=steps))
+            elif engine.chunked:
+                for w in widths:
+                    C = engine._prefill_chunk_for(w)
+                    s_max_c = -(-w // C) * C
+                    if steps >= 2 * (s_max_c // C):
+                        keys.add(space.key("cseg", n_pad=n_pad,
+                                           s_max=s_max_c, c=C,
                                            steps=steps))
-                elif engine.chunked:
-                    for w in widths:
-                        C = engine._prefill_chunk_for(w)
-                        s_max_c = -(-w // C) * C
-                        if steps >= 2 * (s_max_c // C):
-                            keys.add(space.key("cseg", n_pad=n_pad,
-                                               s_max=s_max_c, c=C,
-                                               steps=steps))
-                elif getattr(engine, "quant", None):
-                    from ..quantization.serving import QUANT_CODES
+            elif getattr(engine, "quant", None):
+                from ..quantization.serving import QUANT_CODES
 
-                    code = QUANT_CODES[engine.quant]
-                    for w in widths:
-                        keys.add(space.key("qpseg", n_pad=n_pad, s_max=w,
-                                           steps=steps, dtype=code))
-                else:
-                    fam = "qseg" if engine.quality_digest else "pseg"
-                    for w in widths:
-                        keys.add(space.key(fam, n_pad=n_pad, s_max=w,
-                                           steps=steps))
+                code = QUANT_CODES[engine.quant]
+                for w in widths:
+                    keys.add(space.key("qpseg", n_pad=n_pad, s_max=w,
+                                       steps=steps, dtype=code))
             else:
-                for pre, w in pre_widths:
-                    keys.add(space.key("seg", n_pad=n_pad, s_max=w,
-                                       pre_max=pre, steps=steps))
-    if not engine.paged and engine.mesh is None:
-        from ..inference.serving import _WAVE_WIDTHS
-
-        keys.add(space.key("decode", chunk=engine.chunk))
-        for b in buckets:
-            for nb in _WAVE_WIDTHS:
-                if nb <= engine.slots:
-                    keys.add(space.key("admit", bucket=b, nb=nb))
-        if env.offline_batch:
-            for n in range(1, env.offline_batch + 1):
-                for L in range(1, env.max_prompt + 1):
-                    for g in range(1, env.max_new_tokens + 1):
-                        keys.add(space.key(
-                            "drain", n_pad=_pow2(n),
-                            p_max=engine._bucket_for(L),
-                            g_max=_pow2(g, lo=16)))
+                fam = "qseg" if engine.quality_digest else "pseg"
+                for w in widths:
+                    keys.add(space.key(fam, n_pad=n_pad, s_max=w,
+                                       steps=steps))
     return frozenset(keys)
 
 

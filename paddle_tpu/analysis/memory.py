@@ -389,12 +389,23 @@ def hot_transients(report: MemoryReport, *, frac_bytes: float = 0.33,
     across most of the schedule — the logits_all-across-steps class: a
     per-step value accumulated whole instead of reduced. These are the
     liveness blowups a peak-budget regression usually decomposes into.
+    The span is counted in the schedule's computing positions: a
+    ``parameter`` or ``constant`` does no work, and a lowering that
+    hoists a few of them ahead of the buffer's birth would otherwise
+    dilute a buffer that outlives the whole loop below the bar.
     """
-    n = max(1, report.schedule_len)
+    idle = [b.start for b in report.intervals
+            if b.op in ("parameter", "constant")]
+    n = max(1, report.schedule_len - len(idle))
+
+    def span(b):
+        return (b.end - b.start + 1) - sum(b.start <= i <= b.end
+                                           for i in idle)
+
     return [b for b in report.intervals
             if not b.param and not b.donated
             and b.bytes >= frac_bytes * max(1, report.peak_bytes)
-            and (b.end - b.start + 1) >= frac_span * n]
+            and span(b) >= frac_span * n]
 
 
 # ---------------------------------------------------------------------------

@@ -6,17 +6,13 @@ whose hazard ledger earlier rounds paid for by hand:
 * ``amp_o2_train_step``      — conv+BN+linear AMP-O2 ``fused_train_step``
   (the r8 GradScaler/donation territory: params+opt state must alias,
   zero host syncs per step).
-* ``decode_tick``            — the serving engine's fused decode chunk
-  (r6 territory: pure device loop, zero syncs, zero relayouts of the KV
-  cache).
-* ``serving_segment``        — the re-entrant continuous-batching
-  segment + its host replay (r7 territory: exactly ONE allowed
-  device_get per segment, no stray shape compiles).
 * ``fused_optimizer_update`` — ``Optimizer.step``'s donated jit update
   over a mixed-shape population (the r8 relayout-ledger territory: the
   stack/concat pack bytes are THE metric).
-* ``paged_serving_segment``  — the r11 page-table segment (zero pack
-  bytes: prefix reuse is refcount data, not row copies).
+* ``paged_serving_segment``  — the re-entrant continuous-batching
+  segment over the paged pool + its host replay (exactly ONE allowed
+  device_get per segment, no stray shape compiles, zero pack bytes:
+  prefix reuse is refcount data, not row copies).
 * ``tp_serving_segment``     — the r12 mp-sharded segment (collectives
   must attribute to the 'mp' axis; the one-fetch contract survives
   GSPMD).
@@ -166,108 +162,8 @@ def _build_amp_o2_train_step() -> ProgramHandle:
 
 
 # ---------------------------------------------------------------------------
-# 2 + 3. Serving programs (one tiny engine serves both)
+# 2. Serving programs
 # ---------------------------------------------------------------------------
-
-
-def _tiny_engine():
-    import jax.numpy as jnp
-
-    from paddle_tpu.inference.serving import ServingEngine
-    from paddle_tpu.models import llama
-
-    cfg = llama.LlamaConfig.tiny()
-    params = llama.init_params(cfg)
-    eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,))
-    return cfg, params, eng, jnp
-
-
-@register("decode_tick")
-def _build_decode_tick() -> ProgramHandle:
-    import jax.numpy as jnp
-
-    from paddle_tpu.models import llama
-
-    cfg, params, eng, _ = _tiny_engine()
-    decode = eng._decode_prog
-
-    def fresh_args():
-        cache = llama.init_kv_cache(cfg, eng.slots, eng.max_len)
-        pos = jnp.full((eng.slots,), 4, jnp.int32)
-        nxt = jnp.ones((eng.slots,), jnp.int32)
-        rem = jnp.full((eng.slots,), eng.chunk, jnp.int32)
-        return params, cache, pos, nxt, rem
-
-    def hlo():
-        return decode.lower(*fresh_args()).compile().as_text()
-
-    def replay():
-        # the chunk donates the cache, so every iteration rebuilds one
-        # (zeros program: compiles once in warmup); NO host fetch — the
-        # tick is the pure device loop
-        return decode(*fresh_args())
-
-    return ProgramHandle(
-        name="decode_tick",
-        hlo=_memo(hlo),
-        replay=replay,
-        # model weights legitimately stay live across ticks; only the KV
-        # cache is donation-critical and the budget pins the measured
-        # undonated total so a NEW large undonated buffer regresses it
-        donation_threshold=1 << 16,
-        expected_undonated=(),
-        notes="fused decode chunk (8 ticks), llama-tiny, 4 slots",
-        aot_engine=eng,
-        aot_envelope=_gate_envelope(seg_steps=(12,)),
-        keepalive=(eng,))
-
-
-@register("serving_segment")
-def _build_serving_segment() -> ProgramHandle:
-    import numpy as np
-
-    cfg, params, eng, jnp = _tiny_engine()
-    rng = np.random.RandomState(0)
-
-    def replay():
-        # end-to-end segment: enqueue two requests, run ONE fused
-        # segment, host-replay the event log. The device_get inside
-        # run_segment is the intended per-segment fetch (allowed_sync);
-        # every request finishes inside the segment so slot state drains
-        for _ in range(2):
-            eng.add_request(rng.randint(0, cfg.vocab_size, (12,)), 4)
-        return eng.run_segment(12)
-
-    def hlo():
-        seg = eng._segment_prog(eng._pow2(eng.slots), eng.buckets[-1], 0, 12)
-        n_pad = eng._pow2(eng.slots)
-        s_max = eng.buckets[-1]
-        import jax.numpy as j
-
-        from paddle_tpu.models import llama
-
-        L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        cache = llama.init_kv_cache(cfg, eng.slots, eng.max_len)
-        return seg.lower(
-            params, cache, j.zeros((eng.slots,), j.int32),
-            j.zeros((eng.slots,), j.int32), j.zeros((eng.slots,), j.int32),
-            j.zeros((n_pad, s_max), j.int32), j.ones((n_pad,), j.int32),
-            j.zeros((n_pad,), j.int32),
-            j.zeros((n_pad, L, 0, Hkv, D), cache["k"].dtype),
-            j.zeros((n_pad, L, 0, Hkv, D), cache["v"].dtype),
-            j.zeros((n_pad,), j.int32), j.int32(2)).compile().as_text()
-
-    return ProgramHandle(
-        name="serving_segment",
-        hlo=_memo(hlo),
-        replay=replay,
-        donation_threshold=1 << 16,
-        expected_undonated=(),
-        notes="re-entrant fused segment + host event replay, llama-tiny",
-        aot_engine=eng,
-        aot_envelope=_gate_envelope(seg_steps=(12,)),
-        keepalive=(eng,))
 
 
 @register("paged_serving_segment")
@@ -282,7 +178,7 @@ def _build_paged_serving_segment() -> ProgramHandle:
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg)
     eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,), paged=True, page_size=16)
+                        prompt_buckets=(16,), page_size=16)
     rng = np.random.RandomState(0)
 
     def replay():
@@ -341,7 +237,7 @@ def _build_chunked_serving_segment() -> ProgramHandle:
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg)
     eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,), paged=True, page_size=16,
+                        prompt_buckets=(16,), page_size=16,
                         chunked_prefill=True, prefill_chunks=(8,))
     rng = np.random.RandomState(0)
 
@@ -406,7 +302,7 @@ def _build_longctx_serving_segment() -> ProgramHandle:
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg)
     eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,), paged=True, page_size=16,
+                        prompt_buckets=(16,), page_size=16,
                         prefill_chunks=(8,), seq_parallel=2,
                         long_buckets=(32,))
     rng = np.random.RandomState(0)
@@ -473,7 +369,7 @@ def _build_spec_serving_segment() -> ProgramHandle:
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg)
     eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,), paged=True, page_size=16,
+                        prompt_buckets=(16,), page_size=16,
                         speculative=3)
     rng = np.random.RandomState(0)
 
@@ -542,7 +438,7 @@ def _build_quality_serving_segment() -> ProgramHandle:
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg)
     eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,), paged=True, page_size=16,
+                        prompt_buckets=(16,), page_size=16,
                         quality_digest=True, digest_top_k=4)
     rng = np.random.RandomState(0)
 
@@ -603,7 +499,7 @@ def _build_quant_serving_segment() -> ProgramHandle:
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg)
     eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,), paged=True, page_size=16,
+                        prompt_buckets=(16,), page_size=16,
                         quant="int8")
     rng = np.random.RandomState(0)
 
@@ -645,23 +541,23 @@ def _build_quant_serving_segment() -> ProgramHandle:
 
 @register("tp_serving_segment")
 def _build_tp_serving_segment() -> ProgramHandle:
-    """The r12 tensor-parallel serving segment: the re-entrant fused
-    segment with weights GSPMD-sharded Megatron-style and the KV cache
-    sharded on the head dim over an 'mp' mesh. The contract the budget
-    pins: the ONE-dispatch/one-fetch shape survives sharding (same
-    single allowed event fetch, zero warm compiles) and every collective
-    in the program attributes to the 'mp' axis — an unattributed or
-    off-axis collective is a GSPMD repartition hazard, exactly the class
-    ``collective_check`` was promoted to catch. Builds mp=2 when two
-    devices exist (tier-1's virtual-CPU platform, the MULTICHIP dryrun
-    pattern), mp=1 on a single chip — the sync/compile budgets bind
-    either way, the collective attribution bites at mp=2."""
+    """The r12 tensor-parallel serving segment: the paged segment with
+    weights GSPMD-sharded Megatron-style and the KV pool sharded on the
+    head dim over an 'mp' mesh (``llama.paged_pool_spec``). The contract
+    the budget pins: the ONE-dispatch/one-fetch shape survives sharding
+    (same single allowed event fetch, zero warm compiles) and every
+    collective in the program attributes to the 'mp' axis — an
+    unattributed or off-axis collective is a GSPMD repartition hazard,
+    exactly the class ``collective_check`` was promoted to catch. Builds
+    mp=2 when two devices exist (tier-1's virtual-CPU platform, the
+    MULTICHIP dryrun pattern), mp=1 on a single chip — the sync/compile
+    budgets bind either way, the collective attribution bites at mp=2."""
     import numpy as np
 
     import jax
     import jax.numpy as j
 
-    from paddle_tpu.inference.serving import ServingEngine
+    from paddle_tpu.inference.serving import ServingEngine, _mesh_scope
     from paddle_tpu.models import llama
     from paddle_tpu.parallel.mesh import create_hybrid_mesh
 
@@ -672,57 +568,51 @@ def _build_tp_serving_segment() -> ProgramHandle:
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg)
     eng = ServingEngine(cfg, params, slots=4, max_len=64, chunk=8,
-                        prompt_buckets=(16,), mesh=mesh)
+                        prompt_buckets=(16,), page_size=16, mesh=mesh)
     rng = np.random.RandomState(0)
 
     def replay():
         # end-to-end mp-sharded segment: two requests, ONE fused
         # dispatch over the mesh, the single allowed event fetch, host
-        # replay — every request finishes inside the segment so slot
-        # state drains (the engine scopes the mesh itself)
+        # replay — every request finishes inside the segment so pages
+        # drain back to the free list (the engine scopes the mesh itself)
         for _ in range(2):
             eng.add_request(rng.randint(0, cfg.vocab_size, (12,)), 4)
         return eng.run_segment(12)
 
     def hlo():
-        from jax.sharding import NamedSharding
-
         n_pad = eng._pow2(eng.slots)
         s_max = eng.buckets[-1]
-        seg = eng._progs[("seg", n_pad, s_max, 0, 12)]
-        L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        cache = jax.device_put(
-            llama.init_kv_cache(cfg, eng.slots, eng.max_len),
-            NamedSharding(mesh, llama.kv_cache_spec()))
-        return seg.lower(
-            eng.params, cache, eng._pos, eng._nxt, eng._rem,
-            j.zeros((n_pad, s_max), j.int32), j.ones((n_pad,), j.int32),
-            j.zeros((n_pad,), j.int32),
-            j.zeros((n_pad, L, 0, Hkv, D), cache["k"].dtype),
-            j.zeros((n_pad, L, 0, Hkv, D), cache["v"].dtype),
-            j.zeros((n_pad,), j.int32), j.int32(2)).compile().as_text()
-
-    def hlo_warm():
-        replay()              # materialise the ("seg", ...) program
-        return hlo()
+        pgr = eng.pager
+        # the model's sharding constraints read the global mesh at trace
+        # time: lower under the engine's own
+        with _mesh_scope(mesh):
+            seg = eng._paged_segment_prog(n_pad, s_max, 12)
+            return seg.lower(
+                eng.params, pgr.pool, pgr.page_table, eng._pos, eng._nxt,
+                eng._rem, j.zeros((n_pad, s_max), j.int32),
+                j.ones((n_pad,), j.int32), j.zeros((n_pad,), j.int32),
+                j.zeros((n_pad,), j.int32),
+                j.zeros((n_pad, pgr.max_pages), j.int32),
+                j.int32(2)).compile().as_text()
 
     return ProgramHandle(
         name="tp_serving_segment",
-        hlo=_memo(hlo_warm),
+        hlo=_memo(hlo),
         replay=replay,
         mesh=mesh,
         donation_threshold=1 << 16,
         expected_undonated=(),
         allowed_axes=("mp",),
-        notes=f"mp={mp} GSPMD-sharded re-entrant segment (column/row-"
-              f"parallel weights, head-sharded KV cache), llama-tiny",
+        notes=f"mp={mp} GSPMD-sharded paged segment (column/row-"
+              f"parallel weights, head-sharded KV pool), llama-tiny",
         aot_engine=eng,
         aot_envelope=_gate_envelope(seg_steps=(12,)),
         keepalive=(eng,))
 
 
 # ---------------------------------------------------------------------------
-# 4. Fused optimizer update
+# 3. Fused optimizer update
 # ---------------------------------------------------------------------------
 
 

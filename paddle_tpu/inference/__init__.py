@@ -37,8 +37,8 @@ __all__ = ["Config", "Predictor", "InferTensor", "create_predictor",
 # ``from paddle_tpu.inference.serving import ServingEngine``,
 # ``from paddle_tpu.inference.scheduler import OnlineScheduler /
 # SLOScheduler`` (r13: priorities, preemption, deadline shedding),
-# ``from paddle_tpu.inference.prefix_cache import PrefixCache /
-# PagedPrefixCache / make_prefix_cache``, ``from
+# ``from paddle_tpu.inference.prefix_cache import PagedPrefixCache /
+# make_prefix_cache``, ``from
 # paddle_tpu.inference.paged_kv import PagedKVCache``, ``from
 # paddle_tpu.inference.kv_tiers import HostTier`` (r19: the host-RAM
 # spill tier + tier-transfer accounting), ``from

@@ -245,8 +245,7 @@ class Autoscaler:
         reps = self._managed()
         return dict(sig,
                     queue_depths={str(r.idx): r.queue_depth for r in reps},
-                    pages_free={str(r.idx): (r.engine.pager.pages_free
-                                             if r.engine.paged else None)
+                    pages_free={str(r.idx): r.engine.pager.pages_free
                                 for r in reps},
                     health={str(r.idx): r.health for r in reps},
                     lifecycle={str(r.idx): r.lifecycle for r in reps},
@@ -384,9 +383,8 @@ class Autoscaler:
     # --- chip fit + warmup estimate ---------------------------------------
     def _chip_fit(self, rep) -> Optional[dict]:
         """§3s static proof the candidate fits its HBM budget. ``None``
-        when no budget is configured (fit checking off) or the replica
-        is not paged (no pool to price)."""
-        if self.hbm_bytes is None or not rep.engine.paged:
+        when no budget is configured (fit checking off)."""
+        if self.hbm_bytes is None:
             return None
         from ..analysis import memory as _memory
 

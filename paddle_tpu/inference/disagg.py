@@ -130,9 +130,6 @@ class DisaggRouter(FleetRouter):
         engines = prefill_engines + decode_engines
         for e in engines:
             require(e.cfg, "disaggregated serving")
-            if not e.paged:
-                raise ValueError("disaggregation needs paged engines — "
-                                 "the handoff moves KV page sets")
 
         def _auto(es):
             return [make_prefix_cache(

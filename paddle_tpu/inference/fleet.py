@@ -17,7 +17,7 @@ device) with its OWN prefix cache and its OWN telemetry registry:
 * **Least-loaded fallback + pages-free-aware admission.** When the
   preferred replica's bounded queue is full (or there is no affinity
   key), the request goes to the least-loaded replica (queued + live
-  requests, ties to the lowest index — deterministic); paged replicas
+  requests, ties to the lowest index — deterministic); replicas
   whose pool can hold the request right now are preferred over ones
   that would defer it on page pressure.
 * **Fleet-level backpressure accounting.** Each replica's intake queue
@@ -539,10 +539,9 @@ class FleetRouter:
             raise ValueError(f"{len(prefix_caches)} prefix caches for "
                              f"{len(engines)} engines")
         for e, pc in zip(engines, prefix_caches):
-            if pc is not None and e.paged and getattr(pc, "pager",
-                                                      None) is not e.pager:
+            if pc is not None and pc.pager is not e.pager:
                 raise ValueError(
-                    "paged replica's prefix cache must wrap ITS OWN "
+                    "a replica's prefix cache must wrap ITS OWN "
                     "pager (fleet isolation: one cache per engine)")
         blocks = {pc.block for pc in prefix_caches if pc is not None}
         if len(blocks) > 1:
@@ -612,14 +611,14 @@ class FleetRouter:
         # Opt-in: blind affinity stays the default routing contract.
         self.directory: Optional[CacheDirectory] = None
         if directory:
-            paged_pcs = [(i, pc) for i, pc in enumerate(prefix_caches)
-                         if pc is not None and hasattr(pc, "pager")]
-            if not paged_pcs:
+            pcs = [(i, pc) for i, pc in enumerate(prefix_caches)
+                   if pc is not None]
+            if not pcs:
                 raise ValueError(
-                    "directory steering needs paged prefix caches — it "
+                    "directory steering needs prefix caches — it "
                     "routes on the caches' live entry state")
-            self.directory = CacheDirectory(paged_pcs[0][1].block)
-            for i, pc in paged_pcs:
+            self.directory = CacheDirectory(pcs[0][1].block)
+            for i, pc in pcs:
                 self.directory.attach(i, pc)
         self.tier_migrations = 0            # cross-replica imports
         self.failovers = 0                  # replicas declared dead
@@ -685,8 +684,6 @@ class FleetRouter:
 
     def _page_ready(self, r: _Replica, a: Arrival) -> bool:
         eng = r.engine
-        if not eng.paged:
-            return True
         need = eng.pager.pages_needed(len(a.prompt) + a.max_new_tokens - 1)
         return eng.pager.pages_free >= need
 
@@ -842,15 +839,10 @@ class FleetRouter:
                           "pool": x.pool, "lifecycle": x.lifecycle,
                           "queue": x.queue_depth, "live": x.live,
                           "page_ready": self._page_ready(x, a),
-                          "pages_free": (x.engine.pager.pages_free
-                                         if x.engine.paged else None),
+                          "pages_free": x.engine.pager.pages_free,
                           "reclaimable": (
                               x.prefix_cache.reclaimable_pages()
-                              if x.engine.paged
-                              and x.prefix_cache is not None
-                              and hasattr(x.prefix_cache,
-                                          "reclaimable_pages") else
-                              (0 if x.engine.paged else None)),
+                              if x.prefix_cache is not None else 0),
                           "dir_hit": (dirinfo["rows"]
                                       if x.idx in owners else 0),
                           "dir_tier": (owners[x.idx]["tier"]
@@ -1209,8 +1201,7 @@ class FleetRouter:
                 "dispatches": dict(r.dispatches),
                 "prefix": (r.prefix_cache.stats()
                            if r.prefix_cache is not None else None),
-                "pages": (r.engine.pager.stats()
-                          if r.engine.paged else None),
+                "pages": r.engine.pager.stats(),
             } for r in reps],
         )
 
@@ -1321,7 +1312,7 @@ class FleetRouter:
         # the alert levels replay bit-exactly.
         if self.capacity_monitor is not None:
             cm = self.capacity_monitor
-            if rep.engine.paged and ev["admitted"]:
+            if ev["admitted"]:
                 by_erid = {self._reqs[rid][1].rid: self._reqs[rid][1]
                            for rid in rep.rids}
                 need = sum(
@@ -1333,14 +1324,13 @@ class FleetRouter:
             cm.close_segment()
             free = sum(x.engine.pager.pages_free
                        for x in self._replicas
-                       if x.engine.paged and x.lifecycle == "serving"
+                       if x.lifecycle == "serving"
                        and x.health != "dead")
             reclaim = sum(
                 x.prefix_cache.reclaimable_pages()
                 for x in self._replicas
-                if x.engine.paged and x.lifecycle == "serving"
-                and x.health != "dead" and x.prefix_cache is not None
-                and hasattr(x.prefix_cache, "reclaimable_pages"))
+                if x.lifecycle == "serving"
+                and x.health != "dead" and x.prefix_cache is not None)
             cm.begin_segment(free, reclaim)
         # r22 (ISSUE 17): post-segment hook — a no-op here; the
         # DisaggRouter's handoff sweep (prefill slots whose first token
@@ -1824,22 +1814,15 @@ class FleetRouter:
 
     def leak_report(self) -> List[str]:
         """Aggregated page-leak audit across replicas: with no live
-        requests, every paged replica's pool must be fully returned
+        requests, every replica's pool must be fully returned
         modulo its OWN cache's held pages (the fleet-isolation audit —
         a cache can only pin pages of the pager it wraps)."""
         bad: List[str] = []
         for r in self._replicas:
-            if not r.engine.paged:
-                continue
             pc = r.prefix_cache
-            if pc is not None and hasattr(pc, "physical_pages_held"):
-                # distinct pages, not ref counts: entries sharing a
-                # prefix hold its pages once physically (r19 fix)
-                held = pc.physical_pages_held()
-            elif pc is not None and hasattr(pc, "pages_held"):
-                held = pc.pages_held
-            else:
-                held = 0
+            # distinct pages, not ref counts: entries sharing a
+            # prefix hold its pages once physically (r19 fix)
+            held = pc.physical_pages_held() if pc is not None else 0
             for msg in r.engine.pager.leak_report(expected_held=held):
                 bad.append(f"replica {r.idx}: {msg}")
         return bad

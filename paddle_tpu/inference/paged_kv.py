@@ -18,8 +18,7 @@ cannot express:
   free* (see ``ServingEngine`` + ``OnlineScheduler``).
 * **Prefix sharing is dedup, not copy.** A prefix-cache hit maps the
   SAME physical pages into the new slot's table — one refcount bump per
-  page, zero KV row copies (the r7 cache copied whole row ranges via
-  dynamic_update_slice at every hit). Pages are copy-on-write: sharers
+  page, zero KV row copies. Pages are copy-on-write: sharers
   never write shared pages in the serving path (suffix rows start at a
   page boundary past the shared prefix), and ``cow_break`` materialises
   a private copy for the forking paths (speculative decode, preemption

@@ -1,4 +1,4 @@
-"""Shared-prefix KV cache (r7 tentpole, VERDICT r5 stretch item 9).
+"""Shared-prefix KV cache over the paged pool.
 
 Reference counterpart: the prefix/prompt caches in production serving
 stacks (vLLM's block-level prefix caching, SGLang's RadixAttention; the
@@ -6,30 +6,24 @@ reference's serving engines cache system-prompt KV the same way): when
 many requests share a prompt prefix — a system prompt, few-shot
 exemplars, a long document — the prefix's KV rows are identical across
 requests (greedy prefill is deterministic and rope keys depend only on
-absolute position), so prefilling it once and copying rows is pure win
-over recomputing it per request.
+absolute position), so prefilling it once and sharing the rows is pure
+win over recomputing it per request.
 
-TPU-native shape of the idea: entries are **contiguous row blocks of the
-slot-layout cache** ([L, plen, Hkv, D] device arrays), not paged block
-tables — the serving engine's cache is slot-contiguous (ragged, unpaged;
-see inference/serving.py), so a prefix "hit" is ONE dynamic_update_slice
-of the reused rows into the admit window followed by a *suffix-only*
-prefill, all inside the fused segment program. Matching is exact-token
-and block-aligned, over a flat LRU of entries (entry count is small —
+Entries hold PAGE IDS of the engine's pool (``inference/paged_kv.py``): a
+hit is a refcount bump followed by a *suffix-only* prefill inside the
+segment program; no KV row is copied. Matching is exact-token and
+page-aligned, over a flat LRU of entries (entry count is small —
 dozens — so an O(entries) host scan beats maintaining a radix tree, and
 it naturally credits PARTIAL overlaps: a prompt sharing only the first
 64 of a cached 128-row prefix still reuses those 64 rows).
 
 Population is admission-driven: after a segment admits a request cold,
-the engine harvests rows [0, plen_b) of its slot (they hold exactly the
-prompt's keys until the slot is reused) and inserts them — so the FIRST
-request of a shared-prefix burst warms the cache for the rest, with no
-workload declaration needed. ``put_prompt`` additionally lets a caller
-register a known prefix (system prompt) ahead of traffic via
-``llama.prompt_kv``.
+the engine retains the pages that hold its prompt — so the FIRST request
+of a shared-prefix burst warms the cache for the rest, with no workload
+declaration needed.
 
-Capacity is bounded in KV tokens held; eviction is LRU over entries.
-All lookup state is host-side; only the KV rows live on device.
+Capacity is bounded in pages held; eviction is LRU over entries. All
+lookup state is host-side; only the KV rows live on device.
 """
 
 from __future__ import annotations
@@ -44,56 +38,36 @@ from ..observability import flight as _flight
 from ..observability import metrics as _metrics
 from .paged_kv import _notify as _pool_notify
 
-__all__ = ["PrefixCache", "PrefixMatch", "PagedPrefixCache",
-           "PagedPrefixMatch", "make_prefix_cache"]
+__all__ = ["PagedPrefixCache", "PagedPrefixMatch", "make_prefix_cache"]
 
 
-def make_prefix_cache(engine, block: int = 32,
-                      capacity_tokens: int = 16384,
+def make_prefix_cache(engine, capacity_tokens: int = 16384,
                       host_tier_pages: int = 0):
     """The ONE prefix cache for ONE engine (r12 fleet isolation): a
-    paged engine gets a ``PagedPrefixCache`` wrapping ITS pager (page
-    refs must bump the allocator the slots actually draw from — sharing
-    a cache across engines would retain pages of the wrong pool), a
-    contiguous engine gets a ``PrefixCache`` at the engine-independent
-    block. The fleet router builds one per replica through here
-    (``prefix_caches="auto"``); nothing in this module is process-global
-    state, so N engines in one process never alias lookup state.
+    ``PagedPrefixCache`` wrapping ITS pager (page refs must bump the
+    allocator the slots actually draw from — sharing a cache across
+    engines would retain pages of the wrong pool). The fleet router
+    builds one per replica through here (``prefix_caches="auto"``);
+    nothing in this module is process-global state, so N engines in one
+    process never alias lookup state.
 
-    **Why:** the caches assume their entries' device rows / page ids
-    belong to the engine that harvested them; keyed-off-the-engine
-    construction makes that assumption structural instead of
-    conventional."""
-    if getattr(engine, "paged", False):
-        host_tier = None
-        if host_tier_pages:
-            # r19 tiered KV (ISSUE 14): a host-RAM spill tier behind
-            # THIS pager — host bytes are keyed to the cache that
-            # staged them, so the tier is engine-scoped like the cache
-            from .kv_tiers import HostTier
+    **Why:** the cache assumes its entries' page ids belong to the
+    engine that harvested them; keyed-off-the-engine construction makes
+    that assumption structural instead of conventional."""
+    host_tier = None
+    if host_tier_pages:
+        # r19 tiered KV (ISSUE 14): a host-RAM spill tier behind
+        # THIS pager — host bytes are keyed to the cache that
+        # staged them, so the tier is engine-scoped like the cache
+        from .kv_tiers import HostTier
 
-            host_tier = HostTier(engine.pager,
-                                 capacity_pages=int(host_tier_pages))
-        return PagedPrefixCache(engine.pager,
-                                capacity_pages=max(
-                                    1, capacity_tokens
-                                    // engine.pager.page_size),
-                                host_tier=host_tier)
-    return PrefixCache(block=block, capacity_tokens=capacity_tokens)
-
-
-@dataclass
-class _Entry:
-    tokens: np.ndarray   # [n] int32, n a multiple of block
-    k: object            # [L, n, Hkv, D] device array
-    v: object            # [L, n, Hkv, D]
-
-
-@dataclass
-class PrefixMatch:
-    length: int          # reusable rows (block multiple, < len(prompt))
-    k: object            # [L, >=length, Hkv, D] — slice [:, :length] to use
-    v: object
+        host_tier = HostTier(engine.pager,
+                             capacity_pages=int(host_tier_pages))
+    return PagedPrefixCache(engine.pager,
+                            capacity_pages=max(
+                                1, capacity_tokens
+                                // engine.pager.page_size),
+                            host_tier=host_tier)
 
 
 def _common_prefix(a: np.ndarray, b: np.ndarray) -> int:
@@ -102,136 +76,6 @@ def _common_prefix(a: np.ndarray, b: np.ndarray) -> int:
         return 0
     neq = np.nonzero(a[:n] != b[:n])[0]
     return n if len(neq) == 0 else int(neq[0])
-
-
-class PrefixCache:
-    def __init__(self, block: int = 32, capacity_tokens: int = 16384):
-        if block < 1:
-            raise ValueError(f"block must be >= 1, got {block}")
-        self.block = int(block)
-        self.capacity_tokens = int(capacity_tokens)
-        self._entries: "OrderedDict[bytes, _Entry]" = OrderedDict()
-        self._tokens_held = 0
-        self.hits = 0
-        self.misses = 0
-        self.hit_tokens = 0       # KV rows NOT re-prefilled thanks to hits
-        self.evictions = 0
-
-    # --- alignment helpers (admission code paths share one rule) ---------
-    def round_down(self, n: int) -> int:
-        return (int(n) // self.block) * self.block
-
-    def round_up(self, n: int) -> int:
-        return -(-int(n) // self.block) * self.block
-
-    @staticmethod
-    def _key(tokens: np.ndarray) -> bytes:
-        return tokens.tobytes()
-
-    # --- lookup / population ---------------------------------------------
-    def match(self, prompt) -> Optional[PrefixMatch]:
-        """Longest block-aligned common prefix between ``prompt`` and any
-        cached entry — STRICT (never the whole prompt: at least one
-        token must remain to prefill, since admission samples the first
-        generated token from the prompt's last position)."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        cap = self.round_down(len(prompt))
-        if cap == len(prompt):
-            cap -= self.block
-        best_l, best_key = 0, None
-        if cap > 0:
-            for key, ent in self._entries.items():
-                m = self.round_down(min(_common_prefix(prompt, ent.tokens),
-                                        cap))
-                if m > best_l:
-                    best_l, best_key = m, key
-        if best_key is None:
-            self.misses += 1
-            _metrics.counter("serving.prefix_cache.misses").inc()
-            return None
-        ent = self._entries[best_key]
-        self._entries.move_to_end(best_key)
-        self.hits += 1
-        self.hit_tokens += best_l
-        _metrics.counter("serving.prefix_cache.hits").inc()
-        _metrics.counter("serving.prefix_cache.hit_tokens").inc(best_l)
-        _flight.record("prefix_hit", rows=best_l,
-                       prompt_len=int(len(prompt)))
-        return PrefixMatch(best_l, ent.k, ent.v)
-
-    def insert(self, tokens, k, v) -> None:
-        """Insert KV rows for ``tokens`` (len must be a block multiple;
-        ``k``/``v`` [L, len, Hkv, D] device arrays). An entry already
-        covering these tokens (it starts with them) makes this a no-op;
-        an existing entry that is a PREFIX of the new tokens is replaced
-        (the longer entry serves every lookup the shorter one did)."""
-        tokens = np.asarray(tokens, np.int32).reshape(-1)
-        n = len(tokens)
-        if n % self.block or n == 0:
-            raise ValueError(
-                f"prefix length {n} is not a positive multiple of "
-                f"block {self.block}")
-        stale = []
-        for key, ent in self._entries.items():
-            m = _common_prefix(tokens, ent.tokens)
-            if m == n and len(ent.tokens) >= n:
-                self._entries.move_to_end(key)
-                return                      # already covered
-            if m == len(ent.tokens):
-                stale.append(key)           # subsumed by the new entry
-        for key in stale:
-            old = self._entries.pop(key)
-            self._tokens_held -= len(old.tokens)
-        self._entries[self._key(tokens)] = _Entry(tokens, k, v)
-        self._tokens_held += n
-        while self._tokens_held > self.capacity_tokens and \
-                len(self._entries) > 1:
-            _, old = self._entries.popitem(last=False)
-            self._tokens_held -= len(old.tokens)
-            self.evictions += 1
-            _metrics.counter("serving.prefix_cache.evictions").inc()
-            _flight.record("prefix_evict", rows=len(old.tokens),
-                           tokens_held=self._tokens_held,
-                           reason="capacity")
-        _metrics.gauge("serving.prefix_cache.tokens_held").set(
-            self._tokens_held)
-
-    def put_prompt(self, params, tokens, cfg) -> None:
-        """Ahead-of-traffic registration: prefill ``tokens`` standalone
-        (``llama.prompt_kv``) and insert the block-trimmed rows."""
-        from ..models import llama
-
-        tokens = np.asarray(tokens, np.int32).reshape(-1)
-        n = self.round_down(len(tokens))
-        if n == 0:
-            raise ValueError(
-                f"prompt of {len(tokens)} tokens is shorter than one "
-                f"block ({self.block})")
-        cache, _ = llama.prompt_kv(params, tokens[:n], cfg)
-        self.insert(tokens[:n], cache["k"][:, 0], cache["v"][:, 0])
-
-    def reset(self) -> None:
-        """Drop all entries and zero counters (the scheduler's warm-run
-        isolation hook — warmup must not pre-populate measured hits)."""
-        self.__init__(block=self.block,
-                      capacity_tokens=self.capacity_tokens)
-
-    # --- stats ------------------------------------------------------------
-    @property
-    def tokens_held(self) -> int:
-        return self._tokens_held
-
-    def stats(self) -> dict:
-        return {"hits": self.hits, "misses": self.misses,
-                "hit_tokens": self.hit_tokens,
-                "tokens_held": self._tokens_held,
-                "entries": len(self._entries),
-                "evictions": self.evictions}
-
-
-# ---------------------------------------------------------------------------
-# Paged prefix cache (r11): page-ref LRU — a hit is a ref bump, not a copy
-# ---------------------------------------------------------------------------
 
 
 @dataclass
@@ -254,24 +98,23 @@ class PagedPrefixMatch:
 
 
 class PagedPrefixCache:
-    """Shared-prefix cache over the PAGED KV pool (the r7 row-copy LRU
-    rewritten for inference/paged_kv.py): entries hold page IDS, not KV
-    arrays. Insertion retains the admitted request's prompt pages (one
-    refcount bump per page — the rows are harvested by REFERENCE, the
-    slot and the cache literally share physical pages); a hit hands the
-    same page ids to the new request's reservation, which retains them
-    again. Zero KV rows are copied anywhere in the hit path — the r7
-    cache's dynamic_update_slice of reused rows into the admit window
-    is gone, and "reuse" is true dedup across every live request +
-    the cache (N sharers of a 192-row prefix hold its pages ONCE).
+    """Shared-prefix cache over the paged KV pool
+    (inference/paged_kv.py): entries hold page IDS, not KV arrays.
+    Insertion retains the admitted request's prompt pages (one refcount
+    bump per page — the rows are harvested by REFERENCE, the slot and
+    the cache literally share physical pages); a hit hands the same page
+    ids to the new request's reservation, which retains them again. Zero
+    KV rows are copied anywhere in the hit path, and "reuse" is true
+    dedup across every live request + the cache (N sharers of a 192-row
+    prefix hold its pages ONCE).
 
     Granularity is whole pages (the page IS the block — sharers must
     never write a shared page, and suffix writes start at the page
     boundary after the hit, so the serving path never needs a COW
-    break). Matching is exact-token over a flat LRU, same policy as the
-    r7 cache; capacity is bounded in PAGES held and eviction releases
-    page refs (a page shared with a live slot frees only when that slot
-    retires — eviction can't corrupt anyone). ``evict_until`` lets the
+    break). Matching is exact-token over a flat LRU; capacity is bounded
+    in PAGES held and eviction releases page refs (a page shared with a
+    live slot frees only when that slot retires — eviction can't corrupt
+    anyone). ``evict_until`` lets the
     admission path reclaim cache-held pages under page pressure before
     deferring a request (the cache must yield to live traffic).
 
@@ -326,9 +169,6 @@ class PagedPrefixCache:
 
     def round_down(self, n: int) -> int:
         return (int(n) // self.block) * self.block
-
-    def round_up(self, n: int) -> int:
-        return -(-int(n) // self.block) * self.block
 
     # --- lookup -----------------------------------------------------------
     def match(self, prompt) -> Optional[PagedPrefixMatch]:
@@ -590,9 +430,10 @@ class PagedPrefixCache:
             self._evict(next(iter(self._entries)), reason="reset")
 
     def reset(self) -> None:
-        """Release all page refs and zero counters (warm-run isolation —
-        same hook as ``PrefixCache.reset``; the PAGER keeps its pool and
-        the host tier empties with the entries)."""
+        """Release all page refs and zero counters (the scheduler's
+        warm-run isolation hook — warmup must not pre-populate measured
+        hits; the PAGER keeps its pool and the host tier empties with
+        the entries)."""
         self.clear()
         if self.host_tier is not None:
             self.host_tier.reset()

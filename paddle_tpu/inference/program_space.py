@@ -62,8 +62,8 @@ def chunk_for(prefill_chunks: Sequence[int], s_max: int) -> int:
     return int(prefill_chunks[-1])
 
 
-def _pow2(n: int, lo: int = 1) -> int:
-    p = lo
+def _pow2(n: int) -> int:
+    p = 1
     while p < n:
         p *= 2
     return p
@@ -93,9 +93,6 @@ class WorkloadEnvelope:
     * ``prefix_block`` — the prefix cache's block size when one is
       attached (hit lengths are block multiples; None = no cache, so
       no suffix-bucketed widths are reachable).
-    * ``offline_batch`` — largest ``run(fused=True)`` offline drain
-      batch, or None when the deployment serves online-only (the
-      ``drain`` family is then unreachable and not enumerated).
     """
     max_prompt: int
     max_new_tokens: int
@@ -103,7 +100,6 @@ class WorkloadEnvelope:
     n_pads: Tuple[int, ...] = ()
     resume: bool = True
     prefix_block: Optional[int] = None
-    offline_batch: Optional[int] = None
 
     def __post_init__(self):
         if self.max_prompt < 1:
@@ -137,19 +133,18 @@ class WorkloadEnvelope:
 class ProgramFamily:
     """One segment program-key family: schema + enumerator.
 
-    ``tag`` is the leading string of the key tuple (None for the r5
-    admit family, whose historical ``(bucket, nb)`` format carries no
-    tag). ``axes`` name the remaining positions. ``enumerate_fn(engine,
-    envelope)`` yields every key the family can reach from that config
-    under that envelope; ``applies(engine)`` gates which families an
-    engine config routes dispatches to. ``budget_program`` names the
+    ``tag`` is the leading string of the key tuple. ``axes`` name the
+    remaining positions. ``enumerate_fn(engine, envelope)`` yields every
+    key the family can reach from that config under that envelope;
+    ``applies(engine)`` gates which families an engine config routes
+    dispatches to. ``budget_program`` names the
     canonical gate program (``analysis/programs.py``) that stands in
     for this family in the budget registry — ``analysis.coverage``'s
     budget-completeness lint (r24) fails the gate if that program lacks
     a pinned ``peak_bytes_max``, so every reachable family has a
     statically bounded HBM peak."""
     name: str
-    tag: Optional[str]
+    tag: str
     axes: Tuple[str, ...]
     doc: str
     enumerate_fn: Callable
@@ -163,8 +158,7 @@ class ProgramFamily:
             raise TypeError(
                 f"program family {self.name!r} takes axes {self.axes}; "
                 f"missing {missing}, unexpected {extra}")
-        vals = tuple(int(kw[a]) for a in self.axes)
-        return vals if self.tag is None else (self.tag,) + vals
+        return (self.tag,) + tuple(int(kw[a]) for a in self.axes)
 
 
 class ProgramSpace:
@@ -191,8 +185,7 @@ class ProgramSpace:
         return sorted(self._families)
 
     def tags(self) -> FrozenSet[str]:
-        return frozenset(f.tag for f in self._families.values()
-                         if f.tag is not None)
+        return frozenset(f.tag for f in self._families.values())
 
     def key(self, name: str, **axes) -> tuple:
         """THE key constructor — every jit memo key in serving.py
@@ -207,14 +200,8 @@ class ProgramSpace:
         that as an unenumerated compile)."""
         if not isinstance(key, tuple) or not key:
             return None
-        if isinstance(key[0], str):
-            for f in self._families.values():
-                if f.tag == key[0] and len(key) == 1 + len(f.axes):
-                    return f.name
-            return None
         for f in self._families.values():
-            if f.tag is None and len(key) == len(f.axes) \
-                    and all(isinstance(v, int) for v in key):
+            if f.tag == key[0] and len(key) == 1 + len(f.axes):
                 return f.name
         return None
 
@@ -244,13 +231,6 @@ PROGRAM_SPACE = ProgramSpace()
 # the admission arithmetic over the envelope's integer domain and
 # asserts the two agree (the closed forms below are the fast path, the
 # replay is the proof).
-
-
-def _bucket_for(buckets: Sequence[int], n: int) -> int:
-    for b in buckets:
-        if n <= b:
-            return b
-    raise ValueError(f"no bucket for prompt length {n}")
 
 
 def _n_pads(engine, env: WorkloadEnvelope) -> Tuple[int, ...]:
@@ -286,40 +266,7 @@ def _reachable_widths(engine, env: WorkloadEnvelope,
     return frozenset(widths)
 
 
-def _dense_pre_widths(engine, env: WorkloadEnvelope
-                      ) -> FrozenSet[Tuple[int, int]]:
-    """(pre_max, s_max) pairs the DENSE (contiguous) segment can reach.
-
-    pre_max = 0 always pins s_max to the top bucket (dispatch rule).
-    pre_max > 0 is the block-rounded longest hit: hits are block
-    multiples strictly shorter than the admission length, so pre ranges
-    over {block, 2*block, ...} up to round_down(L_adm - 1); the paired
-    s_max buckets any suffix in the group (1..L_adm). Pairs whose
-    prefix + suffix window exceeds max_len are DROPPED by dispatch
-    (falls back to (0, top), already present)."""
-    buckets = engine.buckets
-    top = buckets[-1]
-    pairs = {(0, top)}
-    blk = env.prefix_block
-    if blk is None:
-        return frozenset(pairs)
-    lo, hi = env.admit_lengths(buckets)
-    max_pre = ((hi - 1) // blk) * blk
-    widths = _reachable_widths(engine, env, spec_pinned=False)
-    pre = blk
-    while pre <= max_pre:
-        for w in widths:
-            if pre + w <= engine.max_len:
-                pairs.add((pre, w))
-        pre += blk
-    return frozenset(pairs)
-
-
 # --- family registrations ---------------------------------------------------
-
-
-def _is_dense(engine) -> bool:
-    return not engine.paged
 
 
 def _quant(engine) -> Optional[str]:
@@ -329,13 +276,13 @@ def _quant(engine) -> Optional[str]:
 
 
 def _is_paged_plain(engine) -> bool:
-    return (engine.paged and not engine.chunked and not engine.speculative
+    return (not engine.chunked and not engine.speculative
             and not engine.sampling and not engine.quality_digest
             and not _quant(engine))
 
 
 def _is_paged_quality(engine) -> bool:
-    return engine.paged and engine.quality_digest and not _quant(engine)
+    return engine.quality_digest and not _quant(engine)
 
 
 def _is_paged_quant(engine) -> bool:
@@ -343,16 +290,15 @@ def _is_paged_quant(engine) -> bool:
     # every paged segment (digests included) lives on the qpseg dtype
     # axis, because the compiled programs differ (narrow pool dtype +
     # scale planes) even where the loop structure is identical
-    return engine.paged and bool(_quant(engine))
+    return bool(_quant(engine))
 
 
 def _is_paged_chunked(engine) -> bool:
-    return (engine.paged and engine.chunked
-            and not (engine.speculative or engine.sampling))
+    return engine.chunked and not (engine.speculative or engine.sampling)
 
 
 def _is_paged_spec(engine) -> bool:
-    return engine.paged and bool(engine.speculative or engine.sampling)
+    return bool(engine.speculative or engine.sampling)
 
 
 def _seq_parallel(engine) -> int:
@@ -363,7 +309,7 @@ def _seq_parallel(engine) -> int:
 def _is_paged_sp(engine) -> bool:
     # r23: the spseg family ADDS to an sp engine's space (regular
     # traffic still rides pseg/cseg — those predicates are untouched)
-    return engine.paged and _seq_parallel(engine) > 0
+    return _seq_parallel(engine) > 0
 
 
 def sp_rungs(engine, env: WorkloadEnvelope) -> Tuple[int, ...]:
@@ -392,50 +338,6 @@ def sp_rungs(engine, env: WorkloadEnvelope) -> Tuple[int, ...]:
                 rungs.add(b)
                 break
     return tuple(sorted(rungs))
-
-
-def _enum_admit(engine, env: WorkloadEnvelope) -> Iterable[tuple]:
-    # windowed-path fused prefill waves: every bucket x wave width that
-    # fits the slot count (exactly the set warmup() has always compiled)
-    from .serving import _WAVE_WIDTHS
-
-    fam = PROGRAM_SPACE.family("admit")
-    for b in engine.buckets:
-        for nb in _WAVE_WIDTHS:
-            if nb <= engine.slots:
-                yield fam.key(bucket=b, nb=nb)
-
-
-def _enum_decode(engine, env: WorkloadEnvelope) -> Iterable[tuple]:
-    yield PROGRAM_SPACE.family("decode").key(chunk=engine.chunk)
-
-
-def _enum_drain(engine, env: WorkloadEnvelope) -> Iterable[tuple]:
-    # offline whole-queue drain (run(fused=True)): n_pad = pow2(batch),
-    # p_max buckets the batch's longest prompt, g_max = pow2(longest
-    # generation, floor 16) — enumerated only when the envelope declares
-    # an offline batch bound
-    if not env.offline_batch:
-        return
-    fam = PROGRAM_SPACE.family("drain")
-    n_pads = sorted({_pow2(n) for n in range(1, env.offline_batch + 1)})
-    p_maxes = sorted({_bucket_for(engine.buckets, l)
-                      for l in range(1, env.max_prompt + 1)})
-    g_maxes = sorted({_pow2(g, lo=16)
-                      for g in range(1, env.max_new_tokens + 1)})
-    for n_pad in n_pads:
-        for p_max in p_maxes:
-            for g_max in g_maxes:
-                yield fam.key(n_pad=n_pad, p_max=p_max, g_max=g_max)
-
-
-def _enum_seg(engine, env: WorkloadEnvelope) -> Iterable[tuple]:
-    fam = PROGRAM_SPACE.family("seg")
-    for n_pad in _n_pads(engine, env):
-        for steps in env.seg_steps:
-            for pre, w in _dense_pre_widths(engine, env):
-                yield fam.key(n_pad=n_pad, s_max=w, pre_max=pre,
-                              steps=steps)
 
 
 def _enum_pseg(engine, env: WorkloadEnvelope) -> Iterable[tuple]:
@@ -498,34 +400,6 @@ def _enum_sseg(engine, env: WorkloadEnvelope) -> Iterable[tuple]:
                 continue        # dispatch raises before building this key
             yield fam.key(n_pad=n_pad, k=engine.speculative, steps=steps)
 
-
-PROGRAM_SPACE.register(ProgramFamily(
-    name="admit", tag=None, axes=("bucket", "nb"),
-    doc="r5 windowed fused prefill+insert wave: (bucket, nb)",
-    enumerate_fn=_enum_admit,
-    applies=lambda e: _is_dense(e) and e.mesh is None,
-    budget_program="serving_segment"))
-
-PROGRAM_SPACE.register(ProgramFamily(
-    name="decode", tag="decode", axes=("chunk",),
-    doc="r5 windowed decode chunk: ('decode', chunk)",
-    enumerate_fn=_enum_decode,
-    applies=lambda e: _is_dense(e) and e.mesh is None,
-    budget_program="decode_tick"))
-
-PROGRAM_SPACE.register(ProgramFamily(
-    name="drain", tag="drain", axes=("n_pad", "p_max", "g_max"),
-    doc="r5 offline whole-queue drain: ('drain', n_pad, p_max, g_max)",
-    enumerate_fn=_enum_drain,
-    applies=lambda e: _is_dense(e) and e.mesh is None,
-    budget_program="serving_segment"))
-
-PROGRAM_SPACE.register(ProgramFamily(
-    name="seg", tag="seg", axes=("n_pad", "s_max", "pre_max", "steps"),
-    doc="r7 dense re-entrant segment: ('seg', n_pad, s_max, pre_max, "
-        "steps)",
-    enumerate_fn=_enum_seg, applies=_is_dense,
-    budget_program="serving_segment"))
 
 PROGRAM_SPACE.register(ProgramFamily(
     name="pseg", tag="pseg", axes=("n_pad", "s_max", "steps"),

@@ -9,8 +9,8 @@ module owns that loop:
 
 * **Clocked arrivals** — seeded Poisson (``poisson_arrivals``) or
   staggered/uniform (``staggered_arrivals``) traces; every trace is a
-  plain list of ``Arrival`` rows so benchmarks replay the identical
-  trace against the engine AND the fixed-batching baseline.
+  plain list of ``Arrival`` rows, so a benchmark or a test replays the
+  identical trace.
 * **Admission control / backpressure** — a bounded intake queue:
   arrivals past ``max_queue`` stay client-side (the arrival stream
   blocks) and each refusal is counted; the queue drains FCFS.
@@ -27,7 +27,7 @@ module owns that loop:
   measurements, not the uniform-step model r5 shipped. Segment spans
   are emitted through ``profiler._hooks`` so ``paddle.profiler``
   captures scheduler activity like any op.
-* **Shared-prefix KV reuse** — pass a ``PrefixCache``; admission
+* **Shared-prefix KV reuse** — pass a ``PagedPrefixCache``; admission
   detects cached prefixes and the segment program prefills suffixes
   only (see inference/prefix_cache.py).
 
@@ -64,7 +64,7 @@ from ..observability import metrics as _metrics
 from ..observability import tracing as _tracing
 from ..observability.metrics import percentile as _pctl
 from ..profiler import _hooks
-from .prefix_cache import PrefixCache
+from .prefix_cache import PagedPrefixCache
 from .serving import Request, ServingEngine
 
 __all__ = ["Arrival", "OnlineScheduler", "SLOScheduler",
@@ -155,10 +155,10 @@ class OnlineReport:
     backpressure_events: int
     # r11 paged engine: admissions deferred because the PAGE POOL (not
     # the queue bound) was the constraint — backpressure{reason="pages"}
-    # — plus the pool's occupancy stats; 0/None on contiguous engines
+    # — plus the pool's occupancy stats
     backpressure_pages: int = 0
     pages: Optional[dict] = None
-    prefix: Optional[dict] = None  # PrefixCache.stats() when enabled
+    prefix: Optional[dict] = None  # prefix_cache.stats() when enabled
     # r13 SLO-aware serving: retry_after_s is the LAST machine-readable
     # backpressure hint handed to a refused client (seconds until the
     # bounded queue is expected to have drained one slot, derived from
@@ -258,7 +258,7 @@ class OnlineScheduler:
 
     def __init__(self, engine: ServingEngine, max_queue: int = 64,
                  seg_steps: int = 32,
-                 prefix_cache: Optional[PrefixCache] = None,
+                 prefix_cache: Optional[PagedPrefixCache] = None,
                  slo_monitor=None, perf_monitor=None,
                  capacity_monitor=None):
         self.engine = engine
@@ -413,7 +413,7 @@ class OnlineScheduler:
                 self._pre_segment(now, t0)
                 idle = (not eng._queue
                         and eng.free_slot_count() == eng.slots)
-                if not idle and cap is not None and eng.paged:
+                if not idle and cap is not None:
                     # r18: evaluate time-to-exhaustion BEFORE the dispatch
                     # that could hit pages-backpressure — the alert must
                     # lead the valve (ISSUE 13 acceptance bar). r19: the
@@ -421,14 +421,12 @@ class OnlineScheduler:
                     # tier pages ride the same evaluation as a separate
                     # (reclaimable-at-restore-cost) pool.
                     pc = self.prefix_cache
-                    has_rec = (pc is not None
-                               and hasattr(pc, "reclaimable_pages"))
                     cap.begin_segment(
                         eng.pager.pages_free,
-                        pc.reclaimable_pages() if has_rec else 0,
-                        host_pages=(pc.host_pages if has_rec
-                                    and getattr(pc, "host_tier", None)
-                                    is not None else None))
+                        pc.reclaimable_pages() if pc is not None else 0,
+                        host_pages=(pc.host_pages if pc is not None
+                                    and pc.host_tier is not None
+                                    else None))
             if idle:
                 # nothing admitted and nothing decoding: sleep to the
                 # next arrival instead of spinning
@@ -482,7 +480,7 @@ class OnlineScheduler:
             ticks=eng.last_run_ticks,
             backpressure_events=self.backpressure_events,
             backpressure_pages=eng.page_backpressure_events,
-            pages=eng.pager.stats() if eng.paged else None,
+            pages=eng.pager.stats(),
             prefix=(self.prefix_cache.stats()
                     if self.prefix_cache is not None else None),
             retry_after_s=self.last_retry_after_s,
@@ -494,16 +492,14 @@ class OnlineScheduler:
                   if self.perf_monitor is not None else None),
             capacity=(self.capacity_monitor.report()
                       if self.capacity_monitor is not None else None),
-            meter=(_capacity.aggregate_meters(
+            meter=_capacity.aggregate_meters(
                 reqs,
                 ledger=(self.capacity_monitor.ledger
                         if self.capacity_monitor is not None else None),
-                page_size=eng.page_size if eng.paged else None)
-                if eng.paged else None),
+                page_size=eng.page_size),
             tiers=(self.prefix_cache.host_tier.stats()
                    if self.prefix_cache is not None
-                   and getattr(self.prefix_cache, "host_tier", None)
-                   is not None else None),
+                   and self.prefix_cache.host_tier is not None else None),
             segment_phases={
                 name.rsplit(".", 1)[1]: {"seconds": ns / 1e9, "count": c}
                 for name, (ns, c) in phases.items()},
@@ -604,7 +600,7 @@ class OnlineScheduler:
             self.perf_monitor.note_segment(
                 ev["steps"], ev.get("tokens", 0),
                 elapsed_s=t_sync - t_seg_pc)
-        if cap is not None and eng.paged:
+        if cap is not None:
             cap.note_admission(
                 sum(self._reqs[rid].pages_fresh
                     for rid in ev["admitted"]),
@@ -706,7 +702,7 @@ class SLOScheduler(OnlineScheduler):
 
     def __init__(self, engine: ServingEngine, max_queue: int = 64,
                  seg_steps: int = 32,
-                 prefix_cache: Optional[PrefixCache] = None,
+                 prefix_cache: Optional[PagedPrefixCache] = None,
                  preempt: bool = True, shed_deadlines: bool = True,
                  slo_monitor=None, perf_monitor=None,
                  capacity_monitor=None):
@@ -882,14 +878,11 @@ class SLOScheduler(OnlineScheduler):
 
     def _head_admissible(self, head: Request) -> bool:
         """Could the queue head be admitted right now without evicting
-        anyone? Slots are the resource on a contiguous engine; pages on
-        a paged one (a conservative full-need check — prefix hits only
-        reduce it)."""
+        anyone? A free slot, and pages for its whole span (a
+        conservative full-need check — prefix hits only reduce it)."""
         eng = self.engine
         if eng.free_slot_count() == 0:
             return False
-        if not eng.paged:
-            return True
         fp, remaining = head.resume_view()
         need = eng.pager.pages_needed(len(fp) + remaining - 1)
         return need <= eng.pager.pages_free

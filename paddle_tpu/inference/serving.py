@@ -6,37 +6,33 @@ reference's GPU serving engines (and vLLM-style systems) keep a fixed pool
 of decode slots and swap finished requests out for queued ones so the
 batch stays full — that scheduling idea, TPU-native:
 
-* **The whole drain is ONE compiled program** (r5, ``run()``'s default;
-  see ``_drain_prog``): slot state lives on device and a ``while_loop``
-  alternates admit (prefill inside a ``lax.cond`` branch) and decode
-  ticks. Admission costs no host round trip, so refill is greedy; the
-  host pays one dispatch + one fetch per drain, making throughput AND
-  latency independent of dispatch cost (2.6-2.9x fixed batching
-  wall-clock in the r05 chip record).
+* **One KV layout: the paged pool** (``inference/paged_kv.py``). One flat
+  ``[L, pages, page, Hkv*D]`` plane per K and V plus per-slot page tables;
+  admission is gated on pages free, a shared prefix is shared pages
+  (``PagedPrefixCache``), and the pool rides every program's loop carry
+  so it is updated in place. The model's side of it —
+  ``forward_with_pages``, ``init_paged_pool``, the attention kernel —
+  comes from ``models.family_of(cfg)``.
+* **A segment is ONE compiled program** (``_paged_segment_prog`` and its
+  chunked / sequence-parallel / speculative siblings): slot state lives
+  on device and a step-bounded ``while_loop`` alternates admit (prefill
+  of the next queued request into a free slot) and decode ticks over
+  all slots. Admission costs no host round trip, so refill is greedy;
+  the host pays one dispatch + one fetch per segment and replays the
+  event log (``_replay_segment``) to hand tokens to requests.
 * **Fixed-shape compiled programs.** Decode is a ragged tick over all
-  slots with per-slot positions (every slot attends and writes at its
-  own ``pos`` — ``llama.forward_with_cache``'s ragged path) and per-slot
-  REMAINING counts: a slot freezes in-program the step its request
-  completes. Shapes never depend on request sizes — nothing recompiles
-  as requests come and go.
-* **Windowed incremental mode** (``run(fused=False)``): for serving on
-  top of an already-partial slot state — wave-batched bucketed
-  admission, decode chunks chained via async dispatch, host reads
-  batched into one ``device_get`` per admission window.
-* **Slot-contiguous (ragged) cache, not paged.** Each slot owns rows
-  [0, max_len) of the shared [L, slots, max_len, H, D] cache. Paging adds
-  an indirection XLA can't fuse well; at serving's typical length spread
-  the ragged layout wins on TPU (documented trade-off vs the reference's
-  paged pools). r6: the decode tick's attention READS are ragged too —
-  the Pallas kernel (`ops/pallas/decode_attention.py`) fetches only KV
-  blocks [0, pos] per slot instead of the full max_len window, and the
-  tick's between-matmul small-op chains run as fused Pallas epilogue
-  ops (`ops/pallas/tick_fusion.py`); both dispatch inside
-  ``llama.forward_with_cache`` so every path here (windowed chunks and
-  the fused drain's decode branch) picks them up (SCALING.md §3c).
+  slots with per-slot positions and per-slot REMAINING counts: a slot
+  freezes in-program the step its request completes. Shapes never
+  depend on request sizes — nothing recompiles as requests come and go,
+  and ``program_space.PROGRAM_SPACE`` enumerates every key a
+  configuration can reach (``aot_warmup`` compiles them at build).
+* ``run()`` drains the queue with segments back to back;
+  ``OnlineScheduler`` / ``FleetRouter`` drive ``dispatch_segment`` /
+  ``finish_segment`` under arriving traffic.
 
 Greedy decoding (temperature 0) — matching ``llama.generate``'s default —
-so engine output is bit-comparable to the dense path request-by-request.
+so engine output is bit-comparable to that reference request-by-request
+(``llama.generate`` keeps the contiguous cache; the engine has none).
 ``eos_token_id`` freezes a slot in-program the step EOS is emitted.
 
 r15 (ISSUE 10): **speculative + sampled decoding inside the segment
@@ -108,8 +104,6 @@ def _mesh_scope(mesh):
     finally:
         set_mesh(prev)
 
-_WAVE_WIDTHS = (8, 4, 2, 1)  # compiled prefill sub-batch sizes
-
 
 # --- in-program sampling primitives (r15, ISSUE 10) -----------------------
 # Per-slot RNG state rides the segment as RAW uint32 [slots, 2] key data
@@ -171,14 +165,13 @@ class _PendingSegment:
     fetches them in turn — replica i+1's device work overlaps replica
     i's fetch wait, with the per-segment sync contract intact (each
     finish is still exactly one ``allowed_sync`` event fetch)."""
-    paged: bool
     picked: List["Request"]
     n: int
     now: float
     prefix_cache: object
     dev: tuple                     # (out, aq, aslot, step, qidx) futures
     pre_lens: object               # [n] reused-prefix rows per request
-    req_pages: Optional[List[List[int]]] = None   # paged reservations
+    req_pages: Optional[List[List[int]]] = None   # page reservations
     # r13: admission-time context per request. full_prompts[j] is the
     # tokens the admit actually prefills — prompt + any tokens already
     # generated before a preemption/failover requeue (the RESUME view);
@@ -205,7 +198,7 @@ class _PendingSegment:
     # its event log additionally carries a [steps, n] int32 column block
     counters: bool = False
     seg: int = 0                   # the engine's index of this segment
-    s_max: int = 0                 # paged: the admit window's width
+    s_max: int = 0                 # the admit window's width
 
 
 @dataclass
@@ -320,7 +313,7 @@ class Request:
 
 
 # Process-wide compiled-program cache (r12): every program an engine
-# builds (admit / decode / drain / segment / paged segment) closes over
+# builds closes over
 # NOTHING but config scalars (cfg, slots, max_len, eos, chunk, mesh) —
 # params and caches are arguments — so engines with identical geometry
 # can share one jitted callable. A fleet of N identical replicas then
@@ -337,7 +330,7 @@ class ServingEngine:
                  max_len: Optional[int] = None, chunk: int = 32,
                  prompt_buckets: Sequence[int] = (32, 64, 128, 256),
                  eos_token_id: Optional[int] = None,
-                 paged: bool = False, page_size: int = 16,
+                 paged: bool = True, page_size: int = 16,
                  num_pages: Optional[int] = None, mesh=None,
                  chunked_prefill: bool = False,
                  prefill_chunks: Sequence[int] = (8, 16, 32, 64),
@@ -349,6 +342,13 @@ class ServingEngine:
                  quant: Optional[str] = None,
                  seq_parallel: int = 0,
                  long_buckets: Sequence[int] = ()):
+        if not paged:
+            # the keyword survives only because the benchmark's config
+            # files still pass ``"paged": true``
+            raise ValueError(
+                "paged=False: the contiguous KV cache is gone — "
+                "ServingEngine serves from the paged pool only "
+                "(llama.generate is the contiguous-cache reference)")
         self.cfg = cfg
         # the model seam (PR 29): what the engine needs of a model — its
         # parameters, its paged pool, its forward over pages, whether its
@@ -357,7 +357,7 @@ class ServingEngine:
         # list is refused here, by name
         self.model = family_of(cfg)
         for engaged, family in (
-                (not paged, "dense cache"), (mesh is not None, "mesh"),
+                (mesh is not None, "mesh"),
                 (chunked_prefill, "chunked prefill"),
                 (speculative or sampling, "speculative"),
                 (quality_digest, "quality digest"),
@@ -372,9 +372,8 @@ class ServingEngine:
         # store on the head dim; a model bigger than one chip's HBM then
         # serves through the SAME one-dispatch/one-fetch segment programs
         # (GSPMD inserts one all-reduce per layer after the row-parallel
-        # projections). Serving is segment-only under a mesh (run() and
-        # warmup() route accordingly); slot bookkeeping stays host-side
-        # and mesh-oblivious.
+        # projections). Slot bookkeeping stays host-side and
+        # mesh-oblivious.
         self.mesh = mesh
         if mesh is not None:
             mp = int(mesh.shape.get("mp", 1))
@@ -391,16 +390,15 @@ class ServingEngine:
         if not self.buckets:
             raise ValueError("no prompt bucket fits max_len")
         self.eos = eos_token_id
-        self._progs: Dict[tuple, object] = {}  # (bucket, nb) -> admit fn
+        self._progs: Dict[tuple, object] = {}  # program key -> jitted fn
         self._queue: List[Request] = []
         self._active: List[Optional[Request]] = [None] * self.slots
         self._rem_host = [0] * self.slots  # host mirror of remaining counts
         self._finished: List[Request] = []
-        self.last_run_chunks = 0  # decode chunks issued by the last run()
-        self.last_run_ticks = 0   # decode TICKS (fused: exact; windowed: chunks*K)
+        self.last_run_chunks = 0  # segments fetched since the last reset
+        self.last_run_ticks = 0   # loop steps those segments ran
         self.last_latencies = {}  # rid -> submit->finish seconds (last run)
         self._next_rid = 0
-        self.paged = bool(paged)
         self.page_backpressure_events = 0  # admissions deferred for pages
         # r13 chunked prefill (ISSUE 8): split each admitted prompt into
         # fixed-width chunks interleaved with decode ticks INSIDE the
@@ -410,11 +408,6 @@ class ServingEngine:
         # program cache keys stay bucketed (a floating chunk width would
         # be the 2.5 s mid-serve XLA-compile class all over again).
         self.chunked = bool(chunked_prefill)
-        if self.chunked and not self.paged:
-            raise ValueError(
-                "chunked_prefill requires paged=True (chunks prefill at "
-                "a context offset through the page tables; the "
-                "contiguous admit branch stages whole windows)")
         self.prefill_chunks = tuple(sorted(int(c) for c in prefill_chunks))
         if self.chunked and not self.prefill_chunks:
             raise ValueError("chunked_prefill needs a non-empty "
@@ -446,11 +439,6 @@ class ServingEngine:
                         float(sampling.get("top_p", 1.0)))
         self.sampling = samp
         self.sample_seed = int(sample_seed)
-        if (self.speculative or self.sampling) and not self.paged:
-            raise ValueError(
-                "speculative/sampled decoding requires paged=True (the "
-                "verify tick reuses the page-indirect q_len>1 path and "
-                "the RNG/history state rides the paged segment family)")
         # r17 quality digests (ISSUE 12): per-emitted-token logit
         # evidence computed IN-PROGRAM and rolled into the segment event
         # log — the emitted token's logit plus the top-k (ids, values)
@@ -468,12 +456,6 @@ class ServingEngine:
         self.quality_digest = bool(quality_digest)
         self.digest_top_k = int(digest_top_k)
         if self.quality_digest:
-            if not self.paged:
-                raise ValueError(
-                    "quality_digest requires paged=True (digests extend "
-                    "the paged segment event log; the contiguous "
-                    "engine's windowed path has no single event fetch "
-                    "to ride)")
             if self.chunked or self.speculative or self.sampling:
                 raise ValueError(
                     "quality_digest composes with the plain paged "
@@ -491,7 +473,7 @@ class ServingEngine:
         # the KV pool carries the narrow dtype with per-page scale
         # planes ("ks"/"vs") keyed by physical page id — COW, refcounts
         # and the host tier treat pages dtype-obliviously, so prefix
-        # sharing and r19 spill survive unchanged. Paged-only: the
+        # sharing and r19 spill survive unchanged. The
         # quantized programs are a new DTYPE AXIS on the paged segment
         # family ("qpseg"), enumerated and AOT-warmed like every other
         # rung. Composes with quality_digest (the shadow-diff quality
@@ -506,11 +488,6 @@ class ServingEngine:
             if self.quant not in QUANT_MODES:
                 raise ValueError(f"quant must be one of {QUANT_MODES}, "
                                  f"got {quant!r}")
-            if not self.paged:
-                raise ValueError(
-                    "quant requires paged=True (per-page KV scales ride "
-                    "the paged pool's fixed tiles; the contiguous cache "
-                    "has no page axis to key them on)")
             if mesh is not None:
                 raise ValueError(
                     "quant under a mesh is not supported — the scale "
@@ -552,11 +529,6 @@ class ServingEngine:
             raise ValueError(f"seq_parallel must be >= 0, got "
                              f"{seq_parallel}")
         if self.seq_parallel:
-            if not self.paged:
-                raise ValueError(
-                    "seq_parallel requires paged=True (prefill shards "
-                    "scatter into the shared paged pool; the contiguous "
-                    "cache has no page indirection to land them in)")
             if self.speculative or self.sampling or self.quality_digest \
                     or self.quant:
                 raise ValueError(
@@ -587,34 +559,21 @@ class ServingEngine:
         # retry_after_s estimates so speculative serves don't over-shed
         # (each tick retires accept_ewma tokens, not one)
         self.spec_accept_ewma = 1.0
-        if self.paged:
-            # paged mode (r11, inference/paged_kv.py): ONE flat page pool
-            # + per-slot page tables replace the [slots, max_len] block.
-            # max_len keeps its meaning as the PER-SLOT virtual cap
-            # (max_pages * page_size); num_pages sizes the PHYSICAL pool
-            # — below slots * max_pages it is the pages-free admission
-            # regime the contiguous cache cannot express.
-            from .paged_kv import PagedKVCache
+        # the KV store (r11, inference/paged_kv.py): ONE flat page pool +
+        # per-slot page tables. max_len is the PER-SLOT virtual cap
+        # (max_pages * page_size); num_pages sizes the PHYSICAL pool —
+        # below slots * max_pages admission is gated on pages free.
+        from .paged_kv import PagedKVCache
 
-            self.page_size = int(page_size)
-            if self.max_len % self.page_size:
-                raise ValueError(f"max_len {self.max_len} is not a "
-                                 f"multiple of page_size {self.page_size}")
-            max_pages = self.max_len // self.page_size
-            self.pager = PagedKVCache(
-                cfg, self.slots, self.page_size,
-                num_pages=int(num_pages or self.slots * max_pages + 1),
-                max_pages=max_pages, mesh=mesh, quant=self.quant)
-            self._cache = None  # no contiguous block exists in paged mode
-        else:
-            self.pager = None
-            self._cache = llama.init_kv_cache(cfg, self.slots, self.max_len)
-            if mesh is not None:
-                from jax.sharding import NamedSharding
-
-                self._cache = jax.device_put(
-                    self._cache,
-                    NamedSharding(mesh, llama.kv_cache_spec()))
+        self.page_size = int(page_size)
+        if self.max_len % self.page_size:
+            raise ValueError(f"max_len {self.max_len} is not a "
+                             f"multiple of page_size {self.page_size}")
+        max_pages = self.max_len // self.page_size
+        self.pager = PagedKVCache(
+            cfg, self.slots, self.page_size,
+            num_pages=int(num_pages or self.slots * max_pages + 1),
+            max_pages=max_pages, mesh=mesh, quant=self.quant)
         self._pos = self._slot_vec()
         self._nxt = self._slot_vec()
         self._rem = self._slot_vec()
@@ -664,12 +623,10 @@ class ServingEngine:
 
     @property
     def pool_bytes(self) -> Dict[str, int]:
-        """Bytes of each plane of the paged pool as it lies on the device
-        ({} on a contiguous engine): the yardstick for ``aot_warmup``'s
-        ``temp_bytes`` — a paged segment program that holds the pool
-        once has temporaries under ``pool_bytes["k"]``."""
-        if not self.paged:
-            return {}
+        """Bytes of each plane of the paged pool as it lies on the device:
+        the yardstick for ``aot_warmup``'s ``temp_bytes`` — a segment
+        program that holds the pool once has temporaries under
+        ``pool_bytes["k"]``."""
         return {n: int(a.nbytes) for n, a in self.pager.pool.items()}
 
     def _slot_vec(self):
@@ -710,22 +667,21 @@ class ServingEngine:
         * ``_rng`` [slots, 2] uint32 — raw per-slot PRNG key state,
           re-seeded from the request's seed at every admission.
         """
-        if self.paged and (self.speculative or self.sampling):
+        if self.speculative or self.sampling:
             self._hist = self._slot_arr((self.slots, self.max_len + 1),
                                         jnp.int32)
             self._hstart = self._slot_vec()
         else:
             self._hist = self._hstart = None
-        if self.paged and self.sampling:
+        if self.sampling:
             self._rng = self._slot_arr((self.slots, 2), jnp.uint32)
         else:
             self._rng = None
 
     def cache_info(self) -> dict:
-        """Compiled-program cache keys (analysis.recompile lint): admit
-        programs key on (bucket, nb), segments on ("seg", n_pad, s_max,
-        pre_max, steps), paged segments on ("pseg", n_pad, s_max, steps),
-        chunked paged segments on ("cseg", n_pad, s_max_c, C, steps) with
+        """Compiled-program cache keys (analysis.recompile lint): paged
+        segments key on ("pseg", n_pad, s_max, steps), chunked paged
+        segments on ("cseg", n_pad, s_max_c, C, steps) with
         C drawn from the declared prefill_chunks ladder, speculative/
         sampled segments on ("sseg", n_pad, K, steps) with the admit
         width PINNED to the largest bucket, quality-digest paged
@@ -736,38 +692,28 @@ class ServingEngine:
         with s_max a slab-rounded long_buckets rung — all bucketed by
         construction, so key-count growth here means a shape leaked
         past the buckets (the 2.5 s mid-serve compile class this
-        engine's width pinning fixed). Note the PAGED keys carry no
-        pre_max: shared-prefix geometry rides the page tables as DATA,
-        so prefix reuse adds zero program shapes."""
+        engine's width pinning fixed). No key carries a prefix width:
+        shared-prefix geometry rides the page tables as DATA, so prefix
+        reuse adds zero program shapes."""
         return {"name": f"serving_engine:slots{self.slots}",
                 "keys": list(self._progs.keys())}
 
-    def decode_kernel_active(self) -> bool:
-        """True when this engine's decode ticks route to the ragged
-        Pallas decode-attention kernel (a trace-time dispatch decision —
-        the serving lane's smoke gate asserts it so a selection
-        regression fails off-chip)."""
-        from ..ops.pallas.decode_attention import decode_attention_active
-
-        return decode_attention_active(self.max_len, self.cfg.num_heads,
-                                       self.cfg.num_kv_heads,
-                                       self.cfg.head_dim)
-
     def paged_kernel_active(self) -> bool:
         """True when this engine's paged segments route attention to the
-        unified page-indirect Pallas kernel (trace-time dispatch — the
-        paged serving lane asserts it like ``decode_kernel_active``)."""
+        unified page-indirect Pallas kernel (a trace-time dispatch
+        decision — ``chip_smoke.py`` and the serve cells assert it, so a
+        selection regression fails loudly)."""
         # a quantized pool takes the dequantizing gather path instead of
         # the page-indirect kernel (its per-page scales need the
         # gather); the weight stream is where the quant bytes win
-        return self.paged and not self.quant \
+        return not self.quant \
             and self.model.paged_kernel_active(self.cfg, self.page_size)
 
     def quant_kernel_active(self) -> bool:
         """True when this engine's quantized projection matmuls route to
-        the in-kernel-dequant Pallas path (trace-time dispatch — the
-        quant serving lane asserts it like ``decode_kernel_active``;
-        CPU tier-1 exercises the same kernel through FORCE_INTERPRET)."""
+        the in-kernel-dequant Pallas path (trace-time dispatch, like
+        ``paged_kernel_active``; CPU tier-1 exercises the same kernel
+        through FORCE_INTERPRET)."""
         from ..ops.pallas.tick_fusion import quant_matmul_active
 
         H = self.cfg.hidden_size
@@ -788,12 +734,11 @@ class ServingEngine:
             raise ValueError(
                 f"prompt {len(prompt)} + max_new_tokens {max_new_tokens} "
                 f"exceeds cache max_len {self.max_len}")
-        if self.paged:
-            need = self.pager.pages_needed(len(prompt) + max_new_tokens - 1)
-            if need > self.pager.num_pages - 1:
-                raise ValueError(
-                    f"request spans {need} pages but the pool holds only "
-                    f"{self.pager.num_pages - 1} — it could never admit")
+        need = self.pager.pages_needed(len(prompt) + max_new_tokens - 1)
+        if need > self.pager.num_pages - 1:
+            raise ValueError(
+                f"request spans {need} pages but the pool holds only "
+                f"{self.pager.num_pages - 1} — it could never admit")
         rid = self._next_rid
         self._next_rid += 1
         # per-request sampling seed: explicit, or derived from the
@@ -815,9 +760,8 @@ class ServingEngine:
         program closure reads, plus the per-shape key. Engines agreeing
         on all of it trace byte-identical programs."""
         return (self.cfg, self.slots, self.max_len, self.eos, self.chunk,
-                self.paged, self.pager.max_pages if self.paged else None,
-                self.mesh, self.speculative, self.sampling,
-                self.chunked, self.prefill_chunks, self.buckets,
+                self.pager.max_pages, self.mesh, self.speculative,
+                self.sampling, self.chunked, self.prefill_chunks, self.buckets,
                 self.digest_top_k if self.quality_digest else None,
                 self.quant,
                 ((self.seq_parallel, self.long_buckets)
@@ -844,73 +788,6 @@ class ServingEngine:
         self._progs[key] = fn
         return fn
 
-    def _admit_prog(self, bucket: int, nb: int):
-        """Fused prefill + slot insert: ONE program call per admission
-        sub-wave (dispatch latency is the dominant admission cost).
-        Memoised per geometry in the process-wide program cache (the
-        closure captures config scalars only — never the engine's params
-        or KV cache, which would pin them forever)."""
-        key = PROGRAM_SPACE.key("admit", bucket=bucket, nb=nb)
-        return self._memo_prog(key,
-                               lambda: self._build_admit_prog(bucket, nb))
-
-    def _build_admit_prog(self, bucket: int, nb: int):
-        cfg, max_len, eos = self.cfg, self.max_len, self.eos
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def admit(params, cache, prompts, true_lens, slot_ids,
-                  pos, nxt, rem, rems_new):
-            # [nb, bucket] padded prompts; logits at each row's true last
-            # token; pad rows beyond true_len are dead weight that decode
-            # overwrites as generation proceeds
-            c = llama.init_kv_cache(cfg, nb, max_len)
-            logits, c = llama.forward_with_cache(
-                params, prompts, cfg, c, jnp.int32(0),
-                logit_pos=true_lens - 1)
-            tok0 = _greedy(logits)
-            k = cache["k"].at[:, slot_ids].set(c["k"])
-            v = cache["v"].at[:, slot_ids].set(c["v"])
-            pos = pos.at[slot_ids].set(true_lens)
-            nxt = nxt.at[slot_ids].set(tok0)
-            if eos is not None:
-                # EOS at prefill freezes the slot IN-PROGRAM — the host
-                # only learns at the next sync point (r5: host reads are
-                # deferred/batched), so the device must not decode on
-                rems_new = jnp.where(tok0 == eos, 0, rems_new)
-            rem = rem.at[slot_ids].set(rems_new)
-            return {"k": k, "v": v}, pos, nxt, rem, tok0
-
-        return admit
-
-    @property
-    def _decode_prog(self):
-        return self._memo_prog(PROGRAM_SPACE.key("decode", chunk=self.chunk),
-                               self._build_decode_prog)
-
-    def _build_decode_prog(self):
-        cfg, K, eos = self.cfg, self.chunk, self.eos
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def decode_chunk(params, cache, pos, nxt, rem):
-            def body(carry, _):
-                cache, pos, nxt, rem = carry
-                live = rem > 0
-                logits, cache = llama.forward_with_cache(
-                    params, nxt[:, None], cfg, cache, pos)
-                tok = _greedy(logits)
-                tok = jnp.where(live, tok, nxt)  # frozen slots idle
-                pos = pos + live.astype(jnp.int32)
-                rem = rem - live.astype(jnp.int32)
-                if eos is not None:
-                    rem = jnp.where(live & (tok == eos), 0, rem)
-                return (cache, pos, tok, rem), tok
-
-            (cache, pos, nxt, rem), toks = jax.lax.scan(
-                body, (cache, pos, nxt, rem), None, length=K)
-            return cache, pos, nxt, rem, toks  # toks: [K, slots]
-
-        return decode_chunk
-
     # --- scheduling -------------------------------------------------------
     def _bucket_for(self, n: int) -> int:
         for b in self.buckets:
@@ -928,104 +805,10 @@ class ServingEngine:
                 return b
         raise ValueError(f"no long bucket for suffix length {n}")
 
-    def _fill_slots(self, admits: List[tuple]) -> None:
-        """Admission wave: take as many queued requests as there are free
-        slots (longest-remaining-first), group them by prompt bucket, and
-        run ONE fused prefill+insert program per sub-group. Hysteresis:
-        between windows, refill only once a few slots are free (the
-        threshold shrinks with the queue so the tail always drains) —
-        wide waves amortise per-program dispatch latency.
-
-        r5: tok0 is NOT fetched here — the device future and its
-        (request, slot) mapping append to ``admits`` and the host reads
-        them in ONE batched ``jax.device_get`` at the next sync point
-        (per-wave blocking fetches were the dominant serving cost on a
-        ~30 ms-round-trip dispatch path). Requests with
-        ``max_new_tokens == 1`` are retired host-side immediately (a
-        host-known condition); their token is delivered at the sync."""
-        free = [s for s in range(self.slots) if self._active[s] is None]
-        if not free or not self._queue:
-            return
-        threshold = min(4, self.slots, len(self._queue))
-        if len(free) < threshold and len(free) < self.slots:
-            return
-        self._queue.sort(key=lambda r: -r.max_new_tokens)
-        picked = self._queue[:len(free)]
-        del self._queue[:len(free)]
-        by_bucket: Dict[int, List[Request]] = {}
-        for r in picked:
-            by_bucket.setdefault(self._bucket_for(len(r.prompt)), []).append(r)
-        it = iter(free)
-        for bucket, group in sorted(by_bucket.items()):
-            i = 0
-            while i < len(group):
-                nb = next(w for w in _WAVE_WIDTHS if w <= len(group) - i)
-                sub = group[i:i + nb]
-                i += nb
-                slots = [next(it) for _ in sub]
-                prompts = np.zeros((nb, bucket), np.int32)
-                lens = np.zeros((nb,), np.int32)
-                for j, r in enumerate(sub):
-                    prompts[j, :len(r.prompt)] = r.prompt
-                    lens[j] = len(r.prompt)
-                rems = np.array([r.max_new_tokens - 1 for r in sub],
-                                np.int32)
-                self._cache, self._pos, self._nxt, self._rem, tok0 = \
-                    self._admit_prog(bucket, nb)(
-                        self.params, self._cache, jnp.asarray(prompts),
-                        jnp.asarray(lens), jnp.asarray(slots, jnp.int32),
-                        self._pos, self._nxt, self._rem, jnp.asarray(rems))
-                admits.append((tok0, list(zip(sub, slots))))
-                for r, s in zip(sub, slots):
-                    if r.max_new_tokens <= 1:
-                        # done at prefill (host-known): free the slot now;
-                        # the device-side rem is already 0
-                        self._rem_host[s] = 0
-                        self._active[s] = None
-                    else:
-                        self._active[s] = r
-                        self._rem_host[s] = r.max_new_tokens - 1
-        # recurse: host-known prefill retirements free slots for the rest
-        if self._queue and any(a is None for a in self._active):
-            self._fill_slots(admits)
-
-    def warmup(self) -> None:
-        """Compile the WINDOWED path's program shapes (fused admit per
-        bucket x wave width, the decode chunk) so incremental serving
-        excludes compiles. The fused drain (``run()``'s default) is
-        specialised to the padded workload shape (n_pad, p_max, g_max)
-        and compiles on the first ``run()`` that sees that shape — warm
-        it by running a representative workload once (the serving
-        benchmark does exactly this)."""
-        if self.paged or self.mesh is not None:
-            # paged and mp-sharded engines serve through segments only;
-            # each (n_pad, s_max, steps) shape compiles on its first
-            # run_segment and the scheduler's warm pass covers it
-            return
-        for b in self.buckets:
-            for nb in _WAVE_WIDTHS:
-                if nb > self.slots:
-                    continue
-                out = self._admit_prog(b, nb)(
-                    self.params, self._cache, jnp.zeros((nb, b), jnp.int32),
-                    jnp.ones((nb,), jnp.int32),
-                    jnp.arange(nb, dtype=jnp.int32),
-                    self._pos, self._nxt, self._rem,
-                    jnp.zeros((nb,), jnp.int32))
-                self._cache = out[0]
-        out = self._decode_prog(self.params, self._cache, self._pos,
-                                self._nxt, self._rem)
-        self._cache = out[0]
-        self._pos = jnp.zeros((self.slots,), jnp.int32)
-        self._nxt = jnp.zeros((self.slots,), jnp.int32)
-        self._rem = jnp.zeros((self.slots,), jnp.int32)
-
     # --- program-space coverage + AOT warmup (r20: ISSUE 15) --------------
     def default_envelope(self, seg_steps: Sequence[int] = (),
                          prefix_block: Optional[int] = None,
-                         resume: bool = True,
-                         offline_batch: Optional[int] = None
-                         ) -> WorkloadEnvelope:
+                         resume: bool = True) -> WorkloadEnvelope:
         """The widest envelope this engine's INTAKE admits: prompts up
         to the largest bucket, generations filling the cache, segments
         at ``run()``'s drain budget unless the caller declares its
@@ -1039,8 +822,7 @@ class ServingEngine:
             max_prompt=max_prompt,
             max_new_tokens=max(1, self.max_len + 1 - max_prompt),
             seg_steps=tuple(seg_steps) or (4 * self.chunk,),
-            resume=resume, prefix_block=prefix_block,
-            offline_batch=offline_batch)
+            resume=resume, prefix_block=prefix_block)
 
     def program_space(self, envelope: Optional[WorkloadEnvelope] = None
                       ) -> Dict[str, frozenset]:
@@ -1109,7 +891,7 @@ class ServingEngine:
         self._rem = self._rem.at[jnp.asarray(0, jnp.int32)].set(0)
         tier = getattr(prefix_cache, "host_tier", None) \
             if prefix_cache is not None else None
-        if tier is not None and self.paged:
+        if tier is not None:
             _, hi = env.admit_lengths(self.buckets)
             if self.seq_parallel:
                 # long-context harvests can park whole long prompts in
@@ -1118,10 +900,8 @@ class ServingEngine:
                 hi = max(hi, min(env.max_prompt + env.max_new_tokens - 1,
                                  self.max_len))
             tier.prewarm_transfers(hi // self.page_size)
-        # windowed-path dummy admits wrote device slot state (pos/nxt);
-        # segments and drains ran empty (n_real=0). Either way the
-        # engine returns to idle zeros — it was asserted idle at entry,
-        # so nothing is lost (the same reset warmup() performs)
+        # the segments ran empty (n_real=0); the engine returns to idle
+        # zeros — it was asserted idle at entry, so nothing is lost
         self._pos = self._slot_vec()
         self._nxt = self._slot_vec()
         self._rem = self._slot_vec()
@@ -1160,42 +940,7 @@ class ServingEngine:
             return prog(*args)
 
         with _mesh_scope(self.mesh):
-            if family == "admit":
-                bucket, nb = key
-                out = run(
-                    self._admit_prog(bucket, nb), self.params, self._cache,
-                    jnp.zeros((nb, bucket), i32), jnp.ones((nb,), i32),
-                    jnp.arange(nb, dtype=i32), self._pos, self._nxt,
-                    self._rem, jnp.zeros((nb,), i32))
-                self._cache = out[0]
-            elif family == "decode":
-                out = run(self._decode_prog, self.params, self._cache,
-                          self._pos, self._nxt, self._rem)
-                (self._cache, self._pos, self._nxt, self._rem) = out[:4]
-            elif family == "drain":
-                _, n_pad, p_max, g_max = key
-                out = run(
-                    self._drain_prog(n_pad, p_max, g_max), self.params,
-                    self._cache,
-                    jnp.zeros((n_pad, p_max), i32),
-                    jnp.ones((n_pad,), i32), jnp.zeros((n_pad,), i32),
-                    i32(0))
-                self._cache = out[0]
-            elif family == "seg":
-                _, n_pad, s_max, pre_max, steps = key
-                cfg = self.cfg
-                L, Hkv, D = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-                kdt = self._cache["k"].dtype
-                out = run(
-                    self._segment_prog(n_pad, s_max, pre_max, steps),
-                    self.params, self._cache, self._pos, self._nxt,
-                    self._rem, jnp.zeros((n_pad, s_max), i32),
-                    jnp.ones((n_pad,), i32), jnp.zeros((n_pad,), i32),
-                    jnp.zeros((n_pad, L, pre_max, Hkv, D), kdt),
-                    jnp.zeros((n_pad, L, pre_max, Hkv, D), kdt),
-                    jnp.zeros((n_pad,), i32), i32(0))
-                (self._cache, self._pos, self._nxt, self._rem) = out[:4]
-            elif family in {"pseg", "qseg", "cseg", "qpseg"}:
+            if family in {"pseg", "qseg", "cseg", "qpseg"}:
                 # qpseg keys carry a trailing dtype code; steps sits at
                 # a fixed index there, key[-1] everywhere else
                 n_pad, s_max = key[1], key[2]
@@ -1251,127 +996,6 @@ class ServingEngine:
             else:
                 raise KeyError(f"unknown program family {family!r}")
 
-    # --- fused whole-drain program (r5) -----------------------------------
-    def _drain_prog(self, n_pad: int, p_max: int, g_max: int):
-        """The WHOLE queue drain as ONE compiled program (the decode
-        analog of ``llama.generate``'s single-scan design, prescribed by
-        r4's verdict): slot state lives on device and a ``while_loop``
-        alternates two branches —
-
-          admit:  a free slot exists and requests remain -> prefill the
-                  next request (bucket-padded [1, p_max]) inside a
-                  ``lax.cond`` branch and scatter its KV/pos/token into
-                  the slot arrays;
-          decode: one ragged tick for all slots (frozen slots idle).
-
-        Admission costs no host round trip, so refill is GREEDY (every
-        free slot refills the moment work is queued — better packing
-        than the windowed path's hysteresis). Host round trips for the
-        whole drain: ONE dispatch + ONE result fetch, making the engine
-        dispatch-latency-robust by construction. Memoised per
-        (n_pad, p_max, g_max) padded workload shape."""
-        key = PROGRAM_SPACE.key("drain", n_pad=n_pad, p_max=p_max,
-                                g_max=g_max)
-        return self._memo_prog(key, lambda: self._build_drain_prog(
-            n_pad, p_max, g_max))
-
-    def _build_drain_prog(self, n_pad: int, p_max: int, g_max: int):
-        cfg, max_len, slots, eos = (self.cfg, self.max_len, self.slots,
-                                    self.eos)
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def drain(params, cache, prompts, lens, gens, n_real):
-            i32 = jnp.int32
-            st = dict(
-                cache=cache,
-                pos=jnp.zeros((slots,), i32),
-                nxt=jnp.zeros((slots,), i32),
-                rem=jnp.zeros((slots,), i32),
-                rid=jnp.full((slots,), n_pad, i32),   # n_pad = trash row
-                cnt=jnp.zeros((slots,), i32),
-                out=jnp.zeros((n_pad + 1, g_max), i32),
-                fin=jnp.zeros((n_pad + 1,), i32),     # finish step / req
-                qidx=i32(0), step=i32(0), ndec=i32(0),
-            )
-
-            def cond(st):
-                return jnp.any(st["rem"] > 0) | (st["qidx"] < n_real)
-
-            @llama.scoped("segment.admit")
-            def admit(st):
-                s = jnp.argmin(st["rem"])  # a rem==0 slot (min is 0)
-                q = st["qidx"]
-                # every prefill pads to the batch-global p_max (no per-
-                # bucket lax.switch): prefill here is HBM-bound — it
-                # streams the whole weight set regardless of width — so a
-                # 32-token prompt padded to 256 costs ~the same wall time,
-                # and one branch keeps the program small
-                prow = jax.lax.dynamic_slice(prompts, (q, 0), (1, p_max))
-                ln = lens[q]
-                c1 = llama.init_kv_cache(cfg, 1, p_max)
-                logits, c1 = llama.forward_with_cache(
-                    params, prow, cfg, c1, jnp.int32(0), logit_pos=ln - 1)
-                t0 = _greedy(logits).reshape(())
-                k = jax.lax.dynamic_update_slice(
-                    st["cache"]["k"], c1["k"], (0, s, 0, 0, 0))
-                v = jax.lax.dynamic_update_slice(
-                    st["cache"]["v"], c1["v"], (0, s, 0, 0, 0))
-                rem_new = gens[q] - 1
-                if eos is not None:
-                    rem_new = jnp.where(t0 == eos, 0, rem_new)
-                fin = jnp.where(rem_new == 0,
-                                st["fin"].at[q].set(st["step"]), st["fin"])
-                return dict(
-                    cache={"k": k, "v": v},
-                    pos=st["pos"].at[s].set(ln),
-                    nxt=st["nxt"].at[s].set(t0),
-                    rem=st["rem"].at[s].set(rem_new),
-                    rid=st["rid"].at[s].set(q),
-                    cnt=st["cnt"].at[s].set(1),
-                    out=st["out"].at[q, 0].set(t0),
-                    fin=fin,
-                    qidx=q + 1, step=st["step"], ndec=st["ndec"],
-                )
-
-            @llama.scoped("segment.decode")
-            def decode(st):
-                live = st["rem"] > 0
-                logits, cache = llama.forward_with_cache(
-                    params, st["nxt"][:, None], cfg, st["cache"], st["pos"])
-                tok = _greedy(logits)
-                tok = jnp.where(live, tok, st["nxt"])
-                rows = jnp.where(live, st["rid"], n_pad)
-                cols = jnp.minimum(st["cnt"], g_max - 1)
-                out = st["out"].at[rows, cols].set(tok)
-                rem = st["rem"] - live.astype(jnp.int32)
-                if eos is not None:
-                    rem = jnp.where(live & (tok == eos), 0, rem)
-                finished = live & (rem == 0)
-                fin = st["fin"].at[
-                    jnp.where(finished, st["rid"], n_pad)].set(st["step"])
-                return dict(
-                    cache=cache,
-                    pos=st["pos"] + live.astype(jnp.int32),
-                    nxt=tok,
-                    rem=rem,
-                    rid=st["rid"], cnt=st["cnt"] + live.astype(jnp.int32),
-                    out=out, fin=fin,
-                    qidx=st["qidx"], step=st["step"],
-                    ndec=st["ndec"] + 1,
-                )
-
-            def body(st):
-                can_admit = (st["qidx"] < n_real) & jnp.any(st["rem"] == 0)
-                st = jax.lax.cond(can_admit, admit, decode, st)
-                st["step"] = st["step"] + 1
-                return st
-
-            st = jax.lax.while_loop(cond, body, st)
-            return (st["cache"], st["out"], st["fin"], st["step"],
-                    st["ndec"])
-
-        return drain
-
     @staticmethod
     def _pow2(n: int, lo: int = 1) -> int:
         p = lo
@@ -1379,193 +1003,7 @@ class ServingEngine:
             p *= 2
         return p
 
-    def _run_fused(self) -> Dict[int, List[int]]:
-        self._queue.sort(key=lambda r: -r.max_new_tokens)
-        picked, self._queue = self._queue, []
-        n = len(picked)
-        n_pad = self._pow2(n)
-        p_max = self._bucket_for(max(len(r.prompt) for r in picked))
-        g_max = self._pow2(max(r.max_new_tokens for r in picked), lo=16)
-        prompts = np.zeros((n_pad, p_max), np.int32)
-        lens = np.ones((n_pad,), np.int32)   # pad rows: 1-token dummy
-        gens = np.zeros((n_pad,), np.int32)  # gen 0 -> never admitted
-        for j, r in enumerate(picked):
-            prompts[j, :len(r.prompt)] = r.prompt
-            lens[j] = len(r.prompt)
-            gens[j] = r.max_new_tokens
-        t0 = time.perf_counter()
-        self._cache, out, fin, steps, ndec = self._drain_prog(
-            n_pad, p_max, g_max)(
-                self.params, self._cache, jnp.asarray(prompts),
-                jnp.asarray(lens), jnp.asarray(gens), jnp.int32(n))
-        out, fin, steps, ndec = jax.device_get([out, fin, steps, ndec])
-        wall = time.perf_counter() - t0
-        if n and self.cold_start_s is None:
-            self._note_cold_start()   # offline drain path's first tokens
-        self.last_run_ticks = int(ndec)
-        self.last_run_chunks = -(-int(ndec) // self.chunk)
-        per_step = wall / max(int(steps), 1)
-        for j, r in enumerate(picked):
-            toks = [int(t) for t in out[j, :r.max_new_tokens]]
-            if self.eos is not None and self.eos in toks:
-                toks = toks[:toks.index(self.eos) + 1]
-            r.tokens = toks
-            # latency estimate: request finished at loop step fin[j] of
-            # steps total (single-program drain has no per-request host
-            # clock; the step clock scales by measured wall time).
-            # Uniform step weighting is deliberate: at this model scale
-            # BOTH branch kinds are HBM-bound and stream the full weight
-            # set once — an admit (prefill [1, p_max]) and a decode tick
-            # ([slots, 1]) cost within ~2x of each other, not the ~p_max x
-            # a FLOP-count model would suggest.
-            r.finish_time = r.submit_time + (int(fin[j]) + 1) * per_step
-            self._finished.append(r)
-        done = {r.rid: r.tokens for r in self._finished}
-        self.last_latencies = {r.rid: r.finish_time - r.submit_time
-                               for r in self._finished if r.finish_time}
-        self._finished = []
-        return done
-
-    # --- re-entrant fused segments (r7: online continuous batching) -------
-    def _segment_prog(self, n_pad: int, s_max: int, pre_max: int,
-                      max_steps: int):
-        """The fused drain, RE-ENTRANT: one compiled program that starts
-        from the engine's *current* slot state (cache/pos/nxt/rem as
-        inputs, not zeros), admits up to ``n_pad`` queued requests into
-        slots as they free, decodes for at most ``max_steps`` loop
-        iterations, and returns the slot state plus an event log the host
-        replays. This is ``_drain_prog``'s while_loop with three changes:
-
-        * slot state is an argument — a segment composes with previous
-          segments (and with the windowed path) instead of assuming an
-          empty engine, so newly arrived requests join slots freed by
-          EOS/retirement mid-flight;
-        * the loop is step-bounded — the host regains control every
-          ``max_steps`` ticks to ingest arrivals and stamp real
-          (measured) per-request times at the sync;
-        * outputs are an event log indexed by (local step, slot)
-          (``out``) plus per-step admit records (``aq``/``aslot``) —
-          NOT per-request rows — so requests admitted in *earlier*
-          segments keep streaming into the same log and the host replay
-          attributes tokens by tracking slot occupancy.
-
-        Shared-prefix admission (``pre_max > 0``): each queue row carries
-        ``pre_len`` already-prefilled KV rows (from the prefix cache);
-        the admit branch writes those rows into a temp cache and runs
-        prefill ONLY on the [1, s_max] suffix at positions
-        pre_len..pre_len+s_max-1 — the quadratic attention and the
-        per-token matmul work of the shared prefix are not re-done.
-        Memoised per (n_pad, s_max, pre_max, max_steps) shape."""
-        key = PROGRAM_SPACE.key("seg", n_pad=n_pad, s_max=s_max,
-                                pre_max=pre_max, steps=max_steps)
-        if pre_max + s_max > self.max_len:
-            raise ValueError(
-                f"segment admit window {pre_max}+{s_max} exceeds cache "
-                f"max_len {self.max_len}")
-        return self._memo_prog(key, lambda: self._build_segment_prog(
-            n_pad, s_max, pre_max, max_steps))
-
-    def _build_segment_prog(self, n_pad: int, s_max: int, pre_max: int,
-                            max_steps: int):
-        cfg, slots, eos = self.cfg, self.slots, self.eos
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def segment(params, cache, pos, nxt, rem, prompts, lens, gens,
-                    pre_k, pre_v, pre_lens, n_real):
-            i32 = jnp.int32
-            st = dict(
-                cache=cache, pos=pos, nxt=nxt, rem=rem,
-                out=jnp.zeros((max_steps, slots), i32),
-                aq=jnp.full((max_steps,), n_pad, i32),    # n_pad = decode
-                aslot=jnp.zeros((max_steps,), i32),
-                qidx=i32(0), step=i32(0),
-            )
-
-            def cond(st):
-                work = jnp.any(st["rem"] > 0) | (st["qidx"] < n_real)
-                return work & (st["step"] < max_steps)
-
-            @llama.scoped("segment.admit")
-            def admit(st):
-                s = jnp.argmin(st["rem"])          # a rem==0 slot
-                q = st["qidx"]
-                prow = jax.lax.dynamic_slice(prompts, (q, 0), (1, s_max))
-                ln = lens[q]
-                pln = pre_lens[q]
-                c1 = llama.init_kv_cache(cfg, 1, pre_max + s_max)
-                if pre_max:
-                    # reused prefix rows land at absolute rows [0, pre_max)
-                    # of the temp cache; rows beyond this request's true
-                    # pre_len are zeros and stay masked (suffix tokens
-                    # write at absolute positions pre_len+t, and decode
-                    # attention never looks past pos)
-                    pk = jax.lax.dynamic_slice(
-                        pre_k, (q, 0, 0, 0, 0),
-                        (1,) + pre_k.shape[1:]).transpose(1, 0, 2, 3, 4)
-                    pv = jax.lax.dynamic_slice(
-                        pre_v, (q, 0, 0, 0, 0),
-                        (1,) + pre_v.shape[1:]).transpose(1, 0, 2, 3, 4)
-                    c1 = {
-                        "k": jax.lax.dynamic_update_slice(
-                            c1["k"], pk.astype(c1["k"].dtype),
-                            (0, 0, 0, 0, 0)),
-                        "v": jax.lax.dynamic_update_slice(
-                            c1["v"], pv.astype(c1["v"].dtype),
-                            (0, 0, 0, 0, 0)),
-                    }
-                logits, c1 = llama.forward_with_cache(
-                    params, prow, cfg, c1, pln, logit_pos=ln - 1)
-                t0 = _greedy(logits).reshape(())
-                k = jax.lax.dynamic_update_slice(
-                    st["cache"]["k"], c1["k"], (0, s, 0, 0, 0))
-                v = jax.lax.dynamic_update_slice(
-                    st["cache"]["v"], c1["v"], (0, s, 0, 0, 0))
-                rem_new = gens[q] - 1
-                if eos is not None:
-                    rem_new = jnp.where(t0 == eos, 0, rem_new)
-                return dict(
-                    cache={"k": k, "v": v},
-                    pos=st["pos"].at[s].set(pln + ln),
-                    nxt=st["nxt"].at[s].set(t0),
-                    rem=st["rem"].at[s].set(rem_new),
-                    out=st["out"].at[st["step"], s].set(t0),
-                    aq=st["aq"].at[st["step"]].set(q),
-                    aslot=st["aslot"].at[st["step"]].set(s),
-                    qidx=q + 1, step=st["step"],
-                )
-
-            @llama.scoped("segment.decode")
-            def decode(st):
-                live = st["rem"] > 0
-                logits, cache = llama.forward_with_cache(
-                    params, st["nxt"][:, None], cfg, st["cache"], st["pos"])
-                tok = _greedy(logits)
-                tok = jnp.where(live, tok, st["nxt"])
-                rem = st["rem"] - live.astype(jnp.int32)
-                if eos is not None:
-                    rem = jnp.where(live & (tok == eos), 0, rem)
-                return dict(
-                    cache=cache,
-                    pos=st["pos"] + live.astype(jnp.int32),
-                    nxt=tok, rem=rem,
-                    out=st["out"].at[st["step"]].set(tok),
-                    aq=st["aq"], aslot=st["aslot"],
-                    qidx=st["qidx"], step=st["step"],
-                )
-
-            def body(st):
-                can_admit = (st["qidx"] < n_real) & jnp.any(st["rem"] == 0)
-                st = jax.lax.cond(can_admit, admit, decode, st)
-                st["step"] = st["step"] + 1
-                return st
-
-            st = jax.lax.while_loop(cond, body, st)
-            return (st["cache"], st["pos"], st["nxt"], st["rem"],
-                    st["out"], st["aq"], st["aslot"], st["step"],
-                    st["qidx"])
-
-        return segment
-
+    # --- segments: host side (r7: online continuous batching) -------------
     def _phase(self, phase: str, seg: Optional[int] = None):
         """One phase of a segment's host work as a span (PR 25):
         ``serving.segment.<phase>`` with the segment's index, timed into
@@ -1579,18 +1017,16 @@ class ServingEngine:
                         chunk_marker: Optional[int] = None,
                         acc=None, spec_stats: Optional[dict] = None,
                         dig=None, on_tick=None):
-        """Host replay of a segment's event log — ONE contract for the
-        contiguous and paged engines: walk the log chronologically,
-        tracking slot occupancy (admits rebind a slot; decode ticks
-        append one token to every slot the HOST knows is live via its
-        rem mirror, so frozen-slot repeats and pad rows are dropped
-        exactly as the windowed _sync does). ``on_admit(q, slot)`` /
-        ``on_retire(req, slot)`` are the paged engine's page-table
-        bookkeeping hooks, called in event order so a slot freed and
-        re-admitted mid-segment releases the old occupant's pages
-        before the new page list installs; ``on_tick(live)`` sees each
-        decode tick's live (slot, request) pairs before their tokens
-        land. ``chunk_marker`` (chunked
+        """Host replay of a segment's event log: walk the log
+        chronologically, tracking slot occupancy (admits rebind a slot;
+        decode ticks append one token to every slot the HOST knows is
+        live via its rem mirror, so frozen-slot repeats and pad rows
+        are dropped). ``on_admit(q, slot)`` / ``on_retire(req, slot)``
+        are the page-table bookkeeping hooks, called in event order so a
+        slot freed and re-admitted mid-segment releases the old
+        occupant's pages before the new page list installs;
+        ``on_tick(live)`` sees each decode tick's live (slot, request)
+        pairs before their tokens land. ``chunk_marker`` (chunked
         prefill): aq values >= it mark NON-FINAL prefill-chunk steps —
         no decode ran and no token surfaced there, so the replay skips
         the step.
@@ -1762,7 +1198,7 @@ class ServingEngine:
                                      if self.aot_warmup_s is not None
                                      else None),
                        first_token_s=round(self.first_token_s, 4),
-                       paged=self.paged, slots=self.slots)
+                       slots=self.slots)
 
     def _segment_telemetry(self, steps, admitted, finished, eos_stops,
                            new_tokens, requeued) -> None:
@@ -1870,8 +1306,7 @@ class ServingEngine:
         self.last_run_chunks = 0
         self.last_latencies = {}
         self.page_backpressure_events = 0
-        if self.paged:
-            self.pager.reset()
+        self.pager.reset()
 
     # --- preemption / teardown (r13: the SLO control plane's hooks) -------
     def can_preempt(self, slot: int) -> bool:
@@ -1888,19 +1323,17 @@ class ServingEngine:
         """Evict ``slot``'s request between segments and return it for
         requeueing — the priority-preemption primitive (ISSUE 8b). The
         device sees one tiny scatter (rem[slot] = 0: the slot freezes
-        and, paged, its writes route to the trash page) and NO sync;
-        everything else is host bookkeeping:
+        and its writes route to the trash page) and NO sync; everything
+        else is host bookkeeping:
 
-        * paged + ``prefix_cache``: the slot's page-aligned prefix
+        * with a ``prefix_cache``: the slot's page-aligned prefix
           (prompt + tokens generated so far) is PARKED in the cache by
           reference before the slot's refs release — harvest-by-
           reference, zero KV row copies — so the resume admission is a
           page-ref bump plus a suffix-only prefill of the unaligned
           tail;
-        * paged without a cache: the pages free outright and resume
-          re-prefills (still token-identical — greedy);
-        * contiguous: the KV rows [0, aligned_len) are harvested into
-          the row-copy cache exactly like post-segment population.
+        * without one: the pages free outright and resume re-prefills
+          (still token-identical — greedy).
 
         The caller decides where the request re-enters the queue (the
         SLO scheduler reinserts it at the head of its class)."""
@@ -1919,22 +1352,15 @@ class ServingEngine:
         self._active[slot] = None
         r.preemptions += 1
         fp, _ = r.resume_view()
-        if self.paged:
-            r._meter_release()
-            pgr = self.pager
-            if prefix_cache is not None:
-                plen_b = prefix_cache.round_down(len(fp))
-                if plen_b:
-                    prefix_cache.insert(
-                        fp[:plen_b],
-                        pgr.slot_pages[slot][:plen_b // self.page_size])
-            pgr.free_slot(slot)
-        elif prefix_cache is not None:
+        r._meter_release()
+        pgr = self.pager
+        if prefix_cache is not None:
             plen_b = prefix_cache.round_down(len(fp))
             if plen_b:
-                prefix_cache.insert(fp[:plen_b],
-                                    self._cache["k"][:, slot, :plen_b],
-                                    self._cache["v"][:, slot, :plen_b])
+                prefix_cache.insert(
+                    fp[:plen_b],
+                    pgr.slot_pages[slot][:plen_b // self.page_size])
+        pgr.free_slot(slot)
         _metrics.counter("serving.preemptions").inc()
         _flight.record("preempt", rid=r.rid, slot=slot,
                        tokens_done=len(r.tokens),
@@ -1955,9 +1381,8 @@ class ServingEngine:
         p, self._pending_seg = self._pending_seg, None
         released_rids = set()
         if p is not None:
-            if p.paged:
-                for pages in p.req_pages:
-                    self.pager.release_pages(pages)
+            for pages in p.req_pages:
+                self.pager.release_pages(pages)
             for r in p.picked:
                 r.admit_time = 0.0
                 r._meter_release()
@@ -1984,8 +1409,7 @@ class ServingEngine:
         self._nxt = self._slot_vec()
         self._rem = self._slot_vec()
         self._init_spec_state()
-        if self.paged:
-            self.pager.reset()
+        self.pager.reset()
         return orphans
 
     def run_segment(self, max_steps: int, prefix_cache=None,
@@ -2013,8 +1437,8 @@ class ServingEngine:
                          n_pad: Optional[int] = None,
                          now: Optional[float] = None) -> _PendingSegment:
         """Launch one fused segment WITHOUT fetching its event log: picks
-        requests, (for paged engines) reserves page lists, dispatches the
-        program, and records the device futures in a ``_PendingSegment``.
+        requests, reserves their page lists, dispatches the program,
+        and records the device futures in a ``_PendingSegment``.
         At most one segment may be in flight per engine — the slot-state
         arrays the next dispatch would consume are this segment's donated
         outputs, and the host queue/slot mirrors only advance at the
@@ -2030,12 +1454,8 @@ class ServingEngine:
             # with a journal attached, fed back during replay
             now = _journal.now()
         n_pad = n_pad or self._pow2(self.slots)
-        if self.paged:
-            pending = self._dispatch_segment_paged(max_steps, prefix_cache,
-                                                   n_pad, now)
-        else:
-            pending = self._dispatch_segment_dense(max_steps, prefix_cache,
-                                                   n_pad, now)
+        pending = self._dispatch_segment_paged(max_steps, prefix_cache,
+                                               n_pad, now)
         pending.seg = self.seg_index
         self.seg_index += 1
         self._pending_seg = pending
@@ -2051,183 +1471,47 @@ class ServingEngine:
             raise RuntimeError("finish_segment without a matching "
                                "dispatched segment")
         self._pending_seg = None
-        if p.paged:
-            return self._finish_segment_paged(p)
-        return self._finish_segment_dense(p)
+        return self._finish_segment_paged(p)
 
-    def _dispatch_segment_dense(self, max_steps: int, prefix_cache,
-                                n_pad: int, now: float) -> _PendingSegment:
-        with self._phase("pick"):
-            # pick up to n_pad regardless of CURRENT free slots: in-program
-            # admission refills slots the moment they retire mid-segment, so
-            # over-picking is exactly what keeps the batch full (requests the
-            # step budget couldn't admit are re-queued below)
-            picked = self._queue[:n_pad]
-            del self._queue[:len(picked)]
-            n = len(picked)
-
-            # admission view (r13): a fresh request prefills its prompt, a
-            # preempted/failed-over one resumes from prompt + generated
-            # tokens and owes only the tail
-            fulls = [r.resume_view() for r in picked]
-
-            # prefix-cache lookup (admission-time detection): per request the
-            # longest cached block-aligned prefix; suffix = the rest
-            pre_lens = np.zeros((n_pad,), np.int32)
-            pre_entries = [None] * n
-            if prefix_cache is not None:
-                for j, r in enumerate(picked):
-                    fp = fulls[j][0]
-                    ent = prefix_cache.match(fp)
-                    if ent is not None and ent.length < len(fp):
-                        pre_entries[j] = ent
-                        pre_lens[j] = ent.length
-                        r.prefix_hit_len = ent.length
-            pre_max = int(max(pre_lens)) if n else 0
-            if pre_max:
-                pre_max = prefix_cache.round_up(pre_max)
-
-            # prompt width: WITHOUT prefix reuse, pin to the largest bucket —
-            # prefill pads there anyway on the drain path (HBM-bound: it
-            # streams the full weight set regardless of width) and ONE
-            # program shape means no mid-serve XLA compile when arrival
-            # jitter regroups admissions (measured: a stray 64-wide segment
-            # compiled 2.5s into an online run, dwarfing the work). WITH
-            # prefix reuse the suffix width IS the saving, so bucket it —
-            # shared-prefix workloads have uniform tails, so the shape set
-            # stays small and the warm pass covers it.
-            if prefix_cache is None or pre_max == 0:
-                s_max = self.buckets[-1]
-            else:
-                suf_max = max((len(fulls[j][0]) - int(pre_lens[j])
-                               for j in range(n)), default=1)
-                s_max = self._bucket_for(suf_max)
-            if pre_max and pre_max + s_max > self.max_len:
-                # prefix + suffix window must fit the cache; drop the hits
-                pre_max = 0
-                pre_lens[:] = 0
-                pre_entries = [None] * n
-                for r in picked:
-                    r.prefix_hit_len = 0
-                s_max = self.buckets[-1]
-
-        with self._phase("inputs"):
-            prompts = np.zeros((n_pad, s_max), np.int32)
-            lens = np.ones((n_pad,), np.int32)
-            gens = np.zeros((n_pad,), np.int32)   # gen 0 -> never admitted
-            for j, r in enumerate(picked):
-                fp, remaining = fulls[j]
-                suf = fp[int(pre_lens[j]):]
-                prompts[j, :len(suf)] = suf
-                lens[j] = len(suf)
-                gens[j] = remaining
-                r.admit_time = now
-            # staged prefix rows; a zero-width block when nothing was
-            # reused: the program specialises pre_max=0 and skips the
-            # prefix writes entirely
-            pshape = (n_pad, self.cfg.num_layers, pre_max,
-                      self.cfg.num_kv_heads, self.cfg.head_dim)
-            pk = jnp.zeros(pshape, self._cache["k"].dtype)
-            pv = jnp.zeros(pshape, self._cache["v"].dtype)
-            for j, ent in enumerate(pre_entries):
-                if ent is not None:
-                    m = ent.length
-                    pk = pk.at[j, :, :m].set(ent.k[:, :m])
-                    pv = pv.at[j, :, :m].set(ent.v[:, :m])
-
-            # the host -> device copies (one small program each)
-            dev_in = (jnp.asarray(prompts), jnp.asarray(lens),
-                      jnp.asarray(gens), pk, pv, jnp.asarray(pre_lens),
-                      jnp.int32(n))
-        with self._phase("launch"), _mesh_scope(self.mesh):
-            out = self._segment_prog(n_pad, s_max, pre_max, max_steps)(
-                self.params, self._cache, self._pos, self._nxt, self._rem,
-                *dev_in)
-        self._cache, self._pos, self._nxt, self._rem = out[:4]
-        return _PendingSegment(paged=False, picked=picked, n=n, now=now,
-                               prefix_cache=prefix_cache, dev=out[4:],
-                               pre_lens=pre_lens,
-                               full_prompts=[f for f, _ in fulls])
-
-    def _finish_segment_dense(self, p: _PendingSegment) -> dict:
-        picked, n, prefix_cache, pre_lens = (p.picked, p.n, p.prefix_cache,
-                                             p.pre_lens)
-        # THE per-segment sync: the one place the online serve loop is
-        # allowed to block on the device (audited — see analysis.syncs;
-        # the budget pins it to exactly one per segment)
-        with self._phase("fetch", p.seg), \
-                allowed_sync("serving.segment_event_fetch"):
-            toks, aq, aslot, steps, qadm = jax.device_get(p.dev)
-        steps, qadm = int(steps), int(qadm)
-        self.last_run_ticks += steps
-        self.last_run_chunks += 1
-
-        with self._phase("replay", p.seg):
-            (admitted, first_tokens, first_steps, finished, new_tokens,
-             eos_stops) = self._replay_segment(picked, toks, aq, aslot,
-                                               steps, n)
-            if qadm < n:
-                # step budget ran out before every picked request found a
-                # slot: back to the queue head, FCFS order preserved
-                for r in picked[qadm:]:
-                    r.admit_time = 0.0
-                self._queue[:0] = picked[qadm:]
-
-            # prefix-cache population: insert each admitted request's full
-            # prompt KV (block-trimmed device slices of the slot cache —
-            # rows [0, plen) hold exactly the prompt's keys until the slot
-            # is reused, and insertion right after the sync precedes any
-            # donation of this cache buffer)
-            if prefix_cache is not None:
-                last_admit = {}            # slot -> its latest admit event
-                for st in range(steps):
-                    q = int(aq[st])
-                    if q < n:
-                        last_admit[int(aslot[st])] = q
-                for s, q in last_admit.items():
-                    fp = p.full_prompts[q]     # the span actually prefilled
-                    plen_b = prefix_cache.round_down(len(fp))
-                    if plen_b > int(pre_lens[q]):
-                        prefix_cache.insert(
-                            fp[:plen_b],
-                            self._cache["k"][:, s, :plen_b],
-                            self._cache["v"][:, s, :plen_b])
-
-        with self._phase("telemetry", p.seg):
-            self._segment_telemetry(steps, admitted, finished, eos_stops,
-                                    new_tokens, max(0, n - qadm))
-        return {"steps": steps, "admitted": admitted,
-                "first_tokens": first_tokens,
-                "first_token_steps": first_steps, "finished": finished,
-                "tokens": new_tokens}
-
-    # --- paged segments (r11: page-table KV, inference/paged_kv.py) -------
+    # --- segment programs (r11: page-table KV, inference/paged_kv.py) -----
     def _paged_segment_prog(self, n_pad: int, s_max: int, max_steps: int):
-        """``_segment_prog`` over the PAGED pool: same while_loop, same
-        event log, same one-dispatch/one-fetch contract — three changes:
+        """The segment program: one compiled, RE-ENTRANT drain step. It
+        starts from the engine's *current* slot state (pool, page table,
+        pos/nxt/rem as inputs, not zeros), admits up to ``n_pad`` queued
+        requests into slots as they free, decodes for at most
+        ``max_steps`` loop iterations, and returns the slot state plus
+        an event log the host replays:
 
-        * slot KV state is (pool, page_table) instead of a contiguous
-          block; both are donated and updated in place: the pool rides
-          the while_loop's carry through ``_one_of`` (never a
-          ``lax.cond``) into ``llama.forward_with_pages``, whose layer
-          loop carries it too, so the compiled program holds ONE copy of
-          each plane and moves no more of it than the rows a step
-          writes and the pages its attention reads (``aot_warmup``'s
-          ``temp_bytes`` against ``pool_bytes`` says so);
+        * slot state is an argument — a segment composes with previous
+          segments, so newly arrived requests join slots freed by
+          EOS/retirement mid-flight;
+        * the loop is step-bounded — the host regains control every
+          ``max_steps`` ticks to ingest arrivals and stamp real
+          (measured) per-request times at the sync;
+        * outputs are an event log indexed by (local step, slot)
+          (``out``) plus per-step admit records (``aq``/``aslot``) —
+          NOT per-request rows — so requests admitted in *earlier*
+          segments keep streaming into the same log and the host replay
+          attributes tokens by tracking slot occupancy;
+        * pool and page table are donated and updated in place: the pool
+          rides the while_loop's carry through ``_one_of`` (never a
+          ``lax.cond``) into the model's ``forward_with_pages``, whose
+          layer loop carries it too, so the compiled program holds ONE
+          copy of each plane and moves no more of it than the rows a
+          step writes and the pages its attention reads
+          (``aot_warmup``'s ``temp_bytes`` against ``pool_bytes`` says
+          so);
         * the admit branch INSTALLS the request's host-reserved page
           list into the slot's table row and prefills the suffix
-          directly into those pages (``llama.forward_with_pages``) —
+          directly into those pages at context offset ``pre_len`` —
           shared-prefix rows are already resident in the shared pages,
-          so a hit contributes ZERO KV row copies to the program (the
-          contiguous segment's pre_k/pre_v staging tensors and their
-          dynamic_update_slice writes do not exist here);
+          so a hit contributes ZERO KV row copies to the program;
         * the decode branch passes the live mask so retired slots'
           writes route to the trash page.
 
         The memo key carries NO prefix width: prefix geometry is page
         DATA (pre_lens + tables), not shape — a shared-prefix workload
-        adds zero program shapes (one fewer recompile hazard than the
-        contiguous engine's ("seg", ..., pre_max, ...) family).
+        adds zero program shapes.
 
         r17 (ISSUE 12): with ``quality_digest`` the program family is
         ("qseg", n_pad, s_max, steps) — same loop, same single fetch,
@@ -3052,16 +2336,12 @@ class ServingEngine:
 
     def _dispatch_segment_paged(self, max_steps: int, prefix_cache,
                                 n_pad: int, now: float) -> _PendingSegment:
-        """The paged ``run_segment``: pick FCFS gated on PAGES FREE
+        """``dispatch_segment``'s body: pick FCFS gated on PAGES FREE
         (admission control is memory admission — the request's page
         span is known exactly at admission since generation length is
         fixed), reserve page lists host-side, launch ONE fused paged
         segment, host-replay the shared event log with page-table
         bookkeeping hooks. Same single audited sync per segment."""
-        if prefix_cache is not None and not hasattr(prefix_cache, "pager"):
-            raise TypeError("paged engine requires a PagedPrefixCache "
-                            "(inference/prefix_cache.py), got "
-                            f"{type(prefix_cache).__name__}")
         pgr = self.pager
         psz = self.page_size
         with self._phase("pick"):
@@ -3171,9 +2451,9 @@ class ServingEngine:
             n = len(picked)
 
             spec = bool(self.speculative or self.sampling)
-            # suffix width: same pinning rule as the contiguous segment —
-            # largest bucket when nothing was reused, the suffix bucket when
-            # prefix hits shorten the prefill. SPEC segments always pin to
+            # suffix width: the largest bucket when nothing was reused,
+            # the suffix bucket when prefix hits shorten the prefill.
+            # SPEC segments always pin to
             # the largest bucket: the ("sseg", n_pad, K, steps) key family
             # deliberately carries no width, so prefix hits stay page DATA
             # and add zero program shapes.
@@ -3270,7 +2550,7 @@ class ServingEngine:
             self._hist, self._hstart = out[5], out[6]
             if self._rng is not None:
                 self._rng = out[7]
-            return _PendingSegment(paged=True, picked=picked, n=n,
+            return _PendingSegment(picked=picked, n=n,
                                    now=now, prefix_cache=prefix_cache,
                                    dev=out[8:], pre_lens=pre_lens_l,
                                    req_pages=req_pages,
@@ -3289,7 +2569,7 @@ class ServingEngine:
                 self._rem, *dev_in)
         pgr.pool, pgr.page_table = out[0], out[1]
         self._pos, self._nxt, self._rem = out[2:5]
-        return _PendingSegment(paged=True, picked=picked, n=n, now=now,
+        return _PendingSegment(picked=picked, n=n, now=now,
                                prefix_cache=prefix_cache, dev=out[5:],
                                pre_lens=pre_lens_l, req_pages=req_pages,
                                full_prompts=fulls, s_max=s_max,
@@ -3305,9 +2585,10 @@ class ServingEngine:
         pre_lens_l, req_pages = p.pre_lens, p.req_pages
         pgr = self.pager
         psz = self.page_size
-        # THE per-segment sync (same audited label + budget as the
-        # contiguous engine: exactly one device contact per segment —
-        # the spec program's acceptance counts ride the same fetch).
+        # THE per-segment sync: the one place the serve loop is allowed
+        # to block on the device (audited — see analysis.syncs; the
+        # budget pins it to exactly one per segment — the spec program's
+        # acceptance counts ride the same fetch).
         # r19 tiered KV (ISSUE 14): queued host-tier stage gathers fold
         # into the SAME single device_get — the D2H spill staging costs
         # zero additional sync events by construction.
@@ -3493,117 +2774,15 @@ class ServingEngine:
         return done
 
     # --- the engine loop --------------------------------------------------
-    def _chunks_until_sync(self) -> int:
-        """How many decode chunks to issue before the next host sync.
-
-        Retirement times are HOST-KNOWN absent EOS (rem counts are fixed
-        at admission), so the host can run the device ahead to exactly
-        the point where the refill hysteresis would admit new work — no
-        per-chunk fetch needed. With EOS enabled, in-program freezing
-        keeps results exact but a frozen slot idles until the host
-        notices, so the run-ahead is capped to bound the waste."""
-        rems = sorted(self._rem_host[s] for s in range(self.slots)
-                      if self._active[s] is not None)
-        if not rems:
-            return 0
-        if self._queue:
-            threshold = min(4, self.slots, len(self._queue))
-            free_now = self.slots - len(rems)
-            need = min(max(threshold - free_now, 1), len(rems))
-            target = rems[need - 1]
-        else:
-            target = rems[-1]  # no queue: drain every active slot
-        n = -(-target // self.chunk)
-        if self.eos is not None:
-            n = min(n, 4)  # EOS can freeze slots the host can't see yet
-        return n
-
-    def _sync(self, admits: List[tuple], chunk_toks: List[object]) -> None:
-        """ONE batched device->host fetch for a whole window (admit tok0s
-        + every decode chunk's [K, slots] tokens), then distribute
-        chronologically: a slot admitted this window consumes its tok0
-        first, then the chunk ticks. Tokens after a slot's remaining
-        count or its first EOS are in-program frozen repeats and are
-        dropped."""
-        if not admits and not chunk_toks:
-            return
-        fetched = jax.device_get([[a[0] for a in admits], chunk_toks])
-        tok0s, toks = fetched
-        for (_, pairs), t0 in zip(admits, tok0s):
-            for (r, s), t in zip(pairs, np.asarray(t0).tolist()):
-                r.tokens.append(int(t))
-                hit_eos = self.eos is not None and int(t) == self.eos
-                if r.done or hit_eos:
-                    if self._active[s] is r:  # not already freed host-side
-                        self._active[s] = None
-                    self._rem_host[s] = 0
-                    self._retire(r)
-        if toks:
-            stream = np.concatenate([np.asarray(t) for t in toks], axis=0)
-            ticks = stream.shape[0]
-            for slot, req in enumerate(self._active):
-                if req is None:
-                    continue
-                take = min(ticks, self._rem_host[slot])
-                for k in range(take):
-                    t = int(stream[k, slot])
-                    req.tokens.append(t)
-                    self._rem_host[slot] -= 1
-                    if self.eos is not None and t == self.eos:
-                        self._rem_host[slot] = 0
-                        break
-                if self._rem_host[slot] == 0:
-                    self._retire(req)
-                    self._active[slot] = None
-
-    def run(self, fused: bool = True) -> Dict[int, List[int]]:
+    def run(self) -> Dict[int, List[int]]:
         """Drain the queue: continuous batching until every request is
         served. Returns rid -> generated tokens (greedy, incl. the first
-        token sampled at prefill).
-
-        ``fused=True`` (default): the whole drain compiles into ONE
-        program — in-program admission + slot freeze, one dispatch + one
-        fetch total (see ``_drain_prog``). The windowed host loop below
-        (``fused=False``) remains for incremental serving on top of an
-        already-partial slot state; it batches its host reads per
-        admission window: admission programs plus every decode chunk up
-        to the next host-known refill point issue without reading
-        anything back (chunks chain device-side through jax async
-        dispatch) and the window ends in ONE batched fetch."""
-        if self.paged or self.mesh is not None:
-            # paged and mp-sharded engines drain through the segment path
-            # (the online product's loop): same greedy in-program
-            # admission, one dispatch + one fetch per segment
-            self.last_run_ticks = 0
-            self.last_run_chunks = 0
-            self.last_latencies = {}
-            while self._queue or any(r is not None for r in self._active):
-                self.run_segment(4 * self.chunk)
-            return self.collect_finished()
-        if fused and self._queue and \
-                all(r is None for r in self._active):
-            return self._run_fused()
+        token sampled at prefill). The online product's loop without a
+        scheduler: segments of ``4 * chunk`` steps back to back, greedy
+        in-program admission, one dispatch + one fetch per segment."""
+        self.last_run_ticks = 0
         self.last_run_chunks = 0
-        admits: List[tuple] = []
-        self._fill_slots(admits)
-        while any(r is not None for r in self._active):
-            chunk_toks: List[object] = []
-            for _ in range(self._chunks_until_sync()):
-                out = self._decode_prog(self.params, self._cache, self._pos,
-                                        self._nxt, self._rem)
-                self.last_run_chunks += 1
-                self._cache, self._pos, self._nxt, self._rem, toks = out
-                chunk_toks.append(toks)
-            self._sync(admits, chunk_toks)
-            admits = []
-            self._fill_slots(admits)
-        self._sync(admits, [])  # tail: admits whose requests all retired
-        self.last_run_ticks = self.last_run_chunks * self.chunk
-        done = {r.rid: r.tokens[:r.max_new_tokens] for r in self._finished}
-        # per-request slot latency (continuous batching's OTHER win besides
-        # packing: short requests retire early instead of waiting for the
-        # batch's longest) — consumed by benchmarks/serving artifacts
-        self.last_latencies = {r.rid: r.finish_time - r.submit_time
-                               for r in self._finished if r.finish_time}
-        self._finished = []
-        return done
+        self.last_latencies = {}
+        while self._queue or any(r is not None for r in self._active):
+            self.run_segment(4 * self.chunk)
+        return self.collect_finished()

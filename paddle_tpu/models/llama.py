@@ -854,23 +854,17 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def kv_cache_spec() -> P:
-    """PartitionSpec of the serving KV cache [L, B, Smax, Hkv, D] under
-    tensor-parallel serving (r12): the kv-head dim follows wk/wv's
-    column-parallel output sharding over 'mp', so the decode tick's new
-    K/V rows scatter into LOCAL shards and cache attention contracts
-    per-shard — GSPMD inserts exactly one all-reduce per layer (after
-    the row-parallel wo), none for the cache itself."""
-    return P(None, None, None, "mp", None)
-
-
 def paged_pool_spec() -> P:
-    """PartitionSpec of the paged KV pool [L, pages, page, Hkv*D]: same
-    rule as ``kv_cache_spec`` — pages replicate, heads shard (a head's D
-    lanes are contiguous in the flat minor dim, so splitting it over
-    'mp' splits whole kv heads), so the host-side page tables (pure
-    int32 indices) stay replicated and page bookkeeping is unchanged
-    under 'mp'."""
+    """PartitionSpec of the paged KV pool [L, pages, page, Hkv*D] under
+    tensor-parallel serving (r12): pages replicate, heads shard (a
+    head's D lanes are contiguous in the flat minor dim, so splitting it
+    over 'mp' splits whole kv heads). The kv-head dim follows wk/wv's
+    column-parallel output sharding, so a tick's new K/V rows scatter
+    into LOCAL shards and attention contracts per-shard — GSPMD inserts
+    exactly one all-reduce per layer (after the row-parallel wo), none
+    for the pool itself — and the host-side page tables (pure int32
+    indices) stay replicated: page bookkeeping is unchanged under
+    'mp'."""
     return P(None, None, None, "mp")
 
 
@@ -1317,7 +1311,7 @@ def paged_kernel_active(cfg: LlamaConfig, page_size: int) -> bool:
 
 # every serving family the engine has serves this model (the model seam:
 # ``models.require`` refuses a family a model's module does not list)
-SERVING_FAMILIES = ("paged", "dense cache", "mesh", "chunked prefill",
+SERVING_FAMILIES = ("paged", "mesh", "chunked prefill",
                     "sequence-parallel prefill", "speculative",
                     "quality digest", "quantized pool", "prefix cache",
                     "host tier", "disaggregated serving")

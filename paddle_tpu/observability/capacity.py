@@ -44,9 +44,10 @@ refcounts, so every signal below is free of device reads:
 * :func:`capacity_plan` — the what-if surface: SCALING §3f pages-free
   arithmetic (span pages × concurrency from Little's law) joined with
   §3g replica scaling (offered tok/s ÷ per-replica capacity) answers
-  "what pool size / how many replicas for this trace", validated ±10%
-  against a measured serve in SERVING_r18.json. ROADMAP item 4's
-  autoscaler closes its loop over exactly this surface.
+  "what pool size / how many replicas for this trace"
+  (``tests/test_capacity.py::TestPlanner`` holds it to ±10% of a
+  saturated serve's own counts). The autoscaler closes its loop over
+  exactly this surface.
 
 Chunked-prefill caveat (honest accounting): the host replay skips
 non-final chunk steps (no token surfaced), so ``meter_streams`` does

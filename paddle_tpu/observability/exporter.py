@@ -211,14 +211,11 @@ class OpsServer:
             # router ranks on
             pages = {}
             for r in self.fleet._replicas:
-                if not r.engine.paged:
-                    continue
                 pc = r.prefix_cache
                 row = {
                     "pages_free": r.engine.pager.pages_free,
                     "reclaimable": (pc.reclaimable_pages()
-                                    if pc is not None and hasattr(
-                                        pc, "reclaimable_pages") else 0),
+                                    if pc is not None else 0),
                 }
                 tier = getattr(pc, "host_tier", None)
                 if tier is not None:
@@ -333,15 +330,12 @@ class OpsServer:
         if self.fleet is not None:
             reps = {}
             for r in self.fleet._replicas:
-                if not r.engine.paged:
-                    continue
                 pc = r.prefix_cache
                 row = {
                     "health": r.health,
                     **r.engine.pager.stats(),
                     "reclaimable": (pc.reclaimable_pages()
-                                    if pc is not None and hasattr(
-                                        pc, "reclaimable_pages") else 0),
+                                    if pc is not None else 0),
                 }
                 tier = getattr(pc, "host_tier", None)
                 if tier is not None:
@@ -366,9 +360,7 @@ class OpsServer:
                 pc = pm.prefix_cache
                 held = 0
                 if pc is not None:
-                    held = (pc.physical_pages_held()
-                            if hasattr(pc, "physical_pages_held")
-                            else pc.pages_held)
+                    held = pc.physical_pages_held()
                 out["audit"] = pm.pager.leak_report(expected_held=held)
             else:
                 out["audit"] = []
@@ -422,11 +414,10 @@ def _pool_rollup(fleet) -> dict:
             "pages_free": 0, "reclaimable": 0})
         row["replicas"].append(r.idx)
         row["healthy"] += 1 if r.health == "healthy" else 0
-        if r.engine.paged:
-            row["pages_free"] += r.engine.pager.pages_free
-            pc = r.prefix_cache
-            if pc is not None and hasattr(pc, "reclaimable_pages"):
-                row["reclaimable"] += pc.reclaimable_pages()
+        row["pages_free"] += r.engine.pager.pages_free
+        pc = r.prefix_cache
+        if pc is not None:
+            row["reclaimable"] += pc.reclaimable_pages()
     return pools
 
 

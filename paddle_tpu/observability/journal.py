@@ -627,9 +627,11 @@ def describe_engine(engine) -> dict:
     return {
         "slots": engine.slots, "max_len": engine.max_len,
         "chunk": engine.chunk, "prompt_buckets": list(engine.buckets),
-        "eos_token_id": engine.eos, "paged": engine.paged,
-        "page_size": engine.page_size if engine.paged else None,
-        "num_pages": engine.pager.num_pages if engine.paged else None,
+        # "paged" stays in the stored format: replay refuses a journal
+        # that recorded the contiguous engine
+        "eos_token_id": engine.eos, "paged": True,
+        "page_size": engine.page_size,
+        "num_pages": engine.pager.num_pages,
         "chunked_prefill": engine.chunked,
         "prefill_chunks": list(engine.prefill_chunks),
         "speculative": engine.speculative, "sampling": samp,
@@ -647,18 +649,14 @@ def describe_engine(engine) -> dict:
 def describe_prefix_cache(pc) -> Optional[dict]:
     if pc is None:
         return None
-    if hasattr(pc, "pager"):                    # PagedPrefixCache
-        d = {"kind": "paged", "block": pc.block,
-             "capacity_pages": pc.capacity_pages}
-        tier = getattr(pc, "host_tier", None)
-        if tier is not None:
-            # r19: the host spill tier is a routing/admission DECIDER
-            # (restore-on-hit, spill-instead-of-drop), so replay must
-            # rebuild it at the recorded capacity
-            d["host_tier_pages"] = tier.capacity_pages
-        return d
-    return {"kind": "rows", "block": pc.block,
-            "capacity_tokens": pc.capacity_tokens}
+    d = {"kind": "paged", "block": pc.block,
+         "capacity_pages": pc.capacity_pages}
+    if pc.host_tier is not None:
+        # r19: the host spill tier is a routing/admission DECIDER
+        # (restore-on-hit, spill-instead-of-drop), so replay must
+        # rebuild it at the recorded capacity
+        d["host_tier_pages"] = pc.host_tier.capacity_pages
+    return d
 
 
 def describe_envelope(env) -> Optional[dict]:
@@ -673,8 +671,7 @@ def describe_envelope(env) -> Optional[dict]:
             "seg_steps": list(env.seg_steps),
             "n_pads": list(env.n_pads),
             "resume": env.resume,
-            "prefix_block": env.prefix_block,
-            "offline_batch": env.offline_batch}
+            "prefix_block": env.prefix_block}
 
 
 def describe_arrivals(arrivals) -> List[dict]:

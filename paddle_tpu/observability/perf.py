@@ -82,7 +82,7 @@ V5E_PEAK_FLOPS = CHIP_PEAKS["TPU v5 lite"]["bf16_flops_s"]
 
 
 def serving_ledger(cfg, params, batch: int, avg_pos: float,
-                   program: str = "serving_segment",
+                   program: str = "paged_serving_segment",
                    hbm_bytes_s: float = V5E_HBM_BPS,
                    peak_flops_s: float = V5E_PEAK_FLOPS) -> dict:
     """Analytic byte/op ledger for a decode-bound serving program,
@@ -151,12 +151,12 @@ class PerfMonitor:
 
     ``tick_budget_s``: pinned seconds/tick the sentinel guards. When
     ``None`` it self-pins to the EWMA after ``pin_after`` segments —
-    the 'no regression vs my own warm baseline' mode the serving lanes
-    use. ``tolerance``: multiplier over budget that trips the sentinel.
+    the 'no regression vs my own warm baseline' mode. ``tolerance``:
+    multiplier over budget that trips the sentinel.
     """
 
     def __init__(self, cfg, params, batch: int, avg_pos: float = 64.0,
-                 program: str = "serving_segment",
+                 program: str = "paged_serving_segment",
                  hbm_bytes_s: float = V5E_HBM_BPS,
                  peak_flops_s: float = V5E_PEAK_FLOPS,
                  tick_budget_s: Optional[float] = None,

@@ -120,10 +120,16 @@ def _engine_from(d: dict, cfg, params):
             f"recorded engine is mesh-sharded over {d['mesh']} — replay "
             f"needs the recording topology's devices; rebuild the mesh "
             f"and engines yourself, then drive rebuild() manually")
+    if not d["paged"]:
+        raise JournalError(
+            'recorded engine has "paged": false — the contiguous-cache '
+            "engine it describes no longer exists, so its decisions "
+            "cannot be replayed")
     kw: Dict[str, Any] = dict(
         slots=d["slots"], max_len=d["max_len"], chunk=d["chunk"],
         prompt_buckets=tuple(d["prompt_buckets"]),
-        eos_token_id=d["eos_token_id"], paged=d["paged"],
+        eos_token_id=d["eos_token_id"],
+        page_size=d["page_size"], num_pages=d["num_pages"],
         chunked_prefill=d["chunked_prefill"],
         prefill_chunks=tuple(d["prefill_chunks"]),
         speculative=d["speculative"], sampling=d["sampling"],
@@ -136,9 +142,6 @@ def _engine_from(d: dict, cfg, params):
         # r23: long-context geometry (absent in pre-r23 journals)
         seq_parallel=d.get("seq_parallel", 0),
         long_buckets=tuple(d.get("long_buckets") or ()))
-    if d["paged"]:
-        kw["page_size"] = d["page_size"]
-        kw["num_pages"] = d["num_pages"]
     eng = ServingEngine(cfg, params, **kw)
     # mutable state the serve started from: rid offsets feed sampling
     # seeds and class-order keys; the acceptance EWMA feeds shed math
@@ -150,22 +153,23 @@ def _engine_from(d: dict, cfg, params):
 def _prefix_cache_from(d: Optional[dict], engine):
     if d is None:
         return None
-    from ..inference.prefix_cache import PagedPrefixCache, PrefixCache
+    from ..inference.prefix_cache import PagedPrefixCache
 
-    if d["kind"] == "paged":
-        host_tier = None
-        if d.get("host_tier_pages"):
-            # r19: the spill tier decides restores/spills — rebuild it
-            # at the recorded capacity so tier_transfer records replay
-            from ..inference.kv_tiers import HostTier
+    if d["kind"] != "paged":
+        raise JournalError(
+            f"recorded prefix cache is of kind {d['kind']!r} — only the "
+            f"paged cache exists")
+    host_tier = None
+    if d.get("host_tier_pages"):
+        # r19: the spill tier decides restores/spills — rebuild it
+        # at the recorded capacity so tier_transfer records replay
+        from ..inference.kv_tiers import HostTier
 
-            host_tier = HostTier(engine.pager,
-                                 capacity_pages=d["host_tier_pages"])
-        return PagedPrefixCache(engine.pager,
-                                capacity_pages=d["capacity_pages"],
-                                host_tier=host_tier)
-    return PrefixCache(block=d["block"],
-                       capacity_tokens=d["capacity_tokens"])
+        host_tier = HostTier(engine.pager,
+                             capacity_pages=d["host_tier_pages"])
+    return PagedPrefixCache(engine.pager,
+                            capacity_pages=d["capacity_pages"],
+                            host_tier=host_tier)
 
 
 def _injector_from(d: Optional[dict]):

@@ -47,19 +47,28 @@ def _seeded():
     yield
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _no_mesh_left_behind():
-    """A test file that sets the global mesh (``fleet.init``, a hybrid mesh
-    for a sharded step) leaves it set when it ends, and whichever file the
-    same xdist worker runs next traces its programs under it:
-    ``test_fleet.py`` before ``test_program_coverage.py`` costs the serve 3
-    backend compiles, before ``test_chip_compile.py`` a CPU mesh under a
-    described TPU. Which files meet in a worker changes with every file a
-    PR adds, so a module ends with no mesh."""
-    yield
-    from paddle_tpu.parallel import set_mesh
+@pytest.fixture
+def compile_cache_restored():
+    """For a test that turns jax's persistent compilation cache on
+    (``paddle.jit.enable_persistent_cache``). jax decides at a compile
+    whether the cache is in use and keeps the answer and the open cache
+    until ``reset_cache()``: setting the directory back to None is not
+    enough, and every later file of the same xdist worker then compiles
+    through a cache whose key leaves metadata out — it hands
+    ``test_tracing_spans.py::TestNames`` the executable WITH scope names
+    for the program it traced without them."""
+    import paddle_tpu as paddle
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-    set_mesh(None)
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in was.items():
+        jax.config.update(n, v)
+    paddle.jit._PERSISTENT_CACHE_DIR[0] = None
+    cc.reset_cache()
 
 
 @pytest.fixture(scope="session")

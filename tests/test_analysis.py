@@ -295,7 +295,7 @@ class TestBudgetGate:
     def test_budget_check_catches_regression(self):
         """A synthetic report over budget produces violations (the gate
         actually bites)."""
-        rep = analysis.AuditReport(program="decode_tick")
+        rep = analysis.AuditReport(program="paged_serving_segment")
         rep.metrics.update(host_syncs_flagged=1, warm_compiles=2,
                            relayout_bytes=10 << 20, replays=2,
                            host_syncs_allowed={})
@@ -305,7 +305,7 @@ class TestBudgetGate:
         assert any("relayout_bytes" in s for s in v)
 
     def test_unknown_allowed_label_is_violation(self):
-        rep = analysis.AuditReport(program="decode_tick")
+        rep = analysis.AuditReport(program="paged_serving_segment")
         rep.metrics.update(host_syncs_flagged=0, warm_compiles=0,
                            replays=2,
                            host_syncs_allowed={"rogue.label": 4})

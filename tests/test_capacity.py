@@ -369,8 +369,8 @@ class TestPlanner:
     def test_plan_within_10pct_of_measured(self, saturated):
         """§3f×§3g arithmetic vs the measured saturated serve: the
         predicted pool high-water and tok/s land within ±10% of what
-        the serve measured (the SERVING_r18 bar, deterministic here by
-        saturating all slots with identical requests)."""
+        the serve measured (deterministic here by saturating all slots
+        with identical requests)."""
         rep = saturated["report"]
         plan = capacity_plan(
             {"mean_prompt_tokens": 8, "mean_new_tokens": 16,

@@ -312,3 +312,24 @@ def test_paged_segment_holds_pool_once(shaped, no_persistent_cache,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < plane_bytes, \
         f"temporaries {temp} B hold a pool plane ({plane_bytes} B)"
+
+
+def test_canonical_paged_segment_is_the_parents_program():
+    """PR 32 took the contiguous engine out from around the paged segment
+    and claims the program itself did not move: the gate's
+    ``paged_serving_segment`` (llama-tiny, 4 slots, this installation's CPU
+    lowering) keeps the key it is memoised under and the instruction
+    count the parent commit compiled it to. A change to
+    ``_build_paged_segment_prog`` or ``forward_with_pages`` moves the
+    count: re-pin it as ``analysis/budgets.py``'s bytes are re-pinned."""
+    from paddle_tpu.analysis import programs
+
+    handle = programs.build("paged_serving_segment")
+    text = handle.hlo()
+    assert text.lstrip().startswith("HloModule jit_segment")
+    assert len(re.findall(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = ", text,
+                          re.M)) == 1858
+    eng = handle.aot_engine
+    assert list(eng._progs) == [("pseg", 4, 16, 12)]
+    assert eng.program_space(handle.aot_envelope) == \
+        {"pseg": frozenset({("pseg", 4, 16, 12)})}

@@ -53,22 +53,10 @@ def test_importing_the_package_imports_no_kernel():
     assert proc.returncode == 0, proc.stderr[-2000:]
 
 
-@pytest.fixture
-def cache_config_restored():
-    import paddle_tpu as paddle
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    was = jax.config.jax_compilation_cache_dir
-    yield
-    jax.config.update("jax_compilation_cache_dir", was)
-    paddle.jit._PERSISTENT_CACHE_DIR[0] = None
-    cc.reset_cache()
-
-
 @pytest.mark.parametrize("from_env", [True, False],
                          ids=["env_set", "env_unset"])
 def test_compile_cache_directory_rule(from_env, monkeypatch, tmp_path,
-                                      cache_config_restored):
+                                      compile_cache_restored):
     """``JAX_COMPILATION_CACHE_DIR`` set: that directory, and no other is
     ever configured. Unset: the one fixed path inside the checkout."""
     import paddle_tpu as paddle

@@ -271,18 +271,14 @@ class TestDisaggReplay:
         assert res.identical, res.first_divergence
 
     def test_constructor_validation(self, tiny_llama):
-        """Both pools must be non-empty and paged; canary serving is
-        rejected (its replica index arithmetic has no pool)."""
+        """Both pools must be non-empty; canary serving is rejected (its
+        replica index arithmetic has no pool)."""
         cfg, params = tiny_llama
         es = _engines(cfg, params)
         with pytest.raises(ValueError, match="pool"):
             DisaggRouter(es[:1], [])
         with pytest.raises(ValueError, match="canary"):
             DisaggRouter(es[:1], es[1:], canary=object())
-        flat = build_fleet(cfg, params, 2, slots=2, max_len=96,
-                           prompt_buckets=(8, 16, 32, 64))
-        with pytest.raises(ValueError, match="paged"):
-            DisaggRouter(flat[:1], flat[1:])
 
 
 class TestDisaggOpsSurface:

@@ -25,6 +25,20 @@ from paddle_tpu.distributed.fleet.meta_parallel import (
 from paddle_tpu.parallel import create_hybrid_mesh, set_mesh
 
 
+@pytest.fixture(autouse=True)
+def _no_mesh_left_behind():
+    """``fleet.init`` (through ``mp4_mesh`` or a test's own ``Fleet``) sets
+    the global mesh and the hybrid communicate group: every test ends with
+    neither, so the next file of this xdist worker traces under no mesh."""
+    yield
+    set_mesh(None)
+    from paddle_tpu.distributed.fleet.base.topology import (
+        set_hybrid_communicate_group,
+    )
+
+    set_hybrid_communicate_group(None)
+
+
 @pytest.fixture
 def mp4_mesh():
     mesh = create_hybrid_mesh(dp=2, mp=4)
@@ -32,13 +46,7 @@ def mp4_mesh():
     strategy = DistributedStrategy()
     strategy.hybrid_configs = {"dp_degree": 2, "mp_degree": 4}
     fleet.init(is_collective=True, strategy=strategy)
-    yield mesh
-    set_mesh(None)
-    from paddle_tpu.distributed.fleet.base.topology import (
-        set_hybrid_communicate_group,
-    )
-
-    set_hybrid_communicate_group(None)
+    return mesh
 
 
 class TestTopology:

@@ -267,6 +267,19 @@ class TestReplay:
         assert res.divergence["kind"] == "dispatch"
         assert res.divergence["field"] == "replica"
 
+    def test_contiguous_engine_header_refused_by_name(self, fleet_recorded):
+        """A journal is still WRITTEN with ``"paged": true`` per engine;
+        one that recorded the contiguous engine names what is gone
+        instead of building some other engine for it."""
+        recs = copy.deepcopy(fleet_recorded["records"])
+        hdr = next(r for r in recs if r["kind"] == "header")["header"]
+        assert all(e["paged"] is True and e["page_size"] == 16
+                   and e["num_pages"] for e in hdr["engines"])
+        hdr["engines"][0]["paged"] = False
+        with pytest.raises(journal.JournalError, match='"paged": false'):
+            replay.replay_serve({"records": recs},
+                                params=fleet_recorded["params"])
+
 
 # ---------------------------------------------------------------------------
 # request journeys (tentpole b)
@@ -395,15 +408,15 @@ class TestJournalAudit:
         sync/compile metrics to journal-off."""
         from paddle_tpu.analysis import auditor, programs
 
-        handle = programs.build("serving_segment")
+        handle = programs.build("paged_serving_segment")
 
         def audit(journaled):
             if not journaled:
-                return auditor.audit_replay("serving_segment",
+                return auditor.audit_replay("paged_serving_segment",
                                             handle.replay, replays=2)
             j = journal.Journal()       # in-memory
             with journal.attach(j):
-                return auditor.audit_replay("serving_segment",
+                return auditor.audit_replay("paged_serving_segment",
                                             handle.replay, replays=2)
 
         rep_on = audit(True)

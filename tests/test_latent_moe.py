@@ -462,7 +462,6 @@ def test_page_bytes_come_from_the_model(tiny):
 # (g) ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("family,kw", [
-    ("dense cache", dict(paged=False)),
     ("chunked prefill", dict(chunked_prefill=True)),
     ("speculative", dict(speculative=2)),
     ("speculative", dict(sampling={"temperature": 0.7})),

@@ -422,7 +422,7 @@ class TestServingTelemetry:
         assert flight.events("backpressure")
 
     def test_prefix_cache_hit_rate_counters(self, tiny_serving):
-        from paddle_tpu.inference.prefix_cache import PrefixCache
+        from paddle_tpu.inference.prefix_cache import PagedPrefixCache
         from paddle_tpu.inference.serving import ServingEngine
 
         cfg, params, _ = tiny_serving
@@ -432,7 +432,7 @@ class TestServingTelemetry:
             0, cfg.vocab_size, (6,)).astype(np.int32)]) for _ in range(4)]
         eng = ServingEngine(cfg, params, slots=2, max_len=96,
                             prompt_buckets=(8, 16, 64))
-        pc = PrefixCache(block=16, capacity_tokens=2048)
+        pc = PagedPrefixCache(eng.pager, capacity_pages=128)
         metrics.reset()
         for p in prompts:
             eng.add_request(p, 4)
@@ -581,12 +581,12 @@ class TestTelemetryAudit:
         the tier-1 cost is one compile + 8 replays."""
         from paddle_tpu.analysis import auditor, budgets, programs
 
-        handle = programs.build("serving_segment")
+        handle = programs.build("paged_serving_segment")
 
         def audit(enabled):
             prev = metrics.set_enabled(enabled)
             try:
-                return auditor.audit_replay("serving_segment",
+                return auditor.audit_replay("paged_serving_segment",
                                             handle.replay, replays=2)
             finally:
                 metrics.set_enabled(prev)
@@ -594,7 +594,7 @@ class TestTelemetryAudit:
         rep_on = audit(True)
         rep_off = audit(False)
         rep_on.merge(auditor.audit_static(
-            "serving_segment", handle.hlo(),
+            "paged_serving_segment", handle.hlo(),
             donation_threshold=handle.donation_threshold,
             expected_undonated=handle.expected_undonated))
         assert budgets.check(rep_on) == [], rep_on.format()

@@ -52,13 +52,6 @@ class TestRegistry:
         assert S.key("cseg", n_pad=4, s_max=16, c=8, steps=16) == \
             ("cseg", 4, 16, 8, 16)
         assert S.key("sseg", n_pad=4, k=3, steps=16) == ("sseg", 4, 3, 16)
-        assert S.key("seg", n_pad=4, s_max=16, pre_max=0, steps=12) == \
-            ("seg", 4, 16, 0, 12)
-        assert S.key("drain", n_pad=2, p_max=16, g_max=16) == \
-            ("drain", 2, 16, 16)
-        assert S.key("decode", chunk=8) == ("decode", 8)
-        # the r5 admit family keeps its historical untagged format
-        assert S.key("admit", bucket=16, nb=2) == (16, 2)
 
     def test_key_rejects_wrong_axes(self):
         with pytest.raises(TypeError):
@@ -73,8 +66,8 @@ class TestRegistry:
         S = PROGRAM_SPACE
         assert S.family_of(("pseg", 4, 16, 12)) == "pseg"
         assert S.family_of(("sseg", 4, 3, 16)) == "sseg"
-        assert S.family_of((16, 2)) == "admit"
-        assert S.family_of(("decode", 8)) == "decode"
+        assert S.family_of((16, 2)) is None           # every key is tagged
+        assert S.family_of(("seg", 4, 16, 0, 12)) is None
         assert S.family_of(("zseg", 1, 2, 3)) is None
         assert S.family_of(("pseg", 4, 16)) is None   # wrong arity
 
@@ -109,6 +102,35 @@ class TestRegistry:
                 chunk_for(eng.prefill_chunks, w)
 
 
+class TestEveryFamilyIsReachable:
+    """``PROGRAM_SPACE`` holds no family whose predicate is true for no
+    engine: each one is engaged by a constructor option that exists, and
+    then enumerates at least one key."""
+
+    ENGAGES = {
+        "pseg": dict(),
+        "qseg": dict(quality_digest=True),
+        "qpseg": dict(quant="int8"),
+        "cseg": dict(chunked_prefill=True, prefill_chunks=(8, 16)),
+        "sseg": dict(speculative=3),
+        "spseg": dict(seq_parallel=2, long_buckets=(64, 96),
+                      prefill_chunks=(8, 16)),
+    }
+
+    def test_table_names_every_family(self):
+        assert sorted(self.ENGAGES) == PROGRAM_SPACE.families()
+
+    @pytest.mark.parametrize("family", sorted(ENGAGES))
+    def test_family_engages_and_enumerates(self, tiny, family):
+        cfg, params = tiny
+        eng = ServingEngine(cfg, params, slots=4, max_len=96, chunk=8,
+                            prompt_buckets=(16, 32), page_size=16,
+                            **self.ENGAGES[family])
+        assert PROGRAM_SPACE.family(family).applies(eng)
+        keys = eng.program_space()[family]
+        assert keys and all(k[0] == family for k in keys)
+
+
 class TestEnumeration:
     """The reachability proof: closed-form enumeration == brute-force
     replay of the admission arithmetic, across configs and envelopes.
@@ -121,7 +143,7 @@ class TestEnumeration:
         dict(max_prompt=12, max_new_tokens=3, seg_steps=(16,),
              prefix_block=16, resume=False),
         dict(max_prompt=20, max_new_tokens=6, seg_steps=(32,),
-             prefix_block=8, offline_batch=3),
+             prefix_block=8),
     ]
 
     @pytest.mark.parametrize("ckw", [
@@ -132,7 +154,9 @@ class TestEnumeration:
              speculative=3),
         dict(paged=True, page_size=16, prompt_buckets=(16, 32),
              quality_digest=True),
-        dict(prompt_buckets=(16, 32, 64)),
+        dict(page_size=16, prompt_buckets=(16, 32), quant="int8"),
+        dict(page_size=16, prompt_buckets=(16, 32), seq_parallel=2,
+             long_buckets=(64, 96), prefill_chunks=(8, 16)),
     ])
     def test_enumeration_matches_admission_replay(self, tiny, ckw):
         cfg, params = tiny
@@ -370,14 +394,12 @@ class TestEscapesFlagged:
 
 class TestPersistentCacheInterplay:
     def test_warm_restart_skips_recompiles_enumeration_unchanged(
-            self, tiny, tmp_path, monkeypatch):
+            self, tiny, tmp_path, monkeypatch, compile_cache_restored):
         """r15 interplay: aot_warmup through a populated persistent
         cache deserialises instead of recompiling — a restarted replica
         pays a fraction of the cold warmup's backend compiles — and the
         enumeration is a pure function of config + envelope (identical
         across the restart)."""
-        import jax
-
         import paddle_tpu as paddle
         from paddle_tpu.inference import serving as S
 
@@ -426,5 +448,3 @@ class TestPersistentCacheInterplay:
         finally:
             S._SHARED_PROGS.clear()
             S._SHARED_PROGS.update(saved)
-            jax.config.update("jax_compilation_cache_dir", None)
-            paddle.jit._PERSISTENT_CACHE_DIR[0] = None

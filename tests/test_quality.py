@@ -146,9 +146,6 @@ class TestDigests:
 
     def test_digest_requires_plain_paged(self, tiny):
         cfg, params = tiny
-        with pytest.raises(ValueError, match="paged"):
-            ServingEngine(cfg, params, slots=2, max_len=96,
-                          prompt_buckets=(8, 16, 32), quality_digest=True)
         with pytest.raises(ValueError, match="token level"):
             _mk(cfg, params, speculative=2)
 

@@ -168,9 +168,6 @@ class TestQuantEngine:
         cfg, params = tiny
         with pytest.raises(ValueError, match="quant"):
             _mk(cfg, params, quant="int4")
-        with pytest.raises(ValueError, match="paged"):
-            ServingEngine(cfg, params, slots=2, max_len=96,
-                          prompt_buckets=(8, 16), quant="int8")
         with pytest.raises(ValueError, match="quant"):
             _mk(cfg, params, speculative=2)
 

@@ -116,31 +116,11 @@ class TestServingEos:
             assert results[rid] == want, (rid, results[rid], want)
 
 
-class TestWindowedPath:
-    def test_windowed_matches_fused(self, tiny):
-        """run(fused=False) — the incremental host loop with batched
-        window syncs — must produce the same greedy tokens as the
-        single-program drain."""
-        cfg, params = tiny
-        rng = np.random.RandomState(3)
-        reqs = [(rng.randint(0, cfg.vocab_size, (l,)).astype(np.int32), n)
-                for l, n in [(5, 7), (12, 3), (30, 9), (3, 12), (17, 5)]]
-
-        def serve(fused):
-            eng = ServingEngine(cfg, params, slots=3, max_len=96, chunk=4,
-                                prompt_buckets=(8, 16, 32))
-            rids = [eng.add_request(p, n) for p, n in reqs]
-            out = eng.run(fused=fused)
-            assert eng.last_run_ticks > 0
-            return [out[r] for r in rids]
-
-        assert serve(True) == serve(False)
-
-    def test_windowed_eos_deferred_freeze(self, tiny):
-        """The windowed path's deferred-EOS machinery (in-program freeze
-        at admit + _sync's tok0 EOS handling) must truncate at the first
-        EOS exactly like the dense path — including EOS emitted AT
-        prefill, which the host only learns at the next batched sync."""
+    def test_eos_at_prefill_and_mid_generation(self, tiny):
+        """EOS emitted AT the prefill token freezes the slot in the admit
+        branch (the host only learns at the segment's fetch), EOS
+        mid-generation in a tick: both truncate at the first EOS exactly
+        like the dense path, and the freed slots serve the queue."""
         cfg, params = tiny
         rng = np.random.RandomState(5)
         prompts = [rng.randint(0, cfg.vocab_size, (6 + i,)).astype(np.int32)
@@ -152,10 +132,11 @@ class TestWindowedPath:
             eng = ServingEngine(cfg, params, slots=2, max_len=96, chunk=4,
                                 prompt_buckets=(16,), eos_token_id=eos)
             rids = [eng.add_request(p, 8) for p in prompts]
-            results = eng.run(fused=False)
+            results = eng.run()
             for rid, ref in zip(rids, refs):
                 want = ref[:ref.index(eos) + 1] if eos in ref else ref
                 assert results[rid] == want, (eos, rid, results[rid], want)
+            assert eng.pager.leak_report() == []
 
 
 class TestSegmentReentry:
@@ -266,107 +247,6 @@ class TestOnlineScheduler:
         spans = [s for s in p._host_spans if s[0] == "serving.segment"]
         assert len(spans) == rep.segments
         assert all(s[1] == "serving" and s[3] > 0 for s in spans)
-
-    def test_smoke_gate(self):
-        """The tier-1 scheduler gate (satellite: llama_serving --online
-        --smoke): engine >= 1.0x fixed batching on the staggered mixed
-        workload, no slot leaks/starvation, prefix-cache hit path
-        token-identical. A scheduler regression fails HERE, on CPU."""
-        import importlib.util
-        import os
-
-        path = os.path.join(os.path.dirname(__file__), "..",
-                            "benchmarks", "llama_serving.py")
-        spec = importlib.util.spec_from_file_location("_llama_serving",
-                                                      path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        ev = mod.smoke()
-        assert ev["served"] == ev["n_requests"]
-        assert not ev["slot_leak"], ev
-        assert ev["prefix_identical"], ev
-        assert ev["prefix_hits"] > 0, ev
-        assert ev["throughput_vs_fixed"] >= 1.0, ev
-
-
-class TestPrefixCache:
-    def test_hit_path_token_identical_and_cheaper(self, tiny):
-        """Satellite test (iii): admission through a prefix-cache hit
-        must produce token-identical output to the cold path — and the
-        hit must actually shorten the prefill (suffix-only)."""
-        from paddle_tpu.inference.prefix_cache import PrefixCache
-
-        cfg, params = tiny
-        rng = np.random.RandomState(41)
-        prefix = rng.randint(0, cfg.vocab_size, (32,)).astype(np.int32)
-        # 4 requests over 2 slots: the first SEGMENT co-admits two cold
-        # (insertion is per-segment), the second segment's two both hit
-        tails = [rng.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
-                 for _ in range(4)]
-        prompts = [np.concatenate([prefix, t]) for t in tails]
-        refs = [_dense_reference(cfg, params, p, 6) for p in prompts]
-
-        def serve(pc):
-            eng = ServingEngine(cfg, params, slots=2, max_len=96,
-                                prompt_buckets=(8, 16, 64))
-            rids = [eng.add_request(p, 6) for p in prompts]
-            while eng._queue or eng.free_slot_count() < eng.slots:
-                eng.run_segment(16, prefix_cache=pc)
-            done = eng.collect_finished()
-            return [done[r] for r in rids]
-
-        cold = serve(None)
-        pc = PrefixCache(block=16, capacity_tokens=2048)
-        hot = serve(pc)
-        assert cold == hot == refs
-        assert pc.hits >= 2 and pc.hit_tokens >= 2 * 32
-
-    def test_partial_overlap_and_eviction(self, tiny):
-        """Block-aligned partial overlap hits; LRU eviction keeps the
-        held-token budget."""
-        from paddle_tpu.inference.prefix_cache import PrefixCache
-        from paddle_tpu.models import llama
-
-        cfg, params = tiny
-        rng = np.random.RandomState(43)
-        base = rng.randint(0, cfg.vocab_size, (48,)).astype(np.int32)
-        pc = PrefixCache(block=16, capacity_tokens=64)
-        pc.put_prompt(params, base, cfg)
-        # same first 16 tokens, different continuation -> 16-row hit
-        probe = np.concatenate(
-            [base[:16], rng.randint(0, cfg.vocab_size, (20,))]
-        ).astype(np.int32)
-        m = pc.match(probe)
-        assert m is not None and m.length == 16
-        # a second insert pushes past capacity_tokens=64 -> LRU eviction
-        other = rng.randint(0, cfg.vocab_size, (48,)).astype(np.int32)
-        pc.put_prompt(params, other, cfg)
-        assert pc.tokens_held <= 64
-        assert pc.evictions >= 1
-
-    def test_harvested_rows_match_standalone_prefill(self, tiny):
-        """Cache plumbing parity: rows harvested from a serving slot
-        after admission equal llama.prompt_kv's standalone prefill."""
-        import jax.numpy as jnp
-
-        from paddle_tpu.inference.prefix_cache import PrefixCache
-        from paddle_tpu.models import llama
-
-        cfg, params = tiny
-        rng = np.random.RandomState(45)
-        prompt = rng.randint(0, cfg.vocab_size, (16,)).astype(np.int32)
-        pc = PrefixCache(block=16, capacity_tokens=1024)
-        eng = ServingEngine(cfg, params, slots=1, max_len=96,
-                            prompt_buckets=(16,))
-        eng.add_request(prompt, 2)
-        while eng._queue or eng.free_slot_count() < eng.slots:
-            eng.run_segment(8, prefix_cache=pc)
-        m = pc.match(np.concatenate([prompt, prompt[:4]]))
-        assert m is not None and m.length == 16
-        cache, _ = llama.prompt_kv(params, prompt, cfg)
-        np.testing.assert_allclose(
-            np.asarray(m.k[:, :16]), np.asarray(cache["k"][:, 0]),
-            rtol=1e-5, atol=1e-6)
 
 
 class TestDecodeKernelLane:

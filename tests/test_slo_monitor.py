@@ -187,7 +187,7 @@ class TestExplainedPerf:
         assert led["kv_bytes_per_tick"] == int(kv)
         assert led["ceiling_tok_s"] == pytest.approx(
             batch / ((wbytes + kv) / V5E_HBM_BPS))
-        b = budgets.budget_for("serving_segment")
+        b = budgets.budget_for("paged_serving_segment")
         assert led["hazard_budget"]["relayout_bytes_max"] == \
             b.relayout_bytes_max
         assert led["hazard_budget"]["allowed_syncs_per_replay"] == \
@@ -210,7 +210,7 @@ class TestExplainedPerf:
             rel=1e-4)
         closed = pm.end_interval()
         assert metrics.gauge(
-            "perf.roofline_fraction[serving_segment]").value == \
+            "perf.roofline_fraction[paged_serving_segment]").value == \
             closed["roofline_fraction"]
         # the interval reset: a fresh one starts empty
         assert pm.interval_report()["tokens"] == 0
@@ -510,7 +510,7 @@ class TestServingIntegration:
             _, text = _get(srv.url + "/perf")
             perf = json.loads(text)
             assert perf["enabled"]
-            assert perf["ledger"]["program"] == "serving_segment"
+            assert perf["ledger"]["program"] == "paged_serving_segment"
             assert perf["last_interval"]["roofline_fraction"] > 0
             _, text = _get(srv.url + "/metrics")
             assert "slo_budget_remaining" in text

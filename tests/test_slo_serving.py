@@ -122,12 +122,6 @@ class TestChunkedPrefill:
         with pytest.raises(ValueError, match="chunked"):
             eng.run_segment(4)        # 4 < 2 * (32/8) worst case
 
-    def test_chunked_requires_paged(self, tiny):
-        cfg, params = tiny
-        with pytest.raises(ValueError, match="paged"):
-            ServingEngine(cfg, params, slots=2, max_len=96,
-                          prompt_buckets=(16,), chunked_prefill=True)
-
 
 # ---------------------------------------------------------------------------
 # priority classes + preemption (tentpole b)

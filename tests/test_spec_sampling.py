@@ -165,15 +165,6 @@ class TestInProgramSampling:
         eng = _engine(cfg, params, sampling={"temperature": 0.0})
         assert eng.sampling is None
 
-    def test_sampling_requires_paged(self, tiny):
-        cfg, params = tiny
-        from paddle_tpu.inference.serving import ServingEngine
-
-        with pytest.raises(ValueError, match="paged"):
-            ServingEngine(cfg, params, slots=4, max_len=64,
-                          prompt_buckets=(16,),
-                          sampling={"temperature": 1.0})
-
 
 # ---------------------------------------------------------------------------
 # speculative decoding
@@ -288,24 +279,22 @@ class TestAcceptanceAwareSLO:
 
 
 class TestPersistentCompileCache:
-    def test_knob_writes_cache_entries(self, tmp_path, monkeypatch, _seeded):
+    def test_knob_writes_cache_entries(self, tmp_path, monkeypatch, _seeded,
+                                       compile_cache_restored):
         """The directory comes from JAX_COMPILATION_CACHE_DIR."""
         import paddle_tpu as paddle
 
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
         d = paddle.jit.enable_persistent_cache()
-        try:
-            assert d == str(tmp_path / "cc")
-            assert paddle.jit.persistent_cache_dir() == d
-            f = jax.jit(lambda x: x * 3 + 1)
-            f(jnp.ones((37,)))        # odd shape: certainly uncached
-            import os
-            assert os.listdir(d), "no persistent cache entries written"
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            paddle.jit._PERSISTENT_CACHE_DIR[0] = None
+        assert d == str(tmp_path / "cc")
+        assert paddle.jit.persistent_cache_dir() == d
+        f = jax.jit(lambda x: x * 3 + 1)
+        f(jnp.ones((37,)))        # odd shape: certainly uncached
+        import os
+        assert os.listdir(d), "no persistent cache entries written"
 
-    def test_knob_without_env_uses_the_checkout_dir(self, monkeypatch):
+    def test_knob_without_env_uses_the_checkout_dir(
+            self, monkeypatch, compile_cache_restored):
         """No directory named from outside: one fixed path inside the
         checkout, never a temporary or per-process name (the path is
         part of the cache's key)."""
@@ -314,12 +303,8 @@ class TestPersistentCompileCache:
         import paddle_tpu as paddle
 
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        try:
-            d = paddle.jit.enable_persistent_cache()
-            root = os.path.dirname(os.path.dirname(
-                os.path.abspath(paddle.__file__)))
-            assert d == os.path.join(root, ".jax_cache")
-            assert paddle.jit.enable_persistent_cache() == d  # stable
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            paddle.jit._PERSISTENT_CACHE_DIR[0] = None
+        d = paddle.jit.enable_persistent_cache()
+        root = os.path.dirname(os.path.dirname(
+            os.path.abspath(paddle.__file__)))
+        assert d == os.path.join(root, ".jax_cache")
+        assert paddle.jit.enable_persistent_cache() == d  # stable

@@ -319,13 +319,15 @@ class TestPageCounters:
             assert metrics.counter("serving." + n).value - before[n] \
                 == seen[n]
 
-    def test_dense_engine_reports_none(self, tiny):
+    def test_chunked_engine_reports_none(self, tiny):
+        """A chunked segment's prefill steps are not replayed, so its
+        page reads are not reckoned."""
         cfg, params = tiny
-        eng = ServingEngine(cfg, params, slots=2, max_len=96,
-                            prompt_buckets=(16,))
-        rep = OnlineScheduler(eng, seg_steps=3).serve(
+        eng = ServingEngine(cfg, params, slots=2, max_len=96, page_size=8,
+                            prompt_buckets=(16,), chunked_prefill=True)
+        rep = OnlineScheduler(eng, seg_steps=6).serve(
             arrivals(cfg, n=2, gen=3))
-        assert rep.page_reads is None
+        assert rep.n_requests == 2 and rep.page_reads is None
 
 
 # ---------------------------------------------------------------------------
@@ -356,19 +358,6 @@ def lower_paged_segment(cfg, params):
         jnp.zeros((4, pgr.max_pages), i32), i32(0))
 
 
-def lower_dense_segment(cfg, params):
-    eng = ServingEngine(cfg, params, slots=4, max_len=96,
-                        prompt_buckets=(16,))
-    s_max = eng.buckets[-1]
-    i32 = jnp.int32
-    pre = jnp.zeros((4, cfg.num_layers, 0, cfg.num_kv_heads, cfg.head_dim),
-                    eng._cache["k"].dtype)
-    return eng._build_segment_prog(4, s_max, 0, 4).lower(
-        eng.params, eng._cache, eng._pos, eng._nxt, eng._rem,
-        jnp.zeros((4, s_max), i32), jnp.ones((4,), i32),
-        jnp.zeros((4,), i32), pre, pre, jnp.zeros((4,), i32), i32(0))
-
-
 def lower_train_step(cfg, params):
     from paddle_tpu.parallel import create_hybrid_mesh
 
@@ -385,9 +374,8 @@ def lower_train_step(cfg, params):
 class TestNames:
     @pytest.mark.parametrize("lower, module, scopes", [
         (lower_paged_segment, "jit_segment", SEGMENT_SCOPES),
-        (lower_dense_segment, "jit_segment", SEGMENT_SCOPES),
         (lower_train_step, "jit_train_step", TRAIN_SCOPES),
-    ], ids=["paged_segment", "dense_segment", "train_step"])
+    ], ids=["paged_segment", "train_step"])
     def test_program_and_scope_names_are_metadata_only(
             self, tiny, monkeypatch, lower, module, scopes):
         cfg, params = tiny
