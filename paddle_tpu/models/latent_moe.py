@@ -310,16 +310,17 @@ def _latent_qkv(cfg: LatentMoEConfig, x, lp, positions):
 
 @scoped("attention")
 def _paged_latent_attention(cfg: LatentMoEConfig, q, plane, layer,
-                            page_table, positions):
+                            page_table, positions, q_len):
     """``o_lat`` [B, T, heads, rank] of absorbed queries over layer
     ``layer`` of the latent plane: the page-indirect kernel where the pool
-    tiles, else the slot's pages gathered and the same mathematics dense."""
+    tiles (it fetches and computes for a slot's first ``q_len`` rows
+    only), else the slot's pages gathered and the same mathematics dense."""
     from ..ops.pallas.mla_attention import mla_paged_attention
 
     R = cfg.kv_lora_rank
     if paged_kernel_active(cfg, plane.shape[2]):
         return mla_paged_attention(q, plane, page_table, positions[:, 0],
-                                   layer=layer, rank=R)
+                                   q_len, layer=layer, rank=R)
     B = q.shape[0]
     rows = plane[layer, page_table].reshape(B, -1, plane.shape[-1])
     s = jnp.einsum("bthc,bwc->bthw", q, rows).astype(jnp.float32)
@@ -476,13 +477,16 @@ def forward_with_pages(params, tokens, cfg: LatentMoEConfig, pool,
         valid = valid & (jnp.arange(T)[None, :]
                          <= jnp.reshape(logit_pos, (-1, 1)))
     phys = jnp.where(writable, phys, 0)
+    # rows whose output anything reads: none of a dead slot's, an
+    # admission's up to ``logit_pos`` (the rest is the bucket's padding)
+    q_len = valid.sum(1, dtype=jnp.int32)
 
     def layer(x, plane, lp, i):
         q, rows = _latent_qkv(cfg, x, lp, positions)
         with jax.named_scope("kv_write"):
             plane = plane.at[i, phys, prow].set(rows.astype(plane.dtype))
         o_lat = _paged_latent_attention(cfg, q, plane, i, page_table,
-                                        positions)
+                                        positions, q_len)
         x = _attn_post(cfg, x, o_lat, lp)
         x, counters = _ffn(cfg, x, lp, valid)
         return x, plane, counters

@@ -9,11 +9,13 @@ below lowers one kernel at ``LlamaConfig.bert_base_equiv`` widths (H=768,
 ``tpu_custom_call`` in the compiled text. Nothing runs: these say a kernel
 compiles, never that it is right or fast.
 
-One case compiles a whole program: ``test_paged_segment_holds_pool_once``
+Two cases compile a whole program: ``test_paged_segment_holds_pool_once``
 lowers the paged segment loop (admit and decode steps around
 ``llama.forward_with_pages``) and reads the compiled text and
 ``memory_analysis()`` for copies of the KV pool, which tier-1 cannot see
-otherwise: they cost two thirds of a serve step before PR 26 (PERF.md).
+otherwise: they cost two thirds of a serve step before PR 26 (PERF.md);
+``test_latent_segment_holds_pool_once`` does the same for the latent
+family's plane at the benchmark's own size.
 
 The topology is described inside a fixture (loading the TPU's library at
 import would break collection under several workers) and everything is
@@ -248,6 +250,56 @@ def test_kernel_compiles_for_v5e(name, shaped, no_persistent_cache):
                                       f"compiled program"
 
 
+def _compiled_segment(shaped, model, cfg, slots, max_pages, pages, n_pad,
+                      s_max, steps):
+    """``('pseg', n_pad, s_max, steps)`` of ``cfg`` (family ``model``) over
+    a pool of ``pages`` pages, lowered from shapes alone and compiled for
+    the described chip; bf16 weights."""
+    from paddle_tpu.inference.serving import ServingEngine
+
+    def abstract(build):
+        return jax.tree.map(lambda a: shaped(a.shape, a.dtype),
+                            jax.eval_shape(build))
+
+    params = abstract(lambda: model.init_params(cfg, jax.random.PRNGKey(0),
+                                                dtype=BF16))
+    pool = abstract(lambda: model.init_paged_pool(cfg, pages, PAGE))
+    engine = types.SimpleNamespace(        # all the builder reads of one
+        cfg=cfg, slots=slots, eos=None,
+        pager=types.SimpleNamespace(max_pages=max_pages))
+    segment = ServingEngine._build_paged_segment_prog(engine, n_pad, s_max,
+                                                      steps)
+    vec = shaped((slots,), I32)
+    req = shaped((n_pad,), I32)
+    return segment.lower(
+        params, pool, shaped((slots, max_pages), I32), vec, vec, vec,
+        shaped((n_pad, s_max), I32), req, req, req,
+        shaped((n_pad, max_pages), I32), shaped((), I32)).compile()
+
+
+def _kernel_call_sites(text, name):
+    return re.findall(rf"%({name}[.\d]*) = [^\n]*"
+                      r"custom_call_target=\"tpu_custom_call\"", text)
+
+
+def _moved(text, layer_elems, of_pages=None):
+    """bf16 copies, reshapes and slices in the compiled text as large as
+    one layer of a pool plane (``of_pages``: only shapes with that many
+    pages a dimension — a weight as large, re-tiled once a call, is not
+    the pool's guard's)."""
+    moved = []
+    for dims, op in re.findall(
+            r"= bf16\[([\d,]+)\]\S* (copy|copy-start|reshape|dynamic-slice|"
+            r"dynamic-update-slice)\(", text):
+        sizes = [int(d) for d in dims.split(",")]
+        n = 1
+        for d in sizes:
+            n *= d
+        if n >= layer_elems and (of_pages is None or of_pages in sizes):
+            moved.append((op, dims))
+    return moved
+
+
 def test_paged_segment_holds_pool_once(shaped, no_persistent_cache,
                                        monkeypatch):
     """The paged segment program (``jit_segment``: a while_loop of admit
@@ -259,59 +311,67 @@ def test_paged_segment_holds_pool_once(shaped, no_persistent_cache,
     program's temporaries are under one plane. Any of the three sites
     undone — the layer scan taking the pool as xs/ys, the kernel's wrapper
     reshaping it, a ``lax.cond`` around the branches — brings them back."""
-    from paddle_tpu.inference.serving import ServingEngine
     from paddle_tpu.models import llama
     from paddle_tpu.ops.pallas import flash_attention
 
     # dispatch asks jax.default_backend(), which says cpu here
     monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
     layers, pages, n_pad, s_max, steps = 4, 2049, 8, 64, 8
-    max_pages = MAX_LEN // PAGE
     cfg = llama.LlamaConfig(
         vocab_size=V, hidden_size=H, intermediate_size=4 * H,
         num_layers=layers, num_heads=NH, num_kv_heads=NH,
         max_seq_len=MAX_LEN, dtype=BF16, remat=False, scan_layers=True)
-
-    def abstract(build):
-        return jax.tree.map(lambda a: shaped(a.shape, a.dtype),
-                            jax.eval_shape(build))
-
-    params = abstract(lambda: llama.init_params(cfg, jax.random.PRNGKey(0),
-                                                dtype=BF16))
-    pool = abstract(lambda: llama.init_paged_pool(cfg, pages, PAGE))
-    engine = types.SimpleNamespace(        # all the builder reads of one
-        cfg=cfg, slots=SLOTS, eos=None,
-        pager=types.SimpleNamespace(max_pages=max_pages))
-    segment = ServingEngine._build_paged_segment_prog(engine, n_pad, s_max,
-                                                      steps)
-    vec = shaped((SLOTS,), I32)
-    req = shaped((n_pad,), I32)
-    compiled = segment.lower(
-        params, pool, shaped((SLOTS, max_pages), I32), vec, vec, vec,
-        shaped((n_pad, s_max), I32), req, req, req,
-        shaped((n_pad, max_pages), I32), shaped((), I32)).compile()
+    compiled = _compiled_segment(shaped, llama, cfg, SLOTS, MAX_LEN // PAGE,
+                                 pages, n_pad, s_max, steps)
     text = compiled.as_text()
     # one kernel a call site: the admit branch's and the decode branch's
     # layer scan, whatever the table's width and the pages a block
-    kernels = re.findall(r"%(ragged_paged_attention[.\d]*) = [^\n]*"
-                         r"custom_call_target=\"tpu_custom_call\"", text)
+    kernels = _kernel_call_sites(text, "ragged_paged_attention")
     assert len(kernels) == 2, f"paged kernel call sites: {kernels}"
 
     layer_elems = pages * PAGE * NH * D
-    moved = []
-    for dims, op in re.findall(
-            r"= bf16\[([\d,]+)\]\S* (copy|copy-start|reshape|dynamic-slice|"
-            r"dynamic-update-slice)\(", text):
-        n = 1
-        for d in dims.split(","):
-            n *= int(d)
-        if n >= layer_elems:
-            moved.append((op, dims))
+    moved = _moved(text, layer_elems)
     assert not moved, f"the compiled segment moves the pool: {moved}"
     plane_bytes = layers * layer_elems * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < plane_bytes, \
         f"temporaries {temp} B hold a pool plane ({plane_bytes} B)"
+
+
+def test_latent_segment_holds_pool_once(shaped, no_persistent_cache,
+                                        monkeypatch):
+    """``test_paged_segment_holds_pool_once``' latent twin, at cell 4's
+    size: ``('pseg', 128, 512, 32)`` of openPangu-Ultra-MoE's share (1
+    dense + 4 expert layers at the published widths, 16 held experts, 128
+    slots x 96 page slots) around ``latent_moe.forward_with_pages``, both
+    Mosaic kernels chosen. ``mla_paged_attention`` is handed the latent
+    plane once, in HBM: no copy / reshape / slice as large as ONE LAYER of
+    it in the compiled text, temporaries under the plane, and four call
+    sites (the dense layer and the expert layers' scan, in the admit and
+    the decode branch) whatever the table's width."""
+    from paddle_tpu.models import latent_moe
+    from paddle_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    slots, max_len, n_pad, s_max, steps = 128, 1536, 128, 512, 32
+    max_pages = max_len // PAGE
+    pages = slots * max_pages + 1
+    cfg = latent_moe.LatentMoEConfig(
+        num_layers=5, first_k_dense=1, held_experts=(0, 16),
+        vocab_slice=(0, 19200), max_seq_len=max_len)
+    compiled = _compiled_segment(shaped, latent_moe, cfg, slots, max_pages,
+                                 pages, n_pad, s_max, steps)
+    text = compiled.as_text()
+    kernels = _kernel_call_sites(text, "mla_paged_attention")
+    assert len(kernels) == 4, f"latent kernel call sites: {kernels}"
+
+    layer_elems = pages * PAGE * cfg.cache_row
+    moved = _moved(text, layer_elems, of_pages=pages)
+    assert not moved, f"the compiled segment moves the latent plane: {moved}"
+    plane_bytes = cfg.num_layers * layer_elems * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < plane_bytes, \
+        f"temporaries {temp} B hold the latent plane ({plane_bytes} B)"
 
 
 def test_canonical_paged_segment_is_the_parents_program():

@@ -174,6 +174,32 @@ def test_kernels_interpreted_match_reference_and_fallback(tiny):
             np.testing.assert_allclose(a, c, rtol=2e-3, atol=2e-3)
 
 
+def mla_dense(q, plane, table, ctx):
+    """``o_lat`` of every query row at its own position, float64: the
+    slot's pages gathered, one softmax over the whole table."""
+    q, plane = np.asarray(q, np.float64), np.asarray(plane, np.float64)
+    B, Tq = q.shape[:2]
+    rows = plane[np.asarray(table)].reshape(B, -1, plane.shape[-1])
+    s = np.einsum("bthc,bwc->bthw", q, rows)
+    posn = np.asarray(ctx)[:, None] + np.arange(Tq)[None]
+    seen = np.arange(rows.shape[1])[None, None] <= posn[:, :, None]
+    s = np.where(seen[:, :, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return p / p.sum(-1, keepdims=True), rows
+
+
+def assert_mla_rows(out, want, qlen, TB, tol=1e-4):
+    """The kernel's contract a slot: live rows are the reference's, the
+    padding rows of a live block are finite, blocks past ``q_len`` zeros."""
+    out = np.asarray(out, np.float32)
+    for b, n in enumerate(np.asarray(qlen)):
+        n, whole = int(n), -(-int(n) // TB) * TB
+        np.testing.assert_allclose(out[b, :n], want[b, :n], rtol=tol,
+                                   atol=tol)
+        assert np.isfinite(out[b, n:whole]).all()
+        assert not out[b, whole:].any()
+
+
 def test_mla_kernel_mixed_chunks_against_dense():
     """Decode ticks and chunks in one launch; a slot whose chunk is all
     padding past a block gets zeros there."""
@@ -189,20 +215,167 @@ def test_mla_kernel_mixed_chunks_against_dense():
     out = mla_attention.mla_paged_attention(
         q, pool, jnp.asarray(table), jnp.asarray(ctx), jnp.asarray(qlen),
         layer=jnp.int32(1), rank=R, interpret=True)
-    rows = np.asarray(pool)[1][table].reshape(B, max_pages * psz, W)
-    s = np.einsum("bthc,bwc->bthw", np.asarray(q), rows)
-    posn = ctx[:, None] + np.arange(Tq)[None]
-    seen = np.arange(rows.shape[1])[None, None] <= posn[:, :, None]
-    s = np.where(seen[:, :, None, :], s, -np.inf)
-    p = np.exp(s - s.max(-1, keepdims=True))
-    want = np.einsum("bthw,bwr->bthr", p / p.sum(-1, keepdims=True),
-                     rows[..., :R])
-    TB = mla_attention.QUERY_ROWS // nH
-    for b in range(B):
-        live = -(-int(qlen[b]) // TB) * TB      # whole blocks computed
-        np.testing.assert_allclose(np.asarray(out)[b, :live], want[b, :live],
-                                   rtol=1e-4, atol=1e-4)
-        assert not np.asarray(out)[b, live:].any()
+    p, rows = mla_dense(q, pool[1], table, ctx)
+    want = np.einsum("bthw,bwr->bthr", p, rows[..., :R])
+    assert_mla_rows(out, want, qlen, mla_attention.QUERY_ROWS // nH)
+
+
+class TestFetchedPages:
+    """``mla_paged_attention`` copies by hand the pages a query block can
+    see, ``_block_pages`` a block: parity with the dense reference at
+    every edge of a page, a block and the table, with every other page
+    poisoned (``tests/test_paged_kv.py::TestFetchedPages``' twin)."""
+
+    nH, W, R, psz, max_pages = 8, 256, 128, 8, 40
+
+    @staticmethod
+    def pages_needed(ctx, qlen, block, TB, psz, max_pages):
+        """Pages query block ``block`` of a slot can see: up to the page
+        of its last LIVE position, inside the table; none past ``qlen``."""
+        end = ctx + np.minimum((block + 1) * TB, qlen) - 1
+        return (np.minimum(end // psz, max_pages - 1) + 1) \
+            * (block * TB < qlen)
+
+    def test_block_follows_the_shapes(self):
+        """512 key rows a block under a decode tick's 128 query rows, 256
+        under an admission's 512, never more than the table names."""
+        assert mla_attention._block_pages(16, 96, 128) == 32
+        assert mla_attention._block_pages(16, 96, 512) == 16
+        assert mla_attention._block_pages(8, 96, 128) == 64
+        assert mla_attention._block_pages(16, 6, 128) == 6
+        assert mla_attention._block_pages(1024, 6, 128) == 1
+
+    @pytest.mark.parametrize("Tq", [1, 64, 256])
+    def test_pages_past_a_blocks_need_are_never_read(self, Tq):
+        """Every page a slot's LAST live query block need not see is NaN,
+        the trash page and the pages nobody names too: a copy of one
+        would poison the output through ``0 * NaN``. Slots with ``q_len``
+        0, ``q_len`` < Tq, a context that ends on a page's edge, on a
+        block's edge and at the table's end, one launch."""
+        rng = np.random.RandomState(Tq)
+        nH, W, R, psz, mp = self.nH, self.W, self.R, self.psz, self.max_pages
+        TB = max(1, min(Tq, mla_attention.QUERY_ROWS // nH))
+        blk = psz * mla_attention._block_pages(psz, mp, TB * nH)
+        # (context, live rows): the chunk's last live position is ...
+        qlen = np.array([Tq, 0, 1, Tq, max(Tq // 2, 1), Tq, Tq,
+                         min(Tq, 3), Tq])
+        end = np.array([0, 50, psz - 1,          # one short of a page edge
+                        psz, 3 * psz,            # on a page edge
+                        blk - 1, blk,            # round a block edge
+                        mp * psz - 1,            # the table's end, padding
+                        mp * psz - 1])           # rows past it; and full
+        ctx = np.maximum(end - (qlen - 1), 0)
+        B = len(ctx)
+        P = 1 + B * mp + 3
+        table = 1 + rng.permutation(B * mp).reshape(B, mp)
+        plane = rng.standard_normal((P, psz, W)).astype(np.float32)
+        q = jnp.asarray(rng.standard_normal((B, Tq, nH, W)) * 0.1,
+                        jnp.float32)
+        p, rows = mla_dense(q, plane, table, ctx)
+        want = np.einsum("bthw,bwr->bthr", p, rows[..., :R])
+        poisoned = plane.copy()
+        poisoned[0] = poisoned[1 + B * mp:] = np.nan
+        last = np.maximum(-(-qlen // TB) - 1, 0)
+        held = self.pages_needed(ctx, qlen, last, TB, psz, mp)
+        for b in range(B):
+            poisoned[table[b, held[b]:]] = np.nan
+        assert held[1] == 0 and held[-1] == mp and np.isnan(
+            poisoned[table[1]]).all()
+        out = mla_attention.mla_paged_attention(
+            q, jnp.asarray(poisoned)[None], jnp.asarray(table, jnp.int32),
+            jnp.asarray(ctx, jnp.int32), jnp.asarray(qlen, jnp.int32),
+            rank=R, interpret=True)
+        assert_mla_rows(out, want, qlen, TB)
+
+    def test_each_query_block_fetches_only_its_own_pages(self):
+        """A chunk of several query blocks: block ``k`` is computed from a
+        pool whose pages past block ``k``'s need are NaN (one slot, so
+        nothing that ran before it saw them) — its live rows still match:
+        no block reads what only a later block of its slot may see."""
+        rng = np.random.RandomState(5)
+        nH, W, R, psz, mp = self.nH, self.W, self.R, self.psz, self.max_pages
+        Tq, TB = 256, mla_attention.QUERY_ROWS // self.nH
+        ctx, qlen = np.array([13]), np.array([200])
+        table = 1 + rng.permutation(mp).reshape(1, mp)
+        plane = rng.standard_normal((1 + mp, psz, W)).astype(np.float32)
+        q = jnp.asarray(rng.standard_normal((1, Tq, nH, W)) * 0.1,
+                        jnp.float32)
+        p, rows = mla_dense(q, plane, table, ctx)
+        want = np.einsum("bthw,bwr->bthr", p, rows[..., :R])
+        for k in range(-(-int(qlen[0]) // TB)):
+            poisoned = plane.copy()
+            held = self.pages_needed(ctx, qlen, k, TB, psz, mp)
+            poisoned[table[0, held[0]:]] = np.nan
+            out = np.asarray(mla_attention.mla_paged_attention(
+                q, jnp.asarray(poisoned)[None],
+                jnp.asarray(table, jnp.int32), jnp.asarray(ctx, jnp.int32),
+                jnp.asarray(qlen, jnp.int32), rank=R, interpret=True))
+            live = slice(k * TB, min((k + 1) * TB, int(qlen[0])))
+            np.testing.assert_allclose(out[0, live], want[0, live],
+                                       rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("Tq", [1, 128])
+    def test_stacked_bf16_pool_with_a_traced_layer(self, Tq):
+        """The call of the model's layer scan: the whole [L, P, psz, W]
+        bf16 plane, the layer a traced scalar, under jit."""
+        rng = np.random.RandomState(3 + Tq)
+        nH, W, R, psz, mp = self.nH, self.W, self.R, self.psz, self.max_pages
+        L, B = 3, 5
+        table = jnp.asarray(1 + rng.permutation(B * mp).reshape(B, mp),
+                            jnp.int32)
+        ctx = jnp.asarray([0, 130, 15, 190, 63], jnp.int32)
+        qlen = jnp.asarray(rng.randint(0, Tq + 1, size=B), jnp.int32)
+        q = jnp.asarray(rng.standard_normal((B, Tq, nH, W)) * 0.1,
+                        jnp.bfloat16)
+        pool = jnp.asarray(rng.standard_normal((L, 1 + B * mp, psz, W)),
+                           jnp.bfloat16)
+        call = jax.jit(lambda lay: mla_attention.mla_paged_attention(
+            q, pool, table, ctx, qlen, layer=lay, rank=R, interpret=True))
+        for lay in (0, 2):
+            p, rows = mla_dense(q, pool[lay], table, ctx)
+            want = np.einsum("bthw,bwr->bthr", p, rows[..., :R])
+            # bf16 probabilities and output: 2^-8 of values of order 1
+            assert_mla_rows(call(jnp.int32(lay)), want, qlen,
+                            max(1, min(Tq, mla_attention.QUERY_ROWS // nH)),
+                            tol=2e-2)
+
+    @staticmethod
+    def kernel_equations(Tq, psz, max_pages, B=4):
+        """Equations in the kernel's body, inner jaxprs (the rolled
+        loops, ``pl.when`` branches) included: what every start traces
+        and lowers, cache hit or not."""
+        S = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(lambda *a: mla_attention.mla_paged_attention(
+            *a, layer=jnp.int32(1), rank=512))(
+                S((B, Tq, 128, 640), jnp.bfloat16),
+                S((3, 1 + B * max_pages, psz, 640), jnp.bfloat16),
+                S((B, max_pages), jnp.int32), S((B,), jnp.int32),
+                S((B,), jnp.int32))
+
+        def count(jp):
+            n = 0
+            for eqn in jp.eqns:
+                n += 1
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    n += count(sub)
+            return n
+
+        calls = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        return count(calls[0].params["jaxpr"])
+
+    @pytest.mark.parametrize("Tq", [1, 512])
+    def test_traced_body_does_not_grow_with_the_table_or_the_block(self,
+                                                                    Tq):
+        """Set-up is traced and lowered at every start: the kernel's
+        body is the same size whatever the table's width (16, 96, 128
+        page slots) and whatever the block (4 to 32 pages), and small."""
+        base = self.kernel_equations(Tq, 16, 96)
+        assert base < 300      # 281: 4 copies a trip at 3 copy sites
+        for psz, max_pages in ((16, 16), (16, 128), (8, 96), (64, 96)):
+            assert self.kernel_equations(Tq, psz, max_pages) == base, \
+                (psz, max_pages)
 
 
 def test_grouped_matmul_skips_absent_and_unpicked_experts():
