@@ -2,11 +2,27 @@
 
 Reference counterpart: vLLM's PagedAttention block manager and the
 Ragged Paged Attention TPU serving design (PAPERS.md #1): instead of one
-contiguous ``[slots, max_len]`` KV block per engine — provisioned for
-the WORST-CASE length of every slot — KV rows live in one flat pool of
-fixed-size pages (``[L, num_pages, page_size, Hkv*D]``) and each slot's
-sequence is the ordered list of pages its page table names. Three
-consequences, each a serving-memory property the contiguous layout
+contiguous ``[slots, max_len]`` block per engine — provisioned for the
+WORST-CASE length of every slot — what a sequence keeps on the device
+lives in one flat pool of fixed-size pages (axis 1 of every plane is the
+page axis) and each slot's sequence is the ordered list of pages its page
+table names. **What a page holds is the model's** (``models.family_of``:
+its ``init_paged_pool``), one of three today:
+
+* ``llama``: ``page_size`` K rows and V rows of every layer, two planes
+  ``[L, num_pages, page_size, Hkv*D]`` (plus scale planes when quantized);
+* ``latent_moe``: ``page_size`` latent rows ``[ckv | kr]`` of every layer,
+  one plane ``[L, num_pages, page_size, row]``;
+* ``power_retention``: ONE SEQUENCE'S RECURRENT STATE in every layer,
+  whatever its length: ``{"s": [L, num_pages, Hkv, D, d], "z": [L,
+  num_pages, Hkv, D]}`` in float32. Its engine is built with ``page_size =
+  max_len``, which makes a page a sequence: ``max_pages`` is 1, the pool is
+  ``slots + 1`` pages, ``pages_needed`` is 1 for every request, a slot's
+  table is one page id, and ``ensure_writable``'s page-granular copy is a
+  snapshot of a state. A freed state page is handed to the next request as
+  it is: the model starts from zero at position 0 whatever the page holds.
+
+Three consequences, each a serving-memory property the contiguous layout
 cannot express:
 
 * **The ``max_len`` provisioning wall is gone.** A slot's physical
@@ -167,14 +183,15 @@ class PageAllocator:
 class PagedKVCache:
     """Device page pool + per-slot page tables over a ``PageAllocator``.
 
-    The serving engine's paged memory: ``pool`` is the flat
-    ``[L, num_pages, page_size, Hkv*D]`` K/V store and ``page_table``
-    the device-side ``[slots, max_pages]`` int32 map the segment program
-    consumes (both donated through the program and updated in place;
-    everything here addresses pages on axis 1 and never looks past the
-    page axis, so the row layout is the model's ``init_paged_pool``'s alone;
-    the host keeps
-    ``slot_pages`` mirrors for bookkeeping). ``max_pages`` bounds ONE
+    The serving engine's paged memory: ``pool`` is the model's planes
+    (``init_paged_pool``: K/V rows, latent rows, or — with ``page_size =
+    max_len`` — a sequence's recurrent state a page; the module's text
+    says which) and ``page_table`` the device-side ``[slots, max_pages]``
+    int32 map the segment program consumes (both donated through the
+    program and updated in place; everything here addresses pages on
+    axis 1 and never looks past the page axis, so what a page holds is
+    the model's alone; the host keeps ``slot_pages`` mirrors for
+    bookkeeping). ``max_pages`` bounds ONE
     slot's virtual length (``max_pages * page_size`` = the engine's
     ``max_len`` contract); ``num_pages`` bounds the POOL — sizing it
     below ``slots * max_pages`` is the whole point (admission degrades
@@ -314,7 +331,8 @@ class PagedKVCache:
             return page
         new = self.allocator.alloc(1)[0]
         # every pool plane copies at page granularity (K/V rows AND any
-        # quantization scale rows — axis 1 is the page axis in all of them)
+        # quantization scale rows, or a state page's S and z — axis 1 is
+        # the page axis in all of them)
         self.pool = {n: a.at[:, new].set(a[:, page])
                      for n, a in self.pool.items()}
         self.allocator.release([page])

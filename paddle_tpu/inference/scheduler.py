@@ -203,6 +203,10 @@ class OnlineReport:
     # that landed on held experts, held experts hit, the largest load of
     # one expert in a step), beside the loop steps they were counted over
     moe: Optional[Dict[str, int]] = None
+    # PR 34: the same of a power-retention model (``COUNTER_GROUP``
+    # "retention": state pages its ticks updated, its admissions' bucket
+    # rows and those of them that were the prompt's)
+    retention: Optional[Dict[str, int]] = None
     # PR 31: a paged engine's ``page_slots`` (rows x table width of every
     # paged attention call, a layer) and ``pages_fetched`` (the pages
     # those rows hold: ``pages_read``) over the serve's segments
@@ -505,8 +509,9 @@ class OnlineScheduler:
                 for name, (ns, c) in phases.items()},
             ttft_parts_mean_s=({k: sum(p[k] for p in parts) / len(parts)
                                 for k in TTFT_PARTS} if parts else None),
-            moe=(dict(eng.segment_counts, steps=eng.last_run_ticks)
-                 if eng.segment_counts else None),
+            **({eng.model.COUNTER_GROUP: dict(
+                eng.segment_counts, steps=eng.last_run_ticks)}
+               if eng.segment_counts else {}),
             page_reads=dict(eng.segment_pages) or None,
             **self._report_extras(reqs),
             per_request=[{

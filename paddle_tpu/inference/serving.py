@@ -585,8 +585,9 @@ class ServingEngine:
         # reduces it into ``OnlineReport.segment_phases``
         self.seg_index = 0
         self.segment_phases: Dict[str, list] = {}
-        # PR 29: sums of the model's per-step counters (``serving.moe.*``)
-        # over the segments since the serve loop last reset the dict
+        # PR 29: sums of the model's per-step counters (``serving.<its
+        # COUNTER_GROUP>.*``: ``moe``, ``retention``) over the segments
+        # since the serve loop last reset the dict
         self.segment_counts: Dict[str, int] = {}
         # PR 31: page slots the paged attention calls of those segments
         # were handed (rows x table width a step) and the pages they had
@@ -1225,20 +1226,21 @@ class ServingEngine:
 
     def _count_telemetry(self, counts) -> Dict[str, int]:
         """One segment's ``SEGMENT_COUNTERS`` ([steps, n] int32, fetched
-        with the tokens) into the ``serving.moe.*`` counters and
-        ``segment_counts``: sums over the steps, a ``max_*`` column its
-        maximum. Returns the segment's own."""
+        with the tokens) into the ``serving.<group>.*`` counters (the
+        model's ``COUNTER_GROUP``) and ``segment_counts``: sums over the
+        steps, a ``max_*`` column its maximum. Returns the segment's own."""
         seg = {}
+        group = f"serving.{self.model.COUNTER_GROUP}"
         for j, name in enumerate(self.model.SEGMENT_COUNTERS):
             col = counts[:, j]
             if name.startswith("max_"):
                 seg[name] = v = int(col.max(initial=0))
-                _metrics.gauge(f"serving.moe.{name}").set(v)
+                _metrics.gauge(f"{group}.{name}").set(v)
                 self.segment_counts[name] = max(
                     self.segment_counts.get(name, 0), v)
             else:
                 seg[name] = v = int(col.sum())
-                _metrics.counter(f"serving.moe.{name}").inc(v)
+                _metrics.counter(f"{group}.{name}").inc(v)
                 self.segment_counts[name] = \
                     self.segment_counts.get(name, 0) + v
         return seg

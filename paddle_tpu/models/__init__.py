@@ -10,8 +10,10 @@ eager/Layer-API model zoo lives in ``paddle_tpu.vision.models`` and the
 from . import bert  # noqa: F401
 from . import latent_moe  # noqa: F401
 from . import llama  # noqa: F401
+from . import power_retention  # noqa: F401
 
-__all__ = ["bert", "llama", "latent_moe", "family_of", "require"]
+__all__ = ["bert", "llama", "latent_moe", "power_retention", "family_of",
+           "require"]
 
 
 def family_of(cfg):
@@ -21,6 +23,8 @@ def family_of(cfg):
     ``forward_with_pages`` (and builds weights with its ``init_params``)."""
     if isinstance(cfg, latent_moe.LatentMoEConfig):
         return latent_moe
+    if isinstance(cfg, power_retention.PowerRetentionConfig):
+        return power_retention
     return llama
 
 

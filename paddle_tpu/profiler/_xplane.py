@@ -52,6 +52,7 @@ _HLO_RE = re.compile(r"=\s*\S+\s+([a-zA-Z][\w-]*)\(")
 # and drops jax's own (jit(..), while, body, cond, branch_N_fun, ...).
 SCOPES = ("embed", "qkv", "kv_write", "attention", "post", "head", "sample",
           "latent_qkv", "router", "experts", "shared_expert", "dense_ffn",
+          "retention_qkv", "gate", "retention", "ffn",
           "segment.admit", "segment.decode",
           "loss", "head_ce", "grad_clip", "optimizer")
 

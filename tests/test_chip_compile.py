@@ -9,13 +9,15 @@ below lowers one kernel at ``LlamaConfig.bert_base_equiv`` widths (H=768,
 ``tpu_custom_call`` in the compiled text. Nothing runs: these say a kernel
 compiles, never that it is right or fast.
 
-Two cases compile a whole program: ``test_paged_segment_holds_pool_once``
+Three cases compile a whole program: ``test_paged_segment_holds_pool_once``
 lowers the paged segment loop (admit and decode steps around
 ``llama.forward_with_pages``) and reads the compiled text and
 ``memory_analysis()`` for copies of the KV pool, which tier-1 cannot see
 otherwise: they cost two thirds of a serve step before PR 26 (PERF.md);
 ``test_latent_segment_holds_pool_once`` does the same for the latent
-family's plane at the benchmark's own size.
+family's plane at the benchmark's own size, and
+``test_retention_segment_holds_pool_once`` for the power-retention family's
+state pages.
 
 The topology is described inside a fixture (loading the TPU's library at
 import would break collection under several workers) and everything is
@@ -214,7 +216,28 @@ def _grouped_experts(tokens, swiglu):
     return build
 
 
+def _retention_decode(slots):
+    """``power_retention_decode``'s kernel at Brumby-14B's widths: 8 kv
+    heads x 5 query heads x 128, the float32 state plane of 8 layers x
+    (slots + 1) pages of ``[8, 9216, 128]`` handed once and aliased to the
+    output: the benchmark's full house, and a replay's four sequences."""
+    from paddle_tpu.ops.pallas import power_retention as op
+
+    def build(S):
+        plane = S((8, slots + 1, 8, 9216, 128), F32)
+        return (lambda q, k, v, dec, s, page, live, fresh, lay:
+                op._decode_kernel(q, k, v, dec, s, page, live, fresh, lay,
+                                  False)), (
+                S((slots, 8, 5, 128), BF16), S((slots, 8, 128), BF16),
+                S((slots, 8, 128), BF16), S((slots, 8), F32), plane,
+                S((slots,), I32), S((slots,), jnp.bool_),
+                S((slots,), jnp.bool_), S((), I32))
+    return build
+
+
 CASES = {
+    "power_retention_decode_16slots": _retention_decode(16),
+    "power_retention_decode_4slots": _retention_decode(4),
     "mla_paged_decode_128slots": _mla_paged(128, 1),
     "mla_paged_admit_tq512": _mla_paged(1, 512),
     "grouped_experts_gate_up_t128": _grouped_experts(128, True),
@@ -372,6 +395,52 @@ def test_latent_segment_holds_pool_once(shaped, no_persistent_cache,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < plane_bytes, \
         f"temporaries {temp} B hold the latent plane ({plane_bytes} B)"
+
+
+def test_retention_segment_holds_pool_once(shaped, no_persistent_cache,
+                                           monkeypatch):
+    """The same guard for state pages, at ``brumby-14b-l8``'s size:
+    ``('pseg', 16, 1024, 32)`` of 8 power-retention layers at the published
+    widths (16 slots, ``page_size = max_len``: one page a slot) around
+    ``power_retention.forward_with_pages``. The ``s`` plane (4.87 GB with
+    the trash page's 0.30) is handed to ``power_retention_decode`` once, in
+    HBM, aliased to its output; the admission writes its page in place: no
+    float32 copy / reshape / slice as large as ONE LAYER of the plane in
+    the compiled text, temporaries under ONE plane, arguments + temporaries
+    inside the chip's 16 GiB, one kernel call site (the layer scan's, in
+    the decode branch)."""
+    from paddle_tpu.models import power_retention
+    from paddle_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    slots, max_len, n_pad, s_max, steps = 16, 2048, 16, 1024, 32
+    cfg = power_retention.PowerRetentionConfig(num_layers=8,
+                                               max_seq_len=max_len)
+    pages = slots + 1
+    compiled = _compiled_segment(shaped, power_retention, cfg, slots, 1,
+                                 pages, n_pad, s_max, steps)
+    text = compiled.as_text()
+    kernels = _kernel_call_sites(text, "power_retention_decode")
+    assert len(kernels) == 1, f"decode kernel call sites: {kernels}"
+
+    layer_elems = pages * cfg.num_kv_heads * cfg.state_width * cfg.head_dim
+    moved = []
+    for dims, op in re.findall(
+            r"= f32\[([\d,]+)\]\S* (copy|copy-start|reshape|dynamic-slice)"
+            r"\(", text):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d)
+        if n >= layer_elems:
+            moved.append((op, dims))
+    assert not moved, f"the compiled segment moves the state plane: {moved}"
+    plane_bytes = cfg.num_layers * layer_elems * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < plane_bytes, \
+        f"temporaries {mem.temp_size_in_bytes} B hold the state plane " \
+        f"({plane_bytes} B)"
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+    assert mem.alias_size_in_bytes >= plane_bytes    # donated, in place
 
 
 def test_canonical_paged_segment_is_the_parents_program():

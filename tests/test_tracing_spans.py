@@ -407,7 +407,7 @@ class TestNames:
                     kw = {k.arg: k.value for k in node.keywords}
                     assert "name" in kw, (path, node.lineno)
                     sites.append(kw["name"])
-        assert len(sites) == 15
+        assert len(sites) == 16
         fixed = sorted(n.value for n in sites if isinstance(n, ast.Constant))
         assert fixed == sorted([
             "flash_attention_fwd", "flash_attention_bwd_dq",
@@ -416,7 +416,7 @@ class TestNames:
             "fused_add_rms_norm", "fused_rope_qk", "quant_matmul",
             "ragged_paged_attention", "ragged_decode_attention",
             "head_dx_softmax", "mla_paged_attention",
-            "grouped_expert_matmul"])
+            "grouped_expert_matmul", "power_retention_decode"])
         assert [ast.unparse(n) for n in sites
                 if not isinstance(n, ast.Constant)] == \
             ["'apply_flat_update_' + kind"]
