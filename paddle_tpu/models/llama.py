@@ -377,6 +377,17 @@ def layer_params(params, cfg: "LlamaConfig"):
     return out
 
 
+def _qkv_dots(cfg: LlamaConfig, h, lp, dt):
+    """The three projections of normed rows ``h`` [..., H], flat:
+    [..., nH*D], [..., Hkv*D], [..., Hkv*D]."""
+    if not cfg.fused_weights:
+        return tuple(_mm(h, lp, n, dt) for n in ("wq", "wk", "wv"))
+    Hq = cfg.num_heads * cfg.head_dim
+    Hkv = cfg.num_kv_heads * cfg.head_dim
+    z = _mm(h, lp, "wqkv", dt)
+    return z[..., :Hq], z[..., Hq:Hq + Hkv], z[..., Hq + Hkv:]
+
+
 @scoped("qkv")
 def _qkv_proj(cfg: LlamaConfig, x, lp, positions=None):
     """rms → q/k/v projections → rope at ``positions`` (default 0..S-1).
@@ -387,16 +398,8 @@ def _qkv_proj(cfg: LlamaConfig, x, lp, positions=None):
     dt = x.dtype
     if positions is None:
         positions = jnp.arange(S)
-    h = _rms_norm(x, lp["ln_attn"], cfg.rms_eps)
-    Hq = cfg.num_heads * cfg.head_dim
-    Hkv = cfg.num_kv_heads * cfg.head_dim
-    if cfg.fused_weights:
-        z = _mm(h, lp, "wqkv", dt)
-        zq, zk, zv = (z[..., :Hq], z[..., Hq:Hq + Hkv], z[..., Hq + Hkv:])
-    else:
-        zq = _mm(h, lp, "wq", dt)
-        zk = _mm(h, lp, "wk", dt)
-        zv = _mm(h, lp, "wv", dt)
+    zq, zk, zv = _qkv_dots(
+        cfg, _rms_norm(x, lp["ln_attn"], cfg.rms_eps), lp, dt)
     if "qkv" in cfg.bwd_barriers:
         zq, zk, zv = map(_barrier_grad, (zq, zk, zv))
     q = zq.reshape(B, S, cfg.num_heads, cfg.head_dim)
@@ -927,30 +930,36 @@ def _tick_fused_active(cfg: LlamaConfig) -> bool:
 
 
 @scoped("qkv")
-def _decode_qkv(cfg: LlamaConfig, x, lp, pos_b):
-    """T=1 fused-tick variant of ``_qkv_proj``: the rmsnorm chain is one
-    Pallas op and the q/k rope chains (cos/sin/slice/concat per head,
-    twice) collapse into one shared-cos/sin kernel. Same math — the
-    projections themselves stay XLA dots (they carry the weight stream
-    the tick is roofline-bound on)."""
+def _rows_qkv(cfg: LlamaConfig, x, lp, positions):
+    """``_qkv_proj`` over flat rows, for the paths whose fused kernels are
+    active (``_tick_fused_active``): x [B, T, H] is B*T rows, each at its
+    own ``positions[b, t]`` — a tick's slots, an admission's or a chunk's
+    positions alike. The rmsnorm chain is one Pallas op and the q/k rope
+    chains (cos/sin/slice/concat per head, twice) collapse into one
+    shared-cos/sin kernel. Same math — the projections themselves stay
+    XLA dots (they carry the weight stream the tick is roofline-bound
+    on), and with no rope chain for XLA to fuse into them the stacked
+    ``wq`` / ``wk`` are read in the layout they lie in at every T: a
+    segment program whose admit arm ropes in XLA and whose decode arm
+    ropes here copies both stacks whole, once a step (PR 35)."""
     from ..ops.pallas.tick_fusion import fused_rms_norm, fused_rope_qk
 
-    B = x.shape[0]
+    B, T, H = x.shape
+    rows = B * T
     dt = x.dtype
-    h = fused_rms_norm(x[:, 0], lp["ln_attn"], cfg.rms_eps)
-    Hq = cfg.num_heads * cfg.head_dim
-    Hkv = cfg.num_kv_heads * cfg.head_dim
-    if cfg.fused_weights:
-        z = _mm(h, lp, "wqkv", dt)
-        zq, zk, zv = (z[..., :Hq], z[..., Hq:Hq + Hkv], z[..., Hq + Hkv:])
-    else:
-        zq = _mm(h, lp, "wq", dt)
-        zk = _mm(h, lp, "wk", dt)
-        zv = _mm(h, lp, "wv", dt)
-    zq, zk = fused_rope_qk(zq, zk, pos_b, cfg.head_dim, cfg.rope_theta)
-    q = zq.reshape(B, 1, cfg.num_heads, cfg.head_dim)
-    k = zk.reshape(B, 1, cfg.num_kv_heads, cfg.head_dim)
-    v = zv.reshape(B, 1, cfg.num_kv_heads, cfg.head_dim)
+    h = fused_rms_norm(x.reshape(rows, H), lp["ln_attn"], cfg.rms_eps)
+    if T > 1:
+        # 3-D for ``_mm``: of narrow weights only a tick's few rows go to
+        # quant_matmul (one block holds them all), an admission's take
+        # the dense dequantize, as they did through ``_qkv_proj``
+        h = h.reshape(B, T, H)
+    zq, zk, zv = _qkv_dots(cfg, h, lp, dt)
+    zq, zk = fused_rope_qk(zq.reshape(rows, -1), zk.reshape(rows, -1),
+                           positions.reshape(rows), cfg.head_dim,
+                           cfg.rope_theta)
+    q = zq.reshape(B, T, cfg.num_heads, cfg.head_dim)
+    k = zk.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    v = zv.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
     return q, k, v
 
 
@@ -1007,7 +1016,7 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache, pos,
             (B,)).astype(jnp.int32)
 
     def _qkv(x, lp):
-        return (_decode_qkv(cfg, x, lp, pos_b) if fused_tick
+        return (_rows_qkv(cfg, x, lp, pos_b) if fused_tick
                 else _qkv_proj(cfg, x, lp, positions))
 
     def _post(x, attn, lp):
@@ -1216,14 +1225,17 @@ def forward_with_pages(params, tokens, cfg: LlamaConfig, pool, page_table,
     if quant:
         from ..quantization.serving import quantize_kv_rows
 
-    fused_tick = T == 1 and _tick_fused_active(cfg)
+    # ONE qkv formulation for a tick and an admission alike, so that the
+    # segment program's two arms read wq / wk in one layout
+    fused = _tick_fused_active(cfg)
+    qkv = _rows_qkv if fused else _qkv_proj
+    fused_tick = fused and T == 1
     # a retired slot's output is dropped by every caller: the paged
     # kernel fetches none of its pages
     q_len = None if live is None else jnp.where(live, T, 0)
 
     def layer(x, planes, lp, i):
-        q, k_new, v_new = (_decode_qkv(cfg, x, lp, pos) if fused_tick
-                           else _qkv_proj(cfg, x, lp, positions))
+        q, k_new, v_new = qkv(cfg, x, lp, positions)
         with jax.named_scope("kv_write"):
             rows = {"k": k_new, "v": v_new}
             if quant:

@@ -9,8 +9,9 @@ tensor whose fixed per-op cost dwarfs its arithmetic. XLA will not fuse
 ACROSS these chains because the matmuls sit between them.
 
 These kernels collapse each between-matmul chain into ONE Pallas call
-(the tick's tensors are tiny — every kernel is a single grid cell wholly
-in VMEM):
+(the tick's tensors are tiny — a single grid cell wholly in VMEM; the
+rmsnorm and rope kernels also serve an admission's rows, and take a grid
+over row blocks once the rows outgrow one block, ``_row_grid``):
 
 - ``fused_rms_norm``      rmsnorm chain -> 1 op
 - ``fused_add_rms_norm``  residual add + next rmsnorm -> 1 op, 2 outputs
@@ -67,8 +68,40 @@ def tick_fusion_active(hidden_size: int) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# rmsnorm (+ residual add) — one kernel per chain, [B, H] single block
+# rmsnorm (+ residual add) — one kernel per chain over [rows, H]
 # ---------------------------------------------------------------------------
+
+# what one grid step's input and output blocks may hold. A tick's rows and
+# an admission of 256 rows at H 2048 (3 MB in fused_rope_qk) are one block;
+# 1,024 rows at Mistral's 4,096 (20 MB) are eight. The kernel's fp32
+# temporaries and the pipeline's second buffers come on top, under the
+# chip's 16 MB of scoped VMEM.
+_BLOCK_BYTES = 4 * 2**20
+
+
+def _row_grid(rows: int, itemsize: int, ins, outs, whole=()):
+    """``pallas_call``'s grid arguments for a kernel whose rows are
+    independent. ``ins``: the widths of its ``[rows, w]`` operands,
+    ``outs``: those of its results, shaped like ``out_shape`` (one width or
+    a list), ``whole``: the widths of ``[1, w]`` operands that follow
+    ``ins`` and every step reads. Nothing (one block, no grid: the tick's
+    call) while the blocks fit ``_BLOCK_BYTES``, else a grid over
+    power-of-two row blocks; a ragged last block reads padding and its
+    writes are dropped."""
+    row_bytes = (sum(ins) + sum(jax.tree.leaves(outs))) * itemsize
+    fit = max(16, _BLOCK_BYTES // row_bytes)
+    if rows <= fit:
+        return {}
+    block = 1 << (fit.bit_length() - 1)
+
+    def rows_of(w):
+        return pl.BlockSpec((block, w), lambda i: (i, 0))
+
+    return dict(
+        grid=(pl.cdiv(rows, block),),
+        in_specs=[rows_of(w) for w in ins]
+        + [pl.BlockSpec((1, w), lambda i: (0, 0)) for w in whole],
+        out_specs=jax.tree.map(rows_of, outs))
 
 
 def _rms_kernel(eps):
@@ -82,14 +115,16 @@ def _rms_kernel(eps):
 
 
 def fused_rms_norm(x, w, eps: float):
-    """rmsnorm(x) * w as ONE op. x: [B, H]; w: [H]. Math matches
-    ``llama._rms_norm`` (fp32 mean-square, cast before the gain)."""
+    """rmsnorm(x) * w as ONE op. x: [B, H] (any number of rows); w: [H].
+    Math matches ``llama._rms_norm`` (fp32 mean-square, cast before the
+    gain)."""
     B, H = x.shape
     return pl.pallas_call(
         _rms_kernel(float(eps)),
         name="fused_rms_norm",
         out_shape=jax.ShapeDtypeStruct((B, H), x.dtype),
         interpret=_interp(),
+        **_row_grid(B, x.dtype.itemsize, (H,), H, whole=(H,)),
     )(x, jnp.broadcast_to(w, (1, H)))
 
 
@@ -125,17 +160,23 @@ def fused_add_rms_norm(x, y, w, eps: float):
 
 def _rope_qk_kernel(D, nq, nk, theta):
     half = D // 2
+    lax = jax.lax
 
+    # lax primitives, not jnp operators, on the values: under a trace every
+    # ``a * b`` / ``z[:, i:j]`` of jnp's is a jitted function traced anew,
+    # 153 of them a call site in this per-head loop (0.6 s of every start
+    # of the llama serve cells, PERF.md §6 PR 35); a primitive binds at once
     def rotate(z_ref, o_ref, nheads, cos, sin):
         z = z_ref[...]
-        dt = z.dtype
-        cos = cos.astype(dt)
-        sin = sin.astype(dt)
+        rows = z.shape[0]
+        cos = lax.convert_element_type(cos, z.dtype)
+        sin = lax.convert_element_type(sin, z.dtype)
         for h in range(nheads):
-            x1 = z[:, h * D:h * D + half]
-            x2 = z[:, h * D + half:(h + 1) * D]
-            o_ref[:, h * D:h * D + half] = x1 * cos - x2 * sin
-            o_ref[:, h * D + half:(h + 1) * D] = x1 * sin + x2 * cos
+            lo, mid, hi = h * D, h * D + half, (h + 1) * D
+            x1 = lax.slice(z, (0, lo), (rows, mid))
+            x2 = lax.slice(z, (0, mid), (rows, hi))
+            o_ref[:, lo:mid] = lax.sub(lax.mul(x1, cos), lax.mul(x2, sin))
+            o_ref[:, mid:hi] = lax.add(lax.mul(x1, sin), lax.mul(x2, cos))
 
     def kernel(pos_ref, q_ref, k_ref, oq_ref, ok_ref):
         B = q_ref.shape[0]
@@ -154,10 +195,11 @@ def _rope_qk_kernel(D, nq, nk, theta):
 
 def fused_rope_qk(zq, zk, pos, head_dim: int, theta: float):
     """Rope both projections in ONE op. zq: [B, nH*D]; zk: [B, Hkv*D];
-    pos: [B] int32 (each row at its own absolute position — the ragged
-    decode convention; broadcast a scalar for the shared-position path).
-    cos/sin are computed in-kernel from ``pos`` — the XLA chain's iota/
-    power/cos/sin/broadcast ops never exist as separate launches."""
+    pos: [B] int32 (each row at its own absolute position — a tick's
+    slots, or the flattened rows of an admission; broadcast a scalar for
+    the shared-position path). cos/sin are computed in-kernel from
+    ``pos`` — the XLA chain's iota/power/cos/sin/broadcast ops never
+    exist as separate launches."""
     B, Hq = zq.shape
     Hk = zk.shape[1]
     return pl.pallas_call(
@@ -167,6 +209,7 @@ def fused_rope_qk(zq, zk, pos, head_dim: int, theta: float):
         out_shape=[jax.ShapeDtypeStruct((B, Hq), zq.dtype),
                    jax.ShapeDtypeStruct((B, Hk), zk.dtype)],
         interpret=_interp(),
+        **_row_grid(B, zq.dtype.itemsize, (1, Hq, Hk), [Hq, Hk]),
     )(jnp.asarray(pos, jnp.int32).reshape(B, 1), zq, zk)
 
 

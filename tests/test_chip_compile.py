@@ -9,11 +9,14 @@ below lowers one kernel at ``LlamaConfig.bert_base_equiv`` widths (H=768,
 ``tpu_custom_call`` in the compiled text. Nothing runs: these say a kernel
 compiles, never that it is right or fast.
 
-Three cases compile a whole program: ``test_paged_segment_holds_pool_once``
+Four cases compile a whole program: ``test_paged_segment_holds_pool_once``
 lowers the paged segment loop (admit and decode steps around
 ``llama.forward_with_pages``) and reads the compiled text and
 ``memory_analysis()`` for copies of the KV pool, which tier-1 cannot see
 otherwise: they cost two thirds of a serve step before PR 26 (PERF.md);
+``test_llama_segment_reads_stacked_weights_where_they_lie`` reads the same
+program at a serve cell's own size for copies of a stacked weight (13 % of
+that step before PR 35);
 ``test_latent_segment_holds_pool_once`` does the same for the latent
 family's plane at the benchmark's own size, and
 ``test_retention_segment_holds_pool_once`` for the power-retention family's
@@ -136,18 +139,23 @@ def _paged_cell(slots, tq):
                   pages=2049, max_pages=64)
 
 
-def _tick(which):
+def _tick(which, rows=SLOTS, width=H, kv_width=H, head_dim=D):
+    """A tick's rows by default; ``rows`` x ``width`` for the rows of an
+    admission (``_rows_qkv``): 256 x 2048 is ``internlm2-1.8b``'s, one
+    block; 1,024 x 4,096 is Mistral's largest bucket, a grid over row
+    blocks."""
     from paddle_tpu.ops.pallas import tick_fusion as tf
 
     def build(S):
-        x, w = S((SLOTS, H), BF16), S((H,), F32)
+        x, w = S((rows, width), BF16), S((width,), F32)
         if which == "rms":
             return (lambda x, w: tf.fused_rms_norm(x, w, 1e-6)), (x, w)
         if which == "add_rms":
             return (lambda x, y, w: tf.fused_add_rms_norm(x, y, w, 1e-6)), \
                 (x, x, w)
-        return (lambda q, k, pos: tf.fused_rope_qk(q, k, pos, D, 10000.0)), \
-            (x, x, S((SLOTS,), I32))
+        return (lambda q, k, pos: tf.fused_rope_qk(q, k, pos, head_dim,
+                                                   10000.0)), \
+            (x, S((rows, kv_width), BF16), S((rows,), I32))
     return build
 
 
@@ -259,6 +267,10 @@ CASES = {
     "fused_rms_norm": _tick("rms"),
     "fused_add_rms_norm": _tick("add_rms"),
     "fused_rope_qk": _tick("rope"),
+    "fused_rms_norm_admit_256x2048": _tick("rms", 256, 2048),
+    "fused_rope_qk_admit_256x2048": _tick("rope", 256, 2048, 1024, 128),
+    "fused_rms_norm_gridded_1024x4096": _tick("rms", 1024, 4096),
+    "fused_rope_qk_gridded_1024x4096": _tick("rope", 1024, 4096, 1024, 128),
     "quant_matmul_int8_768x32000": _quant_matmul,
     "multi_tensor_momentum": _multi_tensor("momentum"),
     "multi_tensor_adam": _multi_tensor("adam"),
@@ -305,15 +317,14 @@ def _kernel_call_sites(text, name):
                       r"custom_call_target=\"tpu_custom_call\"", text)
 
 
-def _moved(text, layer_elems, of_pages=None):
-    """bf16 copies, reshapes and slices in the compiled text as large as
-    one layer of a pool plane (``of_pages``: only shapes with that many
-    pages a dimension — a weight as large, re-tiled once a call, is not
-    the pool's guard's)."""
+def _moved(text, layer_elems, of_pages=None,
+           ops="copy|copy-start|reshape|dynamic-slice|dynamic-update-slice"):
+    """bf16 ``ops`` (by default copies, reshapes and slices) in the compiled
+    text as large as one layer of a pool plane (``of_pages``: only shapes
+    with that many pages a dimension — a weight as large, re-tiled once a
+    call, is not the pool's guard's)."""
     moved = []
-    for dims, op in re.findall(
-            r"= bf16\[([\d,]+)\]\S* (copy|copy-start|reshape|dynamic-slice|"
-            r"dynamic-update-slice)\(", text):
+    for dims, op in re.findall(rf"= bf16\[([\d,]+)\]\S* ({ops})\(", text):
         sizes = [int(d) for d in dims.split(",")]
         n = 1
         for d in sizes:
@@ -359,6 +370,45 @@ def test_paged_segment_holds_pool_once(shaped, no_persistent_cache,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < plane_bytes, \
         f"temporaries {temp} B hold a pool plane ({plane_bytes} B)"
+
+
+def test_llama_segment_reads_stacked_weights_where_they_lie(
+        shaped, no_persistent_cache, monkeypatch):
+    """``internlm2-1.8b``'s serve cells' own program, ``('pseg', 32, 256,
+    32)`` at the published widths (24 layers, 32 slots, a table of 64
+    pages, 2049 pages): the admit arm (1 x 256) and the decode arm (32 x 1)
+    compute q, k, v and their rope by one formulation (``_rows_qkv``), so
+    XLA gives ``wq`` / ``wk`` one layout. Until PR 35 the admit arm roped
+    in XLA, which fused the chain into the dots and wanted both stacks
+    transposed: the program transposed them at its entry and copied them
+    BACK once a step for the decode arm (``copy.52`` / ``copy.51``, 0.88 ms
+    of a 6.57 ms step, and 605.7 MB of temporaries). No ``copy`` /
+    ``transpose`` as large as the smallest stacked layer weight (``wk``) is
+    in the compiled text, and the temporaries are a few MB."""
+    import json
+    import os
+
+    from chipbench.common import llama_config
+    from paddle_tpu.models import llama
+    from paddle_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "chipbench",
+                           "configs", "internlm2-1.8b.json")) as f:
+        cfg = llama_config(json.load(f))
+    slots, max_len, pages, n_pad, s_max, steps = 32, 1024, 2049, 32, 256, 32
+    compiled = _compiled_segment(shaped, llama, cfg, slots, max_len // PAGE,
+                                 pages, n_pad, s_max, steps)
+    text = compiled.as_text()
+    kernels = _kernel_call_sites(text, "ragged_paged_attention")
+    assert len(kernels) == 2, f"paged kernel call sites: {kernels}"
+
+    wk_elems = cfg.num_layers * cfg.hidden_size \
+        * cfg.num_kv_heads * cfg.head_dim
+    moved = _moved(text, wk_elems, ops="copy|copy-start|transpose")
+    assert not moved, f"the compiled segment re-tiles a weight stack: {moved}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64 * 2**20, f"temporaries {temp} B"
 
 
 def test_latent_segment_holds_pool_once(shaped, no_persistent_cache,

@@ -139,7 +139,8 @@ class TestCopyOnWrite:
 # ---------------------------------------------------------------------------
 
 
-def _paged_reference(params, tokens, cfg, cache, pos, quant_dtype=None):
+def _paged_reference(params, tokens, cfg, cache, pos, quant_dtype=None,
+                     logits_all=False):
     """The dense-cache forward the paged one must equal: plain unrolled
     layers over a contiguous [L, B, S, Hkv, D] cache (plus [L, B, S]
     scale planes when ``quant_dtype``), every row of slot b written at
@@ -170,7 +171,8 @@ def _paged_reference(params, tokens, cfg, cache, pos, quant_dtype=None):
         attn = llama._dense_cache_attention(cfg, q, win["k"], win["v"],
                                             positions)
         x = llama._layer_post(cfg, x, attn, lp)
-    return llama._head_logits(cfg, params, x, False), cache
+    return llama._head_logits(cfg, params, x, False,
+                              logits_all=logits_all), cache
 
 
 def _to_pages(plane, pt, psz):
@@ -190,18 +192,17 @@ class TestCarriedFlatPool:
     carried through the layer loop, rows scattered in place) against the
     dense-cache forward: same logits, same rows in every plane."""
 
-    @pytest.mark.parametrize("T", [1, 16])
-    @pytest.mark.parametrize("kind", ["bf16", "int8"])
-    @pytest.mark.parametrize("scan_layers", [True, False])
-    def test_matches_dense_cache_forward(self, scan_layers, kind, T):
+    @staticmethod
+    def check(kind, T, B=3, hidden=64, dtype=jnp.float32, logits_all=False,
+              **cfg_over):
         set_mesh(None)
         cfg = llama.LlamaConfig(
-            vocab_size=96, hidden_size=64, intermediate_size=128,
-            num_layers=3, num_heads=4, num_kv_heads=2, max_seq_len=64,
-            dtype=jnp.float32, remat=False, scan_layers=scan_layers)
-        params = llama.init_params(cfg, jax.random.PRNGKey(1))
+            vocab_size=96, hidden_size=hidden, intermediate_size=2 * hidden,
+            num_layers=3, num_heads=4, num_kv_heads=2, max_seq_len=128,
+            dtype=dtype, remat=False, **cfg_over)
+        params = llama.init_params(cfg, jax.random.PRNGKey(1), dtype=dtype)
         rng = np.random.RandomState(5)
-        B, S, psz, L = 3, 64, 8, cfg.num_layers
+        S, psz, L = 128, 8, cfg.num_layers
         quant = kind == "int8"
         kv_dt = jnp.int8 if quant else jnp.bfloat16
         shape = (L, B, S, cfg.num_kv_heads, cfg.head_dim)
@@ -220,20 +221,23 @@ class TestCarriedFlatPool:
         assert pool["k"].shape == (L, 1 + B * S // psz, psz,
                                    cfg.num_kv_heads * cfg.head_dim)
         toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, T)), jnp.int32)
-        pos = jnp.asarray([9, 30, 17], jnp.int32)
-        live = jnp.asarray([True, False, True])      # slot 1 is retired
+        pos = jnp.asarray([9, 30, 17, 0, 41][:B], jnp.int32)
+        live = jnp.arange(B) != 1                    # slot 1 is retired
         ref_l, ref_cache = _paged_reference(
-            params, toks, cfg, cache, pos, kv_dt if quant else None)
+            params, toks, cfg, cache, pos, kv_dt if quant else None,
+            logits_all)
         out_l, out_pool = jax.jit(
             lambda pool: llama.forward_with_pages(
-                params, toks, cfg, pool, jnp.asarray(pt), pos, live=live))(
-                    pool)
+                params, toks, cfg, pool, jnp.asarray(pt), pos, live=live,
+                logits_all=logits_all))(pool)
         alive = np.asarray(live)
         # logits feel a row that rounded the other way (see below) by
         # ~1e-3; a wrong page, row or layer moves them by ~1
+        tol = 1e-2 if dtype == jnp.float32 else 6e-2
+        assert out_l.shape == ref_l.shape
         np.testing.assert_allclose(np.asarray(out_l)[alive],
                                    np.asarray(ref_l)[alive],
-                                   rtol=1e-2, atol=1e-2)
+                                   rtol=tol, atol=tol)
         assert set(out_pool) == set(pool)
         for n, a in out_pool.items():
             # live slots: the dense forward's rows, page for page; the
@@ -247,10 +251,81 @@ class TestCarriedFlatPool:
             # and later layers' rows feel it by ~1e-3: a step of the
             # plane's dtype, where a wrong page or row is off by ~0.3
             atol = {"k": 5e-3, "v": 5e-3}.get(n, 1e-6)
+            if dtype != jnp.float32:
+                # bf16 activations: the kernel rounds each product of
+                # the rotation, XLA's fusion their sum, so a row parts by
+                # two steps of its OPERANDS (~4 here), whatever the sum
+                atol = 7e-2
             np.testing.assert_allclose(
                 np.asarray(a).astype(np.float32)[:, 1:], want[:, 1:],
-                rtol=2 ** -7, atol=1.0 if a.dtype == jnp.int8 else atol,
-                err_msg=n)
+                rtol=2 ** -7 if dtype == jnp.float32 else 2 ** -6,
+                atol=1.0 if a.dtype == jnp.int8 else atol, err_msg=n)
+
+    @pytest.mark.parametrize("T", [1, 16])
+    @pytest.mark.parametrize("kind", ["bf16", "int8"])
+    @pytest.mark.parametrize("scan_layers", [True, False])
+    def test_matches_dense_cache_forward(self, scan_layers, kind, T):
+        self.check(kind, T, scan_layers=scan_layers)
+
+    FUSED = {
+        "tick": dict(T=1), "chunk16": dict(T=16), "admit64": dict(T=64),
+        "verify_b5_t5": dict(T=5, B=5, logits_all=True),
+        "bf16_admit64": dict(T=64, dtype=jnp.bfloat16),
+        "int8_pool_chunk16": dict(T=16, kind="int8"),
+        "gridded_admit64": dict(T=64),
+        "gridded_ragged_b5_t5": dict(T=5, B=5)}
+
+    @pytest.mark.parametrize("case", sorted(FUSED))
+    def test_fused_rows_match_the_xla_chain(self, monkeypatch, case):
+        """With the tick's fused kernels active (interpreted here)
+        ``forward_with_pages`` computes q, k, v and their rope by ONE
+        formulation over flat rows at every T (``_rows_qkv``: a tick, a
+        chunk, an admission, the verify tick's B x (K+1)); the reference
+        keeps the XLA chain (``_qkv_proj``). Same logits, the same rows
+        in every plane, a dead slot and each slot at its own base
+        position; ``gridded``: the kernels cut the rows into blocks."""
+        from paddle_tpu.ops.pallas import tick_fusion as tf
+
+        monkeypatch.setattr(tf, "FORCE_INTERPRET", True)
+        seen = []
+        real = tf.fused_rope_qk
+        monkeypatch.setattr(tf, "fused_rope_qk", lambda zq, *a: (
+            seen.append(zq.shape[0]), real(zq, *a))[1])
+        if case.startswith("gridded"):
+            # 128-wide rows of float32: blocks of 16 rows
+            monkeypatch.setattr(tf, "_BLOCK_BYTES", 16 * 1024)
+        how = {"kind": "bf16", "B": 3, **self.FUSED[case]}
+        self.check(hidden=128, **how)
+        assert seen == [how["B"] * how["T"]]    # one site in the layer scan
+
+    @pytest.mark.parametrize("rows", [64, 40])
+    def test_gridded_kernels_equal_the_single_block(self, monkeypatch, rows):
+        """``fused_rms_norm`` and ``fused_rope_qk`` over row blocks (the
+        form rows x width beyond one block take; 40 rows: a ragged last
+        block) give the single block's values bit for bit."""
+        from paddle_tpu.ops.pallas import tick_fusion as tf
+
+        monkeypatch.setattr(tf, "FORCE_INTERPRET", True)
+        rng = np.random.RandomState(rows)
+        x, zk = (jnp.asarray(rng.randn(rows, w), jnp.bfloat16)
+                 for w in (256, 128))
+        w = jnp.asarray(rng.rand(256) + 0.5, jnp.float32)
+        pos = jnp.asarray(rng.randint(0, 1024, rows), jnp.int32)
+
+        def both():
+            return (tf.fused_rms_norm(x, w, 1e-5),
+                    *tf.fused_rope_qk(x, zk, pos, 64, 1e6))
+
+        def grid():
+            return tf._row_grid(rows, 2, (256,), 256, whole=(256,))
+
+        assert grid() == {}
+        whole = both()
+        monkeypatch.setattr(tf, "_BLOCK_BYTES", 16 * 1024)   # 16 rows
+        assert grid()["grid"] == (-(-rows // 16),)
+        for a, b in zip(both(), whole):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
 
     @pytest.mark.parametrize("i", [0, 1, 2])
     @pytest.mark.parametrize("Tq", [1, 8])
@@ -613,10 +688,17 @@ class TestKernelThroughTheEngine:
     """The paged segment program with the kernel chosen (interpreted)
     against the same program on the gather path: admissions, decode
     ticks with retired and never-filled slots (``live`` -> ``q_len`` 0),
-    re-admission into a slot that was left."""
+    re-admission into a slot that was left. ``tick``: the tick's fused
+    kernels chosen as well, as on the chip: both arms of the segment
+    program rope their rows in ``fused_rope_qk`` (``_rows_qkv``), the
+    fallback's in XLA."""
 
+    @pytest.mark.parametrize("tick", [False, True])
     @pytest.mark.parametrize("scan_layers", [True, False])
-    def test_serves_the_fallbacks_tokens(self, monkeypatch, scan_layers):
+    def test_serves_the_fallbacks_tokens(self, monkeypatch, scan_layers,
+                                         tick):
+        from paddle_tpu.ops.pallas import tick_fusion
+
         set_mesh(None)
         tokens = {}
         for kernel in (False, True):
@@ -624,10 +706,12 @@ class TestKernelThroughTheEngine:
             cfg = llama.LlamaConfig(
                 vocab_size=128, hidden_size=256, intermediate_size=512,
                 num_layers=2, num_heads=4, num_kv_heads=2,
-                max_seq_len=128 + kernel, dtype=jnp.float32, remat=False,
-                scan_layers=scan_layers)
+                max_seq_len=128 + kernel + 2 * tick, dtype=jnp.float32,
+                remat=False, scan_layers=scan_layers)
             params = llama.init_params(cfg, jax.random.PRNGKey(0))
             monkeypatch.setattr(pa, "FORCE_INTERPRET", kernel)
+            monkeypatch.setattr(tick_fusion, "FORCE_INTERPRET",
+                                kernel and tick)
             pa.reset_selection_count()
             eng = ServingEngine(cfg, params, slots=3, max_len=96,
                                 paged=True, page_size=8,
