@@ -56,7 +56,8 @@ from typing import Dict, List, Optional, Tuple
 from . import hlo as hlo_passes
 
 __all__ = ["BufferInterval", "MemoryReport", "peak_live", "hot_transients",
-           "page_bytes_for", "pool_bytes_for", "transient_estimate",
+           "page_bytes_for", "fixed_part_bytes_for", "pool_bytes_for",
+           "transient_estimate",
            "chip_fit", "family_envelopes", "V5E_HBM_BYTES"]
 
 # per-chip HBM capacity the envelope is priced against by default (the
@@ -417,17 +418,30 @@ def page_bytes_for(cfg, page_size: int, quant: Optional[str] = None) -> int:
     """Bytes one pool page occupies across all layers — the §3f page
     arithmetic, byte-priced. The row layout is the model's: the K + V
     planes of ``llama`` (+ the scale planes under per-page quantization),
-    the one latent plane of ``latent_moe``."""
+    the one latent plane of ``latent_moe``, a state of ``power_retention``,
+    the full layers' K + V rows of ``hybrid_moe`` (whose window layers keep
+    a fixed part a sequence instead: ``fixed_part_bytes_for``)."""
     from ..models import family_of
 
     return family_of(cfg).page_bytes(cfg, page_size, quant)
 
 
+def fixed_part_bytes_for(cfg) -> int:
+    """Bytes of one sequence's FIXED part — what it keeps whatever its
+    length beside its pages (the window layers' last rows of
+    ``hybrid_moe``); 0 for a model whose module declares none."""
+    from ..models import family_of
+
+    return getattr(family_of(cfg), "fixed_part_bytes", lambda _: 0)(cfg)
+
+
 def pool_bytes_for(cfg, num_pages: int, page_size: int,
-                   quant: Optional[str] = None) -> int:
-    """Provisioned pool bytes (``init_paged_pool`` arithmetic):
-    every page is allocated up front, including the trash page."""
-    return num_pages * page_bytes_for(cfg, page_size, quant)
+                   quant: Optional[str] = None, fixed_parts: int = 0) -> int:
+    """Provisioned pool bytes (``init_paged_pool`` arithmetic): every
+    page is allocated up front, including the trash page, and so is every
+    fixed part (``fixed_parts``: the trash part among them)."""
+    return num_pages * page_bytes_for(cfg, page_size, quant) \
+        + fixed_parts * fixed_part_bytes_for(cfg)
 
 
 def transient_estimate(cfg, *, n_pad: int, s_max: int,

@@ -7,7 +7,7 @@ WORST-CASE length of every slot — what a sequence keeps on the device
 lives in one flat pool of fixed-size pages (axis 1 of every plane is the
 page axis) and each slot's sequence is the ordered list of pages its page
 table names. **What a page holds is the model's** (``models.family_of``:
-its ``init_paged_pool``), one of three today:
+its ``init_paged_pool``), one of four today:
 
 * ``llama``: ``page_size`` K rows and V rows of every layer, two planes
   ``[L, num_pages, page_size, Hkv*D]`` (plus scale planes when quantized);
@@ -21,6 +21,20 @@ its ``init_paged_pool``), one of three today:
   table is one page id, and ``ensure_writable``'s page-granular copy is a
   snapshot of a state. A freed state page is handed to the next request as
   it is: the model starts from zero at position 0 whatever the page holds.
+* ``hybrid_moe``: TWO KINDS OF CACHE for one sequence. Its full-attention
+  layers keep ``page_size`` K rows and V rows a page, planes ``{"k", "v"}:
+  [L_full, num_pages, page_size, Hkv*D]``, as llama's. Its window layers
+  need a sequence's last ``sliding_window`` rows and nothing else, so a
+  sequence keeps them in a FIXED PART whose bytes do not depend on its
+  length: planes ``{"wk", "wv"}: [L_window, fixed_parts, sliding_window,
+  Hkv*D]``, position ``p`` at row ``p mod sliding_window``. A model
+  declares the second kind with ``fixed_part_bytes``; this manager then
+  holds ``slots + 1`` parts (part 0 the trash part), BINDS PART ``s + 1``
+  TO SLOT ``s`` (a sequence needs its part exactly while it has a slot, so
+  there is nothing to allocate, to run out of, or to leak: a part is held
+  while its slot holds pages) and gives the page table one more column,
+  the part's id, which the segment program fills in as it admits. A
+  reused part is not cleared: the model masks by position.
 
 Three consequences, each a serving-memory property the contiguous layout
 cannot express:
@@ -215,10 +229,19 @@ class PagedKVCache:
         # changes, and every page-granular copy below iterates the pool
         # dict instead of naming k/v
         self.quant = quant
-        # the planes, their row width and their dtype are the model's
+        # the planes, their row width and their dtype are the model's; so
+        # is a second kind of cache, a fixed part a sequence (the module's
+        # text): one a slot and the trash part, its id the table's last
+        # column
+        from ..analysis.memory import fixed_part_bytes_for
+
+        self.fixed_part_bytes = fixed_part_bytes_for(cfg)
+        self.fixed_parts = self.slots + 1 if self.fixed_part_bytes else 0
+        kinds = {"fixed_parts": self.fixed_parts} if self.fixed_parts else {}
         self.pool = family_of(cfg).init_paged_pool(
-            cfg, self.num_pages, self.page_size, dtype=dtype, quant=quant)
-        self.page_table = jnp.zeros((self.slots, self.max_pages),
+            cfg, self.num_pages, self.page_size, dtype=dtype, quant=quant,
+            **kinds)
+        self.page_table = jnp.zeros((self.slots, self.table_width),
                                     jnp.int32)
         if mesh is not None:
             # tensor-parallel serving (r12): the pool's flat Hkv*D minor
@@ -242,6 +265,26 @@ class PagedKVCache:
     # --- sizing -----------------------------------------------------------
     def pages_needed(self, rows: int) -> int:
         return -(-int(rows) // self.page_size)
+
+    @property
+    def table_width(self) -> int:
+        """Columns of a slot's table row: its ``max_pages`` page ids and,
+        where sequences keep a fixed part, that part's id."""
+        return self.max_pages + bool(self.fixed_parts)
+
+    @property
+    def fixed_parts_held(self) -> int:
+        """Fixed parts in use: a slot's part is held while the slot holds
+        pages."""
+        return sum(map(bool, self.slot_pages)) if self.fixed_parts else 0
+
+    def reserved_bytes(self, rows: int) -> int:
+        """What a request spanning ``rows`` rows keeps on the device: its
+        pages and, whatever ``rows`` is, its fixed part."""
+        from ..analysis.memory import page_bytes_for
+
+        return self.pages_needed(rows) * page_bytes_for(
+            self.cfg, self.page_size, self.quant) + self.fixed_part_bytes
 
     @property
     def pages_free(self) -> int:
@@ -312,6 +355,7 @@ class PagedKVCache:
     def fork_slot(self, src: int, dst: int) -> None:
         """Map ``src``'s pages into ``dst`` (ref bumps, zero copies) —
         the share half of COW. ``dst`` must be empty."""
+        self._pages_only("fork_slot")
         if self.slot_pages[dst]:
             raise RuntimeError(f"fork into occupied slot {dst}")
         pages = list(self.slot_pages[src])
@@ -326,6 +370,7 @@ class PagedKVCache:
         shared (ref > 1), copy its rows into a fresh private page and
         repoint the table — the one place paging ever copies KV rows.
         Returns the (possibly new) physical page id."""
+        self._pages_only("ensure_writable")
         page = self.slot_pages[slot][vpage]
         if self.allocator.ref(page) <= 1:
             return page
@@ -345,6 +390,13 @@ class PagedKVCache:
         self._gauges()
         return new
 
+    def _pages_only(self, what: str) -> None:
+        if self.fixed_parts:
+            raise RuntimeError(
+                f"{what}: a sequence of {type(self.cfg).__name__} keeps a "
+                f"fixed part beside its pages, and a part is its slot's "
+                f"alone: pages can be shared or copied, a sequence cannot")
+
     # --- lifecycle --------------------------------------------------------
     def reset(self) -> None:
         """Free every slot's pages and zero the device table (pool rows
@@ -354,7 +406,7 @@ class PagedKVCache:
             if self.slot_pages[s]:
                 self.allocator.release(self.slot_pages[s])
                 self.slot_pages[s] = []
-        table = jnp.zeros((self.slots, self.max_pages), jnp.int32)
+        table = jnp.zeros((self.slots, self.table_width), jnp.int32)
         if self.mesh is not None:
             import jax
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -374,13 +426,21 @@ class PagedKVCache:
         held = self.allocator.pages_used
         if held != expected_held:
             bad.append(f"{held} pages held, expected {expected_held}")
+        if not expected_held and self.fixed_parts_held:
+            bad.append(f"{self.fixed_parts_held} fixed parts held by slots "
+                       f"with no live request")
         return bad
 
     def stats(self) -> Dict[str, float]:
-        return {"num_pages": self.num_pages - 1,  # usable (sans trash)
-                "page_size": self.page_size,
-                "pages_free": self.allocator.pages_free,
-                "pages_used": self.allocator.pages_used,
-                "occupancy": round(self.occupancy(), 4),
-                "peak_occupancy": round(self.peak_occupancy, 4),
-                "cow_breaks": self.cow_breaks}
+        out = {"num_pages": self.num_pages - 1,  # usable (sans trash)
+               "page_size": self.page_size,
+               "pages_free": self.allocator.pages_free,
+               "pages_used": self.allocator.pages_used,
+               "occupancy": round(self.occupancy(), 4),
+               "peak_occupancy": round(self.peak_occupancy, 4),
+               "cow_breaks": self.cow_breaks}
+        if self.fixed_parts:
+            out.update(fixed_parts=self.fixed_parts - 1,  # sans trash
+                       fixed_parts_held=self.fixed_parts_held,
+                       fixed_part_bytes=self.fixed_part_bytes)
+        return out

@@ -198,20 +198,29 @@ class OnlineReport:
     # mean first-token time)
     segment_phases: Optional[Dict[str, dict]] = None
     ttft_parts_mean_s: Optional[Dict[str, float]] = None
-    # PR 29: the model's per-step counters over the serve, where it has
-    # any (``SEGMENT_COUNTERS`` of a sparse-expert model: picks, those
-    # that landed on held experts, held experts hit, the largest load of
-    # one expert in a step), beside the loop steps they were counted over
-    moe: Optional[Dict[str, int]] = None
-    # PR 34: the same of a power-retention model (``COUNTER_GROUP``
-    # "retention": state pages its ticks updated, its admissions' bucket
-    # rows and those of them that were the prompt's)
-    retention: Optional[Dict[str, int]] = None
+    # the model's per-step counters over the serve, a dict a group of its
+    # ``COUNTER_GROUPS``, each beside the loop steps it was counted over
+    # (PR 29 ``moe``: picks, those that landed on held experts, held
+    # experts hit, the largest load of one expert in a step; PR 34
+    # ``retention``: state pages the ticks updated, the admissions'
+    # bucket rows and those of them that were the prompt's; PR 36
+    # ``window``: key rows the ticks attended in the full and in the
+    # window layers, the admissions' rows likewise). A new family adds a
+    # group in its own module and nothing here
+    counters: Dict[str, Dict[str, int]] = field(default_factory=dict)
     # PR 31: a paged engine's ``page_slots`` (rows x table width of every
     # paged attention call, a layer) and ``pages_fetched`` (the pages
     # those rows hold: ``pages_read``) over the serve's segments
     page_reads: Optional[Dict[str, int]] = None
     per_request: List[dict] = field(default_factory=list)
+
+    @property
+    def moe(self) -> Optional[Dict[str, int]]:
+        return self.counters.get("moe")
+
+    @property
+    def retention(self) -> Optional[Dict[str, int]]:
+        return self.counters.get("retention")
 
     def as_dict(self, with_requests: bool = False) -> dict:
         d = {k: v for k, v in self.__dict__.items() if k != "per_request"}
@@ -509,9 +518,8 @@ class OnlineScheduler:
                 for name, (ns, c) in phases.items()},
             ttft_parts_mean_s=({k: sum(p[k] for p in parts) / len(parts)
                                 for k in TTFT_PARTS} if parts else None),
-            **({eng.model.COUNTER_GROUP: dict(
-                eng.segment_counts, steps=eng.last_run_ticks)}
-               if eng.segment_counts else {}),
+            counters={group: dict(counts, steps=eng.last_run_ticks)
+                      for group, counts in eng.segment_counts.items()},
             page_reads=dict(eng.segment_pages) or None,
             **self._report_extras(reqs),
             per_request=[{

@@ -585,10 +585,11 @@ class ServingEngine:
         # reduces it into ``OnlineReport.segment_phases``
         self.seg_index = 0
         self.segment_phases: Dict[str, list] = {}
-        # PR 29: sums of the model's per-step counters (``serving.<its
-        # COUNTER_GROUP>.*``: ``moe``, ``retention``) over the segments
-        # since the serve loop last reset the dict
-        self.segment_counts: Dict[str, int] = {}
+        # PR 29: sums of the model's per-step counters, by group
+        # (``serving.<group>.*`` of its ``COUNTER_GROUPS``: ``moe``,
+        # ``retention``, ``window``) over the segments since the serve
+        # loop last reset the dict
+        self.segment_counts: Dict[str, Dict[str, int]] = {}
         # PR 31: page slots the paged attention calls of those segments
         # were handed (rows x table width a step) and the pages they had
         # to fetch (``pages_read``), per layer — ``serving.pages_*``
@@ -1226,23 +1227,25 @@ class ServingEngine:
 
     def _count_telemetry(self, counts) -> Dict[str, int]:
         """One segment's ``SEGMENT_COUNTERS`` ([steps, n] int32, fetched
-        with the tokens) into the ``serving.<group>.*`` counters (the
-        model's ``COUNTER_GROUP``) and ``segment_counts``: sums over the
-        steps, a ``max_*`` column its maximum. Returns the segment's own."""
+        with the tokens; the columns of the model's ``COUNTER_GROUPS`` in
+        order) into the ``serving.<group>.*`` counters and
+        ``segment_counts[group]``: sums over the steps, a ``max_*`` column
+        its maximum. Returns the segment's own, by name."""
         seg = {}
-        group = f"serving.{self.model.COUNTER_GROUP}"
-        for j, name in enumerate(self.model.SEGMENT_COUNTERS):
+        columns = ((group, name)
+                   for group, names in self.model.COUNTER_GROUPS.items()
+                   for name in names)
+        for j, (group, name) in enumerate(columns):
             col = counts[:, j]
+            total = self.segment_counts.setdefault(group, {})
             if name.startswith("max_"):
                 seg[name] = v = int(col.max(initial=0))
-                _metrics.gauge(f"{group}.{name}").set(v)
-                self.segment_counts[name] = max(
-                    self.segment_counts.get(name, 0), v)
+                _metrics.gauge(f"serving.{group}.{name}").set(v)
+                total[name] = max(total.get(name, 0), v)
             else:
                 seg[name] = v = int(col.sum())
-                _metrics.counter(f"{group}.{name}").inc(v)
-                self.segment_counts[name] = \
-                    self.segment_counts.get(name, 0) + v
+                _metrics.counter(f"serving.{group}.{name}").inc(v)
+                total[name] = total.get(name, 0) + v
         return seg
 
     def _page_telemetry(self, reads: Dict[str, int]) -> None:
@@ -1560,6 +1563,9 @@ class ServingEngine:
                                   max_steps: int, digest_k: int = 0):
         cfg, slots, eos = self.cfg, self.slots, self.eos
         max_pages = self.pager.max_pages
+        # where sequences keep a fixed part beside their pages, slot s's is
+        # part s + 1 and the table's last column names it (``paged_kv``)
+        fixed_parts = self.pager.fixed_parts
         model = family_of(cfg)
         forward = model.forward_with_pages
         # a model that counts per step (experts picked, held, hit) hands
@@ -1607,6 +1613,9 @@ class ServingEngine:
                 q = st["qidx"]
                 row = jax.lax.dynamic_slice(req_tables, (q, 0),
                                             (1, max_pages))
+                if fixed_parts:
+                    row = jnp.concatenate(
+                        [row, jnp.reshape(s + 1, (1, 1)).astype(i32)], 1)
                 prow = jax.lax.dynamic_slice(prompts, (q, 0), (1, s_max))
                 ln = lens[q]
                 pln = pre_lens[q]

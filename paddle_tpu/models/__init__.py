@@ -8,21 +8,30 @@ eager/Layer-API model zoo lives in ``paddle_tpu.vision.models`` and the
 """
 
 from . import bert  # noqa: F401
+from . import hybrid_moe  # noqa: F401
 from . import latent_moe  # noqa: F401
 from . import llama  # noqa: F401
 from . import power_retention  # noqa: F401
 
-__all__ = ["bert", "llama", "latent_moe", "power_retention", "family_of",
-           "require"]
+__all__ = ["bert", "llama", "latent_moe", "power_retention", "hybrid_moe",
+           "family_of", "require"]
 
 
 def family_of(cfg):
     """The module that implements ``cfg``'s decoder family — the model
     seam of the serving engine (``inference/serving.py``), which asks it
     for ``init_paged_pool``, ``page_bytes``, ``paged_kernel_active`` and
-    ``forward_with_pages`` (and builds weights with its ``init_params``)."""
+    ``forward_with_pages`` (and builds weights with its ``init_params``).
+    One of four: ``llama`` (K / V row pages), ``latent_moe`` (latent row
+    pages, routed experts on a share), ``power_retention`` (a sequence's
+    recurrent state as its one page) and ``hybrid_moe`` (row pages for its
+    full-attention layers beside a fixed part a sequence for its window
+    layers — the one module that declares ``fixed_part_bytes`` — and
+    ``latent_moe``'s experts)."""
     if isinstance(cfg, latent_moe.LatentMoEConfig):
         return latent_moe
+    if isinstance(cfg, hybrid_moe.HybridMoEConfig):
+        return hybrid_moe
     if isinstance(cfg, power_retention.PowerRetentionConfig):
         return power_retention
     return llama

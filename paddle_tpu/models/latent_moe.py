@@ -39,7 +39,7 @@ place at ``[layer, phys, prow]``; trash page 0 and ``live`` as
 What the serving engine asks of a model module (``models.family_of``):
 ``init_params``, ``init_paged_pool``, ``page_bytes``,
 ``paged_kernel_active``, ``forward_with_pages``, ``SERVING_FAMILIES`` and,
-optionally, ``SEGMENT_COUNTERS`` / ``COUNTER_GROUP``.
+optionally, ``COUNTER_GROUPS`` (with ``SEGMENT_COUNTERS``, its columns).
 """
 
 from __future__ import annotations
@@ -57,13 +57,15 @@ __all__ = ["LatentMoEConfig", "init_params", "init_mtp_params",
            "share_params", "init_paged_pool", "page_bytes",
            "paged_kernel_active", "forward_with_pages", "route",
            "mtp_logits", "SERVING_FAMILIES", "SEGMENT_COUNTERS",
-           "COUNTER_GROUP"]
+           "COUNTER_GROUPS"]
 
 # the one serving family this model is served by (``models.require``)
 SERVING_FAMILIES = ("paged",)
-# what an expert layer counts a step, summed over layers (the last: max)
-SEGMENT_COUNTERS = ("picks", "picks_held", "experts_hit", "max_load")
-COUNTER_GROUP = "moe"           # ``serving.moe.*``, ``OnlineReport.moe``
+# what an expert layer counts a step, summed over layers (the last: max),
+# by group: ``serving.moe.*``, ``OnlineReport.moe``; the event log's
+# columns are the groups' counters in order
+COUNTER_GROUPS = {"moe": ("picks", "picks_held", "experts_hit", "max_load")}
+SEGMENT_COUNTERS = sum(COUNTER_GROUPS.values(), ())
 EXPERT_KEYS = ("we_gate", "we_up", "we_down")   # [L, E, ...]: the experts held
 
 

@@ -42,7 +42,7 @@ form and writes the page once.
 What the serving engine asks of a model module (``models.family_of``):
 ``init_params``, ``init_paged_pool``, ``page_bytes``,
 ``paged_kernel_active``, ``forward_with_pages``, ``SERVING_FAMILIES`` and,
-optionally, ``SEGMENT_COUNTERS`` / ``COUNTER_GROUP``.
+optionally, ``COUNTER_GROUPS`` (with ``SEGMENT_COUNTERS``, its columns).
 """
 
 from __future__ import annotations
@@ -59,14 +59,15 @@ from .llama import _head_logits, _rms_norm, _rope_at, scoped
 
 __all__ = ["PowerRetentionConfig", "init_params", "init_paged_pool",
            "page_bytes", "paged_kernel_active", "forward_with_pages",
-           "SERVING_FAMILIES", "SEGMENT_COUNTERS", "COUNTER_GROUP"]
+           "SERVING_FAMILIES", "SEGMENT_COUNTERS", "COUNTER_GROUPS"]
 
 # the one serving family this model is served by (``models.require``)
 SERVING_FAMILIES = ("paged",)
 # a step counts: state pages a tick updated (its live slots), and an
 # admission's bucket rows and those of them that are the prompt's
-SEGMENT_COUNTERS = ("state_pages", "admit_rows", "admit_rows_used")
-COUNTER_GROUP = "retention"     # ``serving.retention.*``
+COUNTER_GROUPS = {             # ``serving.retention.*``
+    "retention": ("state_pages", "admit_rows", "admit_rows_used")}
+SEGMENT_COUNTERS = sum(COUNTER_GROUPS.values(), ())
 # the layer is the DEGREE-2 one (``phi`` expands the square of the dot
 # product); neither constant below is a key of the public config.json
 RETENTION_EPS = 1e-6            # the normaliser's

@@ -212,7 +212,7 @@ def _make_kernel(Hkv: int, D: int, Tq: int, rep: int, psz: int, N: int,
 
 def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
                            scale=None, interpret: bool = False,
-                           layer=None):
+                           layer=None, name: str = "ragged_paged_attention"):
     """Attention over a paged KV pool, mixed prefill/decode in one call.
 
     q: [B, Tq, nH, D] query chunks (row t of slot b sits at absolute
@@ -230,6 +230,8 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
     already in the cache before this chunk. q_len: [B] live rows per
     chunk (None = all Tq). Returns [B, Tq, nH, D] in q.dtype. Raises on
     untileable shapes — callers gate with ``paged_attention_active``.
+    ``name``: the kernel's name in a device trace (a model that calls it
+    over two kinds of cache gives each call its own).
     """
     B, Tq, nH, D = q.shape
     if kp.ndim != (3 if layer is None else 4) or vp.shape != kp.shape:
@@ -279,7 +281,7 @@ def ragged_paged_attention(q, kp, vp, page_table, ctx_len, q_len=None,
     )
     out = pl.pallas_call(
         _make_kernel(Hkv, D, Tq, rep, psz, N, max_pages),
-        name="ragged_paged_attention",
+        name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, R, D), q.dtype),
         # slots in order on one core: the V buffer is zeroed at slot 0

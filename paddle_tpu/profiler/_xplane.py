@@ -50,7 +50,8 @@ _HLO_RE = re.compile(r"=\s*\S+\s+([a-zA-Z][\w-]*)\(")
 # models/llama.py, inference/serving.py (segment programs) and optimizer/.
 # The per-scope table keeps these components of an op's ``op_name`` path
 # and drops jax's own (jit(..), while, body, cond, branch_N_fun, ...).
-SCOPES = ("embed", "qkv", "kv_write", "attention", "post", "head", "sample",
+SCOPES = ("embed", "qkv", "kv_write", "attention", "attention_window",
+          "attention_full", "post", "head", "sample",
           "latent_qkv", "router", "experts", "shared_expert", "dense_ffn",
           "retention_qkv", "gate", "retention", "ffn",
           "segment.admit", "segment.decode",

@@ -9,7 +9,7 @@ below lowers one kernel at ``LlamaConfig.bert_base_equiv`` widths (H=768,
 ``tpu_custom_call`` in the compiled text. Nothing runs: these say a kernel
 compiles, never that it is right or fast.
 
-Four cases compile a whole program: ``test_paged_segment_holds_pool_once``
+Five cases compile a whole program: ``test_paged_segment_holds_pool_once``
 lowers the paged segment loop (admit and decode steps around
 ``llama.forward_with_pages``) and reads the compiled text and
 ``memory_analysis()`` for copies of the KV pool, which tier-1 cannot see
@@ -20,7 +20,8 @@ that step before PR 35);
 ``test_latent_segment_holds_pool_once`` does the same for the latent
 family's plane at the benchmark's own size, and
 ``test_retention_segment_holds_pool_once`` for the power-retention family's
-state pages.
+state pages, and ``test_hybrid_segment_holds_both_caches_once`` for the
+window / full family's row pages and fixed parts.
 
 The topology is described inside a fixture (loading the TPU's library at
 import would break collection under several workers) and everything is
@@ -286,9 +287,10 @@ def test_kernel_compiles_for_v5e(name, shaped, no_persistent_cache):
 
 
 def _compiled_segment(shaped, model, cfg, slots, max_pages, pages, n_pad,
-                      s_max, steps):
+                      s_max, steps, fixed_parts=0):
     """``('pseg', n_pad, s_max, steps)`` of ``cfg`` (family ``model``) over
-    a pool of ``pages`` pages, lowered from shapes alone and compiled for
+    a pool of ``pages`` pages (and, where sequences keep a fixed part,
+    ``fixed_parts`` of them), lowered from shapes alone and compiled for
     the described chip; bf16 weights."""
     from paddle_tpu.inference.serving import ServingEngine
 
@@ -298,16 +300,19 @@ def _compiled_segment(shaped, model, cfg, slots, max_pages, pages, n_pad,
 
     params = abstract(lambda: model.init_params(cfg, jax.random.PRNGKey(0),
                                                 dtype=BF16))
-    pool = abstract(lambda: model.init_paged_pool(cfg, pages, PAGE))
+    kinds = {"fixed_parts": fixed_parts} if fixed_parts else {}
+    pool = abstract(lambda: model.init_paged_pool(cfg, pages, PAGE, **kinds))
     engine = types.SimpleNamespace(        # all the builder reads of one
         cfg=cfg, slots=slots, eos=None,
-        pager=types.SimpleNamespace(max_pages=max_pages))
+        pager=types.SimpleNamespace(max_pages=max_pages,
+                                    fixed_parts=fixed_parts))
     segment = ServingEngine._build_paged_segment_prog(engine, n_pad, s_max,
                                                       steps)
     vec = shaped((slots,), I32)
     req = shaped((n_pad,), I32)
     return segment.lower(
-        params, pool, shaped((slots, max_pages), I32), vec, vec, vec,
+        params, pool, shaped((slots, max_pages + bool(fixed_parts)), I32),
+        vec, vec, vec,
         shaped((n_pad, s_max), I32), req, req, req,
         shaped((n_pad, max_pages), I32), shaped((), I32)).compile()
 
@@ -491,6 +496,63 @@ def test_retention_segment_holds_pool_once(shaped, no_persistent_cache,
         f"({plane_bytes} B)"
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
     assert mem.alias_size_in_bytes >= plane_bytes    # donated, in place
+
+
+def test_hybrid_segment_holds_both_caches_once(shaped, no_persistent_cache,
+                                               monkeypatch):
+    """The same guard for two kinds of cache in one program, at
+    ``k-exaone-236b-l5-ep8``'s size: ``('pseg', 64, 4096, 32)`` of 1 dense
+    + 4 sparse layers at the published widths (window, window, window,
+    full, window; 16 held experts; 64 slots x 320 page slots + a fixed part
+    a slot) around ``hybrid_moe.forward_with_pages``. Each plane is handed
+    to its kernels once, in HBM: no copy / reshape / slice as large as ONE
+    LAYER of the row pool or of the fixed parts, no copy as large as one
+    layer's held experts or as ``wq`` (a layer's weights are separate
+    arrays: nothing is sliced out of a stack inside the step loop), every
+    kernel at its call sites under its own name, the pool donated, and
+    arguments + temporaries inside the chip's 16 GiB."""
+    # (~20 s: the one whole program of this size in the file)
+    from paddle_tpu.models import hybrid_moe
+    from paddle_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    slots, max_len, n_pad, s_max, steps = 64, 5120, 64, 4096, 32
+    max_pages = max_len // PAGE
+    pages = slots * max_pages + 1
+    cfg = hybrid_moe.HybridMoEConfig(
+        num_layers=5, held_experts=(0, 16), vocab_slice=(0, 19200),
+        max_seq_len=max_len)
+    compiled = _compiled_segment(shaped, hybrid_moe, cfg, slots, max_pages,
+                                 pages, n_pad, s_max, steps,
+                                 fixed_parts=slots + 1)
+    text = compiled.as_text()
+    sites = {name: len(_kernel_call_sites(text, name))
+             for name in hybrid_moe.KERNEL_NAMES.values()}
+    assert sites == {"paged_attention_full": 1, "paged_attention_window": 4,
+                     "prefill_attention_full": 1,
+                     "prefill_attention_window": 4}
+    assert len(_kernel_call_sites(text, "grouped_expert_matmul")) == 16
+
+    row_layer = pages * PAGE * cfg.kv_width
+    moved = _moved(text, row_layer, of_pages=pages)
+    assert not moved, f"the compiled segment moves the row pool: {moved}"
+    part_layer = (slots + 1) * cfg.sliding_window * cfg.kv_width
+    moved = _moved(text, part_layer, of_pages=slots + 1,
+                   ops="copy|copy-start|reshape|dynamic-slice")
+    assert not moved, f"the compiled segment moves the fixed parts: {moved}"
+    # the step loop's computations come before ENTRY in the text (XLA
+    # re-tiles each wq ONCE A SEGMENT at the program's entry, 0.5 GB of
+    # temporaries and 1.2 ms in ~500: not the loop's)
+    loop = text[:text.index("\nENTRY ")]
+    wq = cfg.hidden_size * cfg.num_heads * cfg.head_dim
+    copied = [m for m in _moved(loop, wq, ops="copy|copy-start")
+              if "4096" not in m[1].split(",")]     # not an activation
+    assert not copied, f"the step loop copies a weight: {copied}"
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * row_layer * 2 + 2 * 4 * part_layer * 2
+    assert mem.alias_size_in_bytes >= pool_bytes     # donated, in place
+    assert mem.temp_size_in_bytes < 2 * 2**30, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
 
 
 def test_canonical_paged_segment_is_the_parents_program():
