@@ -81,8 +81,9 @@ __all__ = ["HybridMoEConfig", "init_params", "share_params",
 SERVING_FAMILIES = ("paged",)
 # a step counts the expert layers' four (``latent_moe``) and, of its two
 # caches: key rows the ticks' full layers attended, the same of the window
-# layers (min(position + 1, window) a live slot a layer), an admission's
-# bucket rows and those of them that are the prompt's
+# layers (min(position + 1, window) a live slot a layer), the rows an
+# admission computed (its row blocks up to the prompt's end) and those of
+# them that are the prompt's
 COUNTER_GROUPS = {
     "moe": latent_moe.COUNTER_GROUPS["moe"],
     "window": ("rows_full", "rows_window", "admit_rows", "admit_rows_used"),
@@ -102,6 +103,9 @@ KERNEL_NAMES = {
     (FULL, "admit"): "prefill_attention_full",
     (WINDOW, "admit"): "prefill_attention_window",
 }
+# rows of one trip of an admission's row-wise work (norms, projections,
+# FFN, experts); the trips follow the prompt's length (``_admit``)
+ADMIT_BLOCK = 1024
 
 
 @dataclasses.dataclass(frozen=True)
@@ -377,19 +381,20 @@ def _post(x, o, lp):
 
 def _ffn(cfg: HybridMoEConfig, x, lp, valid):
     """N2, the layer's FFN (dense or experts, by its parameters), add.
-    Returns (x, the expert layer's counters or None)."""
+    Returns (x, the expert layer's counters over these rows or None, its
+    picks [B*T, k] or None)."""
     B, T, H = x.shape
     h = _rms_norm(x, lp["n2"], cfg.rms_eps)
     if "router" not in lp:
         with jax.named_scope("dense_ffn"):
             return x + _swiglu(h, lp["w_gate"], lp["w_up"],
-                               lp["w_down"]), None
+                               lp["w_down"]), None, None
     h2 = h.reshape(B * T, H)
     picks, w = latent_moe.route(cfg, h2, lp["router"])
     routed, counters = latent_moe._routed_experts(
         cfg, h2, picks, w, valid.reshape(B * T), lp)
     m = latent_moe._shared_expert(h2, lp) + routed
-    return x + m.reshape(B, T, H), counters
+    return x + m.reshape(B, T, H), counters, picks
 
 
 # ---------------------------------------------------------------------------
@@ -413,26 +418,75 @@ def _attention_scope(kind: str):
                            else "attention_window")
 
 
+def _loads(cfg: HybridMoEConfig, picks, valid):
+    """The valid rows' picks each held expert received, [held] int32
+    (``latent_moe._routed_experts``' ``sizes``, which it does not hand
+    out: an admission sums them over its row blocks)."""
+    e0, E = cfg.experts
+    return jax.lax.reduce_sum(
+        ((picks[..., None] == e0 + jnp.arange(E))
+         & valid.reshape(-1, 1, 1)).astype(jnp.int32), (0, 1))
+
+
 def _admit(params, tokens, cfg: HybridMoEConfig, n_valid, logit_pos,
            logits_all: bool):
     """An admission's rows through the stack, over each other (position
-    0, nothing cached). Returns (logits, what each layer keeps for its
-    cache, the expert layers' counters): a full layer's K and V rows [B,
-    T, kv*d], a window layer's last ``sliding_window`` of the prompt [B,
-    W, kv*d] in ring order (row r: the prompt's last position that is r
-    mod W; one before position 0 where the prompt is shorter: masked
-    until a tick writes the row)."""
+    0, nothing cached). Everything row-wise (norms, projections, rotary,
+    the FFN, the experts) runs in blocks of ``C = ADMIT_BLOCK`` rows (the
+    whole bucket where it is no multiple of that) under a trip count taken
+    from the longest prompt, ``ceil(max(n_valid) / C)``: rows past the last
+    block are never computed (their q / k / v are zeros, their ``x`` the
+    embedding; nobody reads them): one loop a layer boundary, a layer's
+    attention over the whole bucket between two of them. Returns (logits,
+    what each layer keeps for its cache, the expert layers' counters, the
+    rows computed a sequence): a full layer's K and V rows [B, T, kv*d], a
+    window layer's last ``sliding_window`` of the prompt [B, W, kv*d] in
+    ring order (row r: the prompt's last position that is r mod W; one
+    before position 0 where the prompt is shorter: masked until a tick
+    writes the row)."""
     B, T = tokens.shape
     W = cfg.sliding_window
+    C = T if T % ADMIT_BLOCK else ADMIT_BLOCK
+    n_blk = jnp.minimum(-(-jnp.max(n_valid) // C), T // C)
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
-    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
-    valid = positions < n_valid[:, None]
     last = n_valid[:, None] - 1
     ring_src = jnp.clip(last - (last - jnp.arange(W)) % W, 0, T - 1)
+    rows_in = n_valid.sum(dtype=jnp.int32)
+
+    # (a block's start is never negative: no index to normalize, at every
+    # start's trace)
+    def block(a, start):
+        return jax.lax.dynamic_slice_in_dim(a, start, C, 1,
+                                            allow_negative_indices=False)
+
+    def put(a, rows, start):
+        return jax.lax.dynamic_update_slice_in_dim(
+            a, rows, start, 1, allow_negative_indices=False)
+
+    def qkv_rows(layer, xb, start, qkv):
+        """q, k, v of layer ``layer`` for the block of rows ``xb`` that
+        starts at ``start``, into the bucket-wide buffers ``qkv``."""
+        at = jnp.broadcast_to(start + jnp.arange(C), (B, C))
+        rows = _qkv(cfg, xb, params["layers"][layer], at,
+                    cfg.kinds[layer] in ROTARY_KINDS)
+        return tuple(put(a, r, start) for a, r in zip(qkv, rows))
+
+    def no_qkv():
+        return tuple(jnp.zeros((B, T) + shape, x.dtype) for shape in (
+            (cfg.num_heads, cfg.head_dim), (cfg.num_kv_heads, cfg.head_dim),
+            (cfg.kv_width,)))
+
+    def enter(i, qkv):
+        start = i * C
+        return qkv_rows(0, block(x, start), start, qkv)
+
+    # one loop a layer boundary: a block's rows leave layer l - 1 (the
+    # output projection, the FFN) and enter layer l (q, k, v) in one trip
+    qkv = jax.lax.fori_loop(0, n_blk, enter, no_qkv())
     keeps, moe = [], jnp.zeros((4,), jnp.int32)
-    for lp, kind in zip(params["layers"], cfg.kinds):
-        q, k, v = _qkv(cfg, x, lp, positions, kind in ROTARY_KINDS)
+    for layer, (lp, kind) in enumerate(zip(params["layers"], cfg.kinds)):
+        q, k, v = qkv
         with _attention_scope(kind):
             o = _admit_attention(cfg, q, k, v, kind, n_valid)
         rows = (k.reshape(B, T, -1), v)
@@ -440,11 +494,31 @@ def _admit(params, tokens, cfg: HybridMoEConfig, n_valid, logit_pos,
             rows = tuple(jnp.take_along_axis(r, ring_src[..., None], axis=1)
                          for r in rows)
         keeps.append(rows)
-        x, c = _ffn(cfg, _post(x, o, lp), lp, valid)
-        if c is not None:
-            moe = _merge(moe, c)
+
+        def leave(i, carry):
+            x, loads, qkv = carry
+            start = i * C
+            valid = start + jnp.arange(C) < n_valid[:, None]
+            xb, _, picks = _ffn(
+                cfg, _post(block(x, start), block(o, start), lp), lp, valid)
+            if picks is not None:
+                loads = loads + _loads(cfg, picks, valid)
+            if qkv:
+                qkv = qkv_rows(layer + 1, xb, start, qkv)
+            return put(x, xb, start), loads, qkv
+
+        x, loads, qkv = jax.lax.fori_loop(0, n_blk, leave, (
+            x, jnp.zeros((cfg.experts[1],), jnp.int32),
+            no_qkv() if layer + 1 < cfg.num_layers else ()))
+        if "router" in lp:
+            # the layer's counters A STEP: a held expert counts once
+            # however many blocks hit it, the largest load is the
+            # admission's
+            moe = _merge(moe, jnp.stack([
+                cfg.num_experts_per_tok * rows_in, loads.sum(),
+                (loads > 0).sum(), loads.max()]).astype(jnp.int32))
     return _head_logits(cfg, params, x, False, logit_pos, logits_all), \
-        keeps, moe
+        keeps, moe, n_blk * C
 
 
 def _tick(params, tokens, cfg: HybridMoEConfig, planes, table, part, where,
@@ -472,7 +546,7 @@ def _tick(params, tokens, cfg: HybridMoEConfig, planes, table, part, where,
         with _attention_scope(kind):
             o = _tick_attention(cfg, q, *(planes[n] for n in PLANES[kind]),
                                 j, pages, ctx, q_len, kind)
-        x, c = _ffn(cfg, _post(x, o, lp), lp, valid)
+        x, c, _ = _ffn(cfg, _post(x, o, lp), lp, valid)
         if c is not None:
             moe = _merge(moe, c)
     return _head_logits(cfg, params, x, False, None, logits_all), moe
@@ -486,12 +560,15 @@ def forward_with_pages(params, tokens, cfg: HybridMoEConfig, pool,
     max_pages + 1]: the slot's row pages, then its fixed part's id. ``T ==
     1`` is a tick at any position; ``T > 1`` is an admission and ``pos``
     MUST BE 0 (its rows attend each other and nothing cached, and are
-    written afterwards); rows past ``logit_pos`` (the bucket's padding)
-    are written to the row pages beyond the prompt, where the ticks
-    overwrite them, and never to the fixed part. Dead slots (``live``) and
-    positions past the table write the trash page and the trash part.
-    Every plane is written in place. Returns (logits, pool), and with
-    ``with_counters`` the step's ``SEGMENT_COUNTERS`` [8] int32."""
+    written afterwards; only the row blocks up to the longest prompt's end
+    are computed: ``_admit``); rows past ``logit_pos`` (the bucket's
+    padding, computed or left at zero) are written to the row pages beyond
+    the prompt, where the ticks overwrite them, and never to the fixed
+    part. Dead slots (``live``) and positions past the table write the
+    trash page and the trash part. Every plane is written in place.
+    Returns (logits, pool), and with ``with_counters`` the step's
+    ``SEGMENT_COUNTERS`` [8] int32 (``admit_rows``: the rows an admission
+    computed, ``B`` x its blocks' rows)."""
     B, T = tokens.shape
     planes = dict(pool)
     psz = planes["k"].shape[2]
@@ -515,11 +592,11 @@ def forward_with_pages(params, tokens, cfg: HybridMoEConfig, pool,
     n_valid = valid.sum(1, dtype=jnp.int32)
     zero = jnp.int32(0)
     if T > 1:
-        logits, keeps, moe = _admit(params, tokens, cfg, n_valid, logit_pos,
-                                    logits_all)
+        logits, keeps, moe, computed = _admit(params, tokens, cfg, n_valid,
+                                              logit_pos, logits_all)
         for kind, j, rows in zip(cfg.kinds, cfg.plane_index, keeps):
             _write(planes, kind, j, rows, where if kind == FULL else (part,))
-        cache = [zero, zero, jnp.int32(B * T), n_valid.sum(dtype=jnp.int32)]
+        cache = [zero, zero, B * computed, n_valid.sum(dtype=jnp.int32)]
     else:
         logits, moe = _tick(params, tokens, cfg, planes, table, part, where,
                             pos, n_valid, logits_all)

@@ -509,8 +509,15 @@ def test_hybrid_segment_holds_both_caches_once(shaped, no_persistent_cache,
     LAYER of the row pool or of the fixed parts, no copy as large as one
     layer's held experts or as ``wq`` (a layer's weights are separate
     arrays: nothing is sliced out of a stack inside the step loop), every
-    kernel at its call sites under its own name, the pool donated, and
-    arguments + temporaries inside the chip's 16 GiB."""
+    kernel at its call sites under its own name (a loop's body is ONE site:
+    a ladder of traced admit widths would multiply them), the pool donated,
+    and arguments + temporaries inside the chip's 16 GiB. The admit
+    branch's row-wise work runs in row blocks under a trip count the
+    program reads from the prompt's length: every ``while`` directly under
+    ``segment.admit`` compares its counter with a carried value, not a
+    constant, and nothing as wide as the bucket's dense intermediate
+    ``[4096, 18432]`` or the worst-case expert buffer ``[33280, 6144]`` is
+    left."""
     # (~20 s: the one whole program of this size in the file)
     from paddle_tpu.models import hybrid_moe
     from paddle_tpu.ops.pallas import flash_attention
@@ -532,6 +539,10 @@ def test_hybrid_segment_holds_both_caches_once(shaped, no_persistent_cache,
                      "prefill_attention_full": 1,
                      "prefill_attention_window": 4}
     assert len(_kernel_call_sites(text, "grouped_expert_matmul")) == 16
+    trips = re.findall(r"compare\(([^)]*)\), direction=LT, metadata=\{"
+                       r"op_name=\"[^\"]*segment\.admit/while/cond/lt\"", text)
+    assert trips and not [t for t in trips if "constant" in t], trips
+    assert not re.findall(r"\[(?:4096,18432|33280,6144)\]", text)
 
     row_layer = pages * PAGE * cfg.kv_width
     moved = _moved(text, row_layer, of_pages=pages)
@@ -551,7 +562,9 @@ def test_hybrid_segment_holds_both_caches_once(shaped, no_persistent_cache,
     mem = compiled.memory_analysis()
     pool_bytes = 2 * row_layer * 2 + 2 * 4 * part_layer * 2
     assert mem.alias_size_in_bytes >= pool_bytes     # donated, in place
-    assert mem.temp_size_in_bytes < 2 * 2**30, mem.temp_size_in_bytes
+    # 1,084,419,072 B + 10 % (1,588,627,456 B with the whole bucket's rows
+    # in every intermediate)
+    assert mem.temp_size_in_bytes < 1_190_000_000, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
 
 

@@ -5,7 +5,8 @@ admissions then ticks through the row pages and the fixed parts, past twice
 the window and on reused slots, (b) the window itself and the planted
 faults, (c) the share, (d) the cache manager's two kinds, (e) the engine end
 to end with its counters, (f) the kernels in interpret mode, at the
-published head counts too, (g) the refusals and the scopes.
+published head counts too, (g) the refusals and the scopes, (h) an admission
+in row blocks against the same admission in one trip.
 
 Tolerances: the program and the reference are both float32 here and differ
 by the order of their sums (a paged gather and an online softmax against
@@ -25,6 +26,7 @@ import pytest
 import reference_hybrid_moe as ref
 from paddle_tpu.inference.paged_kv import PagedKVCache
 from paddle_tpu.inference.scheduler import Arrival, OnlineScheduler
+from paddle_tpu.inference import serving
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.models import family_of, hybrid_moe as hm, latent_moe, require
 from paddle_tpu.ops.pallas import (grouped_matmul, paged_attention,
@@ -102,6 +104,24 @@ def kernels_interpreted():
         yield
 
 
+@pytest.fixture(params=[None, 8], ids=["one_trip", "block_8"])
+def admit_block(request, monkeypatch):
+    """The admission's row block as the module has it (wider than every
+    bucket here: one trip) or forced to 8 rows (an engine then builds its
+    programs anew: the process-wide store is keyed by the configuration)."""
+    if request.param:
+        monkeypatch.setattr(hm, "ADMIT_BLOCK", request.param)
+        monkeypatch.setattr(serving, "_SHARED_PROGS", {})
+    return request.param
+
+
+def computed_rows(width, n):
+    """Rows an admission of ``n`` prompt rows in a bucket of ``width``
+    computes: its row blocks up to the prompt's end."""
+    c = width if width % hm.ADMIT_BLOCK else hm.ADMIT_BLOCK
+    return -(-n // c) * c
+
+
 def tables(B):
     """Slot b: row pages 1 + b*MAX_PAGES .., fixed part b + 1."""
     pages = 1 + np.arange(B * MAX_PAGES, dtype=np.int32).reshape(B, -1)
@@ -142,7 +162,8 @@ def paged_run(cfg, params, prompts, n_decode, widths=None, dead=(),
         logits, pool, cnt = forward(
             jnp.asarray(row), pool, jnp.asarray(table[b:b + 1]),
             jnp.zeros((1,), jnp.int32), logit_pos=jnp.int32(len(p) - 1))
-        assert list(np.asarray(cnt[4:])) == [0, 0, widths[b], len(p)]
+        assert list(np.asarray(cnt[4:])) == [
+            0, 0, computed_rows(widths[b], len(p)), len(p)]
         got[b].append(np.asarray(logits[0]))
         full[b][len(p)] = int(np.argmax(logits[0]))
     pos = np.array([len(p) for p in prompts], np.int32)
@@ -184,9 +205,11 @@ def check_against_reference(cfg, params, got, full, prompts, m=None):
 
 # (a) ----------------------------------------------------------------------
 
-def test_admissions_then_ticks_through_both_caches_match_reference(tiny):
+def test_admissions_then_ticks_through_both_caches_match_reference(
+        tiny, admit_block):
     """Every sequence ends past 2 x the window (the ring wraps at least
-    twice), admitted at three widths; slot 1 stops after its admission."""
+    twice), admitted at three widths (one, two and three of four row
+    blocks at a block of 8); slot 1 stops after its admission."""
     cfg, params = tiny
     got, full, _ = paged_run(cfg, params, PROMPTS, 22, widths=[8, 16, 32],
                              dead=(1,))
@@ -468,7 +491,7 @@ def serve(cfg, params, slots=4):
     return report, sched.results(), eng
 
 
-def test_engine_serves_the_references_greedy_tokens(tiny):
+def test_engine_serves_the_references_greedy_tokens(tiny, admit_block):
     cfg, params = tiny
     assert family_of(cfg) is hm
     report, results, eng = serve(cfg, params)
@@ -494,7 +517,8 @@ def test_engine_serves_the_references_greedy_tokens(tiny):
              range(len(a.prompt), len(a.prompt) + a.max_new_tokens - 1)]
     assert report.counters["window"]["rows_full"] == sum(ticks)
     assert report.counters["window"]["rows_window"] == 4 * sum(min(t, W) for t in ticks)
-    assert report.counters["window"]["admit_rows"] == 32 * len(requests())
+    assert report.counters["window"]["admit_rows"] == \
+        sum(computed_rows(32, len(a.prompt)) for a in requests())
     assert report.counters["window"]["admit_rows_used"] == \
         sum(len(a.prompt) for a in requests())
     assert report.counters["window"]["steps"] == report.moe["steps"]
@@ -539,7 +563,7 @@ def test_counters_and_tokens_identical_with_a_trace_live(tiny, tmp_path):
 
 # (f) ----------------------------------------------------------------------
 
-def test_kernels_through_the_model_match_reference(tiny):
+def test_kernels_through_the_model_match_reference(tiny, admit_block):
     """The three kernels in interpret mode (the admission's over its own
     rows, the paged one over the row pages and over the fixed part as one
     page, the grouped expert matmul) give the reference's logits."""
@@ -611,6 +635,112 @@ def test_tick_over_a_fixed_part_matches_the_gather(heads):
         got = hm._tick_attention(cfg, q, kp, vp, 1, part, ctx, q_len,
                                  hm.WINDOW)
     np.testing.assert_allclose(got[:3], want[:3], rtol=1e-4, atol=1e-5)
+
+
+# (h) ----------------------------------------------------------------------
+
+BUCKET, BLOCK = 32, 8
+PAD = 255                # the padding's token: no prompt below holds it
+
+
+def admit(cfg, params, lengths, block, pad_from=None):
+    """One admission of ``len(lengths)`` prompts in a bucket of 32 at a row
+    block of ``block``; positions from ``pad_from`` on hold ``PAD``.
+    Returns (logits, pool, counters)."""
+    B = len(lengths)
+    rng = np.random.RandomState(sum(lengths))
+    tokens = np.zeros((B, BUCKET), np.int32)
+    for b, n in enumerate(lengths):
+        tokens[b, :n] = rng.randint(0, PAD, (n,))
+    if pad_from is not None:
+        tokens[:, pad_from:] = PAD
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(hm, "ADMIT_BLOCK", block)
+        out = jax.jit(lambda t, pool: hm.forward_with_pages(
+            params, t, cfg, pool, jnp.asarray(tables(B)),
+            jnp.zeros((B,), jnp.int32),
+            logit_pos=jnp.asarray(lengths, jnp.int32) - 1,
+            with_counters=True))(jnp.asarray(tokens), fresh_pool(cfg, B))
+    return jax.tree_util.tree_map(np.asarray, out)
+
+
+def written(pool, lengths):
+    """Every row the prompts' own rows wrote: a slot's row pages up to its
+    length, and its whole fixed part (ring rows past a prompt shorter than
+    the window hold the prompt's row 0: ``_admit``)."""
+    rows = []
+    for b, n in enumerate(lengths):
+        pages = tables(len(lengths))[b]
+        rows += [pool[m][:, pages[:-1]].reshape(
+            pool[m].shape[0], -1, pool[m].shape[-1])[:, :n]
+                 for m in ("k", "v")]
+        rows += [pool[m][:, pages[-1]] for m in ("wk", "wv")]
+    return rows
+
+
+@pytest.mark.parametrize("lengths", [
+    (1,), (BLOCK - 1,), (BLOCK,), (BLOCK + 1,), (2 * BLOCK,), (BUCKET,),
+    (1, BLOCK + 1), (2 * BLOCK, BLOCK - 1), (BLOCK, BUCKET)],
+    ids=lambda lengths: "x".join(map(str, lengths)))
+def test_blocked_admission_is_the_one_shot_admission(tiny, lengths):
+    """Row blocks of 8 under a trip count from the longest prompt against
+    ONE trip over the bucket: the logits at each prompt's last row, every
+    row both caches receive from a prompt's own rows, and the counters
+    (two block widths are two matmul shapes: equal to rounding; the
+    counters exactly, but for the rows computed)."""
+    cfg, params = tiny
+    logits, pool, cnt = admit(cfg, params, lengths, BLOCK)
+    want_logits, want_pool, want_cnt = admit(cfg, params, lengths, BUCKET)
+    np.testing.assert_allclose(logits, want_logits, rtol=1e-4, atol=1e-5)
+    for got, want in zip(written(pool, lengths),
+                         written(want_pool, lengths)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    names = hm.SEGMENT_COUNTERS
+    got, want = dict(zip(names, cnt)), dict(zip(names, want_cnt))
+    B, blocks = len(lengths), -(-max(lengths) // BLOCK)
+    assert want.pop("admit_rows") == B * BUCKET
+    assert got.pop("admit_rows") == B * blocks * BLOCK
+    assert got == want and got["admit_rows_used"] == sum(lengths)
+
+
+@pytest.mark.parametrize("lengths", [(3,), (BLOCK,), (BLOCK + 2, 5)],
+                         ids=lambda lengths: "x".join(map(str, lengths)))
+def test_rows_past_the_last_block_are_never_read(tiny, lengths):
+    """NaN in the embedding of the tokens past the last row block: the
+    logits and every row a prompt wrote are what they are without it, bit
+    for bit, and the row pages past the last block hold zeros."""
+    cfg, params = tiny
+    end = -(-max(lengths) // BLOCK) * BLOCK
+    logits, pool, cnt = admit(cfg, params, lengths, BLOCK, pad_from=end)
+    poisoned = dict(params, embed=params["embed"].at[PAD].set(jnp.nan))
+    got_logits, got_pool, got_cnt = admit(cfg, poisoned, lengths, BLOCK,
+                                          pad_from=end)
+    np.testing.assert_array_equal(got_logits, logits)
+    np.testing.assert_array_equal(got_cnt, cnt)
+    for got, want in zip(written(got_pool, lengths),
+                         written(pool, lengths)):
+        np.testing.assert_array_equal(got, want)
+    mine = tables(len(lengths))[0, :-1]
+    for m in ("k", "v"):
+        rows = got_pool[m][:, mine].reshape(1, -1, cfg.kv_width)
+        assert not rows[:, end:BUCKET].any() and rows[:, :end].any(-1).all()
+
+
+def test_counters_keep_their_meaning_over_three_blocks(tiny):
+    """A prompt of 20 rows at a block of 8: a held expert counts once a
+    layer an admission however many blocks hit it, the picks are the
+    prompt's, the rows computed are the three blocks'."""
+    cfg, params = tiny
+    _, _, cnt = admit(cfg, params, (20,), BLOCK)
+    c = dict(zip(hm.SEGMENT_COUNTERS, cnt))
+    layers = cfg.num_expert_layers
+    # (32 picks a block over 16 experts, 4 held: a sum over the blocks
+    # would pass the held experts)
+    assert SHARE[1] * layers // 2 < c["experts_hit"] <= SHARE[1] * layers
+    assert c["picks"] == cfg.num_experts_per_tok * 20 * layers
+    assert 0 < c["max_load"] <= c["picks_held"] < c["picks"]
+    assert (c["admit_rows"], c["admit_rows_used"]) == (3 * BLOCK, 20)
+    assert (c["rows_full"], c["rows_window"]) == (0, 0)
 
 
 # (g) ----------------------------------------------------------------------
