@@ -1099,6 +1099,9 @@ class FleetRouter:
                     # targets busy, so the next turn dispatches them)
                     self._pre_dispatch(None)
                 elif pending:
+                    # waiting for work: no engine's gap spans it
+                    for r in reps:
+                        r.engine.gap_from_ns = None
                     gap = pending[0].t - (_journal.now() - t0)
                     if gap > 0:
                         _journal.sleep(min(gap, 0.05))

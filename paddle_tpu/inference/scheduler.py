@@ -193,9 +193,13 @@ class OnlineReport:
     # reduction of the ``serving.sched.ingest`` / ``serving.segment.*``
     # spans: phase -> {"seconds", "count"} over the serve (``telemetry``
     # is entered twice a segment: the engine's counters, then this
-    # loop's stamps) — and the means over requests of the four parts a
-    # first token's wait splits into (``_ttft_parts``; they sum to the
-    # mean first-token time)
+    # loop's stamps; ``put``, PR 38, lies inside ``inputs``; ``gap``,
+    # PR 38, is no span but the interval from a fetch's return to the
+    # next launch's return, every segment's but a serve's first, one
+    # after a loop turn that waited for work and one in which a jax
+    # trace started or stopped) — and the means over
+    # requests of the four parts a first token's wait splits into
+    # (``_ttft_parts``; they sum to the mean first-token time)
     segment_phases: Optional[Dict[str, dict]] = None
     ttft_parts_mean_s: Optional[Dict[str, float]] = None
     # the model's per-step counters over the serve, a dict a group of its
@@ -208,10 +212,6 @@ class OnlineReport:
     # window layers, the admissions' rows likewise). A new family adds a
     # group in its own module and nothing here
     counters: Dict[str, Dict[str, int]] = field(default_factory=dict)
-    # PR 31: a paged engine's ``page_slots`` (rows x table width of every
-    # paged attention call, a layer) and ``pages_fetched`` (the pages
-    # those rows hold: ``pages_read``) over the serve's segments
-    page_reads: Optional[Dict[str, int]] = None
     per_request: List[dict] = field(default_factory=list)
 
     @property
@@ -409,7 +409,7 @@ class OnlineScheduler:
         # engine's phase spans and this loop's tally into one dict
         phases = eng.segment_phases = {}
         eng.segment_counts = {}
-        eng.segment_pages = {}
+        eng.gap_from_ns = None
         t0 = _journal.now()
         self._serve_t0 = t0
         while pending or eng._queue or eng.free_slot_count() < eng.slots:
@@ -442,7 +442,9 @@ class OnlineScheduler:
                                     else None))
             if idle:
                 # nothing admitted and nothing decoding: sleep to the
-                # next arrival instead of spinning
+                # next arrival instead of spinning (that wait is the
+                # traffic's, so no gap spans it)
+                eng.gap_from_ns = None
                 if pending:
                     gap = pending[0].t - (_journal.now() - t0)
                     if gap > 0:
@@ -520,7 +522,6 @@ class OnlineScheduler:
                                 for k in TTFT_PARTS} if parts else None),
             counters={group: dict(counts, steps=eng.last_run_ticks)
                       for group, counts in eng.segment_counts.items()},
-            page_reads=dict(eng.segment_pages) or None,
             **self._report_extras(reqs),
             per_request=[{
                 "rid": r.rid,

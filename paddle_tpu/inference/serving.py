@@ -198,7 +198,6 @@ class _PendingSegment:
     # its event log additionally carries a [steps, n] int32 column block
     counters: bool = False
     seg: int = 0                   # the engine's index of this segment
-    s_max: int = 0                 # the admit window's width
 
 
 @dataclass
@@ -585,15 +584,17 @@ class ServingEngine:
         # reduces it into ``OnlineReport.segment_phases``
         self.seg_index = 0
         self.segment_phases: Dict[str, list] = {}
+        # PR 38: the return of the last ``fetch`` (its span's end stamp),
+        # where the next segment's gap opens; None where no gap is open
+        # (a serve's first segment, a loop turn that waited for work),
+        # and whether a jax trace was live then
+        self.gap_from_ns: Optional[int] = None
+        self._gap_traced = False
         # PR 29: sums of the model's per-step counters, by group
         # (``serving.<group>.*`` of its ``COUNTER_GROUPS``: ``moe``,
         # ``retention``, ``window``) over the segments since the serve
         # loop last reset the dict
         self.segment_counts: Dict[str, Dict[str, int]] = {}
-        # PR 31: page slots the paged attention calls of those segments
-        # were handed (rows x table width a step) and the pages they had
-        # to fetch (``pages_read``), per layer — ``serving.pages_*``
-        self.segment_pages: Dict[str, int] = {}
         # r14 cold-start metric (ISSUE 9 satellite; ROADMAP item 5's
         # first deliverable): build→first-emitted-token wall time, the
         # number autoscaling/rollout decisions gate on. Stamped ONCE per
@@ -1014,11 +1015,29 @@ class ServingEngine:
                            tally=self.segment_phases,
                            seg=self.seg_index if seg is None else seg)
 
+    def _open_gap(self, fetch_end_ns: int) -> None:
+        """The next segment's gap opens where this ``fetch`` returned."""
+        self.gap_from_ns = fetch_end_ns
+        self._gap_traced = _hooks.tracing()
+
+    def _close_gap(self, launch_end_ns: int) -> None:
+        """``serving.segment.gap`` (PR 38): from the return of the last
+        ``fetch`` to the return of this ``launch`` — the host time in
+        which this engine has nothing in flight — into
+        ``segment_phases`` and to collectors, from the two phase spans'
+        own stamps. A gap in which a jax trace started or stopped is
+        the profiler's (a slice's ``stop_trace`` writes the whole
+        trace), and is dropped like one that waited for work."""
+        t0, self.gap_from_ns = self.gap_from_ns, None
+        if t0 is not None and self._gap_traced == _hooks.tracing():
+            _hooks.record("serving.segment.gap", t0, launch_end_ns,
+                          "serving", tally=self.segment_phases)
+
     def _replay_segment(self, picked, toks, aq, aslot, steps: int, n: int,
                         on_admit=None, on_retire=None,
                         chunk_marker: Optional[int] = None,
                         acc=None, spec_stats: Optional[dict] = None,
-                        dig=None, on_tick=None):
+                        dig=None):
         """Host replay of a segment's event log: walk the log
         chronologically, tracking slot occupancy (admits rebind a slot;
         decode ticks append one token to every slot the HOST knows is
@@ -1026,12 +1045,10 @@ class ServingEngine:
         are dropped). ``on_admit(q, slot)`` / ``on_retire(req, slot)``
         are the page-table bookkeeping hooks, called in event order so a
         slot freed and re-admitted mid-segment releases the old
-        occupant's pages before the new page list installs;
-        ``on_tick(live)`` sees each decode tick's live (slot, request)
-        pairs before their tokens land. ``chunk_marker`` (chunked
-        prefill): aq values >= it mark NON-FINAL prefill-chunk steps —
-        no decode ran and no token surfaced there, so the replay skips
-        the step.
+        occupant's pages before the new page list installs.
+        ``chunk_marker`` (chunked prefill): aq values >= it mark
+        NON-FINAL prefill-chunk steps — no decode ran and no token
+        surfaced there, so the replay skips the step.
 
         r15 speculative event logs: ``acc`` ([steps, slots]) makes
         ``toks`` a [steps, slots, K+1] token matrix — a decode step is
@@ -1088,8 +1105,6 @@ class ServingEngine:
             elif acc is None:              # decode tick
                 live_now = [(s, r) for s, r in enumerate(self._active)
                             if r is not None and self._rem_host[s] > 0]
-                if on_tick is not None:
-                    on_tick(live_now)
                 share = 1.0 / len(live_now) if live_now else 0.0
                 for s, r in live_now:
                     # r18 meter: every live slot consumed this tick's
@@ -1248,13 +1263,6 @@ class ServingEngine:
                 total[name] = total.get(name, 0) + v
         return seg
 
-    def _page_telemetry(self, reads: Dict[str, int]) -> None:
-        """One segment's ``pages_fetched`` / ``page_slots`` into the
-        ``serving.*`` counters of those names and ``segment_pages``."""
-        for name, v in reads.items():
-            _metrics.counter(f"serving.{name}").inc(v)
-            self.segment_pages[name] = self.segment_pages.get(name, 0) + v
-
     def _spec_telemetry(self, stats: dict) -> None:
         """Per-segment speculative accounting (r15 satellite): counters
         for drafts proposed/accepted/rejected, the live accept-rate and
@@ -1384,6 +1392,7 @@ class ServingEngine:
         recovered replica re-enters service empty."""
         orphans: List[Request] = []
         p, self._pending_seg = self._pending_seg, None
+        self.gap_from_ns = None
         released_rids = set()
         if p is not None:
             for pages in p.req_pages:
@@ -2541,21 +2550,23 @@ class ServingEngine:
 
             # the host -> device copies (one small program each: the
             # jit_convert_element_type programs a device trace shows
-            # between two segments)
-            dev_in = [jnp.asarray(a) for a in
-                      (prompts, lens, gens, pre_lens, req_tables)]
-            if spec:
-                dev_in.append(jnp.asarray(seeds))
-            dev_in.append(jnp.int32(n))
+            # between two segments), a phase of their own inside inputs
+            with self._phase("put"):
+                dev_in = [jnp.asarray(a) for a in
+                          (prompts, lens, gens, pre_lens, req_tables)]
+                if spec:
+                    dev_in.append(jnp.asarray(seeds))
+                dev_in.append(jnp.int32(n))
 
         if spec:
-            with self._phase("launch"), _mesh_scope(self.mesh):
+            with self._phase("launch") as launch, _mesh_scope(self.mesh):
                 rng = (self._rng if self._rng is not None
                        else jnp.zeros((self.slots, 2), jnp.uint32))
                 out = self._spec_segment_prog(n_pad, max_steps)(
                     self.params, pgr.pool, pgr.page_table, self._pos,
                     self._nxt, self._rem, self._hist, self._hstart, rng,
                     *dev_in)
+            self._close_gap(launch.t1)
             pgr.pool, pgr.page_table = out[0], out[1]
             self._pos, self._nxt, self._rem = out[2:5]
             self._hist, self._hstart = out[5], out[6]
@@ -2568,7 +2579,7 @@ class ServingEngine:
                                    full_prompts=fulls,
                                    chunk_marker=chunk_marker, spec=True)
 
-        with self._phase("launch"), _mesh_scope(self.mesh):
+        with self._phase("launch") as launch, _mesh_scope(self.mesh):
             prog = (self._sp_segment_prog(n_pad, s_max, C, max_steps)
                     if sp_mode
                     else self._chunked_segment_prog(n_pad, s_max, C,
@@ -2578,20 +2589,19 @@ class ServingEngine:
             out = prog(
                 self.params, pgr.pool, pgr.page_table, self._pos, self._nxt,
                 self._rem, *dev_in)
+        self._close_gap(launch.t1)
         pgr.pool, pgr.page_table = out[0], out[1]
         self._pos, self._nxt, self._rem = out[2:5]
         return _PendingSegment(picked=picked, n=n, now=now,
                                prefix_cache=prefix_cache, dev=out[5:],
                                pre_lens=pre_lens_l, req_pages=req_pages,
-                               full_prompts=fulls, s_max=s_max,
+                               full_prompts=fulls,
                                chunk_marker=chunk_marker,
                                digest=self.quality_digest, sp=sp_mode,
                                counters=hasattr(self.model,
                                                 "SEGMENT_COUNTERS"))
 
     def _finish_segment_paged(self, p: _PendingSegment) -> dict:
-        from ..ops.pallas.paged_attention import pages_read
-
         picked, n, prefix_cache = p.picked, p.n, p.prefix_cache
         pre_lens_l, req_pages = p.pre_lens, p.req_pages
         pgr = self.pager
@@ -2607,7 +2617,7 @@ class ServingEngine:
         tier = getattr(prefix_cache, "host_tier", None) \
             if prefix_cache is not None else None
         staged = tier.take_pending() if tier is not None else []
-        with self._phase("fetch", p.seg), \
+        with self._phase("fetch", p.seg) as fetch, \
                 allowed_sync("serving.segment_event_fetch"):
             payload = (p.dev if not staged
                        else (p.dev, [s[2:] for s in staged]))
@@ -2630,6 +2640,7 @@ class ServingEngine:
                 toks, aq, aslot, counts, steps, qadm = dev
             else:
                 toks, aq, aslot, steps, qadm = dev
+        self._open_gap(fetch.t1)
         if staged:
             tier.complete(staged, got[1])
         steps, qadm = int(steps), int(qadm)
@@ -2645,28 +2656,9 @@ class ServingEngine:
             # harvest-by-reference can still retain a finished request's
             # prompt pages
             pending_frees: List[List[int]] = []
-            # what the segment's paged attention calls were handed and
-            # what they had to fetch, a layer: an admission is one row
-            # of ``s_max`` queries after its reused prefix, a tick one
-            # query a slot (free slots fetch nothing) at the position
-            # the host holds; the hooks only note the positions. The
-            # chunked, speculative and sequence-parallel programs'
-            # prefill steps are not replayed, so their segments are
-            # not reckoned.
-            counted = p.chunk_marker is None
-            admit_ctx: List[int] = []
-            tick_ctx: List[int] = []
-            ticks = 0
 
             def on_admit(q, s):
                 pgr.install(s, req_pages[q])
-                admit_ctx.append(pre_lens_l[q])
-
-            def on_tick(live):
-                nonlocal ticks
-                ticks += 1
-                tick_ctx.extend([len(r.prompt) + len(r.tokens) - 1
-                                 for _, r in live])
 
             def on_retire(r, s):
                 r._meter_release()
@@ -2677,8 +2669,7 @@ class ServingEngine:
              eos_stops) = self._replay_segment(
                  picked, toks, aq, aslot, steps, n, on_admit, on_retire,
                  chunk_marker=p.chunk_marker, acc=acc,
-                 spec_stats=spec_stats, dig=dig,
-                 on_tick=on_tick if counted else None)
+                 spec_stats=spec_stats, dig=dig)
             if p.chunk_marker is not None:
                 chunk_steps = int(np.sum(np.asarray(aq[:steps])
                                          >= p.chunk_marker))
@@ -2744,18 +2735,6 @@ class ServingEngine:
                                     new_tokens, max(0, n - qadm))
             if counts is not None:
                 counts = self._count_telemetry(counts[:steps])
-            reads = None
-            if counted:
-                def held(ctx, q_len):
-                    return int(np.minimum(pages_read(
-                        np.asarray(ctx, np.int64), q_len, psz),
-                        pgr.max_pages).sum())
-
-                reads = {"pages_fetched": held(admit_ctx, p.s_max)
-                         + held(tick_ctx, 1),
-                         "page_slots": (len(admit_ctx) + ticks
-                                        * self.slots) * pgr.max_pages}
-                self._page_telemetry(reads)
         out = {"steps": steps, "admitted": admitted,
                "first_tokens": first_tokens,
                "first_token_steps": first_steps, "finished": finished,
@@ -2764,8 +2743,6 @@ class ServingEngine:
             out["spec"] = spec_stats
         if counts is not None:
             out["counters"] = counts
-        if reads is not None:
-            out.update(reads)
         return out
 
     def collect_finished(self) -> Dict[int, List[int]]:

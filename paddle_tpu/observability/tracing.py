@@ -5,8 +5,11 @@
 plane, on the device planes' clock) and reports to every recording
 ``paddle.profiler.Profiler``. The serve loop's spans are there, where the
 work happens: ``serving.sched.ingest``, ``serving.segment`` and its phases
-``serving.segment.{pick,inputs,launch,fetch,replay,telemetry}``
-(inference/scheduler.py, inference/serving.py).
+``serving.segment.{pick,inputs,launch,fetch,replay,telemetry}`` (``put``,
+the host -> device copies, inside ``inputs``; inference/scheduler.py,
+inference/serving.py). ``serving.segment.gap`` (an engine's fetch return
+-> its next launch return, PR 38) is stamped from two of those spans'
+own stamps and, like the replays below, reaches collectors only.
 
 This module adds what can only be stamped AFTER the fact, from
 ``perf_counter`` stamps the serve loop already took, with no clock source

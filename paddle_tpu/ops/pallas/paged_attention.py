@@ -72,8 +72,7 @@ def pages_read(ctx_len, q_len, page_size: int):
     """Pages the kernel fetches for a slot whose chunk ends at position
     ``ctx_len + q_len - 1`` (keys [0, end] visible -> end//page + 1), and
     none for a slot with no query row. The kernel's loop over a slot's
-    pages runs to exactly this bound; the engine's ``pages_fetched``
-    counter is its sum over a segment's steps."""
+    pages runs to exactly this bound."""
     return ((ctx_len + q_len - 1) // page_size + 1) * (q_len > 0)
 
 

@@ -171,6 +171,12 @@ class Profiler:
         halves, ``grad_clip``, ``optimizer``; a named Pallas kernel is a
         leaf). A TPU trace carries the names; where a backend's does not
         (CPU), pass ``scopes=_xplane.scope_map(compiled.as_text())``.
+        "Device idle by host span" (DeviceView) answers why the chip
+        waited: its idle time between programs under the innermost
+        ``serving.*`` span open on the host (``_xplane.idle_by_span``):
+        under ``serving.segment.fetch`` the device finished before the
+        host was told, under ``.launch`` it was dispatched and had not
+        started, under ``serving.segment`` itself code no phase names.
         ``views`` selects a subset (SummaryView values)."""
         from . import _xplane
 
@@ -205,6 +211,10 @@ class Profiler:
             head = f"Device op view ({dev}"
             head += f", occupancy {occ:.1%})" if occ is not None else ")"
             print(_xplane.format_table(head, tables["modules"]))
+        if tables["idle"] and wanted(SummaryView.DeviceView):
+            print(_xplane.format_table("Device idle by host span",
+                                       tables["idle"], width=40,
+                                       count="gaps"))
         if tables["kernels"] and wanted(SummaryView.KernelView):
             print(_xplane.format_table("Device kernel view (HLO)",
                                        tables["kernels"]))

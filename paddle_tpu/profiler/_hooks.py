@@ -21,7 +21,10 @@ channels leave this module, and ``span`` writes to both:
 
 ``span(..., tally=d)`` also adds the span's duration to ``d[name]``
 (``[ns, count]``): the always-on reduction ``OnlineReport.segment_phases``
-is read from. Spans are per segment or per request, never per op:
+is read from. A span keeps its two stamps (``t0``, ``t1``) after it
+closes, so an interval between two spans is timed with no clock read of
+its own and reported by ``record`` (the engine's ``serving.segment.gap``,
+PR 38). Spans are per segment or per request, never per op:
 ``ops.dispatch`` (the hot path) imports nothing but this module, calls
 ``emit`` behind its single ``if COLLECTORS`` check, and gets no annotation.
 """
@@ -45,9 +48,27 @@ def now_ns() -> int:
     return time.perf_counter_ns()
 
 
+def tracing() -> bool:
+    """Whether a jax trace is live (annotations are being recorded)."""
+    return TraceAnnotation.is_enabled()
+
+
 def emit(name: str, start_ns: int, end_ns: int, kind: str = "op") -> None:
     for c in COLLECTORS:
         c._host_event(name, start_ns, end_ns, kind)
+
+
+def record(name: str, start_ns: int, end_ns: int, kind: str = "op",
+           tally: Optional[dict] = None) -> None:
+    """A finished interval: added to ``tally[name]`` (``[ns, count]``)
+    and, while a profiler records, emitted to it. Back-dated, so it
+    reaches no jax trace."""
+    if tally is not None:
+        acc = tally.setdefault(name, [0, 0])
+        acc[0] += end_ns - start_ns
+        acc[1] += 1
+    if COLLECTORS:
+        emit(name, start_ns, end_ns, kind)
 
 
 class span:
@@ -55,10 +76,11 @@ class span:
     serving scheduler and engine wrap each phase of a segment in one, so a
     trace shows what the host was doing beside the device's programs.
     ``ids`` ride the trace event as stats; ``tally`` (a dict) accumulates
-    ``[total ns, count]`` under the span's name. With no trace live and
-    no collector the cost is two clock reads and a no-op TraceMe."""
+    ``[total ns, count]`` under the span's name; ``t0`` / ``t1`` are its
+    ``perf_counter_ns`` stamps. With no trace live and no collector the
+    cost is two clock reads and a no-op TraceMe."""
 
-    __slots__ = ("name", "kind", "tally", "t0", "_ann")
+    __slots__ = ("name", "kind", "tally", "t0", "t1", "_ann")
 
     def __init__(self, name: str, kind: str = "op",
                  tally: Optional[dict] = None, **ids):
@@ -73,12 +95,7 @@ class span:
         return self
 
     def __exit__(self, *exc):
-        t1 = now_ns()
+        self.t1 = now_ns()
         self._ann.__exit__(*exc)
-        if self.tally is not None:
-            acc = self.tally.setdefault(self.name, [0, 0])
-            acc[0] += t1 - self.t0
-            acc[1] += 1
-        if COLLECTORS:
-            emit(self.name, self.t0, t1, self.kind)
+        record(self.name, self.t0, self.t1, self.kind, self.tally)
         return False

@@ -10,18 +10,21 @@ emits xplane protos into the trace dir — so this module PARSES it
   (program-level spans — the op-level view) and the "XLA Ops" line
   (HLO-instruction spans — the kernel-level view, and the per-SCOPE view:
   each op under the ``jax.named_scope`` path of its ``op_name``, PR 25),
-  device occupancy (busy module time / observed wall), and the measured
-  offset between the trace's clock and ``perf_counter_ns``.
+  device occupancy (busy module time / observed wall), the measured
+  offset between the trace's clock and ``perf_counter_ns``, and the
+  device's idle time between programs put down to the program's
+  ``serving.*`` host spans on the same clock (``idle_by_span``, PR 38).
 * ``parse`` -> chrome events: the same spans as chrome-trace "X" events,
   merged with the profiler's host spans into one ``chrome_trace.json``.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 
 def latest_xplane(log_dir: str) -> Optional[str]:
@@ -120,6 +123,89 @@ def _module_key(name: str) -> str:
 # line lists too — counting both would bill the same device time twice
 _CONTAINERS = frozenset({"while", "conditional", "call"})
 
+# the host spans the idle table reads, and its key for idle under none
+IDLE_SPANS = "serving."
+NO_SPAN = "(no span)"
+# the clock check's anchors: a segment program starts after the launch
+# span that dispatched it started, and ends before the fetch span that
+# waited for it ended
+SEGMENT_ANCHORS = ("jit_segment", "serving.segment.launch",
+                   "serving.segment.fetch")
+
+
+def device_offset(modules: List[Tuple[int, int, str]],
+                  spans: List[Tuple[int, int, str]]) -> int:
+    """ns to add to one device plane's times so that the host's and the
+    device's clocks agree on every segment: each ``jit_segment`` starts
+    after its ``launch`` span started and ends before its ``fetch`` span
+    ended (a span is paired with the program nearest its end). 0 where
+    they already agree, or where no shift satisfies both; else the least
+    shift that does."""
+    prog, launch, fetch = SEGMENT_ANCHORS
+    runs = [(s, e) for s, e, n in modules if n == prog]
+    if not runs:
+        return 0
+    starts = sorted(s for s, _ in runs)
+    ends = sorted(e for _, e in runs)
+
+    def nearest(xs, t):
+        i = bisect.bisect_left(xs, t)
+        return min(xs[max(0, i - 1):i + 1], key=lambda x: abs(x - t))
+
+    lo = max((s - nearest(starts, e) for s, e, n in spans if n == launch),
+             default=None)
+    hi = min((e - nearest(ends, e) for s, e, n in spans if n == fetch),
+             default=None)
+    if lo is None or hi is None or lo > hi or lo <= 0 <= hi:
+        return 0
+    return lo if lo > 0 else hi
+
+
+def idle_by_span(device_modules: Iterable[List[Tuple[int, int]]],
+                 spans: List[Tuple[int, int, str]]
+                 ) -> Dict[str, List[float]]:
+    """The device's idle time put down to the host span that was open.
+
+    ``device_modules``: per device plane, its ``XLA Modules`` events as
+    (start_ns, end_ns); ``spans``: host spans as (start_ns, end_ns,
+    name), on the same clock. Every interval between consecutive modules
+    of a plane is split over the spans that overlap it, each part given
+    to the INNERMOST span open there (the latest started: a phase wins
+    over ``serving.segment``, which wins over nothing), a part under no
+    span to ``NO_SPAN``. Returns {name: [gaps it has a part of, ns]},
+    summed over planes."""
+    spans = sorted(spans)
+    starts = [sp[0] for sp in spans]
+    longest = max((e - s for s, e, _ in spans), default=0)
+    out: Dict[str, List[float]] = {}
+    for modules in device_modules:
+        hi = None
+        for s, e in sorted(modules):
+            if hi is not None and s > hi:
+                for name, ns in _split_gap(hi, s, spans, starts,
+                                           longest).items():
+                    acc = out.setdefault(name, [0, 0.0])
+                    acc[0] += 1
+                    acc[1] += ns
+            hi = e if hi is None else max(hi, e)
+    return out
+
+
+def _split_gap(a, b, spans, starts, longest) -> Dict[str, float]:
+    """(a, b) split by the innermost span open in each part."""
+    lo = bisect.bisect_left(starts, a - longest)
+    cover = [sp for sp in spans[lo:bisect.bisect_left(starts, b)]
+             if sp[1] > a]
+    cuts = sorted({a, b} | {t for s, e, _ in cover for t in (s, e)
+                            if a < t < b})
+    parts: Dict[str, float] = {}
+    for p, q in zip(cuts, cuts[1:]):
+        open_ = [sp for sp in cover if sp[0] <= p and sp[1] >= q]
+        name = (max(open_, key=lambda sp: (sp[0], -sp[1]))[2] if open_
+                else NO_SPAN)
+        parts[name] = parts.get(name, 0.0) + (q - p)
+    return parts
+
 
 def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
     """Returns (tables, chrome_events) or (None, []) when no xplane exists.
@@ -131,6 +217,8 @@ def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
       'occupancy': float | None,   # busy/wall over the device plane
       'device': plane name,
       'clock_offset_ns': int | None,  # trace clock - perf_counter_ns
+      'idle': {span: [gaps, ns]},   # see idle_by_span
+      'idle_offset_ns': [int],      # per device plane, device_offset
     }
 
     An op's scope comes from its ``op_name``: on a TPU trace the event
@@ -140,7 +228,9 @@ def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
     ``XLA Ops`` line: there a host-thread event with an ``hlo_op`` stat
     is an op. ``clock_offset_ns`` is measured on a host-plane span that
     carries its own ``perf_counter_ns`` start as the stat ``pc_ns``
-    (``serving.segment``, ``profiler.clock``)."""
+    (``serving.segment``, ``profiler.clock``). ``idle`` reads event
+    names and times only, so the in-tree reader (no stats) gives it
+    too."""
     path = latest_xplane(log_dir)
     if path is None:
         return None, []
@@ -153,6 +243,8 @@ def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
               "device": "", "clock_offset_ns": None}
     chrome: List[dict] = []
     occs: List[float] = []
+    device_modules: List[List[Tuple[int, int, str]]] = []
+    host_spans: List[Tuple[int, int, str]] = []
 
     def add(table: str, key: str, ns: float) -> None:
         # accumulate across planes (multi-chip: every device plane runs
@@ -182,6 +274,10 @@ def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
                            _kernel_key(ev.name))
             elif line.name == "XLA Modules":
                 lo, hi, busy = None, None, 0.0
+                if is_device:
+                    device_modules.append(
+                        [(ev.start_ns, ev.start_ns + ev.duration_ns,
+                          _module_key(ev.name)) for ev in line.events])
                 for ev in line.events:
                     key = _module_key(ev.name)
                     add("modules", key, ev.duration_ns)
@@ -200,6 +296,10 @@ def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
                     tables["device"] = plane.name
             elif not is_device:
                 for ev in line.events:
+                    if ev.name.startswith(IDLE_SPANS):
+                        host_spans.append((ev.start_ns,
+                                           ev.start_ns + ev.duration_ns,
+                                           ev.name))
                     # (the in-tree fallback reader's events have no stats)
                     st = dict(getattr(ev, "stats", None) or ())
                     if "hlo_op" in st:      # the CPU backend's op events
@@ -211,18 +311,25 @@ def parse(log_dir: str, scopes: Optional[Dict[str, str]] = None):
                             int(ev.start_ns) - int(st["pc_ns"]))
     if occs:
         tables["occupancy"] = sum(occs) / len(occs)  # mean over planes
+    # the device's idle, read against the host spans on ONE clock: each
+    # plane shifted by what its segments say the two clocks disagree by
+    offsets = [device_offset(m, host_spans) for m in device_modules]
+    tables["idle_offset_ns"] = offsets
+    tables["idle"] = idle_by_span(
+        [[(s + d, e + d) for s, e, _ in m]
+         for m, d in zip(device_modules, offsets)], host_spans)
     return tables, chrome
 
 
 def format_table(title: str, rows: Dict[str, List[float]],
                  total_ns: Optional[float] = None, limit: int = 20,
-                 width: int = 34) -> str:
+                 width: int = 34, count: str = "calls") -> str:
     """name / calls / total / avg / share — the reference's summary shape."""
     if not rows:
         return ""
     total = total_ns or sum(v[1] for v in rows.values()) or 1.0
     out = [f"\n--- {title} " + "-" * max(1, 24 + width - len(title)),
-           f"{'name':<{width}} {'calls':>6} {'total(ms)':>10} "
+           f"{'name':<{width}} {count:>6} {'total(ms)':>10} "
            f"{'avg(us)':>9} {'share':>6}"]
     for name, (calls, ns) in sorted(rows.items(),
                                     key=lambda kv: -kv[1][1])[:limit]:

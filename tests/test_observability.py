@@ -747,6 +747,7 @@ class TestTelemetryAudit:
         from paddle_tpu.inference.scheduler import (OnlineScheduler,
                                                     staggered_arrivals)
         from paddle_tpu.inference.serving import ServingEngine
+        from paddle_tpu.observability import journal
         from paddle_tpu.parallel import set_mesh
 
         set_mesh(None)
@@ -771,10 +772,24 @@ class TestTelemetryAudit:
         sch.serve(arrivals)            # warm pass: compiles + fetches
         assert sch.results() == base, "speculative serve changed tokens"
 
-        def replay():
+        def serve():
             eng.reset_slots()
             sch._reqs.clear()
             return sch.serve(arrivals)
+
+        # the arrivals are staggered, so how many segments a serve takes
+        # follows the wall clock: under six test workers a slower segment
+        # let two requests in at once and the two audits counted 8 and 7
+        # fetches. Every audited serve replays one recorded clock, so the
+        # two sides make the same decisions
+        rec = journal.Journal()
+        with journal.attach(rec):
+            serve()
+        clock = [r["c"] for r in rec.records() if r["kind"] == "clock"]
+
+        def replay():
+            with journal.feed_clock(clock):
+                return serve()
 
         def audit(enabled):
             prev = metrics.set_enabled(enabled)

@@ -1,7 +1,9 @@
 """PR 25: the program times itself.
 
 (a) the serve loop's spans land on a live jax trace's host plane, nested
-    and numbered, and agree with ``OnlineReport.segment_phases``;
+    and numbered, and agree with ``OnlineReport.segment_phases``; the
+    gap between two segments (PR 38) runs from one's fetch to the next
+    one's launch, and the trace's device idle is read against the spans;
 (b) a first token's wait splits into four parts that sum to ``ttft_s``;
 (c) every device program, scope and kernel has a stable name, and the
     names are HLO metadata only;
@@ -31,7 +33,8 @@ from paddle_tpu.models import llama
 from paddle_tpu.parallel import set_mesh
 from paddle_tpu.profiler import _hooks, _xplane
 
-PHASES = ("pick", "inputs", "launch", "fetch", "replay", "telemetry")
+PHASES = ("pick", "inputs", "put", "launch", "fetch", "replay",
+          "telemetry")
 SEGMENT_SCOPES = ("embed", "qkv", "kv_write", "attention", "post", "head",
                   "sample", "segment.admit", "segment.decode")
 TRAIN_SCOPES = ("loss", "embed", "qkv", "attention", "post", "head",
@@ -82,24 +85,37 @@ def host_spans(log_dir):
     return out
 
 
+def close_to_the_trace(mine, traced, rows):
+    """A tally against the trace's own durations (seconds): a span's two
+    clock reads sit inside its annotation."""
+    return abs(traced - mine) <= 0.05 * traced + 50e-6 * rows
+
+
 # ---------------------------------------------------------------------------
 # (a) spans on the profiler's clock
 # ---------------------------------------------------------------------------
 
 class TestSpansInATrace:
-    def test_every_span_nested_numbered_and_summed(self, tiny, tmp_path):
+    @pytest.fixture(scope="class")
+    def traced(self, tiny, tmp_path_factory):
+        """One serve under a live jax trace: its report and its
+        ``serving.*`` host spans by name, (start, end, stats) each."""
         cfg, params = tiny
         eng = paged_engine(cfg, params)
         sch = OnlineScheduler(eng, seg_steps=4)
         sch.serve(arrivals(cfg))                 # builds the program
         eng.reset_slots()
         sch._reqs.clear()
-        with jax_trace(tmp_path):
+        log_dir = tmp_path_factory.mktemp("trace")
+        with jax_trace(log_dir):
             rep = sch.serve(arrivals(cfg))
-        spans = host_spans(tmp_path)
         by_name = {}
-        for name, s, e, st in spans:
+        for name, s, e, st in host_spans(log_dir):
             by_name.setdefault(name, []).append((s, e, st))
+        return rep, by_name
+
+    def test_every_span_nested_numbered_and_summed(self, traced):
+        rep, by_name = traced
         want = {"serving.sched.ingest", "serving.segment"} | {
             "serving.segment." + p for p in PHASES}
         assert set(by_name) == want
@@ -134,11 +150,58 @@ class TestSpansInATrace:
             assert mine["count"] == len(rows)
             # a span's own two clock reads sit inside its annotation
             assert mine["seconds"] <= traced
-            assert traced - mine["seconds"] <= \
-                0.05 * traced + 50e-6 * len(rows), (p, traced, mine)
+            assert close_to_the_trace(mine["seconds"], traced, len(rows)), \
+                (p, traced, mine)
             total_trace += traced
             total_report += mine["seconds"]
         assert total_report == pytest.approx(total_trace, rel=0.05)
+
+    def test_put_once_a_segment_inside_its_inputs(self, traced):
+        rep, by_name = traced
+        inputs = {st["seg"]: (s, e) for s, e, st in
+                  by_name["serving.segment.inputs"]}
+        puts = by_name["serving.segment.put"]
+        assert sorted(st["seg"] for _, _, st in puts) == sorted(inputs)
+        for s, e, st in puts:
+            lo, hi = inputs[st["seg"]]
+            assert lo <= s and e <= hi
+        # inside inputs, so segment_host_ms's phases keep their time
+        assert rep.segment_phases["put"]["seconds"] <= \
+            rep.segment_phases["inputs"]["seconds"]
+
+    def test_gap_from_a_fetch_to_the_next_launch(self, traced):
+        """Every segment after the first has a gap (the requests are all
+        due at once: the loop never waits for work), from the END of the
+        last segment's fetch span to the END of its launch span."""
+        rep, by_name = traced
+        ends = {}
+        for phase in ("fetch", "launch"):
+            ends[phase] = {st["seg"]: e for _, e, st in
+                           by_name["serving.segment." + phase]}
+        segs = sorted(ends["launch"])
+        assert len(segs) == rep.segments >= 3
+        gaps = [ends["launch"][b] - ends["fetch"][a]
+                for a, b in zip(segs, segs[1:])]
+        assert all(g > 0 for g in gaps)
+        mine = rep.segment_phases["gap"]
+        assert mine["count"] == len(gaps) == rep.segments - 1
+        assert close_to_the_trace(mine["seconds"], sum(gaps) / 1e9,
+                                  len(gaps)), (mine, sum(gaps) / 1e9)
+        # the gap is no TraceAnnotation: it reaches collectors only
+        assert "serving.segment.gap" not in by_name
+        # what fills it: every phase but fetch (and ``put``, inside
+        # ``inputs``) lies wholly inside one gap, but for the first
+        # segment's dispatch and the last one's replay and telemetry
+        windows = [(ends["fetch"][a], ends["launch"][b])
+                   for a, b in zip(segs, segs[1:])]
+        for names, outside in (
+                (("sched.ingest", "segment.pick", "segment.inputs",
+                  "segment.launch"), segs[0]),
+                (("segment.replay", "segment.telemetry"), segs[-1])):
+            for p in names:
+                for s, e, st in by_name["serving." + p]:
+                    assert st["seg"] == outside or any(
+                        lo <= s and e <= hi for lo, hi in windows), (p, st)
 
     def test_profiler_places_stamped_spans_by_measured_offset(
             self, tiny, tmp_path):
@@ -169,6 +232,65 @@ class TestSpansInATrace:
         hi = max(e["ts"] + e["dur"] for e in segs)
         for e in reqs:
             assert lo - 50e3 <= e["ts"] and e["ts"] + e["dur"] <= hi + 50e3
+        # PR 38: each gap (stamped, too) ends at a launch, inside a segment
+        gaps = [e for e in events if e["name"] == "serving.segment.gap"]
+        assert len(gaps) == len(segs) - 1
+        for g in gaps:
+            end = g["ts"] + g["dur"]
+            assert any(s["ts"] - 2e3 <= end <= s["ts"] + s["dur"] + 2e3
+                       for s in segs)
+
+
+class TestGapChain:
+    def test_a_loop_turn_that_waits_for_work_breaks_it(self, tiny):
+        """Two requests half a second apart, each served whole in one
+        segment: the loop waits for the second, so neither segment has a
+        gap (the first of a serve has none)."""
+        cfg, params = tiny
+        eng = paged_engine(cfg, params, slots=2)
+        sch = OnlineScheduler(eng, seg_steps=4)
+        sch.serve(arrivals(cfg, n=2, gen=2))      # builds the program
+        eng.reset_slots()
+        sch._reqs.clear()
+        rep = sch.serve(arrivals(cfg, n=2, gap=0.5, gen=2))
+        assert rep.segments == 2
+        assert "gap" not in rep.segment_phases
+        assert eng.gap_from_ns is not None        # the last fetch's end
+
+    def test_each_engine_times_its_own(self, tiny):
+        """``run_segment`` back to back outside a serve: a gap a segment
+        after the first, closed by the launch; ``abort`` drops the open
+        one."""
+        cfg, params = tiny
+        eng = paged_engine(cfg, params, slots=2)
+        rng = np.random.RandomState(0)
+        for _ in range(2):
+            eng.add_request(rng.randint(0, cfg.vocab_size, (6,))
+                            .astype(np.int32), 6)
+        for _ in range(3):
+            eng.run_segment(2)
+        assert eng.segment_phases["serving.segment.gap"][1] == 2
+        eng.abort()
+        assert eng.gap_from_ns is None
+
+    def test_a_gap_the_profiler_starts_or_stops_in_is_dropped(
+            self, tiny, tmp_path):
+        """A traced slice opens and closes between two segments: its
+        ``start_trace`` / ``stop_trace`` are the profiler's cost, not the
+        host's, so those two gaps do not count; the gap inside does."""
+        cfg, params = tiny
+        eng = paged_engine(cfg, params, slots=2)
+        rng = np.random.RandomState(1)
+        for _ in range(2):
+            eng.add_request(rng.randint(0, cfg.vocab_size, (6,))
+                            .astype(np.int32), 12)
+        eng.run_segment(2)
+        eng.run_segment(2)                       # gap 1
+        with jax_trace(tmp_path):
+            eng.run_segment(2)                   # dropped: trace started
+            eng.run_segment(2)                   # gap 2
+        eng.run_segment(2)                       # dropped: trace stopped
+        assert eng.segment_phases["serving.segment.gap"][1] == 2
 
 
 # ---------------------------------------------------------------------------
@@ -260,74 +382,6 @@ class TestFirstTokenSplit:
         assert sum(victim[k] for k in TTFT_PARTS) == \
             pytest.approx(victim["ttft_s"], abs=1.5e-4)
         assert victim["ttft_s"] < victim["e2e_s"]
-
-
-class TestPageCounters:
-    """PR 31: ``pages_fetched`` / ``page_slots`` of a paged segment — the
-    pages its attention calls had to fetch (``pages_read`` at the context
-    lengths the host holds) against the page slots they were handed (rows
-    x the table's width), a layer."""
-
-    def test_hand_made_segment(self, tiny):
-        """2 slots, page 8, table 12 wide, admit width 16: prompts of 6
-        and 12 tokens owed 2 and 4; admit, admit, then three ticks of
-        which the last two find slot 0 free."""
-        from paddle_tpu.ops.pallas.paged_attention import pages_read
-
-        cfg, params = tiny
-        eng = paged_engine(cfg, params, slots=2)
-        psz, width, s_max = eng.page_size, eng.pager.max_pages, 16
-        assert (psz, width) == (8, 12)
-        rng = np.random.RandomState(0)
-        for n, gen in ((6, 2), (12, 4)):
-            eng.add_request(rng.randint(0, cfg.vocab_size, (n,))
-                            .astype(np.int32), gen)
-        ev = eng.run_segment(8)
-        assert ev["steps"] == 5 and len(ev["admitted"]) == 2
-        admits = 2 * pages_read(0, s_max, psz)
-        ticks = [[(6, True), (12, True)],       # (position, live)
-                 [(7, False), (13, True)], [(8, False), (14, True)]]
-        fetched = admits + sum(int(pages_read(pos, int(live), psz))
-                               for tick in ticks for pos, live in tick)
-        assert fetched == 4 + (1 + 2) + 2 + 2
-        assert ev["pages_fetched"] == fetched
-        assert ev["page_slots"] == 2 * width + 3 * 2 * width
-        assert eng.segment_pages == {"pages_fetched": fetched,
-                                     "page_slots": ev["page_slots"]}
-
-    def test_report_sums_the_segments(self, tiny):
-        from paddle_tpu.observability import metrics
-
-        cfg, params = tiny
-        eng = paged_engine(cfg, params, slots=2)
-        seen = {"pages_fetched": 0, "page_slots": 0}
-        inner = eng.run_segment
-
-        def run_segment(*a, **k):
-            ev = inner(*a, **k)
-            for name in seen:
-                seen[name] += ev[name]
-            return ev
-
-        eng.run_segment = run_segment
-        before = {n: metrics.counter("serving." + n).value for n in seen}
-        rep = OnlineScheduler(eng, seg_steps=3).serve(
-            arrivals(cfg, n=5, gen=4))
-        assert rep.page_reads == seen == rep.as_dict()["page_reads"]
-        assert 0 < seen["pages_fetched"] < seen["page_slots"]
-        for n in seen:
-            assert metrics.counter("serving." + n).value - before[n] \
-                == seen[n]
-
-    def test_chunked_engine_reports_none(self, tiny):
-        """A chunked segment's prefill steps are not replayed, so its
-        page reads are not reckoned."""
-        cfg, params = tiny
-        eng = ServingEngine(cfg, params, slots=2, max_len=96, page_size=8,
-                            prompt_buckets=(16,), chunked_prefill=True)
-        rep = OnlineScheduler(eng, seg_steps=6).serve(
-            arrivals(cfg, n=2, gen=3))
-        assert rep.n_requests == 2 and rep.page_reads is None
 
 
 # ---------------------------------------------------------------------------
@@ -605,3 +659,180 @@ class TestSpansTouchNothingElse:
         assert not any("ingest" in str(k) or "slot_wait" in str(k)
                        for r in recs1 for k in r)
         assert len(host_spans(tmp_path)) > 0
+
+
+# ---------------------------------------------------------------------------
+# (e) the device's idle time, by the host span open in it (PR 38)
+# ---------------------------------------------------------------------------
+
+class _Ev:
+    """An event as the in-tree reader gives it: a name and times, no
+    stats."""
+
+    def __init__(self, name, start_ns, end_ns):
+        self.name, self.start_ns = name, start_ns
+        self.duration_ns = end_ns - start_ns
+
+
+class _Line:
+    def __init__(self, name, events):
+        self.name, self.events = name, [_Ev(*e) for e in events]
+
+
+class _Plane:
+    def __init__(self, name, lines):
+        self.name = name
+        self.lines = [_Line(k, v) for k, v in lines.items()]
+
+
+# two segments' boundary, ns: segment 0's module ends at 100, the
+# inputs' copy runs 130-131, segment 1's module starts at 140
+DEVICE = {"XLA Modules": [("jit_segment(1)", 0, 100),
+                          ("jit_convert_element_type(2)", 130, 131),
+                          ("jit_segment(1)", 140, 240)]}
+HOST = {"python": [
+    ("serving.segment", 0, 122), ("serving.segment.fetch", 50, 105),
+    ("serving.segment.replay", 105, 115),
+    ("serving.segment.telemetry", 115, 120),
+    ("serving.sched.ingest", 122, 126),         # 126-127: no span
+    ("serving.segment", 127, 300), ("serving.segment.pick", 128, 129),
+    ("serving.segment.inputs", 129, 135), ("serving.segment.put", 130, 134),
+    ("serving.segment.launch", 135, 139), ("serving.segment.fetch", 139, 245),
+    ("profiler.clock", 100, 140),               # not the program's
+]}
+# one plane's idle, by innermost span: [gaps with a part, ns]
+IDLE = {"serving.segment.fetch": [2, 6], "serving.segment.replay": [1, 10],
+        "serving.segment.telemetry": [1, 5], "serving.segment": [1, 3],
+        "serving.sched.ingest": [1, 4], _xplane.NO_SPAN: [1, 1],
+        "serving.segment.pick": [1, 1], "serving.segment.inputs": [2, 2],
+        "serving.segment.put": [1, 3], "serving.segment.launch": [1, 4]}
+
+
+def shifted(device, ns):
+    return {line: [(n, s + ns, e + ns) for n, s, e in evs]
+            for line, evs in device.items()}
+
+
+@pytest.fixture
+def planes():
+    return [_Plane("/device:TPU:0", DEVICE), _Plane("/device:TPU:1", DEVICE),
+            _Plane("/host:CPU", HOST)]
+
+
+@pytest.fixture
+def hand_built_trace(monkeypatch, tmp_path, planes):
+    """A trace directory whose xplane reads as ``planes`` (two device
+    planes and the host plane above), through a reader without stats."""
+
+    class Space:
+        @classmethod
+        def from_file(cls, path):
+            return type("S", (), {"planes": planes})()
+
+    (tmp_path / "x.xplane.pb").write_bytes(b"")
+    monkeypatch.setattr(_xplane, "_profile_data", lambda: Space)
+    return tmp_path
+
+
+class TestIdleBySpan:
+    def test_innermost_span_wins_and_the_rest_is_no_span(self):
+        spans = [(s, e, n) for n, s, e in HOST["python"]
+                 if n.startswith(_xplane.IDLE_SPANS)]
+        modules = [[(s, e) for _, s, e in DEVICE["XLA Modules"]]]
+        idle = _xplane.idle_by_span(modules, spans)
+        assert idle == IDLE
+        assert sum(v[1] for v in idle.values()) == (130 - 100) + (140 - 131)
+        # overlapping modules leave no gap; no span at all: all no-span
+        assert _xplane.idle_by_span([[(0, 10), (5, 20), (30, 40)]], []) == \
+            {_xplane.NO_SPAN: [1, 10]}
+
+    def test_parse_sums_the_device_planes(self, hand_built_trace):
+        tables, _ = _xplane.parse(str(hand_built_trace))
+        assert tables["idle_offset_ns"] == [0, 0]   # the clocks agree
+        assert tables["idle"] == {k: [2 * c, 2 * ns]
+                                  for k, (c, ns) in IDLE.items()}
+
+    def test_a_device_clock_that_disagrees_is_shifted_first(
+            self, hand_built_trace, planes):
+        """Plane 0 reads 6 ns early: its second segment would start
+        before the launch that dispatched it began (134 < 135), so it
+        is shifted by the least that makes every segment causal (1 ns);
+        plane 1 reads 20 ns late: its segments would end after their
+        fetches (120 > 105), shifted back by 15."""
+        planes[0] = _Plane("/device:TPU:0", shifted(DEVICE, -6))
+        planes[1] = _Plane("/device:TPU:1", shifted(DEVICE, 20))
+        tables, _ = _xplane.parse(str(hand_built_trace))
+        assert tables["idle_offset_ns"] == [1, -15]
+        spans = [(s, e, n) for n, s, e in HOST["python"]
+                 if n.startswith(_xplane.IDLE_SPANS)]
+        want = _xplane.idle_by_span(
+            [[(s + d, e + d) for _, s, e in DEVICE["XLA Modules"]]
+             for d in (-5, 5)], spans)
+        assert tables["idle"] == want
+        assert sum(v[1] for v in want.values()) == 2 * 39
+
+    def test_profiler_summary_prints_the_idle_view(self, hand_built_trace,
+                                                   capsys):
+        import paddle_tpu.profiler as profiler
+
+        profiler.Profiler(log_dir=str(hand_built_trace)).summary()
+        out = capsys.readouterr().out
+        assert "Device idle by host span" in out
+        row = next(ln for ln in out.splitlines()
+                   if ln.startswith("serving.segment.replay"))
+        assert row.split()[1:3] == ["2", "0.000"]
+
+
+# ---------------------------------------------------------------------------
+# (f) the benchmark's readers of the gap (chipbench/layer_metrics)
+# ---------------------------------------------------------------------------
+
+GAP_READERS = ("segment_gap_ms", "segment_gap_ms.saturated")
+
+
+def gap_reader(name):
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        os.pardir, "chipbench", "layer_metrics",
+                        name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "lm_" + name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def served_record(tiny):
+    """A report as the serve kinds record it (``as_dict``)."""
+    cfg, params = tiny
+    rep = OnlineScheduler(paged_engine(cfg, params), seg_steps=4).serve(
+        arrivals(cfg))
+    return {"kind": "serve", "report": rep.as_dict(with_requests=True)}
+
+
+class TestGapReaders:
+    @pytest.mark.parametrize("name", GAP_READERS)
+    def test_mean_gap_of_the_whole_serve(self, name, served_record):
+        gap = served_record["report"]["segment_phases"]["gap"]
+        assert gap["count"] == served_record["report"]["segments"] - 1
+        mod = gap_reader(name)
+        assert mod.compute(served_record) == \
+            pytest.approx(gap["seconds"] / gap["count"] * 1e3)
+        assert mod.META["layer"] == "engine"
+        assert mod.META["moves"] == ("tpot_mean_ms"
+                                     if name == "segment_gap_ms"
+                                     else "serve_tokens_per_s")
+
+    @pytest.mark.parametrize("name", GAP_READERS)
+    def test_none_without_the_gap(self, name, served_record):
+        """The parent's report has no ``gap`` (before PR 38)."""
+        mod = gap_reader(name)
+        report = dict(served_record["report"])
+        report["segment_phases"] = {k: v for k, v in
+                                    report["segment_phases"].items()
+                                    if k not in ("gap", "put")}
+        assert mod.compute(dict(served_record, report=report)) is None
+        assert mod.compute({"kind": "serve"}) is None
+        assert mod.compute({"kind": "train", "report": None}) is None
